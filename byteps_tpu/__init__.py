@@ -22,61 +22,6 @@ from typing import Optional
 
 import jax
 
-# jax API drift: ``jax.shard_map`` was promoted from
-# ``jax.experimental.shard_map`` (where the kwarg is ``check_rep``, not
-# ``check_vma``). Alias it on older installs so every call site can use
-# the modern spelling unconditionally.
-if not hasattr(jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map(f, mesh=None, in_specs=None, out_specs=None,
-                   check_vma=None, **kw):
-        if check_vma is not None and "check_rep" not in kw:
-            kw["check_rep"] = check_vma
-        return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kw)
-
-    jax.shard_map = _shard_map
-
-# ``jax.lax.axis_size`` is likewise newer than some installs; a psum of
-# a concrete 1 over the named axis resolves to the axis size at trace
-# time with no runtime collective.
-if not hasattr(jax.lax, "axis_size"):
-    def _axis_size(axis_name):
-        return jax.lax.psum(1, axis_name)
-
-    jax.lax.axis_size = _axis_size
-
-# ``jax.sharding.AbstractMesh`` drift: modern jax takes
-# ``AbstractMesh(axis_sizes, axis_names)``; jax 0.4.37 takes one
-# ``((name, size), ...)`` pairs tuple. Adapt the modern spelling (what
-# parallel/scaling_model.py uses) onto the old constructor so the
-# AOT-lowering scaling model runs on both.
-import inspect as _inspect  # noqa: E402
-import jax.sharding as _jsharding  # noqa: E402
-
-if "axis_names" not in _inspect.signature(
-        _jsharding.AbstractMesh.__init__).parameters:
-    _RealAbstractMesh = _jsharding.AbstractMesh
-
-    class _AbstractMesh(_RealAbstractMesh):
-        def __init__(self, axis_sizes, axis_names=None, axis_types=None):
-            if axis_names is None:     # caller already speaks 0.4.37
-                super().__init__(tuple(axis_sizes), axis_types)
-            else:
-                if axis_types is not None:
-                    # modern per-axis axis_types and 0.4.37's dict form
-                    # are not interconvertible — refuse loudly rather
-                    # than silently building a differently-typed mesh
-                    raise NotImplementedError(
-                        "axis_types is not supported by the jax-0.4.37 "
-                        "AbstractMesh compatibility shim")
-                super().__init__(tuple(zip(tuple(axis_names),
-                                           tuple(axis_sizes))))
-
-    _jsharding.AbstractMesh = _AbstractMesh
-del _inspect, _jsharding
-
 from .common.config import Config
 from .common.global_state import GlobalState
 from .common import naming
@@ -120,8 +65,9 @@ def resume(num_worker: Optional[int] = None, config: Optional[Config] = None,
             overrides["num_worker"] = num_worker
         # host_only is sticky across suspend/resume: torch init sets it
         # PROGRAMMATICALLY (default-on, no env var), so a from-env
-        # rebuild would silently drop it and resume() would hang in
-        # device discovery on a dead tunnel. An explicit env var wins.
+        # rebuild would silently drop it and resume() would start
+        # accelerator discovery in a process that never asked for a
+        # device. An explicit env var wins.
         if _suspended_config is not None \
                 and "BPS_HOST_ONLY" not in os.environ:
             overrides["host_only"] = _suspended_config.host_only

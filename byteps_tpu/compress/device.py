@@ -18,10 +18,10 @@ Byte-identity contract: the payload produced here is BYTE-IDENTICAL to
 ``wire.encode`` on the same dense input — same pure-f32 ``amax/denom``
 scale rule (``wire.amax_scale``), the PR-7-proven int8 kernel, and the
 fp8 kernel whose uint32 SR math is shared with the numpy reference.
-``probe()`` verifies this end to end on an adversarial vector at
+``_probe()`` verifies this end to end on an adversarial vector at
 startup; any mismatch (or a backend whose Mosaic rejects the kernels)
-falls back to the host codec with one INFO line — probe-or-fallback,
-the staged-grad contract applied to the codec plane.
+is an error that names the codec — ``BPS_COMPRESS_DEVICE=0`` is how to
+ask for the host codec.
 
 ``BPS_COMPRESS_DEVICE``: ``auto`` (default — on when the default JAX
 backend is an accelerator), ``1`` (force, e.g. CPU tests via Pallas
@@ -34,7 +34,7 @@ import functools
 import os
 import struct
 import threading
-from typing import List, Optional, Tuple
+from typing import List, Tuple
 
 import numpy as np
 
@@ -50,7 +50,7 @@ DEVICE_CODECS = (wire.CODEC_INT8, wire.CODEC_FP8_E4M3,
 
 _log = get_logger()
 _probe_lock = threading.Lock()
-_probe_result: Optional[bool] = None
+_probed = False
 
 
 def _fp8_decode_device(q, kind):
@@ -162,10 +162,12 @@ def encode_bucket(parts: List[tuple], size: int, level: int, seed: int,
     return payload, new_r, len(body) + 4
 
 
-def _probe() -> bool:
+def _probe() -> None:
     """Bitwise probe: device payloads must equal the host codec's on an
     adversarial vector (ties, zeros, binade edges, denormal-range
-    values). Any exception or byte mismatch -> fallback."""
+    values). A kernel the backend refuses, or a byte mismatch, raises
+    with the codec's name."""
+    import jax
     import jax.numpy as jnp
     rng = np.random.RandomState(0xB5C1)
     x = np.concatenate([
@@ -177,23 +179,29 @@ def _probe() -> bool:
     xd = jnp.asarray(x)
     n = x.size
     for cid in DEVICE_CODECS:
+        what = (f"BPS_COMPRESS_DEVICE: device {wire.codec_name(cid)} "
+                f"encode on the {jax.default_backend()} backend")
         host = wire.encode(cid, x, seed=1234)
-        dev, _, _ = encode_bucket([(xd, 0, n)], n, cid, 1234,
-                                  None, False)
+        try:
+            dev, _, _ = encode_bucket([(xd, 0, n)], n, cid, 1234,
+                                      None, False)
+        except Exception as e:
+            raise RuntimeError(
+                f"{what} failed ({type(e).__name__}: {e}); "
+                f"BPS_COMPRESS_DEVICE=0 asks for the host codec") from e
         if dev != host:
-            _log.info(
-                "BPS_COMPRESS_DEVICE: device %s payload diverges from "
-                "the host codec on this backend — falling back to host "
-                "encode", wire.codec_name(cid))
-            return False
-    return True
+            raise RuntimeError(
+                f"{what} diverges from the host codec; "
+                f"BPS_COMPRESS_DEVICE=0 asks for the host codec")
 
 
 def device_encode_enabled() -> bool:
-    """Resolve BPS_COMPRESS_DEVICE (probe result cached per process;
+    """Resolve BPS_COMPRESS_DEVICE (probe verdict cached per process;
     ``reset_probe`` for tests). ``auto`` keeps CPU rigs on the host
-    codec — interpret-mode kernels are correct but not a speed-up."""
-    global _probe_result
+    codec — interpret-mode kernels are correct but not a speed-up.
+    Anywhere else the probe must pass: a failure raises, it does not
+    fall back to the host codec."""
+    global _probed
     v = (os.environ.get("BPS_COMPRESS_DEVICE", "auto") or "auto") \
         .strip().lower()
     if v in ("0", "off", "false", "none"):
@@ -203,22 +211,16 @@ def device_encode_enabled() -> bool:
         if jax.default_backend() == "cpu":
             return False
     with _probe_lock:
-        if _probe_result is None:
-            try:
-                _probe_result = _probe()
-            except Exception as e:   # noqa: BLE001 — probe-or-fallback
-                _log.info(
-                    "BPS_COMPRESS_DEVICE: device encode unavailable "
-                    "(%s: %s) — falling back to host encode",
-                    type(e).__name__, e)
-                _probe_result = False
-        return _probe_result
+        if not _probed:
+            _probe()
+            _probed = True
+    return True
 
 
 def reset_probe() -> None:
     """Forget the cached probe verdict (tests flip envs/backends)."""
-    global _probe_result
+    global _probed
     with _probe_lock:
-        _probe_result = None
+        _probed = False
     _gather_amax.cache_clear()
     _quantize.cache_clear()

@@ -16,7 +16,6 @@ vs baseline" can't hide an underutilized chip.
 
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 
@@ -57,30 +56,18 @@ _CHIP_PEAK = {
 }
 
 
-def chip_peak_flops(device=None) -> Optional[float]:
-    """Peak bf16 FLOP/s of ``device`` (default: first JAX device), or
-    None when unknown (CPU, unrecognized kind). Override with
-    BPS_PEAK_TFLOPS for new parts."""
-    env = os.environ.get("BPS_PEAK_TFLOPS")
-    if env:
-        return float(env) * 1e12
+def chip_peak_flops(device=None) -> float:
+    """Peak bf16 FLOP/s of ``device`` (default: first JAX device). A
+    ``device_kind`` that is not in the table is an error, not a default:
+    MFU against a guessed peak is not a measurement."""
     import jax
     d = device if device is not None else jax.devices()[0]
-    if d.platform == "cpu":
-        return None
     kind = d.device_kind
     if kind in _CHIP_PEAK:
         return _CHIP_PEAK[kind]
     for name, peak in _CHIP_PEAK.items():   # prefix match ("TPU v5 lite …")
         if kind.startswith(name):
             return peak
-    return None
-
-
-def mfu(samples_per_sec: float, flops_per_sample: float,
-        device=None) -> Optional[float]:
-    """Model-FLOPs utilization in [0, 1], or None when peak is unknown."""
-    peak = chip_peak_flops(device)
-    if not peak:
-        return None
-    return samples_per_sec * flops_per_sample / peak
+    raise ValueError(
+        f"device_kind {kind!r} ({d.platform}) is not in the peak-FLOPs "
+        f"table of models/flops.py; add it with its published source")

@@ -199,7 +199,9 @@ def _fp8_sr_kernel(x_ref, scale_ref, seed_ref, out_ref, *, kind: int,
     mask = (u32(1) << d) - u32(1)
     mag_grid = (mag + (h & mask)) & ~mask
     tiny = e < jnp.int32(e_sub)
-    u24 = (h >> u32(8)).astype(jnp.float32) * jnp.float32(2.0 ** -24)
+    # < 2**24, so the int32 hop is exact; Mosaic has no uint32 -> f32 cast
+    u24 = (h >> u32(8)).astype(jnp.int32).astype(jnp.float32) \
+        * jnp.float32(2.0 ** -24)
     t = jnp.abs(y) * jnp.float32(2.0 ** (127 - e_sub))
     mag_tiny = jnp.where(u24 < t, u32(qbits), u32(0))
     mag2 = jnp.where(tiny, mag_tiny, mag_grid)

@@ -33,14 +33,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
-# CompilerParams was named TPUCompilerParams in older jax releases
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 # every kernel's grid is (outer..., carried): only the innermost dim
 # carries scratch state across iterations; the rest are independent
 # programs the pipeliner may reorder/overlap
-_DIM_SEMANTICS = _CompilerParams(
+_DIM_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
 
 
@@ -570,7 +566,7 @@ def _flash_bwd_fused(q, k, v, lse, do, delta, causal, scale, bq, bk,
         out_shape=[jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
                    jax.ShapeDtypeStruct((b, h, sk, d), k.dtype),
                    jax.ShapeDtypeStruct((b, h, sk, d), v.dtype)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(*inputs)
@@ -641,7 +637,7 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, bq, bk, interpret,
         out_shape = [out_shape, jax.ShapeDtypeStruct(
             (b, h // ht) + _DT_PAD, jnp.float32)]
         scratches.append(pltpu.VMEM(_DT_PAD, jnp.float32))
-        params = _CompilerParams(dimension_semantics=(
+        params = pltpu.CompilerParams(dimension_semantics=(
             "parallel", "parallel", "arbitrary", "arbitrary"))
     res = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,

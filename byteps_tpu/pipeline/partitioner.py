@@ -45,7 +45,8 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax import core as jcore
+from jax.core import DropVar
+from jax.extend import core as jcore
 
 from ..common.logging import get_logger
 from ..obs.metrics import get_registry
@@ -382,7 +383,7 @@ class StagePartitioner:
         for si, (s, e) in enumerate(bounds):
             for eq in jaxpr.eqns[s:e]:
                 for v in eq.outvars:
-                    if not isinstance(v, jcore.DropVar):
+                    if not isinstance(v, DropVar):
                         produced_in[v] = si
         consumers: Dict = {}
         for si, (s, e) in enumerate(bounds):
@@ -427,7 +428,7 @@ class StagePartitioner:
             prod_here = set()
             for eq in eqns:
                 prod_here.update(v for v in eq.outvars
-                                 if not isinstance(v, jcore.DropVar))
+                                 if not isinstance(v, DropVar))
             used_here = set()
             for eq in eqns:
                 used_here.update(v for v in eq.invars

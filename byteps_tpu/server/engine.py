@@ -37,16 +37,16 @@ def _lib() -> ctypes.CDLL:
     here = os.path.join(os.path.dirname(__file__), "csrc")
     so = os.path.join(here, "libbps_server.so")
     # run make unconditionally (not just when the .so is missing): the
-    # Makefile's source dependency decides whether to rebuild, so a
-    # stale .so from before a source change can never be dlopened with
-    # missing symbols (every binding below would AttributeError)
+    # Makefile's source dependency decides whether to rebuild, so the
+    # library loaded is always the one the committed sources build. A
+    # failed build is an error — never a silent load of a stale binary.
     try:
         subprocess.run(["make", "-C", here], check=True,
-                       capture_output=True)
-    except (subprocess.CalledProcessError, OSError):
-        if not os.path.exists(so):
-            raise                      # no library at all: surface it
-        # toolchain unavailable but a prebuilt .so exists — use it
+                       capture_output=True, text=True)
+    except subprocess.CalledProcessError as e:
+        raise RuntimeError(
+            f"building the native PS server failed (make -C {here}):\n"
+            f"{e.stderr}") from e
     lib = ctypes.CDLL(so)
     lib.bps_server_create.restype = ctypes.c_void_p
     lib.bps_server_create.argtypes = [ctypes.c_int] * 4

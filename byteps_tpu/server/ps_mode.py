@@ -1091,11 +1091,10 @@ class PSGradientExchange:
             if self._cplane is None:
                 self._dev_enc = False
             else:
-                try:
-                    from ..compress.device import device_encode_enabled
-                    self._dev_enc = device_encode_enabled()
-                except Exception:   # noqa: BLE001 — probe-or-fallback
-                    self._dev_enc = False
+                # a probe failure raises (naming the codec): never a
+                # silent downgrade to the host codec
+                from ..compress.device import device_encode_enabled
+                self._dev_enc = device_encode_enabled()
         return self._dev_enc
 
     def _d2h_account(self, pskey: int, nbytes: int) -> None:

@@ -53,7 +53,8 @@ from typing import Callable, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
-from jax import core as jcore
+from jax.core import DropVar
+from jax.extend import core as jcore
 
 from .common.logging import get_logger
 from .obs.metrics import get_registry as _registry
@@ -207,7 +208,7 @@ def _assemble(cj, cuts: Sequence[int], leaf_ready, loss_var,
     for si, (s, e) in enumerate(bounds):
         for eq in jaxpr.eqns[s:e]:
             for v in eq.outvars:
-                if not isinstance(v, jcore.DropVar):
+                if not isinstance(v, DropVar):
                     produced_in[v] = si
     last_use = {}
     for si, (s, e) in enumerate(bounds):
@@ -258,7 +259,7 @@ def _assemble(cj, cuts: Sequence[int], leaf_ready, loss_var,
         prod_here = set()
         for eq in eqns:
             prod_here.update(v for v in eq.outvars
-                             if not isinstance(v, jcore.DropVar))
+                             if not isinstance(v, DropVar))
         used_here = set()
         for eq in eqns:
             used_here.update(v for v in eq.invars

@@ -27,7 +27,7 @@ import _bootstrap  # noqa: F401
 WORKER_SNIPPET = r"""
 import os, sys
 sys.path.insert(0, os.path.join(os.environ["BPS_REPO_ROOT"], "examples"))
-import _bootstrap  # repo root on sys.path + honor JAX_PLATFORMS
+import _bootstrap  # repo root on sys.path + compile cache
 import jax
 import numpy as np
 import optax

@@ -1,0 +1,108 @@
+"""AOT compiles of every Pallas kernel for a described (not attached)
+TPU v5e, at the shapes the main paths use. The chip's compiler is
+installed in the CPU sandbox; what it refuses here it refuses on the
+chip (a uint32 -> f32 cast in the fp8 kernel passed every
+interpret-mode test and was refused by Mosaic). A compile that passes
+is not a chip run — numerics and times come from ``chip_smoke.py``.
+
+All in ONE file, topology described inside a fixture: only one process
+may load the TPU library, so nothing here touches it at import or
+collection time (xdist workers must collect the same tests).
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+BUCKET_ELEMS = 4096000 // 4     # one BPS_PARTITION_BYTES bucket of f32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def compile_for_chip(one_chip):
+    """``compile_for_chip(fn, (shape, dtype), ...)``: compile ``fn`` for
+    the described chip and require a Mosaic kernel in the result. A
+    described-device executable can be written to the persistent cache
+    but not read back, so the cache is off around these compiles."""
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+
+    def compile_(fn, *shapes):
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                for s, d in shapes]
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        assert "tpu_custom_call" in text
+
+    yield compile_
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """The compression kernels pick interpret mode from the attached
+    device (CPU here); steer them to the Mosaic path for the compile."""
+    from byteps_tpu.ops.compression import pallas_kernels as pk
+    pk._interpret.cache_clear()
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    return pk
+
+
+@pytest.mark.parametrize("shape,causal", [
+    ((64, 512, 16, 64), False),      # BERT-large, batch 64 x seq 512
+    ((64, 512, 8, 128), False),      # its d_head-128 twin
+    ((1, 32768, 12, 64), True),      # GPT-2-small at 32k, causal
+], ids=["bert_large", "dh128", "gpt2_32k_causal"])
+def test_flash_fwd_bwd_compiles_for_v5e(compile_for_chip, shape, causal):
+    from byteps_tpu.ops.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        return (flash_attention(q, k, v, causal).astype(jnp.float32)
+                ** 2).sum()
+
+    compile_for_chip(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                     *[(shape, jnp.bfloat16)] * 3)
+
+
+def test_onebit_pack_unpack_compile_for_v5e(compile_for_chip, mosaic):
+    n = BUCKET_ELEMS
+    chunks = (n + mosaic.PACK - 1) // mosaic.PACK
+    compile_for_chip(lambda x: mosaic.onebit_pack(x, chunks),
+                     ((n,), jnp.float32))
+    compile_for_chip(lambda p: mosaic.onebit_unpack(p, n),
+                     ((chunks,), jnp.uint32))
+
+
+def test_int8_quantize_dequantize_compile_for_v5e(compile_for_chip, mosaic):
+    n = BUCKET_ELEMS
+    compile_for_chip(mosaic.int8_quantize,
+                     ((n,), jnp.float32), ((), jnp.float32))
+    compile_for_chip(lambda q, s: mosaic.int8_dequantize(q, s, n),
+                     ((n,), jnp.int8), ((), jnp.float32))
+
+
+@pytest.mark.parametrize("kind", ["E4M3", "E5M2"])
+def test_fp8_sr_quantize_compiles_for_v5e(compile_for_chip, mosaic, kind):
+    from byteps_tpu.ops.compression import fp8sr
+    k = getattr(fp8sr, kind)
+    compile_for_chip(
+        lambda x, s, seed: mosaic.fp8_sr_quantize(x, s, seed, k),
+        ((BUCKET_ELEMS,), jnp.float32), ((), jnp.float32),
+        ((), jnp.uint32))

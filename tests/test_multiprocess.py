@@ -7,14 +7,10 @@ Both tests drive the launcher's command-fleet path
 contract is DERIVED, the processes are supervised, and per-rank output
 is captured per role — no hand-rolled Popen choreography.
 
-Root cause of the long-standing failures here (fixed in
-``GlobalState._enable_cpu_collectives``): jaxlib's CPU client defaults
-to ``collectives=none``, so every cross-process computation died with
-"Multiprocess computations aren't implemented on the CPU backend".
-jax 0.4.37 ships a gloo implementation behind the
-``jax_cpu_collectives_implementation`` config, which this jax does NOT
-read from the environment — ``bps.init()`` now enables it in-process,
-before the first backend client exists.
+Cross-process computations on the CPU backend need a collectives
+implementation; on the supported jax (0.9.0) the public
+``jax.config.jax_cpu_collectives_implementation`` defaults to ``gloo``,
+so ``bps.init()`` sets nothing.
 """
 
 import os

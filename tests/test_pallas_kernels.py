@@ -195,19 +195,24 @@ def test_device_encode_bucket_matches_wire_payloads():
         assert d2h == 3000 + 4      # 1B/elem + the scale scalar
 
 
-def test_device_encode_probe_fallback(monkeypatch):
-    """probe-or-fallback: a diverging kernel (simulated) flips the
-    probe verdict to False — the exchange keeps the host codec, never
-    a wrong payload."""
+def test_device_encode_probe_failure_is_an_error(monkeypatch):
+    """No hidden fallback: a kernel the backend refuses, or a diverging
+    payload (both simulated), raises with the codec's name; the host
+    codec is used only when BPS_COMPRESS_DEVICE=0 asks for it."""
     from byteps_tpu.compress import device as cdev
     cdev.reset_probe()
-    assert cdev._probe() is True        # this backend is bit-clean
+    cdev._probe()                       # this backend is bit-clean
     monkeypatch.setenv("BPS_COMPRESS_DEVICE", "1")
-    monkeypatch.setattr(cdev, "_probe",
-                        lambda: (_ for _ in ()).throw(RuntimeError("x")))
-    cdev.reset_probe()
-    assert cdev.device_encode_enabled() is False
+
+    def refuse(*a, **k):
+        raise NotImplementedError("Unsupported cast")
+    monkeypatch.setattr(cdev, "encode_bucket", refuse)
+    with pytest.raises(RuntimeError, match="int8.*Unsupported cast"):
+        cdev.device_encode_enabled()
+    monkeypatch.setattr(cdev, "encode_bucket",
+                        lambda *a, **k: (b"wrong", None, 0))
+    with pytest.raises(RuntimeError, match="int8.*diverges"):
+        cdev.device_encode_enabled()
     monkeypatch.setenv("BPS_COMPRESS_DEVICE", "0")
-    cdev.reset_probe()
     assert cdev.device_encode_enabled() is False
     cdev.reset_probe()      # drop the poisoned verdict for later tests

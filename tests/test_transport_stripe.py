@@ -4,9 +4,10 @@ protocol correctness + a utilization regression floor.
 The round-5 fast paths (whole-frame token charge, zero-copy pull
 receive, reused server recv buffer) lifted the 10 Gbps emulated-NIC
 push utilization from 32% (r4 single-stream) to 79-98% depending on
-payload mix — the floor asserted here is far below the measured band
-but far above the r4 number, so a regression to chunked-Python
-pacing fails CI without flaking on a busy box.
+payload mix. Wall clock on a shared box cannot hold a floor (40% under
+six parallel test workers), so the guard counts pacing charges per
+frame instead: a regression to chunked-Python pacing fails CI whatever
+the load.
 
 Striping (BPS_STRIPE_MIN > 0) splits one logical push/pull over the
 connection pool with server-side reassembly/scatter. It is OFF by
@@ -84,23 +85,47 @@ def test_striped_retry_applies_once(rig):
     np.testing.assert_array_equal(out, x)  # ones, not twos
 
 
-def test_throttled_push_utilization_floor(rig):
-    """Regression floor for the wire fast path: ≥45% of a 10 Gbps NIC
-    on 8 MB pushes (r4's chunked path measured 32%; round 5 measures
-    79-98% — see docs/performance.md)."""
+def test_throttled_push_pacing_granularity(rig):
+    """Regression guard for the wire fast path, in what a CPU run can
+    count. r4's path paced an 8 MB push in 64 KB Python-loop chunks
+    (128 token-bucket charges per frame, 32% of a 10 Gbps NIC); the
+    fast path charges a frame whole when the bucket covers it and
+    otherwise in ~2 ms-of-link chunks (2.5 MB at this rate: at most 4
+    charges after the one whole-frame attempt). Wall-clock utilization
+    is a property of the box's load (40% under six xdist workers, 79-98%
+    alone — docs/performance.md), so only its hard bounds are asserted:
+    every byte was booked, and the emulated NIC was never outrun."""
     rate = 10e9 / 8
     cli = rig(nic_rate=rate)
     NB = 8 << 20
     x = np.random.RandomState(0).randn(NB // 4).astype(np.float32)
     cli.init_key(0, NB)
     cli.push(0, x)                         # warm (dials, first buffers)
+    nic = cli._nic
+    charges = {"n": 0}
+    for name in ("consume", "try_consume"):
+        real = getattr(nic.tx, name)
+
+        def counted(n, _real=real):
+            if n > nic.SMALL_FRAME:        # control frames are exempt
+                charges["n"] += 1
+            return _real(n)
+        setattr(nic.tx, name, counted)
     iters = 12
+    tx0 = nic.tx_bytes
     t0 = time.perf_counter()
     for _ in range(iters):
         cli.push(0, x)
     dt = time.perf_counter() - t0
-    util = NB * iters / dt / rate
-    assert util >= 0.45, f"push utilization regressed: {util:.2%}"
+    sent = nic.tx_bytes - tx0
+    assert NB * iters <= sent <= NB * iters * 1.01, sent
+    per_push = charges["n"] / iters
+    assert 1 <= per_push <= 1 + -(-NB // nic.chunk_size()) + 1, \
+        f"{per_push} pacing charges per 8 MB push: chunked-Python " \
+        f"pacing is back (r4 made 128)"
+    # the bucket starts at most one burst ahead; past that it paces
+    assert dt >= (sent - nic.tx.burst) / rate * 0.95, \
+        f"pushes outran the emulated NIC: {sent / dt / rate:.2%}"
 
 
 def test_byte_accounting_exact_for_large_frames(rig):
