@@ -1,0 +1,98 @@
+"""A tiny benchmark in a temporary directory, made of NEW files only (a
+manifest, two configurations, two traffic mixes and a per-layer metric),
+which the harness under ``benchmark/`` runs unchanged on the CPU."""
+
+import json
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+TINY_SIZES = {"vocab_size": 512, "hidden": 64, "layers": 2, "heads": 4,
+              "mlp_dim": 256, "max_seq": 64, "ln_eps": 1e-5}
+OPTIMIZER = {"name": "adamw", "learning_rate": 1e-4, "b1": 0.9,
+             "b2": 0.999, "eps": 1e-8, "weight_decay": 1e-4}
+# float32 program against the float32 reference: rounding only
+TIGHT = {"loss_rel": 1e-5, "grad_norm_rel": 1e-4, "change_norm_rel": 1e-3,
+         "trainer_vs_plain_loss_rel": 1e-5}
+
+
+def tiny_config(kind: str, dtype: str = "float32", limits=None) -> dict:
+    causal = kind == "lm"
+    family = "gpt2:gpt2_config" if causal else "bert:bert_config"
+    loss = "gpt2:causal_lm_loss" if causal else "bert:mlm_loss"
+    return {
+        "sizes": dict(TINY_SIZES, causal=causal),
+        "optimizer": OPTIMIZER,
+        "program": {
+            "config": "byteps_tpu.models." + family,
+            "config_kwargs": {"hidden": 64, "layers": 2, "heads": 4,
+                              "vocab_size": 512, "max_seq": 64,
+                              "dtype": dtype},
+            "loss": "byteps_tpu.models." + loss,
+            "loss_kwargs": ({} if causal else
+                            {"max_predictions": "$max_predictions_per_seq"}),
+            "step_must_contain": ["tpu_custom_call"]},
+        "reference": "benchmark.reference.pre_ln_transformer",
+        "flops_rule": "transformer_lm",
+        "limits": limits or TIGHT}
+
+
+TINY_MIXES = {
+    "mlm_tiny": {"kind": "mlm", "batch_per_chip": 8, "seq": 64,
+                 "masked_lm_prob": 0.15, "max_predictions_per_seq": 12,
+                 "mask_token_id": 103, "reference_rows_per_block": 4},
+    "lm_tiny": {"kind": "lm", "batch_per_chip": 4, "seq": 64,
+                "reference_rows_per_block": 2},
+}
+
+NEW_METRIC = '''"""A per-layer metric added as a file: steps the traced
+window attempted."""
+UNIT, LAYER, MOVES, SOURCE = "steps", "trainer", "tokens_per_s_chip", "program_counter"
+
+
+def read(run):
+    return float(len(run.spans.get("step", [])))
+'''
+
+
+def write_tiny_benchmark(root, chips=1, dtype="float32", limits=None):
+    """A whole benchmark under ``root``: two configurations, two cells, the
+    repo's per-layer metrics and one new one."""
+    bench = os.path.join(root, "tinybench")
+    for sub in ("configs", "traffic", "metrics"):
+        os.makedirs(os.path.join(bench, sub))
+    for name, mix in TINY_MIXES.items():
+        with open(os.path.join(bench, "traffic", name + ".json"), "w") as f:
+            json.dump(mix, f)
+    for name, kind in (("tiny_mlm", "mlm"), ("tiny_lm", "lm")):
+        with open(os.path.join(bench, "configs", name + ".json"), "w") as f:
+            json.dump(tiny_config(kind, dtype, limits), f)
+    with open(os.path.join(bench, "metrics", "trainer.steps_traced.py"),
+              "w") as f:
+        f.write(NEW_METRIC)
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        real = json.load(f)
+    cells = ["tiny_mlm_cell", "tiny_lm_cell"]
+    per_layer = [dict(m, workloads=cells[:1]) if "workloads" in m else m
+                 for m in real["per_layer"]]
+    per_layer.append({"name": "trainer.steps_traced", "unit": "steps",
+                      "better": "higher", "source": "program_counter",
+                      "layer": "trainer", "moves": "tokens_per_s_chip"})
+    manifest = dict(
+        real, paths=["tinybench"],
+        configs=[{"name": n, "source": "test", "reduced": [], "why": "test",
+                  "file": f"tinybench/configs/{n}.json"}
+                 for n in ("tiny_mlm", "tiny_lm")],
+        workloads=[
+            {"name": cells[0], "config": "tiny_mlm", "traffic": "mlm_tiny",
+             "chips": chips, "why": "test"},
+            {"name": cells[1], "config": "tiny_lm", "traffic": "lm_tiny",
+             "chips": chips, "why": "test"}],
+        per_layer=per_layer)
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(manifest, f)
+    return str(root)
