@@ -1,0 +1,13 @@
+"""Device time a step of the forward flash kernel, on the first chip:
+the ``bps_flash_fwd`` events of the trace (the program's ``name=`` on the
+``pallas_call``), which run in the forward pass and again as its
+recompute."""
+from benchmark.trace import program
+
+UNIT, LAYER, MOVES, SOURCE = "ms", "kernels", "tokens_per_s_chip", "device_trace"
+
+
+def read(run):
+    trace = program.of_run(run)
+    return (None if trace is None
+            else trace.kernels_ms(program.FORWARD_KERNELS))

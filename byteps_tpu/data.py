@@ -66,6 +66,12 @@ def shard_local_batch(batch, mesh: Mesh, spec: Optional[P] = None,
         batch)
 
 
+def _host_bytes(batch) -> int:
+    """Bytes of a batch's leaves, as the host holds them."""
+    return sum(int(getattr(x, "nbytes", 0))
+               for x in jax.tree_util.tree_leaves(batch))
+
+
 def prefetch_to_mesh(it: Iterable, mesh: Mesh, spec: Optional[P] = None,
                      buffer_size: int = 2, local: bool = False) -> Iterator:
     """Iterate ``it``, yielding mesh-sharded batches, transferring up to
@@ -85,12 +91,24 @@ def prefetch_to_mesh(it: Iterable, mesh: Mesh, spec: Optional[P] = None,
     sharding = data_sharding(mesh, spec)
     place = shard_local_batch if local else shard_batch
 
+    # bps.feed.* annotations are host spans in the profiler's own trace
+    # and a flag test when no profiler session runs (docs/timeline.md)
+    annotate = jax.profiler.TraceAnnotation
+
     def producer():
+        source = iter(it)
         try:
-            for batch in it:
+            while True:
+                with annotate("bps.feed.source"):
+                    batch = next(source, _END)
+                if batch is _END:
+                    break
                 if stop.is_set():
                     return
-                q.put(place(batch, mesh, sharding=sharding))
+                with annotate("bps.feed.h2d", bytes=_host_bytes(batch)
+                              if annotate.is_enabled() else 0):
+                    placed = place(batch, mesh, sharding=sharding)
+                q.put(placed)
             q.put(_END)
         except BaseException as e:          # propagate into the consumer
             q.put(e)
@@ -100,7 +118,8 @@ def prefetch_to_mesh(it: Iterable, mesh: Mesh, spec: Optional[P] = None,
     t.start()
     try:
         while True:
-            item = q.get()
+            with annotate("bps.feed.wait"):
+                item = q.get()
             if item is _END:
                 return
             if isinstance(item, BaseException):

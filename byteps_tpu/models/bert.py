@@ -57,6 +57,13 @@ def mlm_loss(params, cfg: TransformerConfig, batch,
     b, s = tokens.shape
     k = min(max_predictions, s)
     h = apply(params, cfg, tokens)                      # [b, s, hid]
+    return _mlm_head(params, cfg, h, targets, k)
+
+
+@jax.named_scope("bps.head")
+def _mlm_head(params, cfg: TransformerConfig, h, targets, k: int):
+    """The LM head and masked NLL on the ``k`` gathered positions."""
+    s = h.shape[1]
     mask = targets >= 0
     # masked positions first; earlier positions win ties/cap overflow
     score = mask.astype(jnp.float32) * 2.0 - jnp.arange(s) / s

@@ -48,9 +48,10 @@ def causal_lm_loss(params, cfg: TransformerConfig, batch):
         # eligible — a s-1 shift silently fell back to the O(s²) naive
         # path (28x slower at seq 8k, OOM at 16k).
         import jax.numpy as jnp
-        targets = jnp.concatenate(
-            [tokens[:, 1:],
-             jnp.full((tokens.shape[0], 1), -1, tokens.dtype)], axis=1)
+        with jax.named_scope("bps.head"):      # the head's targets
+            targets = jnp.concatenate(
+                [tokens[:, 1:],
+                 jnp.full((tokens.shape[0], 1), -1, tokens.dtype)], axis=1)
         return lm_loss(params, cfg, (tokens, targets))
 
     sp = jax.lax.axis_size(cfg.sp_axis)

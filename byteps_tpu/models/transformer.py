@@ -244,8 +244,10 @@ def _block(x, blk, cfg: TransformerConfig, tp_size: int,
            remat_mlp: bool = False):
     """Transformer block; remat_mlp checkpoints only the MLP half
     (remat_policy="mlp_only": attention residuals kept, MLP recomputed)."""
-    x = x + _attention(_layernorm(x, blk["ln1"]["scale"], blk["ln1"]["bias"]),
-                       blk, cfg, tp_size)
+    with jax.named_scope("bps.attn"):
+        x = x + _attention(
+            _layernorm(x, blk["ln1"]["scale"], blk["ln1"]["bias"]),
+            blk, cfg, tp_size)
 
     def mlp_half(y, b):
         return _mlp(_layernorm(y, b["ln2"]["scale"], b["ln2"]["bias"]),
@@ -253,7 +255,8 @@ def _block(x, blk, cfg: TransformerConfig, tp_size: int,
 
     if remat_mlp:
         mlp_half = jax.checkpoint(mlp_half)
-    return x + mlp_half(x, blk)
+    with jax.named_scope("bps.mlp"):
+        return x + mlp_half(x, blk)
 
 
 def apply(params, cfg: TransformerConfig, tokens: jnp.ndarray,
@@ -277,8 +280,9 @@ def apply(params, cfg: TransformerConfig, tokens: jnp.ndarray,
             offset = 0
         positions = offset + jnp.arange(s)
     tp_size = jax.lax.axis_size(cfg.tp_axis) if cfg.tp_axis else 1
-    x = embed_lookup(params["embed"]["tok"], tokens).astype(dt)
-    x = x + params["embed"]["pos"][positions].astype(dt)
+    with jax.named_scope("bps.embed"):
+        x = embed_lookup(params["embed"]["tok"], tokens).astype(dt)
+        x = x + params["embed"]["pos"][positions].astype(dt)
 
     plain_fn = partial(_block, cfg=cfg, tp_size=tp_size)
     if cfg.remat and cfg.remat_policy == "mlp_only":
@@ -347,7 +351,9 @@ def apply(params, cfg: TransformerConfig, tokens: jnp.ndarray,
         x = xm.reshape(b, *x.shape[1:])   # valid on the last stage only
     else:
         x = stack_fn(params["blocks"], x)
-    x = _layernorm(x, params["final_ln"]["scale"], params["final_ln"]["bias"])
+    with jax.named_scope("bps.head"):    # the final norm feeds the head
+        x = _layernorm(x, params["final_ln"]["scale"],
+                       params["final_ln"]["bias"])
     return x
 
 
@@ -365,6 +371,7 @@ def logits(params, cfg: TransformerConfig, hidden: jnp.ndarray) -> jnp.ndarray:
 _warned_chunk: set = set()
 
 
+@jax.named_scope("bps.head")
 def _chunked_nll_sum(h, emb, targets, mask, chunk: int, dt) -> jnp.ndarray:
     """Masked NLL sum with the LM head applied per sequence chunk.
 
@@ -421,11 +428,13 @@ def lm_loss(params, cfg: TransformerConfig, batch) -> jnp.ndarray:
         nll_sum = _chunked_nll_sum(h, params["embed"]["tok"], targets,
                                    mask, chunk, jnp.dtype(cfg.dtype))
     else:
-        lg = logits(params, cfg, h)
-        logp = jax.nn.log_softmax(lg, axis=-1)
-        tgt = jnp.where(mask, targets, 0)
-        nll = -jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
-        nll_sum = (nll * mask).sum()
+        with jax.named_scope("bps.head"):
+            lg = logits(params, cfg, h)
+            logp = jax.nn.log_softmax(lg, axis=-1)
+            tgt = jnp.where(mask, targets, 0)
+            nll = -jnp.take_along_axis(logp, tgt[..., None],
+                                       axis=-1)[..., 0]
+            nll_sum = (nll * mask).sum()
     cnt = mask.sum().astype(jnp.float32)
     if cfg.sp_axis is not None:
         nll_sum = jax.lax.psum(nll_sum, cfg.sp_axis)

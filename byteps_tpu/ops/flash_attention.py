@@ -296,10 +296,12 @@ def _flash_fwd(q, k, v, causal, scale, bq, bk, interpret, out_dtype=None,
         ],
         compiler_params=_DIM_SEMANTICS,
         interpret=interpret,
+        name="bps_flash_fwd",
     )(*inputs)
     return out, lse
 
 
+@jax.named_scope("bps_attn_xla")
 def _xla_fwd(qt, kt, vt, causal, scale, out_dtype=None, bias=None):
     """[b,h,s,d] → (out, lse [b,h,s,1] fp32) with plain XLA ops.
 
@@ -569,6 +571,7 @@ def _flash_bwd_fused(q, k, v, lse, do, delta, causal, scale, bq, bk,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
+        name="bps_flash_bwd_fused",
     )(*inputs)
 
 
@@ -650,6 +653,7 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, bq, bk, interpret,
         scratch_shapes=scratches,
         compiler_params=params,
         interpret=interpret,
+        name="bps_flash_bwd_dq",
     )(*inputs)
     dbias = drel = None
     if has_bias:
@@ -689,6 +693,7 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, bq, bk, interpret,
                         pltpu.VMEM((ht * bk, d), jnp.float32)],
         compiler_params=_DIM_SEMANTICS,
         interpret=interpret,
+        name="bps_flash_bwd_dkv",
     )(*inputs2)
     return dq, dk, dv, dbias, drel
 
@@ -862,8 +867,10 @@ def attention(q, k, v, causal=False, scale=None, impl="auto", bias=None,
             b = relative_bias(rel_table.T, q.shape[1], k.shape[1],
                               rel_bidirectional, rel_table.shape[1],
                               rel_max_distance)
-        return local_attention(q, k, v, causal=causal, scale=scale,
-                               bias=b)
+        # named so that a fall-back from the kernels shows in a trace
+        with jax.named_scope("bps_attn_xla"):
+            return local_attention(q, k, v, causal=causal, scale=scale,
+                                   bias=b)
 
     if impl == "naive":
         return _naive()

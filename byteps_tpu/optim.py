@@ -18,6 +18,7 @@ from __future__ import annotations
 
 from typing import Sequence, Tuple
 
+import jax
 import optax
 
 from .parallel.collectives import Reducer, bucketed_allreduce, psum_reducer
@@ -29,10 +30,12 @@ def _make(inner: optax.GradientTransformation, axes: Tuple[str, ...],
         return inner.init(params)
 
     def update_fn(grads, state, params=None, **extra):
-        grads = bucketed_allreduce(grads, axes=axes,
-                                   partition_bytes=partition_bytes,
-                                   average=average, reducer=reducer)
-        return inner.update(grads, state, params, **extra)
+        with jax.named_scope("bps.exchange"):
+            grads = bucketed_allreduce(grads, axes=axes,
+                                       partition_bytes=partition_bytes,
+                                       average=average, reducer=reducer)
+        with jax.named_scope("bps.optimizer"):
+            return inner.update(grads, state, params, **extra)
 
     return optax.GradientTransformation(init_fn, update_fn)
 
@@ -57,7 +60,6 @@ def _make_compressed(inner: optax.GradientTransformation, axes: Tuple[str, ...],
     canonicalize "replicated" state to one rank's copy, losing every other
     rank's error memory.
     """
-    import jax
     import jax.numpy as jnp
     from .ops.compression.reducer import CompressionPlan
     plan_holder = {}
@@ -83,11 +85,14 @@ def _make_compressed(inner: optax.GradientTransformation, axes: Tuple[str, ...],
     def update_fn(grads, state, params=None, **extra):
         plan = plan_holder["plan"]
         local = jax.tree_util.tree_map(lambda x: x[0], state["bps_comp"])
-        grads, comp_state = plan.reduce_tree(grads, local, axes,
-                                             average=average)
+        with jax.named_scope("bps.exchange"):
+            grads, comp_state = plan.reduce_tree(grads, local, axes,
+                                                 average=average)
         comp_state = jax.tree_util.tree_map(lambda x: x[None],
                                             comp_state)
-        updates, inner_state = inner.update(grads, state["inner"], params, **extra)
+        with jax.named_scope("bps.optimizer"):
+            updates, inner_state = inner.update(grads, state["inner"],
+                                                params, **extra)
         return updates, {"inner": inner_state, "bps_comp": comp_state}
 
     return optax.GradientTransformation(init_fn, update_fn)
@@ -213,7 +218,6 @@ class ChunkedApply:
 
     def __init__(self, inner: optax.GradientTransformation, params,
                  groups, donate: bool = True, owned=None) -> None:
-        import jax
         import threading
         self.inner = inner
         leaves, _ = jax.tree_util.tree_flatten(params)
@@ -266,7 +270,6 @@ class ChunkedApply:
         OWNERSHIP of (membership reshard handoff / sharded-checkpoint
         restore). Leaves are placed on device so the donating jitted
         apply never consumes host buffers."""
-        import jax
         import jax.numpy as jnp
         if not self.decomposable:
             raise RuntimeError(

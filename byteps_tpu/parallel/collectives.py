@@ -138,11 +138,14 @@ def bucketed_allreduce(tree, axes: Sequence[str], partition_bytes: int = 4 << 20
     for ax in axes:
         n *= jax.lax.axis_size(ax)
     for b in buckets:
-        buf = _pack_bucket(flat, b)
-        buf = reducer(buf, axes)
-        if average:
-            buf = buf / n
-        _unpack_bucket(buf, b, flat)
+        with jax.named_scope("bps.exchange.pack"):
+            buf = _pack_bucket(flat, b)
+        with jax.named_scope("bps.exchange.reduce"):
+            buf = reducer(buf, axes)
+            if average:
+                buf = buf / n
+        with jax.named_scope("bps.exchange.unpack"):
+            _unpack_bucket(buf, b, flat)
     out = [f.reshape(s) for f, s in zip(flat, shapes)]
     return jax.tree_util.tree_unflatten(treedef, out)
 

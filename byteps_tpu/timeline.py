@@ -36,6 +36,19 @@ captures a ``jax.profiler`` device trace into
 ``<trace_dir>/<local_rank>/profile`` — host spans land in comm.json
 (reference schema, existing viewers work), device-side op timing in the
 profiler trace.
+
+Every stage above is a row of the eager ``push_pull`` engine or of the
+PS path. The collective path (``DistributedTrainer``'s one jitted step,
+the path the benchmark's cells run) records NONE of them: all it does
+here is advance the step tag (``set_step``), which opens and closes the
+profiler capture. What that path does is named inside the profiler's
+own trace instead, on the device's clock: ``bps.step`` /
+``bps.shard_batch`` / ``bps.dispatch`` / ``bps.stats`` (trainer) and
+``bps.feed.source`` / ``bps.feed.h2d`` / ``bps.feed.wait``
+(``prefetch_to_mesh``) on the host threads, the ``bps.*`` scopes and the
+``bps_flash_*`` kernels on the device lines (docs/timeline.md,
+"Profiling a job on the chip"; ``benchmark/trace/program.py`` reduces
+such a trace).
 """
 
 from __future__ import annotations
@@ -79,7 +92,11 @@ class Timeline:
                                   str(self.cfg.local_rank), "profile")
             os.makedirs(outdir, exist_ok=True)
             try:
-                jax.profiler.start_trace(outdir)
+                # no Python tracer, as in the benchmark's traced runs: a
+                # few MB for a few seconds instead of a Python trace
+                options = jax.profiler.ProfileOptions()
+                options.python_tracer_level = 0
+                jax.profiler.start_trace(outdir, profiler_options=options)
                 self._profiling = True
             except Exception as e:        # profiling must never kill a run
                 from .common.logging import get_logger

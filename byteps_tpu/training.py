@@ -379,9 +379,13 @@ class DistributedTrainer:
         loss_axes = tuple(a for a in axes if mesh.shape[a] > 1)
 
         def step(params, opt_state, batch):
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+            # bps.* scopes name the step's phases in a profiler trace
+            # (tx.update opens bps.exchange and bps.optimizer itself)
+            with jax.named_scope("bps.model"):
+                loss, grads = jax.value_and_grad(loss_fn)(params, batch)
             updates, opt_state = tx.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("bps.optimizer"):
+                params = optax.apply_updates(params, updates)
             # loss is per-shard; report the global mean
             if loss_axes:
                 loss = jax.lax.pmean(loss, loss_axes)
@@ -1057,23 +1061,28 @@ class DistributedTrainer:
         loss. With stats enabled (``BPS_STATS``, default on) each step
         also emits a ``StepStats`` record — wall time, per-stage deltas,
         throughput — through ``GlobalState.stats``."""
-        gs = GlobalState._instance
-        em = gs.stats if gs is not None else None
-        if em is None:
-            return self._step_impl(batch)
-        t0 = time.time()
-        loss = self._step_impl(batch)
-        # PS/async paths are host-synchronous by construction, so their
-        # loss is already materialized and float() is free; the
-        # collective path dispatches asynchronously and floating its
-        # loss would add a per-step device sync — report None there
-        sync_loss = (self._ps_engine is not None
-                     or self._async_worker is not None)
-        em.on_step(self.step_count, time.time() - t0,
-                   loss=loss if sync_loss else None,
-                   samples=_batch_samples(batch),
-                   timeline=gs.timeline if gs is not None else None)
-        return loss
+        # bps.* annotations are host spans in the profiler's own trace:
+        # a flag test when no profiler session runs (docs/timeline.md)
+        with jax.profiler.StepTraceAnnotation("bps.step",
+                                              step_num=self.step_count):
+            gs = GlobalState._instance
+            em = gs.stats if gs is not None else None
+            if em is None:
+                return self._step_impl(batch)
+            t0 = time.time()
+            loss = self._step_impl(batch)
+            # PS/async paths are host-synchronous by construction, so
+            # their loss is already materialized and float() is free; the
+            # collective path dispatches asynchronously and floating its
+            # loss would add a per-step device sync — report None there
+            sync_loss = (self._ps_engine is not None
+                         or self._async_worker is not None)
+            with jax.profiler.TraceAnnotation("bps.stats"):
+                em.on_step(self.step_count, time.time() - t0,
+                           loss=loss if sync_loss else None,
+                           samples=_batch_samples(batch),
+                           timeline=gs.timeline if gs is not None else None)
+            return loss
 
     def _step_impl(self, batch) -> jnp.ndarray:
         if self._async_worker is not None:
@@ -1090,16 +1099,19 @@ class DistributedTrainer:
             # — and multi-process meshes can't place raw numpy through
             # in_shardings at all ("non-trivial shardings for numpy
             # inputs"), so they always take the device_put path
-            batch = self.shard_batch(batch)
+            with jax.profiler.TraceAnnotation("bps.shard_batch"):
+                batch = self.shard_batch(batch)
         # single-process host (numpy) batches go straight in: the step's
         # in_shardings place them inside the jit dispatch — one dispatch
         # per step
-        self.params, self.opt_state, loss = self._step_fn(
-            self.params, self.opt_state, batch)
+        with jax.profiler.TraceAnnotation("bps.dispatch"):
+            self.params, self.opt_state, loss = self._step_fn(
+                self.params, self.opt_state, batch)
         self.step_count += 1
         gs = GlobalState._instance
         if gs is not None and gs.timeline is not None:
-            gs.timeline.set_step(self.step_count)
+            with jax.profiler.TraceAnnotation("bps.stats"):
+                gs.timeline.set_step(self.step_count)
         return loss
 
 
@@ -1177,7 +1189,8 @@ class ShardedTrainer:
         other_prod = math.prod(mesh.shape[a] for a in other_axes) if other_axes else 1
 
         def step(params, opt_state, batch):
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+            with jax.named_scope("bps.model"):
+                loss, grads = jax.value_and_grad(loss_fn)(params, batch)
             # Per-leaf grad sync over the non-dp axes the leaf is NOT
             # sharded on, then a uniform 1/prod(other_axes) rescale.
             # Why the rescale: inside shard_map the VJP of a forward psum
@@ -1189,15 +1202,18 @@ class ShardedTrainer:
             # P is a tuple subclass, so flatten both trees explicitly.
             g_leaves, g_def = jax.tree_util.tree_flatten(grads)
             synced = []
-            for g, s in zip(g_leaves, flat_specs):
-                axes = tuple(a for a in other_axes if a not in _spec_axes(s))
-                g = jax.lax.psum(g, axes) if axes else g
-                if other_prod > 1:
-                    g = g / other_prod
-                synced.append(g)
+            with jax.named_scope("bps.exchange"):
+                for g, s in zip(g_leaves, flat_specs):
+                    axes = tuple(a for a in other_axes
+                                 if a not in _spec_axes(s))
+                    g = jax.lax.psum(g, axes) if axes else g
+                    if other_prod > 1:
+                        g = g / other_prod
+                    synced.append(g)
             grads = jax.tree_util.tree_unflatten(g_def, synced)
             updates, opt_state = self.tx.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("bps.optimizer"):
+                params = optax.apply_updates(params, updates)
             if loss_axes:
                 loss = jax.lax.pmean(loss, loss_axes)
             return params, opt_state, loss
@@ -1216,18 +1232,24 @@ class ShardedTrainer:
         return shard_batch(batch, self.mesh, self.batch_spec)
 
     def step(self, batch):
-        gs = GlobalState._instance
-        em = gs.stats if gs is not None else None
-        t0 = time.time() if em is not None else 0.0
-        batch = self.shard_batch(batch)
-        self.params, self.opt_state, loss = self._step_fn(
-            self.params, self.opt_state, batch)
-        self.step_count += 1
-        if em is not None:
-            # loss is still in flight (async dispatch): None, not a sync
-            em.on_step(self.step_count, time.time() - t0,
-                       samples=_batch_samples(batch),
-                       timeline=gs.timeline)
-        return loss
+        with jax.profiler.StepTraceAnnotation("bps.step",
+                                              step_num=self.step_count):
+            gs = GlobalState._instance
+            em = gs.stats if gs is not None else None
+            t0 = time.time() if em is not None else 0.0
+            with jax.profiler.TraceAnnotation("bps.shard_batch"):
+                batch = self.shard_batch(batch)
+            with jax.profiler.TraceAnnotation("bps.dispatch"):
+                self.params, self.opt_state, loss = self._step_fn(
+                    self.params, self.opt_state, batch)
+            self.step_count += 1
+            if em is not None:
+                # loss is still in flight (async dispatch): None, not a
+                # sync
+                with jax.profiler.TraceAnnotation("bps.stats"):
+                    em.on_step(self.step_count, time.time() - t0,
+                               samples=_batch_samples(batch),
+                               timeline=gs.timeline)
+            return loss
 
 

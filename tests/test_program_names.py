@@ -1,0 +1,176 @@
+"""The names the program gives its own work (PERF.md section 3): scopes
+on the step's phases, the model's parts and the exchange, ``name=`` on
+the flash kernels, and the trainer's and the feed's host spans in the
+profiler's trace. CPU only: what the names cost and read on the chip is
+the benchmark's business (``benchmark/trace/program.py``)."""
+
+import glob
+import itertools
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from byteps_tpu.data import prefetch_to_mesh
+from byteps_tpu.models import bert, gpt2, transformer
+from byteps_tpu.parallel.mesh import make_mesh
+from byteps_tpu.training import DistributedTrainer
+
+# every scope of the table in ISSUE 25 section 1 that a CPU lowering can
+# hold (the kernels' own names need the Mosaic path: see the jaxpr tests)
+SCOPES = ("bps.model", "bps.optimizer", "bps.exchange", "bps.exchange.pack",
+          "bps.exchange.reduce", "bps.exchange.unpack", "bps.embed",
+          "bps.attn", "bps.mlp", "bps.head", "bps_attn_xla")
+
+
+def _trainer(model: str, mesh):
+    if model == "bert_tiny":
+        cfg = bert.bert_tiny()
+        loss = lambda p, b: bert.mlm_loss(p, cfg, b, max_predictions=8)  # noqa: E731
+        make = lambda rng: bert.synth_mlm_batch(rng, 8, 32, 128)  # noqa: E731
+    else:
+        cfg = gpt2.gpt2_tiny()
+        loss = lambda p, b: gpt2.causal_lm_loss(p, cfg, b)  # noqa: E731
+        make = lambda rng: gpt2.synth_lm_batch(rng, 8, 32, 128)  # noqa: E731
+    params = transformer.init_params(jax.random.PRNGKey(0), cfg)
+    # small buckets, so that the exchange has more than one of them
+    trainer = DistributedTrainer(loss, params, optax.adamw(1e-3), mesh=mesh,
+                                 partition_bytes=1 << 16)
+    return trainer, make
+
+
+@pytest.fixture(scope="module")
+def lowered():
+    """The step's lowering with its locations, once a model."""
+    mesh = make_mesh({"data": 4}, devices=jax.devices()[:4])
+    texts = {}
+    for model in ("bert_tiny", "gpt2_tiny"):
+        trainer, make = _trainer(model, mesh)
+        step = trainer._step_fn.lower(trainer.params, trainer.opt_state,
+                                      make(np.random.RandomState(0)))
+        texts[model] = step.as_text(debug_info=True)
+    # the last model's compiled module: its op_name metadata holds the
+    # whole scope path of an instruction, as a trace of the chip does
+    texts["paths"] = set(re.findall(r'op_name="([^"]*)"',
+                                    step.compile().as_text()))
+    return texts
+
+
+@pytest.mark.parametrize("model,scope", list(itertools.product(
+    ("bert_tiny", "gpt2_tiny"), SCOPES)))
+def test_lowered_step_holds_the_scope(lowered, model, scope):
+    names = set(re.findall(r"bps[._][A-Za-z_.]+", lowered[model]))
+    assert scope in names
+
+
+def test_scopes_nest_as_the_phases_do(lowered):
+    paths = lowered["paths"]
+
+    def some(pattern):
+        return any(re.search(pattern, p) for p in paths)
+
+    # forward, backward (transposed) and the exchange's parts each under
+    # their phase's scope, and the phases beside each other
+    assert some(r"bps\.model/jvp\(\)/.*bps\.attn")
+    assert some(r"bps\.model/transpose\(.*bps\.mlp")
+    assert some(r"bps\.model/.*jvp\(bps\.head\)")
+    assert some(r"bps\.exchange/bps\.exchange\.pack")
+    assert some(r"shard_map/bps\.optimizer/")
+    assert not some(r"bps\.optimizer/.*bps\.exchange")
+    assert not some(r"bps\.model/.*bps\.optimizer")
+
+
+@pytest.mark.parametrize("seq,kernels", [
+    (128, ("bps_flash_fwd", "bps_flash_bwd_fused")),
+    (256, ("bps_flash_fwd", "bps_flash_bwd_dq", "bps_flash_bwd_dkv")),
+], ids=["one_block_fused", "two_blocks_split"])
+def test_flash_kernels_carry_their_names(seq, kernels):
+    from byteps_tpu.ops.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, True, None, 128, 128,
+                               True).astype(jnp.float32).sum()
+
+    x = jnp.zeros((1, seq, 2, 64), jnp.bfloat16)
+    jaxpr = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(x, x, x))
+    assert set(re.findall(r"name=(bps_flash_\w+)", jaxpr)) == set(kernels)
+
+
+def _host_events(trace_dir):
+    from jax.profiler import ProfileData
+    path, = glob.glob(trace_dir + "/plugins/profile/*/*.xplane.pb")
+    plane = next(p for p in ProfileData.from_file(path).planes
+                 if p.name == "/host:CPU")
+    return [(e.name, dict(e.stats)) for line in plane.lines
+            for e in line.events if e.name.startswith("bps.")]
+
+
+def test_host_spans_land_in_the_profilers_trace(tmp_path):
+    mesh = make_mesh({"data": 2}, devices=jax.devices()[:2])
+    trainer, make = _trainer("bert_tiny", mesh)
+    rng = np.random.RandomState(0)
+    feed = prefetch_to_mesh((make(rng) for _ in range(4)), mesh)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        for batch in feed:
+            loss = trainer.step(batch)
+        jax.block_until_ready(loss)
+    finally:
+        jax.profiler.stop_trace()
+    events = _host_events(str(tmp_path))
+    steps = [stats["step_num"] for name, stats in events
+             if name == "bps.step"]
+    assert steps == [0, 1, 2, 3]
+    count = {name: sum(n == name for n, _ in events)
+             for name in {n for n, _ in events}}
+    assert count["bps.dispatch"] == count["bps.feed.h2d"] == 4
+    assert count["bps.feed.source"] == 5       # the last finds the end
+    assert count["bps.feed.wait"] >= 4
+    assert count["bps.shard_batch"] == 4       # device batches: eager put
+    host_bytes = sum(x.nbytes for x in make(rng))
+    assert {stats["bytes"] for name, stats in events
+            if name == "bps.feed.h2d"} == {host_bytes}
+
+
+class _CountingTime:
+    """``time`` with its clock calls counted."""
+
+    def __init__(self):
+        import time
+        self._time, self.calls = time, 0
+
+    def __getattr__(self, name):
+        value = getattr(self._time, name)
+        if callable(value):
+            def counted(*a, **kw):
+                self.calls += 1
+                return value(*a, **kw)
+            return counted
+        return value
+
+
+@pytest.mark.parametrize("stats_on,calls_a_step", [(True, 2), (False, 0)])
+def test_no_session_no_clock(monkeypatch, stats_on, calls_a_step):
+    """With no profiler session a step reads the clock as often as it
+    did before the spans: twice for ``StepStats`` where ``bps.init`` ran,
+    never otherwise."""
+    import byteps_tpu as bps
+    from byteps_tpu import training
+    mesh = make_mesh({"data": 2}, devices=jax.devices()[:2])
+    if stats_on:
+        bps.init(mesh=mesh)
+    trainer, make = _trainer("bert_tiny", mesh)
+    batch = make(np.random.RandomState(0))
+    trainer.step(batch)
+    clock = _CountingTime()
+    monkeypatch.setattr(training, "time", clock)
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    for _ in range(3):
+        loss = trainer.step(batch)
+    jax.block_until_ready(loss)
+    assert clock.calls == 3 * calls_a_step
