@@ -36,17 +36,18 @@ import jax.numpy as jnp
 
 def _block_attn(q, k, v, bias, scale):
     """One block: scores [*, hq, sq, sk] → (unnormalized out, row max, row
-    normalizer). Inputs stay in their compute dtype (bf16 on the MXU);
-    accumulation is fp32 via preferred_element_type."""
+    normalizer; the two statistics [*, hq, sq]). Inputs stay in their
+    compute dtype (bf16 on the MXU); accumulation is fp32 via
+    preferred_element_type."""
     s = jnp.einsum("...qhd,...khd->...hqk", q, k,
                    preferred_element_type=jnp.float32) * scale
     if bias is not None:
         s = s + bias
-    m = jnp.max(s, axis=-1, keepdims=True)            # [..., h, sq, 1]
+    m = jnp.max(s, axis=-1)                           # [..., h, sq]
     # guard fully-masked rows (all -inf)
     m = jnp.maximum(m, -1e30)
-    p = jnp.exp(s - m)
-    l = jnp.sum(p, axis=-1, keepdims=True)
+    p = jnp.exp(s - m[..., None])
+    l = jnp.sum(p, axis=-1)
     o = jnp.einsum("...hqk,...khd->...qhd", p.astype(v.dtype), v,
                    preferred_element_type=jnp.float32)
     return o, m, l
@@ -98,8 +99,8 @@ def _ring_naive(q, k, v, axis_name, causal, scale):
 
     # online softmax state
     o = jnp.zeros_like(q, dtype=jnp.float32)
-    m = jnp.full((b, h, sq, 1), -jnp.inf, dtype=jnp.float32)
-    l = jnp.zeros((b, h, sq, 1), dtype=jnp.float32)
+    m = jnp.full((b, h, sq), -jnp.inf, dtype=jnp.float32)
+    l = jnp.zeros((b, h, sq), dtype=jnp.float32)
 
     def accumulate(step, o, m, l, k_blk, v_blk):
         kv_rank = (idx - step) % sp
@@ -109,9 +110,9 @@ def _ring_naive(q, k, v, axis_name, causal, scale):
         alpha = jnp.exp(m - new_m)        # rescale old accumulation
         beta = jnp.exp(m_b - new_m)       # rescale new block
         l_new = l * alpha + l_b * beta
-        # alpha/beta are [b, h, sq, 1]; o is [b, sq, h, d]
-        a_t = jnp.swapaxes(alpha, 1, 2)   # [b, sq, h, 1]
-        b_t = jnp.swapaxes(beta, 1, 2)
+        # alpha/beta are [b, h, sq]; o is [b, sq, h, d]
+        a_t = jnp.swapaxes(alpha, 1, 2)[..., None]   # [b, sq, h, 1]
+        b_t = jnp.swapaxes(beta, 1, 2)[..., None]
         o_new = o * a_t + o_b * b_t
         return o_new, new_m, l_new
 
@@ -131,8 +132,8 @@ def _ring_naive(q, k, v, axis_name, causal, scale):
     o, m, l, k_last, v_last = jax.lax.fori_loop(0, sp - 1, body,
                                                 (o, m, l, k, v))
     o, m, l = accumulate(sp - 1, o, m, l, k_last, v_last)
-    l = jnp.maximum(jnp.swapaxes(l, 1, 2), 1e-30)     # [b, sq, h, 1]
-    return (o / l).astype(q.dtype)
+    l = jnp.maximum(jnp.swapaxes(l, 1, 2), 1e-30)     # [b, sq, h]
+    return (o / l[..., None]).astype(q.dtype)
 
 
 # ------------------------------------------------------------- flash ring
@@ -152,7 +153,7 @@ def _flash_blk_fwd(q_t, k_t, v_t, case, scale, interpret):
     """One ring step's flash forward. q_t/k_t/v_t: [b,h,s,d].
     Returns a normalized fp32 partial out [b,h,s,d] (fp32 so the
     per-step combine doesn't accumulate a bf16 rounding per ring step)
-    and lse [b,h,s,1] fp32. ``case`` None → non-causal visible."""
+    and lse [b,h,s] fp32. ``case`` None → non-causal visible."""
     from ..ops.flash_attention import _flash_fwd, _pick_block
 
     b, h, s, d = q_t.shape
@@ -171,19 +172,19 @@ def _flash_blk_fwd(q_t, k_t, v_t, case, scale, interpret):
 
     def hidden(_):
         return (jnp.zeros(q_t.shape, jnp.float32),
-                jnp.full((b, h, s, 1), -1e30, jnp.float32))
+                jnp.full((b, h, s), -1e30, jnp.float32))
 
     return jax.lax.switch(case, [hidden, diagonal, visible], None)
 
 
 def _combine(o, lse, o_b, lse_b):
-    """Merge two normalized partials ([b,h,s,d] fp32, [b,h,s,1] fp32)."""
+    """Merge two normalized partials ([b,h,s,d] fp32, [b,h,s] fp32)."""
     m = jnp.maximum(lse, lse_b)
     w = jnp.exp(lse - m)
     w_b = jnp.exp(lse_b - m)
     new_lse = m + jnp.log(w + w_b)
-    return (o * jnp.exp(lse - new_lse)
-            + o_b * jnp.exp(lse_b - new_lse)), new_lse
+    return (o * jnp.exp(lse - new_lse)[..., None]
+            + o_b * jnp.exp(lse_b - new_lse)[..., None]), new_lse
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -201,7 +202,7 @@ def _ring_flash_fwd_impl(q, k, v, axis_name, causal, scale, interpret):
     perm = _ring_perm(sp)
 
     o = jnp.zeros((b, h, sq, d), jnp.float32)
-    lse = jnp.full((b, h, sq, 1), -1e30, jnp.float32)
+    lse = jnp.full((b, h, sq), -1e30, jnp.float32)
 
     def accumulate(step, o, lse, k_blk, v_blk):
         kv_rank = (idx - step) % sp
@@ -221,8 +222,7 @@ def _ring_flash_fwd_impl(q, k, v, axis_name, causal, scale, interpret):
                                                (o, lse, k, v))
     o, lse = accumulate(sp - 1, o, lse, k_last, v_last)
     out = jnp.swapaxes(o, 1, 2).astype(q.dtype)       # [b,sq,h,d]
-    # lse stored [b,h,sq]: a trailing unit dim lane-pads 128x on TPU
-    return out, (q, k, v, out, lse[..., 0])
+    return out, (q, k, v, out, lse)                   # lse [b,h,sq]
 
 
 def _ring_flash_vjp_fwd(q, k, v, axis_name, causal, scale, interpret):
@@ -233,7 +233,6 @@ def _ring_flash_vjp_bwd(axis_name, causal, scale, interpret, res, g):
     from ..ops.flash_attention import _flash_bwd, _pick_block
 
     q, k, v, out, lse = res
-    lse = lse[..., None]                              # back to [b,h,sq,1]
     sp = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     b, sq, h, d = q.shape
@@ -244,7 +243,7 @@ def _ring_flash_vjp_bwd(axis_name, causal, scale, interpret, res, g):
     # delta is loop-invariant (depends only on do and the final out):
     # compute it once instead of once per ring step inside _flash_bwd
     delta = jnp.sum(do_t.astype(jnp.float32) * out_t.astype(jnp.float32),
-                    axis=-1, keepdims=True)           # [b,h,sq,1]
+                    axis=-1)                          # [b,h,sq]
     perm = _ring_perm(sp)
 
     def blk_bwd(k_t, v_t, case):

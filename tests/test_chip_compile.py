@@ -65,20 +65,31 @@ def mosaic(monkeypatch):
     return pk
 
 
-@pytest.mark.parametrize("shape,causal", [
-    ((64, 512, 16, 64), False),      # BERT-large, batch 64 x seq 512
-    ((64, 512, 8, 128), False),      # its d_head-128 twin
-    ((1, 32768, 12, 64), True),      # GPT-2-small at 32k, causal
-], ids=["bert_large", "dh128", "gpt2_32k_causal"])
-def test_flash_fwd_bwd_compiles_for_v5e(compile_for_chip, shape, causal):
+@pytest.mark.parametrize("shape,causal,extra", [
+    ((64, 512, 16, 64), False, None),     # BERT-large, batch 64 x seq 512
+    ((64, 512, 8, 128), False, None),     # its d_head-128 twin
+    ((1, 32768, 12, 64), True, None),     # GPT-2-small at 32k, causal
+    # GPT-2-medium's cell: split backward, ht = 1, nq = 2
+    ((8, 1024, 16, 64), True, None),
+    ((256, 128, 16, 64), False, None),    # BERT phase 1: ht = 8
+    ((8, 512, 8, 64), False, "bias"),     # T5's two score-bias forms
+    ((8, 1024, 8, 64), False, "rel_table"),
+], ids=["bert_large", "dh128", "gpt2_32k_causal", "gpt2_medium_causal",
+        "bert_s128", "bias", "rel_table"])
+def test_flash_fwd_bwd_compiles_for_v5e(compile_for_chip, shape, causal,
+                                        extra):
     from byteps_tpu.ops.flash_attention import flash_attention
+    _, s, h, _ = shape
+    extra_shape = {None: [], "bias": [((h, s, s), jnp.float32)],
+                   "rel_table": [((h, 32), jnp.float32)]}[extra]
 
-    def loss(q, k, v):
-        return (flash_attention(q, k, v, causal).astype(jnp.float32)
-                ** 2).sum()
+    def loss(q, k, v, *e):
+        return (flash_attention(q, k, v, causal, **dict(zip([extra], e)))
+                .astype(jnp.float32) ** 2).sum()
 
-    compile_for_chip(jax.value_and_grad(loss, argnums=(0, 1, 2)),
-                     *[(shape, jnp.bfloat16)] * 3)
+    compile_for_chip(
+        jax.value_and_grad(loss, argnums=tuple(range(3 + len(extra_shape)))),
+        *[(shape, jnp.bfloat16)] * 3, *extra_shape)
 
 
 def test_onebit_pack_unpack_compile_for_v5e(compile_for_chip, mosaic):

@@ -323,3 +323,195 @@ def test_rel_table_no_materialized_bias_in_jaxpr():
             shape = getattr(getattr(var, "aval", None), "shape", ())
             assert int(np.prod(shape or (1,))) < big, (
                 f"O(s^2) intermediate {shape} materialized by {eqn.primitive}")
+
+
+# ---- the row statistics' contract: lane-dense across every pallas_call
+
+def _sub_jaxprs(eqn):
+    for value in eqn.params.values():
+        for v in value if isinstance(value, (list, tuple)) else (value,):
+            v = getattr(v, "jaxpr", v)            # ClosedJaxpr -> Jaxpr
+            if hasattr(v, "eqns"):
+                yield v
+
+
+def equations(jaxpr, primitive):
+    """Every ``primitive`` equation of a jaxpr, those of its sub-jaxprs
+    (scan, cond, shard_map, custom_vjp...) included; kernel bodies are
+    not entered."""
+    found = []
+
+    def walk(j):
+        for eqn in j.eqns:
+            if eqn.primitive.name == primitive:
+                found.append(eqn)
+            if eqn.primitive.name != "pallas_call":
+                for sub in _sub_jaxprs(eqn):
+                    walk(sub)
+
+    walk(getattr(jaxpr, "jaxpr", jaxpr))
+    return found
+
+
+def assert_statistics_lane_dense(jaxpr, rows, min_calls):
+    """No operand or result of any pallas_call ends in a dimension of 1,
+    and each call's float32 row statistics (one number a row of
+    attention: ``rows`` numbers) end in whole 128-lane rows. Every flash
+    kernel moves at least one statistic, so a call without one means
+    this test no longer sees them."""
+    calls = equations(jaxpr, "pallas_call")
+    assert len(calls) >= min_calls, [str(c.params.get("name")) for c in calls]
+    for eqn in calls:
+        name = eqn.params.get("name")
+        statistics = 0
+        for var in list(eqn.invars) + list(eqn.outvars):
+            shape, dtype = var.aval.shape, var.aval.dtype
+            assert shape[-1] != 1, (name, shape)
+            if dtype == jnp.float32 and int(np.prod(shape)) == rows:
+                statistics += 1
+                assert shape[-1] % 128 == 0, (name, shape)
+        assert statistics >= 1, (name, [v.aval.shape for v in eqn.invars])
+
+
+@pytest.mark.parametrize("sq,sk,h,causal,blocks,extra,calls", [
+    (512, 512, 16, False, (512, 512), None, 2),       # fused backward, ht 4/2
+    (128, 128, 16, False, (512, 512), None, 2),       # fused, ht 8
+    (1024, 1024, 16, False, (512, 512), None, 3),     # split backward, ht 1
+    (1024, 1024, 16, True, (512, 512), None, 3),      # causal split
+    (256, 256, 4, False, (128, 128), "bias", 3),
+    (256, 256, 4, True, (128, 128), "rel_table", 3),
+    (128, 384, 4, False, (128, 128), None, 3),        # cross: follows sq
+    (384, 128, 4, False, (128, 128), None, 3),
+    (1024, 1024, 16, True, (None, None), None, 3),    # GPT-2's cell
+], ids=["fused", "fused_s128", "split", "causal", "bias", "rel_table",
+        "cross_sq128", "cross_sq384", "default_blocks"])
+def test_no_padded_statistic_crosses_a_kernel(sq, sk, h, causal, blocks,
+                                              extra, calls):
+    """lse (and delta) cross HBM with the sequence on the lane axis: a
+    [b, h, s, 1] column is padded 128x there (512 bytes a row of
+    attention instead of 4). Traced without interpret mode, so the head
+    tiles are the chip's."""
+    b, d = 2, 64
+    q = jnp.zeros((b, sq, h, d), jnp.bfloat16)
+    k = jnp.zeros((b, sk, h, d), jnp.bfloat16)
+    operand = {None: (), "bias": (jnp.zeros((h, sq, sk), jnp.float32),),
+               "rel_table": (jnp.zeros((h, 32), jnp.float32),)}[extra]
+
+    def loss(q, k, v, *e):
+        return (flash_attention(q, k, v, causal, None, *blocks, False, False,
+                                **dict(zip([extra], e)))
+                .astype(jnp.float32) ** 2).sum()
+
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(
+        loss, argnums=tuple(range(3 + len(operand)))))(q, k, k, *operand)
+    assert_statistics_lane_dense(jaxpr, b * h * sq, calls)
+
+
+@pytest.mark.parametrize("ht", [1, 8])
+@pytest.mark.parametrize("s", [128, 384, 1024])
+def test_lse_matches_xla_forward(monkeypatch, s, ht):
+    """The kernel's lse, converted to rows 128 at a time, is the plain
+    forward's [b, h, s] log-sum-exp: one block (128, 384) and two (1024),
+    one head a program and eight."""
+    from byteps_tpu.ops.flash_attention import _flash_fwd, _xla_fwd
+    monkeypatch.setenv("BPS_FLASH_HT", str(ht))
+    rng = np.random.RandomState(13)
+    b, h, d = 1, 8, 16
+    q, k, v = (jnp.asarray(rng.randn(b, h, s, d).astype(np.float32))
+               for _ in range(3))
+    bq = min(s, 512)
+    out, lse = _flash_fwd(q, k, v, True, d ** -0.5, bq, bq, True)
+    want_out, want = _xla_fwd(q, k, v, True, d ** -0.5)
+    assert lse.shape == want.shape == (b, h, s)
+    assert lse.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want),
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want_out),
+                               rtol=2e-5, atol=2e-5)
+
+
+# ---- the single-block forward (one kv block holds the row's keys)
+
+def _bhsd(rng, b, h, s, d):
+    return jnp.asarray(rng.randn(b, h, s, d).astype(np.float32))
+
+
+@pytest.mark.parametrize("s,causal,blocks", [
+    (256, False, (256, 256)),
+    (256, True, (256, 256)),
+    (384, True, (384, 384)),        # triangle, one chunk of rows
+    (1024, True, (1024, 1024)),     # triangle, two chunks of 512 rows
+    (1024, False, (512, 1024)),     # two q blocks against the whole kv
+    (512, True, (128, 512)),        # causal, q block from the grid
+], ids=["s256", "s256_causal", "s384_causal", "s1024_triangle",
+        "s1024_nq2", "s512_causal_nq4"])
+def test_single_block_forward_matches_online(s, causal, blocks):
+    """With the whole kv row in one block the forward is a plain softmax
+    (no carried scratch state); out and lse are the online kernel's (128
+    blocks) and the plain XLA forward's."""
+    from byteps_tpu.ops.flash_attention import _flash_fwd, _xla_fwd
+    rng = np.random.RandomState(17)
+    b, h, d = 1, 2, 32
+    q, k, v = (_bhsd(rng, b, h, s, d) for _ in range(3))
+    scale = d ** -0.5
+    out, lse = _flash_fwd(q, k, v, causal, scale, *blocks, True)
+    online_out, online_lse = _flash_fwd(q, k, v, causal, scale, 128, 128,
+                                        True)
+    ref_out, ref_lse = _xla_fwd(q, k, v, causal, scale)
+    for got, want in ((out, online_out), (out, ref_out)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
+    for got, want in ((lse, online_lse), (lse, ref_lse)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-6, atol=2e-6)
+
+
+@pytest.mark.parametrize("sq,sk,causal,extra,forward,want", [
+    (512, 512, False, False, True, (512, 512)),
+    (128, 128, False, False, True, (128, 128)),
+    (1024, 1024, True, False, True, (1024, 1024)),   # whole q too: triangle
+    (1024, 1024, False, False, True, (512, 1024)),
+    (2048, 1024, False, False, True, (512, 1024)),   # cross: kv whole
+    (2048, 2048, True, False, True, (512, 512)),     # past _WHOLE_KV
+    (1024, 1024, True, True, True, (512, 512)),      # bias / rel_table
+    (1024, 1024, True, False, False, (512, 512)),    # the backward
+], ids=["s512", "s128", "s1024_causal", "s1024", "cross", "s2048",
+        "biased", "backward"])
+def test_default_blocks(sq, sk, causal, extra, forward, want):
+    """No block named: 512, and in a plain call's forward the whole kv
+    sequence up to 1024 keys (with the whole q when causal); a named
+    block is taken as given."""
+    from byteps_tpu.ops.flash_attention import _resolve
+    q = jnp.zeros((1, sq, 2, 64), jnp.bfloat16)
+    k = jnp.zeros((1, sk, 2, 64), jnp.bfloat16)
+    whole = forward and not extra
+    assert _resolve(q, k, None, None, None, whole, causal)[1:] == want
+    assert _resolve(q, k, None, 128, 128, whole, causal)[1:] == (128, 128)
+
+
+def test_default_forward_is_single_block_at_1024():
+    """GPT-2's benchmark shape (causal, 1024) takes the single-block
+    forward by default, one program a head tile, and its gradients are
+    the reference's."""
+    rng = np.random.RandomState(19)
+    q, k, v = make_qkv(rng, 1, 1024, 2, 32, np.float32)
+
+    def loss(q, k, v):
+        o = flash_attention(q, k, v, True, None, None, None, True)
+        return jnp.sum(jnp.sin(o))
+
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2)))(
+        q, k, v)
+    grids = {str(e.params["name"]): e.params["grid_mapping"].grid
+             for e in equations(jaxpr, "pallas_call")}
+    assert grids["bps_flash_fwd"][2:] == (1, 1)
+    assert grids["bps_flash_bwd_dq"][2:] == (2, 2)
+
+    def loss_ref(q, k, v):
+        return jnp.sum(jnp.sin(local_attention(q, k, v, causal=True)))
+
+    gf = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    for a, b_, name in zip(gf, gr, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=2e-4, atol=2e-4, err_msg=name)

@@ -130,3 +130,35 @@ def test_flash_ring_matches_local_single_device():
     sharding = NamedSharding(mesh, spec)
     got = np.asarray(fn(*(jax.device_put(x, sharding) for x in (q, k, v))))
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
+
+def test_flash_ring_statistics_lane_dense():
+    """The ring's flash kernels, its lse carry and its hoisted delta keep
+    the row statistics [b, h, s]: no [.., s, 1] column crosses a
+    pallas_call (every branch of the causal switch included) or rides a
+    loop as a carry, where it would be padded 128x on the TPU."""
+    from tests.test_flash_attention import (assert_statistics_lane_dense,
+                                            equations)
+    mesh = make_mesh({"seq": 4}, devices=jax.devices()[:4])
+    b, s, h, d = 1, 2048, 2, 64          # 512-token shards: fused backward
+    q = jnp.zeros((b, s, h, d), jnp.bfloat16)
+
+    def f(q, k, v):
+        def loss(q, k, v):
+            o = ring_attention(q, k, v, "seq", causal=True, impl="flash")
+            return (o.astype(jnp.float32) ** 2).sum()
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    spec = P(None, "seq")
+    jaxpr = jax.make_jaxpr(jax.shard_map(
+        f, mesh=mesh, in_specs=spec, out_specs=(P(), spec),
+        check_vma=False))(q, q, q)
+    # forward: diagonal + visible, in the loop and after it; as many
+    # backward calls
+    assert_statistics_lane_dense(jaxpr, b * h * (s // 4), 8)
+    # fori_loop over a static trip count traces as a scan
+    loops = equations(jaxpr, "scan") + equations(jaxpr, "while")
+    assert loops
+    for eqn in loops:
+        for var in eqn.outvars:
+            assert var.aval.shape[-1:] != (1,), var.aval.shape
