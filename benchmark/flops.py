@@ -7,6 +7,10 @@ counted, a causal model's attention counts the lower triangle only, and
 the head counts the targeted positions only. A share of the peak made
 from this count cannot pass 100 %: no program can do the work in fewer
 operations.
+
+A configuration names its rule under ``flops_rule``: ``transformer_lm``
+here, or ``module:function`` of a file of its own under the manifest's
+``paths`` with the same arguments (``harness.named_count``).
 """
 
 from __future__ import annotations
@@ -25,5 +29,3 @@ def transformer_lm(sizes: dict, seq: int, targets_per_row: int) -> float:
     head = 2 * h * sizes["vocab_size"] * targets_per_row / seq
     return 3.0 * (sizes["layers"] * layer + head)
 
-
-RULES = {"transformer_lm": transformer_lm}

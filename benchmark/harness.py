@@ -4,8 +4,9 @@ measured window, and the result line.
 Driven by data. A cell is an entry of ``BENCHMARK.json``'s ``workloads``;
 its configuration is the file the manifest names, its traffic mix is
 ``traffic/<traffic>.json`` and each per-layer metric is
-``metrics/<metric>.py``, all found by name under the manifest's ``paths``.
-This file knows no cell, configuration or metric by name.
+``metrics/<metric>.py``, all found by name under the manifest's ``paths``,
+as are the counts a configuration names (``named_count``). This file knows
+no cell, configuration or metric by name.
 
 The order of a run:
 
@@ -40,8 +41,9 @@ import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from . import correct, flops, generator
+from . import correct, flops, generator, kernel_counts
 from .trace import reduce as trace_reduce
+from .trace.program import root_of
 
 CHECK_STEPS = 3          # steps the reference follows
 WARM_STEPS = 2           # further steps before the window opens
@@ -71,6 +73,49 @@ def emit(phase: str, **fields) -> None:
 def resolve(dotted: str):
     module, _, attr = dotted.partition(":")
     return getattr(importlib.import_module(module), attr)
+
+
+def load_file(name: str, path: str):
+    """The module in the file at ``path``, executed under ``name``: how the
+    files a manifest's ``paths`` add (readers, counts) are loaded, whether
+    or not their directory can be imported."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+# the counts the benchmark brings itself, by the name a configuration gives
+COUNTS = {"transformer_lm": flops.transformer_lm,
+          "benchmark.kernel_counts:of_cell": kernel_counts.of_cell}
+# the name a configuration that gives none is read as, by its key
+DEFAULT_COUNT = {"kernel_counts": "benchmark.kernel_counts:of_cell"}
+
+
+def named_count(cell, key: str):
+    """The function a cell's configuration names under ``key``
+    (``flops_rule``: required operations a token; ``kernel_counts``: each
+    kernel's operations and bytes a call): a name of ``COUNTS``, or
+    ``module:function`` where the module is a file under the manifest's
+    ``paths``, found from the checkout's root and loaded from that file as
+    a metric's reader is, so that a configuration brings its count with it
+    and cannot point outside the benchmark."""
+    name = cell.config.get(key, DEFAULT_COUNT.get(key))
+    if name in COUNTS:
+        return COUNTS[name]
+    module, _, attr = str(name).partition(":")
+    path = os.path.normpath(
+        os.path.join(root_of(cell.dirs), *module.split(".")) + ".py")
+    count = None
+    if os.path.exists(path) and any(
+            path.startswith(os.path.abspath(d) + os.sep) for d in cell.dirs):
+        count = getattr(load_file(
+            "benchmark_count_" + module.replace(".", "_"), path), attr, None)
+    if not callable(count):
+        raise ValueError(
+            f"{key} {name!r} is neither one of {sorted(COUNTS)} nor "
+            f"module:function of a file under the manifest's paths")
+    return count
 
 
 # ----------------------------------------------------------------- cells
@@ -126,11 +171,8 @@ def load_metric(name: str, dirs):
     for d in list(dirs) + [os.path.dirname(os.path.abspath(__file__))]:
         path = os.path.join(d, "metrics", name + ".py")
         if os.path.exists(path):
-            spec = importlib.util.spec_from_file_location(
-                "benchmark_metric_" + name.replace(".", "_"), path)
-            module = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(module)
-            return module
+            return load_file("benchmark_metric_" + name.replace(".", "_"),
+                             path)
     raise FileNotFoundError(f"no metrics/{name}.py under {list(dirs)}")
 
 
@@ -545,7 +587,16 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
         "device": device}
     if breakdown is not None:
         result["breakdown"] = breakdown
+    # last in the line: each number compared, beside its limit
+    result["checks"] = {r["check"]: {"value": printable(r["value"]),
+                                     "limit": r["limit"]} for r in checks}
     return result
+
+
+def printable(value):
+    """A compared number as the result line can carry it: JSON has no
+    infinity, which is what a gap over a NaN reads."""
+    return value if math.isfinite(value) else repr(value)
 
 
 # ------------------------------------------------------------ traced run
@@ -633,8 +684,14 @@ def traced_run(cell, spans, up: SetUp, seed, trace_dir, require_chip,
             raise SystemExit(f"benchmark: device kind {kind!r} is not in "
                              f"peaks.json; add it with its source")
         peaks = table[kind]
-    rule = flops.RULES[cell.config["flops_rule"]]
     return TracedRun(
         cell, dict(spans.seconds), chips, trainer_step_s, plain_step_s,
-        rule(cell.config["sizes"], cell.mix["seq"],
-             generator.targets_per_row(cell.mix)), peaks)
+        flops_per_token(cell), peaks)
+
+
+def flops_per_token(cell: Cell) -> float:
+    """Required operations a token of a training step, by the rule the
+    cell's configuration names."""
+    return named_count(cell, "flops_rule")(
+        cell.config["sizes"], cell.mix["seq"],
+        generator.targets_per_row(cell.mix))

@@ -2,7 +2,7 @@
 around ``next(feed)`` on ``data.py::prefetch_to_mesh``."""
 import statistics
 
-UNIT, LAYER, MOVES, SOURCE = "ms", "input", "tokens_per_s_chip", "program_span"
+UNIT, LAYER, MOVES, SOURCE = "ms", "input", "tokens_per_s_chip", "host_clock"
 
 
 def read(run):

@@ -2,7 +2,7 @@
 enqueue, not the step): the benchmark's span around the call."""
 import statistics
 
-UNIT, LAYER, MOVES, SOURCE = "ms", "trainer", "tokens_per_s_chip", "program_span"
+UNIT, LAYER, MOVES, SOURCE = "ms", "trainer", "tokens_per_s_chip", "host_clock"
 
 
 def read(run):

@@ -6,9 +6,10 @@ Runs one cell of ``BENCHMARK.json`` once on the TPU chips the cell asks
 for and prints, as the last line of its standard output, one JSON object:
 ``correct``, ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end
 metrics with ``--trace 0``, its per-layer metrics with ``--trace 1``),
-``device`` and, traced, ``breakdown``. Earlier lines, one JSON object
-each, say what was compared with what limit, and how many samples each
-number stands on. Without the chips it exits non-zero and prints no
+``device``, traced ``breakdown``, and last ``checks``: each number that
+decided ``correct`` beside its limit, which are also the last lines of its
+standard error. Earlier lines, one JSON object each, say what was compared
+with what limit and where, and how many samples each number stands on. Without the chips it exits non-zero and prints no
 result: it never falls back to the CPU.
 """
 
@@ -37,6 +38,9 @@ def main(argv=None) -> None:
     harness.use_compile_cache(ROOT)
     result = harness.run_cell(ROOT, args.workload, args.seed, args.seconds,
                               bool(args.trace), T_START)
+    for name, check in result["checks"].items():    # stderr's last lines
+        print(f"check {name}: value {check['value']} limit {check['limit']}",
+              file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
 
 

@@ -148,20 +148,49 @@ def ns_by_kernel(ops: Iterable[Op]) -> dict:
     return out
 
 
-def roofline(by_kernel: dict, counts: dict, peaks: dict) -> dict:
-    """For each kernel of ``by_kernel`` with a count: its share of the
-    roofline (the least seconds the chip could take for its calls over
-    the seconds they took) and which bound sets it; under ``"all"`` the
-    same over all of them together."""
-    out, least_all, took_all = {}, 0.0, 0.0
+def least_of_calls(count, calls: int, steps: int, peaks: dict):
+    """(the least seconds the chip could take for a kernel's ``calls``
+    calls in ``steps`` steps, which bound sets it). ``count`` is one
+    ``{"flops", "bytes"}`` where every call is alike, or a list of
+    ``{"flops", "bytes", "calls"}``, one entry a kind of call: then the
+    list's least seconds, scaled by how many times the calls of a step
+    hold the list. None where that is no whole number: the count does not
+    describe this step, and a share made from it would be a guess."""
+    if isinstance(count, dict):
+        least, bound = kernel_counts.least_seconds(count, peaks)
+        return least * calls, bound
+    listed = sum(kind["calls"] for kind in count)
+    if not listed or calls % steps or (calls // steps) % listed:
+        return None
+    of_list, bounds = 0.0, set()
+    for kind in count:
+        least, bound = kernel_counts.least_seconds(kind, peaks)
+        of_list += least * kind["calls"]
+        bounds.add(bound)
+    return (of_list * (calls // listed),
+            bounds.pop() if len(bounds) == 1 else "mixed")
+
+
+def roofline(by_kernel: dict, counts: dict, peaks: dict,
+             steps: int = 1) -> dict:
+    """For each kernel of ``by_kernel`` (``(ns, calls)`` over ``steps``
+    steps) with a count: its share of the roofline (the least seconds the
+    chip could take for its calls over the seconds they took) and which
+    bound sets it; under ``"all"`` the same over all of them together.
+    A kernel whose count does not fit its calls (``least_of_calls``) has
+    no share, and then neither has ``"all"``."""
+    out, least_all, took_all, fits = {}, 0.0, 0.0, True
     for k, (ns, calls) in by_kernel.items():
         if k not in counts or not ns:
             continue
-        least, bound = kernel_counts.least_seconds(counts[k], peaks)
-        out[k] = {"pct": 100.0 * least * calls / (ns / 1e9), "bound": bound}
-        least_all += least * calls
+        least = least_of_calls(counts[k], calls, steps, peaks)
+        if least is None:
+            fits = False
+            continue
+        out[k] = {"pct": 100.0 * least[0] / (ns / 1e9), "bound": least[1]}
+        least_all += least[0]
         took_all += ns / 1e9
-    if took_all:
+    if took_all and fits:
         out["all"] = {"pct": 100.0 * least_all / took_all}
     return out
 
@@ -370,10 +399,13 @@ def of_run(run) -> Optional[Program]:
         return None
 
 
-def flash_roofline(program: Program, sizes: dict, mix: dict,
-                   peaks: dict) -> dict:
-    return roofline(program.by_kernel, kernel_counts.of_cell(sizes, mix),
-                    peaks)
+def flash_roofline(program: Program, cell, peaks: dict) -> dict:
+    """``roofline`` of a trace's flash calls, by the count the cell's
+    configuration names (``harness.named_count``)."""
+    from benchmark import harness
+    counts = harness.named_count(cell, "kernel_counts")(
+        cell.config["sizes"], cell.mix)
+    return roofline(program.by_kernel, counts, peaks, program.steps)
 
 
 # ---------------------------------------------------------------- a dump
@@ -411,8 +443,7 @@ def report(program: Program, cell=None, peaks=None) -> str:
                            key=lambda kv: -kv[1])[:6]:
         out.append(f"  {ms(ns):10.3f}  {name}")
     by_kernel = ns_by_kernel(program.ops)
-    shares = (flash_roofline(program, cell.config["sizes"], cell.mix, peaks)
-              if cell is not None else {})
+    shares = flash_roofline(program, cell, peaks) if cell is not None else {}
     out.append("kernels, ms a step (calls a step; share of roofline, bound):")
     for k, (ns, calls) in sorted(by_kernel.items()):
         share = shares.get(k)

@@ -24,17 +24,19 @@ def _run(root, cell, trace=False, seconds=0.3):
 
 
 @pytest.mark.parametrize("trace", [False, True])
-@pytest.mark.parametrize("cell", ["tiny_mlm_cell", "tiny_lm_cell"])
+@pytest.mark.parametrize("cell", ["tiny_mlm_cell", "tiny_lm_cell",
+                                  "tiny_cut_cell"])
 def test_cell_config_and_metric_added_as_new_files_only(tiny_root, cell,
                                                         trace, capsys):
-    """``tiny_root`` holds a manifest, two configurations, two traffic
-    mixes and one per-layer metric that the repo does not have; nothing
-    under ``benchmark/`` was edited to run them."""
+    """``tiny_root`` holds a manifest, three configurations (one of them
+    cut, with counts of its own in a file it names), two traffic mixes
+    and one per-layer metric that the repo does not have; nothing under
+    ``benchmark/`` was edited to run them."""
     result = _run(tiny_root, cell, trace)
     assert result["correct"] is True, capsys.readouterr().out
     assert result["failed"] == 0 and result["attempted"] > 3
-    assert set(result) == {"correct", "attempted", "failed", "metrics",
-                           "device"}
+    assert list(result) == ["correct", "attempted", "failed", "metrics",
+                            "device", "checks"]
     got = result["metrics"]
     if not trace:
         assert set(got) == {"tokens_per_s_chip", "step_ms_p95", "setup_s"}
@@ -55,6 +57,10 @@ def test_cell_config_and_metric_added_as_new_files_only(tiny_root, cell,
             "compiles_in_window"} <= set(checks)
     assert all("limit" in c and "value" in c for c in checks.values())
     assert checks["compiles_in_window"]["value"] == 0
+    # the result line carries them too, each beside its limit, and last
+    assert result["checks"] == {name: {"value": c["value"],
+                                       "limit": c["limit"]}
+                                for name, c in checks.items()}
 
 
 def _freeze_the_step(monkeypatch):

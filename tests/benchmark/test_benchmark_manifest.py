@@ -7,6 +7,7 @@ import re
 
 import pytest
 
+import manifest_rules
 from tinybench import ROOT
 
 from benchmark import generator, harness
@@ -36,47 +37,53 @@ def test_top_level_keys_and_command(manifest):
     assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) < 64 << 10
 
 
-def test_configs(manifest):
+def _configs():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)["configs"]
+
+
+def _file(entry):
+    with open(os.path.join(ROOT, entry["file"])) as f:
+        return json.load(f)
+
+
+def test_config_entries(manifest):
     used = {w["config"] for w in manifest["workloads"]}
-    files = set()
+    files = [c["file"] for c in manifest["configs"]]
+    assert 1 <= len(files) <= 24 and len(set(files)) == len(files)
     for c in manifest["configs"]:
         assert set(c) == {"name", "source", "file", "reduced", "why"}
         assert NAME.match(c["name"]) and c["name"] in used
         assert _line(c["source"]) and _line(c["why"])
         assert any(c["file"].startswith(p + "/") for p in manifest["paths"])
-        assert c["file"] not in files
-        files.add(c["file"])
-        with open(os.path.join(ROOT, c["file"])) as f:
-            doc = json.load(f)
-        assert doc["reduced"] == c["reduced"] == []
-        # the sizes the program is built with are the sizes the reference
-        # and the FLOPs count are given
-        kw, sizes = doc["program"]["config_kwargs"], doc["sizes"]
-        for key in ("hidden", "layers", "heads", "vocab_size", "max_seq"):
-            assert kw[key] == sizes[key]
-        assert sizes["mlp_dim"] == 4 * sizes["hidden"]
-        assert doc["flops_rule"] in __import__(
-            "benchmark.flops", fromlist=["RULES"]).RULES
-        for limit in ("loss_rel", "trainer_vs_plain_loss_rel"):
-            assert doc["limits"][limit] > 0
+        assert len(c["reduced"]) <= 16
+        assert all(NAME.match(key) for key in c["reduced"])
 
 
-def test_published_sizes_are_the_sizes_run(manifest):
-    for c in manifest["configs"]:
-        with open(os.path.join(ROOT, c["file"])) as f:
-            doc = json.load(f)
-        pub, sizes = doc["published"], doc["sizes"]
-        if "hidden_size" in pub:                 # BERT's config.json
-            assert (pub["hidden_size"], pub["num_hidden_layers"],
-                    pub["num_attention_heads"], pub["intermediate_size"],
-                    pub["vocab_size"], pub["max_position_embeddings"]) == (
-                sizes["hidden"], sizes["layers"], sizes["heads"],
-                sizes["mlp_dim"], sizes["vocab_size"], sizes["max_seq"])
-        else:                                    # GPT-2's
-            assert (pub["n_embd"], pub["n_layer"], pub["n_head"],
-                    pub["vocab_size"], pub["n_positions"]) == (
-                sizes["hidden"], sizes["layers"], sizes["heads"],
-                sizes["vocab_size"], sizes["max_seq"])
+@pytest.mark.parametrize("entry", _configs(), ids=lambda c: c["name"])
+def test_configs(manifest, entry):
+    """One case a configuration: the file agrees with its manifest entry
+    and with itself (``manifest_rules.config_file``)."""
+    dirs = [os.path.join(ROOT, p) for p in manifest["paths"]]
+    manifest_rules.config_file(_file(entry), entry, dirs)
+
+
+@pytest.mark.parametrize("entry", _configs(), ids=lambda c: c["name"])
+def test_published_sizes_are_the_sizes_run(entry):
+    """One rule, no branch on a family: what the file publishes is what
+    it runs, but for the keys it lists in ``reduced``, which run at less
+    and keep to the floors (``manifest_rules.published_sizes``)."""
+    manifest_rules.published_sizes(_file(entry))
+
+
+@pytest.mark.parametrize("entry", [c for c in _configs() if not c["reduced"]],
+                         ids=lambda c: c["name"])
+def test_an_uncut_dense_file_keeps_the_fourfold_feed_forward(entry):
+    """The two dense configurations' own: GPT-2 publishes no inner width
+    (``n_inner`` null means four times the hidden size), BERT publishes
+    exactly that."""
+    sizes = _file(entry)["sizes"]
+    assert sizes["mlp_dim"] == 4 * sizes["hidden"]
 
 
 def test_workloads(manifest):

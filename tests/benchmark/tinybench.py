@@ -1,6 +1,9 @@
 """A tiny benchmark in a temporary directory, made of NEW files only (a
-manifest, two configurations, two traffic mixes and a per-layer metric),
-which the harness under ``benchmark/`` runs unchanged on the CPU."""
+manifest, three configurations, two traffic mixes, a per-layer metric and
+a file of counts), which the harness under ``benchmark/`` runs unchanged
+on the CPU. The third configuration is written the way a cut one will be:
+a made-up ``published`` of which it runs half the depth and an eighth of
+the vocabulary, a ``deployment``, and counts of its own, found by name."""
 
 import json
 import os
@@ -25,6 +28,7 @@ def tiny_config(kind: str, dtype: str = "float32", limits=None) -> dict:
     family = "gpt2:gpt2_config" if causal else "bert:bert_config"
     loss = "gpt2:causal_lm_loss" if causal else "bert:mlm_loss"
     return {
+        "reduced": [],
         "sizes": dict(TINY_SIZES, causal=causal),
         "optimizer": OPTIMIZER,
         "program": {
@@ -40,6 +44,58 @@ def tiny_config(kind: str, dtype: str = "float32", limits=None) -> dict:
         "flops_rule": "transformer_lm",
         "limits": limits or TIGHT}
 
+
+CUT_PUBLISHED = {"n_layer": 8, "n_embd": 64, "n_head": 4, "n_inner": 256,
+                 "vocab_size": 4096, "n_positions": 64}
+CUT_TO_SIZES = {
+    "n_layer": {"sizes": "layers", "kind": "depth"},
+    "n_embd": {"sizes": "hidden", "kind": "width"},
+    "n_head": {"sizes": "heads", "kind": "heads_held"},
+    "n_inner": {"sizes": "mlp_dim", "kind": "width"},
+    "vocab_size": {"sizes": "vocab_size", "kind": "vocab_rows"},
+    "n_positions": {"sizes": "max_seq", "kind": "positions"}}
+
+
+def tiny_cut_config(dtype: str = "float32", limits=None) -> dict:
+    """The causal tiny configuration as one chip's share of a made-up
+    model twice as deep with eight times the vocabulary."""
+    doc = tiny_config("lm", dtype, limits)
+    doc["sizes"]["layers"] = doc["program"]["config_kwargs"]["layers"] = 4
+    doc.update(
+        published=dict(CUT_PUBLISHED),
+        published_to_sizes={k: dict(v) for k, v in CUT_TO_SIZES.items()},
+        reduced=["n_layer", "vocab_size"],
+        deployment={"chips_sharing_a_layer": 8,
+                    "held_here": "an eighth of the vocabulary's rows; the "
+                                 "layers left out lie on further chips"},
+        n_layer=4, vocab_size=512,      # the published keys as they are run
+        flops_rule="tinybench.counts:flops_per_token",
+        kernel_counts="tinybench.counts:two_kinds_of_call")
+    return doc
+
+
+TINY_COUNTS = '''"""The tiny cut configuration's counts, added as a file and found by the
+name its configuration gives."""
+from benchmark import flops, kernel_counts
+
+WINDOW = 16
+
+
+def flops_per_token(sizes, seq, targets_per_row):
+    return flops.transformer_lm(sizes, seq, targets_per_row)
+
+
+def two_kinds_of_call(sizes, mix):
+    """Made up like the published sizes: as if three layers of four
+    attended a causal band of WINDOW keys and the fourth the triangle."""
+    def call(kernel, window):
+        return kernel_counts.flash_call(
+            kernel, mix["batch_per_chip"], sizes["heads"], mix["seq"],
+            sizes["hidden"] // sizes["heads"], True, window=window)
+    return {kernel: [dict(call(kernel, WINDOW), calls=3),
+                     dict(call(kernel, None), calls=1)]
+            for kernel in kernel_counts.KERNELS}
+'''
 
 TINY_MIXES = {
     "mlm_tiny": {"kind": "mlm", "batch_per_chip": 8, "seq": 64,
@@ -60,23 +116,28 @@ def read(run):
 
 
 def write_tiny_benchmark(root, chips=1, dtype="float32", limits=None):
-    """A whole benchmark under ``root``: two configurations, two cells, the
-    repo's per-layer metrics and one new one."""
+    """A whole benchmark under ``root``: three configurations, three
+    cells, the repo's per-layer metrics and one new one."""
     bench = os.path.join(root, "tinybench")
     for sub in ("configs", "traffic", "metrics"):
         os.makedirs(os.path.join(bench, sub))
     for name, mix in TINY_MIXES.items():
         with open(os.path.join(bench, "traffic", name + ".json"), "w") as f:
             json.dump(mix, f)
-    for name, kind in (("tiny_mlm", "mlm"), ("tiny_lm", "lm")):
+    configs = {"tiny_mlm": tiny_config("mlm", dtype, limits),
+               "tiny_lm": tiny_config("lm", dtype, limits),
+               "tiny_cut": tiny_cut_config(dtype, limits)}
+    for name, doc in configs.items():
         with open(os.path.join(bench, "configs", name + ".json"), "w") as f:
-            json.dump(tiny_config(kind, dtype, limits), f)
+            json.dump(doc, f)
+    with open(os.path.join(bench, "counts.py"), "w") as f:
+        f.write(TINY_COUNTS)
     with open(os.path.join(bench, "metrics", "trainer.steps_traced.py"),
               "w") as f:
         f.write(NEW_METRIC)
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         real = json.load(f)
-    cells = ["tiny_mlm_cell", "tiny_lm_cell"]
+    cells = ["tiny_mlm_cell", "tiny_lm_cell", "tiny_cut_cell"]
     per_layer = [dict(m, workloads=cells[:1]) if "workloads" in m else m
                  for m in real["per_layer"]]
     per_layer.append({"name": "trainer.steps_traced", "unit": "steps",
@@ -84,13 +145,15 @@ def write_tiny_benchmark(root, chips=1, dtype="float32", limits=None):
                       "layer": "trainer", "moves": "tokens_per_s_chip"})
     manifest = dict(
         real, paths=["tinybench"],
-        configs=[{"name": n, "source": "test", "reduced": [], "why": "test",
-                  "file": f"tinybench/configs/{n}.json"}
-                 for n in ("tiny_mlm", "tiny_lm")],
+        configs=[{"name": n, "source": "test", "reduced": doc["reduced"],
+                  "why": "test", "file": f"tinybench/configs/{n}.json"}
+                 for n, doc in configs.items()],
         workloads=[
             {"name": cells[0], "config": "tiny_mlm", "traffic": "mlm_tiny",
              "chips": chips, "why": "test"},
             {"name": cells[1], "config": "tiny_lm", "traffic": "lm_tiny",
+             "chips": chips, "why": "test"},
+            {"name": cells[2], "config": "tiny_cut", "traffic": "lm_tiny",
              "chips": chips, "why": "test"}],
         per_layer=per_layer)
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
