@@ -1,0 +1,240 @@
+"""A causal decoder whose layers are a LIST OF KINDS, and the ``afmoe``
+family (arcee-ai Trinity) built on it.
+
+``transformer.py`` is one kind of block under one ``lax.scan``. Here each
+layer is data: whether its attention is a causal band (``window``) or the
+whole triangle, whether it rotates q and k (RoPE) or has no positions,
+and whether its feed-forward is a dense gated-SiLU MLP or the routed
+layer of ``moe.py::routed_ffn`` (experts held here, a shared expert).
+Every layer has four RMSNorms (before and after each half), RMSNorm on
+q and k a head, grouped kv heads, and a sigmoid gate on the attention
+output. The embedding is scaled by sqrt(hidden) and the head is untied.
+
+One chip's SHARE of a model is a configuration like any other: ``heads``
+and ``kv_heads`` are the heads held here, ``vocab_size`` the rows of the
+embedding and of the head held here, ``held`` the experts held here of
+``router_outputs``. Nothing stands in for the chips that hold the rest.
+
+The parameter tree (``init_params``) is a list of per-layer dicts (the
+layers differ in shape, so they are not stacked); the layers run as a
+Python loop, each under ``jax.checkpoint``. The embedding lookup, the
+chunked head and the target convention are ``transformer.py``'s.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+from typing import Optional, Sequence, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ..ops.flash_attention import attention
+from .moe import RoutedConfig, gated_silu, routed_ffn
+from .transformer import _chunked_nll_sum, embed_lookup
+
+KINDS = ("dense_sliding", "dense_full", "moe_sliding", "moe_full")
+
+
+@dataclasses.dataclass(frozen=True)
+class DecoderConfig:
+    vocab_size: int
+    hidden: int
+    heads: int                    # query heads held here
+    kv_heads: int                 # kv heads held here
+    head_dim: int
+    mlp_dim: int                  # a dense layer's feed-forward width
+    layer_kinds: Tuple[str, ...]  # one of KINDS a layer
+    window: int                   # keys a ``*_sliding`` layer attends
+    moe_dim: int = 0              # an expert's width
+    shared_experts: int = 0       # shared experts, each of moe_dim
+    routed: Optional[RoutedConfig] = None
+    max_seq: int = 1 << 17        # positions RoPE is defined for
+    rope_theta: float = 10000.0
+    norm_eps: float = 1e-5
+    dtype: str = "bfloat16"       # compute dtype (params stay fp32)
+    remat: bool = True            # checkpoint each layer
+    lm_head_chunk: int = 0        # >0: the head a chunk of positions at
+    # a time (transformer._chunked_nll_sum); 0: all positions at once
+
+    def __post_init__(self):
+        bad = [k for k in self.layer_kinds if k not in KINDS]
+        if bad:
+            raise ValueError(f"layer kinds {bad} are none of {KINDS}")
+        if self.heads % self.kv_heads:
+            raise ValueError(f"{self.heads} heads over {self.kv_heads} kv")
+        if any(k.startswith("moe") for k in self.layer_kinds) and (
+                self.routed is None or not self.moe_dim):
+            raise ValueError("a routed layer needs `routed` and `moe_dim`")
+
+
+def afmoe_config(vocab_size, hidden, heads, kv_heads, head_dim, mlp_dim,
+                 moe_dim, layer_kinds: Sequence[str], window, top_k,
+                 router_outputs, held: Sequence[int], shared_experts=1,
+                 route_scale=1.0, max_seq=1 << 17, rope_theta=10000.0,
+                 norm_eps=1e-5, balanced=False, routed_kw=None,
+                 **kw) -> DecoderConfig:
+    """The afmoe family (arcee-ai Trinity) from its sizes, as the
+    benchmark's configuration gives them (``benchmark/configs``).
+    ``balanced``: the routed layers choose on standardised outputs
+    (``moe.route``). ``routed_kw``: further fields of the routed layers' ``RoutedConfig``
+    (the tests' row tile and kernel choice); further keywords (``dtype``,
+    ``lm_head_chunk``, ...) are DecoderConfig's."""
+    return DecoderConfig(
+        vocab_size=vocab_size, hidden=hidden, heads=heads, kv_heads=kv_heads,
+        head_dim=head_dim, mlp_dim=mlp_dim, moe_dim=moe_dim,
+        layer_kinds=tuple(layer_kinds), window=window,
+        shared_experts=shared_experts, max_seq=max_seq,
+        rope_theta=rope_theta, norm_eps=norm_eps,
+        routed=RoutedConfig(router_outputs, tuple(held), top_k, route_scale,
+                            balanced=balanced, **(routed_kw or {})),
+        **kw)
+
+
+def afmoe_tiny(**kw) -> DecoderConfig:
+    """Test-sized: every kind of layer, 2 query heads a kv head, a window
+    shorter than the sequence, 4 of 8 experts held."""
+    sizes = dict(vocab_size=128, hidden=64, heads=4, kv_heads=2, head_dim=16,
+                 mlp_dim=96, moe_dim=32, window=8, top_k=2, router_outputs=8,
+                 held=(0, 1, 2, 3), route_scale=2.0,
+                 routed_kw={"row_tile": 8},
+                 layer_kinds=("dense_sliding", "moe_sliding", "moe_full"),
+                 dtype="float32", remat=False)
+    return afmoe_config(**{**sizes, **kw})
+
+
+# ----------------------------------------------------------------- params
+
+def init_params(rng, cfg: DecoderConfig):
+    """The parameter tree: N(0, 0.02) matrices, unit norm scales, fp32."""
+    h, d = cfg.hidden, cfg.head_dim
+    keys = iter(jax.random.split(rng, 16 * len(cfg.layer_kinds) + 2))
+
+    def normal(*shape):
+        return jax.random.normal(next(keys), shape, jnp.float32) * 0.02
+
+    def mlp(width, *lead):
+        return {"gate_up": normal(*lead, h, 2 * width),
+                "down": normal(*lead, width, h)}
+
+    def layer(kind):
+        attn = {"norm_in": jnp.ones((h,)), "q": normal(h, cfg.heads, d),
+                "k": normal(h, cfg.kv_heads, d),
+                "v": normal(h, cfg.kv_heads, d),
+                "gate": normal(h, cfg.heads, d), "q_norm": jnp.ones((d,)),
+                "k_norm": jnp.ones((d,)), "o": normal(cfg.heads, d, h),
+                "norm_post": jnp.ones((h,))}
+        ffn = {"norm_pre": jnp.ones((h,)), "norm_post": jnp.ones((h,))}
+        if kind.startswith("dense"):
+            ffn.update(mlp(cfg.mlp_dim))
+        else:
+            ffn["router"] = normal(h, cfg.routed.num_experts)
+            ffn["experts"] = mlp(cfg.moe_dim, len(cfg.routed.held))
+            if cfg.shared_experts:
+                ffn["shared"] = mlp(cfg.shared_experts * cfg.moe_dim)
+        return {"attn": attn, "ffn": ffn}
+
+    return {"embed": normal(cfg.vocab_size, h),
+            "layers": [layer(kind) for kind in cfg.layer_kinds],
+            "final_norm": jnp.ones((h,)),
+            "head": normal(cfg.vocab_size, h)}
+
+
+# ----------------------------------------------------------------- layers
+
+def rmsnorm(x, scale, eps):
+    x32 = x.astype(jnp.float32)
+    out = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
+    return (out * scale).astype(x.dtype)
+
+
+def rope(x, theta: float):
+    """Rotary positions on [b, s, heads, d], position = row of ``s``, the
+    halves of ``d`` paired (i, i + d/2) as the published code pairs them."""
+    half = x.shape[-1] // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * freq
+    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    x32 = x.astype(jnp.float32)
+    a, b = x32[..., :half], x32[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           -1).astype(x.dtype)
+
+
+def _attention_half(x, blk, cfg: DecoderConfig, sliding: bool):
+    dt = x.dtype
+    a = rmsnorm(x, blk["norm_in"], cfg.norm_eps)
+    q = jnp.einsum("bsh,hnd->bsnd", a, blk["q"].astype(dt))
+    k = jnp.einsum("bsh,hnd->bsnd", a, blk["k"].astype(dt))
+    v = jnp.einsum("bsh,hnd->bsnd", a, blk["v"].astype(dt))
+    gate = jnp.einsum("bsh,hnd->bsnd", a, blk["gate"].astype(dt))
+    q = rmsnorm(q, blk["q_norm"], cfg.norm_eps)
+    k = rmsnorm(k, blk["k_norm"], cfg.norm_eps)
+    if sliding:         # rotary positions on the window layers only
+        q, k = rope(q, cfg.rope_theta), rope(k, cfg.rope_theta)
+    out = attention(q, k, v, causal=True,
+                    window=cfg.window if sliding else None)
+    out = jnp.einsum("bsnd,ndh->bsh", out * jax.nn.sigmoid(gate),
+                     blk["o"].astype(dt))
+    return rmsnorm(out, blk["norm_post"], cfg.norm_eps)
+
+
+def _ffn_half(x, blk, cfg: DecoderConfig, routed: bool):
+    dt = x.dtype
+    f = rmsnorm(x, blk["norm_pre"], cfg.norm_eps)
+    if routed:
+        b, s, h = f.shape
+        m = routed_ffn(f.reshape(b * s, h), blk, cfg.routed,
+                       sequences=b).reshape(b, s, h)
+    else:
+        m = gated_silu(f @ blk["gate_up"].astype(dt)) @ blk["down"].astype(dt)
+    return rmsnorm(m, blk["norm_post"], cfg.norm_eps)
+
+
+def _layer(x, blk, cfg: DecoderConfig, kind: str):
+    with jax.named_scope("bps.attn"):
+        x = x + _attention_half(x, blk["attn"], cfg,
+                                kind.endswith("sliding"))
+    with jax.named_scope("bps.mlp"):
+        return x + _ffn_half(x, blk["ffn"], cfg, kind.startswith("moe"))
+
+
+def apply(params, cfg: DecoderConfig, tokens) -> jnp.ndarray:
+    """Forward to the final hidden states [b, s, hidden], normed."""
+    dt = jnp.dtype(cfg.dtype)
+    if tokens.shape[1] > cfg.max_seq:
+        raise ValueError(f"{tokens.shape[1]} positions, the model has "
+                         f"{cfg.max_seq}")
+    with jax.named_scope("bps.embed"):
+        x = (embed_lookup(params["embed"], tokens)
+             * math.sqrt(cfg.hidden)).astype(dt)
+    for kind, blk in zip(cfg.layer_kinds, params["layers"]):
+        layer = functools.partial(_layer, cfg=cfg, kind=kind)
+        if cfg.remat:
+            layer = jax.checkpoint(layer)
+        x = layer(x, blk)
+    with jax.named_scope("bps.head"):    # the final norm feeds the head
+        return rmsnorm(x, params["final_norm"], cfg.norm_eps)
+
+
+def causal_lm_loss(params, cfg: DecoderConfig, batch) -> jnp.ndarray:
+    """batch = tokens [b, s]; mean negative log-likelihood of the next
+    token in fp32. The full sequence is the input and the last target is
+    masked (``gpt2.causal_lm_loss``'s convention: s stays a multiple of
+    128 for the kernels)."""
+    tokens = batch
+    with jax.named_scope("bps.head"):
+        targets = jnp.concatenate(
+            [tokens[:, 1:],
+             jnp.full((tokens.shape[0], 1), -1, tokens.dtype)], axis=1)
+    h = apply(params, cfg, tokens)
+    mask = targets >= 0
+    s = h.shape[1]
+    chunk = cfg.lm_head_chunk
+    if not (chunk and s > chunk and s % chunk == 0):
+        chunk = s
+    nll_sum = _chunked_nll_sum(h, params["head"], targets, mask, chunk,
+                               jnp.dtype(cfg.dtype))
+    return nll_sum / jnp.maximum(mask.sum().astype(jnp.float32), 1.0)

@@ -18,6 +18,15 @@ Absent"). TPU-first design:
 
 References (public techniques): GShard (Lepikhin et al. 2020), Switch
 Transformer (Fedus et al. 2021).
+
+Beside it, ``routed_ffn``: routing WITHOUT drops over the experts THIS
+chip holds of a layer that is shared by several chips (the expert
+layer of ``models/decoder.py``; docs/routed-experts.md). It scores and
+chooses over all the router's outputs, sorts the rows chosen for held
+experts into per-expert runs of whole row tiles (static shapes sized
+for the worst routing, no capacity), runs the grouped products over the
+runs that are there (``ops/grouped_matmul.py``) and gathers the weighted
+results back. What the experts held elsewhere would add is left out.
 """
 
 from __future__ import annotations
@@ -30,6 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..ops.grouped_matmul import grouped_matmul
 from .transformer import (TransformerConfig, _attention, _layernorm,
                           embed_lookup)
 
@@ -233,3 +243,203 @@ def moe_tiny(**kw) -> MoEConfig:
     return MoEConfig(vocab_size=128, hidden=64, layers=2, heads=4,
                      mlp_dim=128, max_seq=64, causal=False, dtype="float32",
                      remat=False, num_experts=4, top_k=2, **kw)
+
+
+# ------------------------------------------- routing without drops (afmoe)
+
+@dataclasses.dataclass(frozen=True)
+class RoutedConfig:
+    """A routed feed-forward layer as one chip sees it."""
+    num_experts: int              # the router's outputs: the whole layer's
+    held: Tuple[int, ...]         # the experts held here, by router output
+    top_k: int
+    route_scale: float = 1.0      # on the weights, normalised over the k
+    row_tile: int = 512           # rows a tile of the grouped products
+    impl: str = "auto"            # ops.grouped_matmul.grouped_matmul's
+    balanced: bool = False        # choose on standardised outputs (route)
+
+    def __post_init__(self):
+        held = tuple(self.held)
+        if len(set(held)) != len(held) or not held or not all(
+                0 <= e < self.num_experts for e in held):
+            raise ValueError(f"held experts {held} are not distinct outputs "
+                             f"of a router with {self.num_experts}")
+        if not 1 <= self.top_k <= self.num_experts:
+            raise ValueError(f"top_k {self.top_k} of {self.num_experts}")
+
+    @property
+    def rows(self):
+        """Most rows a token can send to the experts held here."""
+        return min(self.top_k, len(self.held))
+
+
+def gated_silu(h):
+    """``silu(gate) * up`` of a fused [.., 2 m] gate|up projection."""
+    m = h.shape[-1] // 2
+    return jax.nn.silu(h[..., :m]) * h[..., m:]
+
+
+def route(f, router_w, cfg: RoutedConfig, sequences: int = 1):
+    """(weights [T, k] fp32, experts [T, k] int32): sigmoid scores over
+    ALL the router's outputs in fp32, the k largest, their scores
+    normalised over the k and scaled.
+
+    With ``cfg.balanced`` the k are the largest of the router's outputs
+    STANDARDISED an expert over the tokens of a sequence (``f`` is
+    ``sequences`` of them, one after another), ``(l - mean_t l) /
+    deviation_t l`` with ``l`` the output before its sigmoid, so that
+    every expert is chosen about equally often whatever the router's
+    weights; the weights are still the chosen experts' own scores. It is
+    what the published model's selection bias is there for (the k largest
+    of ``s + b``, ``b`` moved a little a step toward equal counts by the
+    training framework), done on the batch at hand and without state."""
+    logits = jnp.dot(f.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    if not cfg.balanced:
+        top, experts = jax.lax.top_k(scores, cfg.top_k)
+    else:
+        by_seq = jax.lax.stop_gradient(logits).reshape(
+            sequences, -1, logits.shape[-1])
+        centred = by_seq - by_seq.mean(1, keepdims=True)
+        centred *= jax.lax.rsqrt(
+            jnp.mean(centred * centred, 1, keepdims=True) + 1e-12)
+        _, experts = jax.lax.top_k(centred.reshape(logits.shape), cfg.top_k)
+        top = jnp.take_along_axis(scores, experts, axis=-1)
+    return cfg.route_scale * top / top.sum(-1, keepdims=True), experts
+
+
+def plan_rows(experts, cfg: RoutedConfig):
+    """Where each chosen (token, expert) pair's row goes. The pairs whose
+    expert is held are sorted by expert; expert g's run starts on a tile
+    border and is padded to whole tiles (at least one, so that every
+    expert's weight gradient is written). Static sizes, for the worst
+    routing: ``buffer`` rows. Returns a dict of int32 arrays:
+
+    ``dest`` [T, k]: the pair's row, or ``buffer`` (none: not held);
+    ``row_pair`` [buffer]: the row's pair t * k + j, or T * k (a pad row),
+    and ``row_token`` [buffer]: that pair's token t, or T;
+    ``tile_group`` [tiles], ``num_tiles`` [1], ``group_rows`` [held]: what
+    ``grouped_matmul`` reads; ``counts`` [held]: rows routed to each."""
+    t, k = experts.shape
+    held, tile = len(cfg.held), cfg.row_tile
+    pairs = t * k
+    tiles = -(-t * cfg.rows // tile) + held
+    slot = jnp.full((cfg.num_experts,), held, jnp.int32).at[
+        jnp.asarray(cfg.held)].set(jnp.arange(held, dtype=jnp.int32))
+    local = slot[experts.reshape(-1)]                   # [pairs], held: none
+    order = jnp.argsort(local, stable=True).astype(jnp.int32)
+    rank = jnp.zeros((pairs,), jnp.int32).at[order].set(
+        jnp.arange(pairs, dtype=jnp.int32))
+    counts = (local[:, None] == jnp.arange(held)).sum(0, dtype=jnp.int32)
+    start = jnp.cumsum(counts) - counts                 # in the sorted pairs
+    padded = jnp.maximum(-(-counts // tile), 1) * tile
+    run = jnp.cumsum(padded) - padded                   # in the buffer
+    g = jnp.minimum(local, held - 1)
+    dest = jnp.where(local < held, run[g] + rank - start[g], tiles * tile)
+    tile_group = jnp.clip(jnp.searchsorted(
+        run, jnp.arange(tiles, dtype=jnp.int32) * tile, side="right") - 1,
+        0, held - 1).astype(jnp.int32)
+    row = jnp.arange(tiles * tile, dtype=jnp.int32)
+    rg = tile_group[row // tile]
+    at = row - run[rg]
+    row_pair = jnp.where(at < counts[rg],
+                         order[jnp.clip(start[rg] + at, 0, pairs - 1)], pairs)
+    return {"dest": dest.reshape(t, k), "row_pair": row_pair,
+            "row_token": jnp.where(row_pair < pairs, row_pair // k, t),
+            "tile_group": tile_group,
+            "num_tiles": (padded.sum() // tile).astype(jnp.int32)[None],
+            "group_rows": padded, "counts": counts}
+
+
+def _take(x, index):
+    """Rows of ``x`` by ``index``; zeros where the index is past the end."""
+    return jnp.take(x, index, axis=0, mode="fill", fill_value=0)
+
+
+# Dispatch and combine are each other's transposes, and both are written
+# as GATHERS (rows by token, tokens by row): autodiff's transpose of a
+# gather is a scatter-add, which serialises on the TPU.
+
+@jax.custom_vjp
+def _dispatch(x, row_token, dest):
+    """[buffer, h]: token ``row_token[r]``'s row of ``x``, zeros in pad
+    rows."""
+    return _take(x, row_token)
+
+
+def _dispatch_fwd(x, row_token, dest):
+    return _take(x, row_token), dest
+
+
+def _dispatch_bwd(dest, d_rows):
+    return _take(d_rows, dest.reshape(-1)).reshape(
+        dest.shape + d_rows.shape[1:]).sum(1), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(y, weights, row_pair, row_token, dest):
+    """[T, h]: sum over a token's pairs of weight * the pair's row of
+    ``y``; a pair with no row here (its expert is not held) adds zero."""
+    rows = _take(y, dest.reshape(-1)).reshape(dest.shape + y.shape[1:])
+    return jnp.einsum("tkh,tk->th", rows, weights.astype(y.dtype))
+
+
+def _combine_fwd(y, weights, row_pair, row_token, dest):
+    return (_combine(y, weights, row_pair, row_token, dest),
+            (y, weights, row_pair, row_token, dest))
+
+
+def _combine_bwd(res, d_out):
+    y, weights, row_pair, row_token, dest = res
+    row_weight = _take(weights.reshape(-1), row_pair)
+    d_y = _take(d_out, row_token) * row_weight[:, None].astype(d_out.dtype)
+    rows = _take(y, dest.reshape(-1)).reshape(dest.shape + y.shape[1:])
+    d_w = jnp.einsum("tkh,th->tk", rows, d_out,
+                     preferred_element_type=jnp.float32)
+    return d_y, d_w.astype(weights.dtype), None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def routed_ffn(f, blk, cfg: RoutedConfig, sequences: int = 1):
+    """The routed feed-forward of one layer over flattened tokens
+    ``f`` [T, h] -> [T, h] (``sequences`` of them end to end: what a
+    balanced choice is balanced over, see ``route``):
+    ``shared(f) + sum over the token's chosen
+    experts that are held here of weight * expert(f)``, every expert a
+    gated-SiLU MLP. ``blk``: ``router`` [h, num_experts]; ``experts``
+    ``gate_up`` [held, h, 2 m], ``down`` [held, m, h]; optional ``shared``
+    ``gate_up`` [h, 2 ms], ``down`` [ms, h].
+
+    No row is dropped, whatever the imbalance: the buffer of rows is sized
+    for the worst routing. Only the grouped products skip the part of it
+    that holds no row. The gathers do not: those by row walk the whole
+    buffer and those by pair all ``T * top_k`` chosen pairs, held or not
+    (most of the layer's device time: PERF.md section 5)."""
+    dt = f.dtype
+    with jax.named_scope("bps.moe"):
+        with jax.named_scope("bps.moe.route"):
+            weights, experts = route(f, blk["router"], cfg, sequences)
+            plan = plan_rows(experts, cfg)
+            rows = _dispatch(f, plan["row_token"], plan["dest"])
+        with jax.named_scope("bps.moe.experts"):
+            def product(lhs, w):
+                return grouped_matmul(
+                    lhs, w.astype(dt), plan["tile_group"], plan["num_tiles"],
+                    plan["group_rows"], cfg.row_tile, cfg.impl)
+            y = product(gated_silu(product(rows, blk["experts"]["gate_up"])),
+                        blk["experts"]["down"])
+        with jax.named_scope("bps.moe.route"):
+            out = _combine(y, weights, plan["row_pair"], plan["row_token"],
+                           plan["dest"])
+        if "shared" in blk:
+            with jax.named_scope("bps.moe.shared"):
+                out = out + gated_silu(
+                    f @ blk["shared"]["gate_up"].astype(dt)
+                ) @ blk["shared"]["down"].astype(dt)
+    return out

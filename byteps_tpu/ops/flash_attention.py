@@ -52,6 +52,61 @@ def _pick_block(s: int, want: int) -> int:
     return s
 
 
+# ---- a causal band (``window``) and grouped kv heads ----
+# ``window`` = w: query i sees key j where 0 <= i - j < w. A q block then
+# meets only the kv blocks its band touches, and the grid's kv dimension
+# is that many steps long, not sk // bk: the step's kv block is
+# ``_band_lo_k(q block) + step``, skipped where it passes the diagonal
+# (the index maps clamp it there, so a skipped step moves nothing). The
+# dk/dv kernel walks the q blocks of a kv block's band the same way.
+# Grouped kv heads (``group`` = query heads a kv head): q, out, do and
+# the statistics are FOLDED, [b, hq, s, ..] -> [b, hkv, group * s, ..],
+# a free reshape, so that a kv head's queries are consecutive q blocks
+# of one kernel row: block ``i`` of the fold is position block
+# ``i % nq``; k and v are read as they are, never repeated, and the
+# dk/dv kernel's carried loop over the fold's q blocks IS the sum over
+# the group. With no window and group 1 every kernel traces as before.
+
+def _band_lo_k(qb, bq, bk, window):
+    """First kv block that q block ``qb``'s band touches."""
+    return jnp.maximum(qb * bq - (window - 1), 0) // bk
+
+
+def _band_steps_k(nq, bq, bk, window):
+    """kv steps a q block needs at most (static)."""
+    return max((i * bq + bq - 1) // bk - max(i * bq - (window - 1), 0) // bk
+               + 1 for i in range(nq))
+
+
+def _band_steps_q(nq, nk, bq, bk, window):
+    """q steps a kv block needs at most (static): from its own diagonal
+    block to the last q block whose band still reaches it."""
+    return max(min(nq - 1, (i * bk + bk + window - 2) // bq)
+               - (i * bk) // bq + 1 for i in range(nk))
+
+
+def _visible(rows, cols, window):
+    """The causal mask, cut to a band of ``window`` keys where given."""
+    if window is None:
+        return rows >= cols
+    return jnp.logical_and(rows >= cols, rows - cols < window)
+
+
+def _fold(x, group):
+    """[b, hq, s, ...] -> [b, hq // group, group * s, ...]."""
+    if group == 1:
+        return x
+    b, h, s = x.shape[:3]
+    return x.reshape((b, h // group, group * s) + x.shape[3:])
+
+
+def _unfold(x, group):
+    if group == 1:
+        return x
+    b, hk, gs = x.shape[:3]
+    return x.reshape((b, hk * group, gs // group) + x.shape[3:])
+
+
 # ---- row statistics (lse, delta): lane-dense across HBM ----
 # Outside the kernel bodies a row statistic is [b, h, s] fp32. Across a
 # pallas_call boundary it is [b, h, 1, s] in blocks of (1, ht, 1, bq):
@@ -219,7 +274,9 @@ def _head_tile(h: int, nq: int, nk: int, bq: int, bk: int, d: int,
 # --------------------------------------------------------------- forward
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, bq, bk, nk,
-                ht, has_bias=False, rel=None):
+                ht, has_bias=False, rel=None, window=None, group=1, nqh=0):
+    """``nk`` is the kv dimension's length in grid steps; ``nqh`` the q
+    blocks a head has (read only where the q axis is folded)."""
     bias_ref = rel_ref = None
     if has_bias:
         bias_ref, o_ref, lse_ref, acc, m_scr, l_scr = rest
@@ -227,12 +284,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, bq, bk, nk,
         rel_ref, o_ref, lse_ref, acc, m_scr, l_scr = rest
     else:
         o_ref, lse_ref, acc, m_scr, l_scr = rest
-    kb = pl.program_id(3)
+    ik = kb = pl.program_id(3)
     qb = pl.program_id(2)
     ih = pl.program_id(1)     # evaluated OUTSIDE pl.when: the traced
                               # cond body can't introduce program_id
+    if group > 1:
+        qb = qb % nqh
+    if window is not None:
+        kb = _band_lo_k(qb, bq, bk, window) + ik
 
-    @pl.when(kb == 0)
+    @pl.when(ik == 0)
     def _init():
         acc[...] = jnp.zeros_like(acc)
         m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
@@ -269,11 +330,17 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, bq, bk, nk,
                     jnp.int32, (bq, bk), 0)
                 cols = kb * bk + jax.lax.broadcasted_iota(
                     jnp.int32, (bq, bk), 1)
-                s = jnp.where(rows >= cols, s, _NEG_INF)
+                visible = _visible(rows, cols, window)
+                s = jnp.where(visible, s, _NEG_INF)
             r = slice(t * bq, (t + 1) * bq)
             m_prev = m_scr[r, :1]                             # [bq, 1]
             m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
             p = jnp.exp(s - m_new)
+            if window is not None:
+                # a row past the band's lower edge sees nothing of the
+                # band's first block: m_new is still the mask's value
+                # there and exp(0) is not a probability
+                p = jnp.where(visible, p, 0.0)
             alpha = jnp.exp(m_prev - m_new)
             l_new = l_scr[r, :1] * alpha + jnp.sum(p, -1, keepdims=True)
             pv = jax.lax.dot_general(
@@ -283,7 +350,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, bq, bk, nk,
             m_scr[r] = jnp.broadcast_to(m_new, (bq, 128))
             l_scr[r] = jnp.broadcast_to(l_new, (bq, 128))
 
-    @pl.when(kb == nk - 1)
+    @pl.when(ik == nk - 1)
     def _finish():
         for t in range(ht):
             r = slice(t * bq, (t + 1) * bq)
@@ -293,7 +360,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, bq, bk, nk,
 
 
 def _fwd_single_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
-                       causal, bq, bk, nq, ht):
+                       causal, bq, bk, nq, ht, window=None, group=1):
     """Forward when ONE kv block holds every key of the row (nk == 1;
     caller guarantees no bias/rel_table): a plain softmax a q row, no
     state carried from grid step to grid step. The online form's round
@@ -308,6 +375,8 @@ def _fwd_single_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
     the static triangle (seq 1024: 3 of 4 [512, 512] squares, as block
     skipping did over a grid of 4 steps)."""
     qb = pl.program_id(2)
+    if group > 1:             # folded q axis: ``nq`` blocks a head
+        qb = qb % nq
     triangle = causal and nq == 1
     rc = _pick_block(bq, 512) if triangle else bq
     for r0 in range(0, bq, rc):
@@ -316,7 +385,7 @@ def _fwd_single_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
             rows = qb * bq + r0 + jax.lax.broadcasted_iota(
                 jnp.int32, (rc, keys), 0)
             cols = jax.lax.broadcasted_iota(jnp.int32, (rc, keys), 1)
-            visible = rows >= cols
+            visible = _visible(rows, cols, window)
         for t in range(ht):                  # heads per program (see
             q = q_ref[0, t, r0:r0 + rc]      # _fwd_kernel)   [rc, d]
             k = k_ref[0, t, :keys]                          # [keys, d]
@@ -337,39 +406,47 @@ def _fwd_single_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
 
 
 def _flash_fwd(q, k, v, causal, scale, bq, bk, interpret, out_dtype=None,
-               bias=None, rel_table=None, rel=None):
-    """q: [b, h, sq, d]; k,v: [b, h, sk, d] → (out [b,h,sq,d],
+               bias=None, rel_table=None, rel=None, window=None):
+    """q: [b, h, sq, d]; k,v: [b, hkv, sk, d] → (out [b,h,sq,d],
     lse [b,h,sq] fp32). sq and sk may DIFFER (cross-attention: the
     decoder's queries over the encoder's keys) — the kernels only ever
-    see (bq, bk) blocks, so the tiling contract is per-axis.
+    see (bq, bk) blocks, so the tiling contract is per-axis. ``hkv`` may
+    divide ``h`` (grouped kv heads) and ``window`` cut the causal
+    triangle to a band (see the note at the top).
 
     out_dtype overrides the output dtype (default q.dtype) — ring
     attention requests fp32 partials so the per-step LSE combine does
     not accumulate one bf16 rounding per ring step."""
-    b, h, sq, d = q.shape
-    sk = k.shape[2]
-    nq, nk = sq // bq, sk // bk
-    ht = _head_tile(h, nq, nk, bq, bk, d, interpret,
+    b, hq, sq, d = q.shape
+    h, sk = k.shape[1], k.shape[2]
+    group = hq // h
+    q = _fold(q, group)
+    nq, nk = sq // bq, sk // bk           # blocks a head
+    steps = nk if window is None else _band_steps_k(nq, bq, bk, window)
+    ht = _head_tile(h, group * nq, nk, bq, bk, d, interpret,
                     mats=3 if rel is not None else 1)
     if rel is not None:
         ht = _clamp_ht(ht, h)   # matches the bwd dtable tile bound
-    grid = (b, h // ht, nq, nk)
+    grid = (b, h // ht, group * nq, steps)
     has_bias = bias is not None
     if nk == 1 and not has_bias and rel is None:
         kernel = functools.partial(_fwd_single_kernel, scale=scale,
-                                   causal=causal, bq=bq, bk=bk, nq=nq, ht=ht)
+                                   causal=causal, bq=bq, bk=bk, nq=nq, ht=ht,
+                                   **_band_args(window, group))
         scratch = []
     else:
         kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                                   bq=bq, bk=bk, nk=nk, ht=ht,
-                                   has_bias=has_bias, rel=rel)
+                                   bq=bq, bk=bk, nk=steps, ht=ht,
+                                   has_bias=has_bias, rel=rel,
+                                   **_band_args(window, group, nqh=nq))
         scratch = [pltpu.VMEM((ht * bq, d), jnp.float32),
                    pltpu.VMEM((ht * bq, 128), jnp.float32),
                    pltpu.VMEM((ht * bq, 128), jnp.float32)]
+    kv_spec = _kv_spec(ht, bq, bk, d, nq, group,
+                       None if nk == 1 else window)
     in_specs = [
         pl.BlockSpec((1, ht, bq, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-        pl.BlockSpec((1, ht, bk, d), lambda ib, ih, iq, ik: (ib, ih, ik, 0)),
-        pl.BlockSpec((1, ht, bk, d), lambda ib, ih, iq, ik: (ib, ih, ik, 0)),
+        kv_spec, kv_spec,
     ]
     inputs = [q, k, v]
     if has_bias:
@@ -389,19 +466,43 @@ def _flash_fwd(q, k, v, causal, scale, bq, bk, interpret, out_dtype=None,
             pl.BlockSpec((1, ht, 1, bq), lambda ib, ih, iq, ik: (ib, ih, 0, iq)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, sq, d), out_dtype or q.dtype),
-            jax.ShapeDtypeStruct((b, h, 1, sq), jnp.float32),
+            jax.ShapeDtypeStruct(q.shape, out_dtype or q.dtype),
+            jax.ShapeDtypeStruct((b, h, 1, q.shape[2]), jnp.float32),
         ],
         scratch_shapes=scratch,
         compiler_params=_DIM_SEMANTICS,
         interpret=interpret,
         name="bps_flash_fwd",
     )(*inputs)
-    return out, lse[:, :, 0]
+    return _unfold(out, group), _unfold(lse[:, :, 0], group)
+
+
+def _kv_spec(ht, bq, bk, d, nq, group, window):
+    """k's and v's BlockSpec on a (b, heads, q blocks, kv steps) grid: the
+    step's own block, or under a band the block the step stands for,
+    held at the q block's diagonal once the band has passed it."""
+    if window is None:
+        return pl.BlockSpec((1, ht, bk, d),
+                            lambda ib, ih, iq, ik: (ib, ih, ik, 0))
+
+    def kv_block(ib, ih, iq, ik):
+        qb = iq % nq if group > 1 else iq
+        return (ib, ih, jnp.minimum(_band_lo_k(qb, bq, bk, window) + ik,
+                                    (qb * bq + bq - 1) // bk), 0)
+    return pl.BlockSpec((1, ht, bk, d), kv_block)
+
+
+def _band_args(window, group, **more):
+    """The kernels' static arguments for a band or a fold; none at all
+    where there is neither, so that such a call traces as it always did."""
+    if window is None and group == 1:
+        return {}
+    return dict(window=window, group=group, **more)
 
 
 @jax.named_scope("bps_attn_xla")
-def _xla_fwd(qt, kt, vt, causal, scale, out_dtype=None, bias=None):
+def _xla_fwd(qt, kt, vt, causal, scale, out_dtype=None, bias=None,
+             window=None):
     """[b,h,s,d] → (out, lse [b,h,s] fp32) with plain XLA ops.
 
     At moderate sequence lengths the XLA-fused softmax-attention forward
@@ -409,29 +510,37 @@ def _xla_fwd(qt, kt, vt, causal, scale, out_dtype=None, bias=None):
     261→239 ms — the [s,s] scores fit HBM easily and XLA's fusion wins),
     while the flash BACKWARD kernels still beat XLA's backward (which
     must materialize softmax gradients). The hybrid uses this forward +
-    the same (out, lse) residual contract the Pallas backward needs."""
+    the same (out, lse) residual contract the Pallas backward needs.
+    Grouped kv heads: the queries are folded as the kernels fold them."""
+    group, sq = qt.shape[1] // kt.shape[1], qt.shape[2]
+    qt = _fold(qt, group)
     s = jax.lax.dot_general(qt, kt, (((3,), (3,)), ((0, 1), (0, 1))),
                             preferred_element_type=jnp.float32) * scale
     if bias is not None:
         s = s + bias[None].astype(jnp.float32)
     if causal:
         rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        if group > 1:                     # a fold's row: position row % sq
+            rows = rows % sq
         cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 3)
-        s = jnp.where(rows >= cols, s, _NEG_INF)
+        s = jnp.where(_visible(rows, cols, window), s, _NEG_INF)
     m = jnp.max(s, -1)
     p = jnp.exp(s - m[..., None])
     l = jnp.sum(p, -1)
     out = jax.lax.dot_general((p / l[..., None]).astype(vt.dtype), vt,
                               (((3,), (2,)), ((0, 1), (0, 1))),
                               preferred_element_type=jnp.float32)
-    return out.astype(out_dtype or qt.dtype), m + jnp.log(l)
+    return (_unfold(out.astype(out_dtype or qt.dtype), group),
+            _unfold(m + jnp.log(l), group))
 
 
 # -------------------------------------------------------------- backward
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                scale, causal, bq, bk, nk, ht, has_bias=False, rel=None,
-               nq=0):
+               nq=0, window=None, group=1, nqh=0):
+    """``nk``: the kv dimension's grid steps; ``nqh``: q blocks a head
+    (folded q axis), as in ``_fwd_kernel``."""
     bias_ref = dbias_ref = rel_ref = dt_ref = dt_scr = None
     if has_bias:
         bias_ref, dq_ref, dbias_ref, dq_acc = rest
@@ -439,11 +548,15 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         rel_ref, dq_ref, dt_ref, dq_acc, dt_scr = rest
     else:
         dq_ref, dq_acc = rest
-    kb = pl.program_id(3)
+    ik = kb = pl.program_id(3)
     qb = pl.program_id(2)
     ih = pl.program_id(1)     # outside pl.when (see _fwd_kernel)
+    if group > 1:
+        qb = qb % nqh
+    if window is not None:
+        kb = _band_lo_k(qb, bq, bk, window) + ik
 
-    @pl.when(kb == 0)
+    @pl.when(ik == 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
@@ -489,7 +602,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                     jnp.int32, (bq, bk), 0)
                 cols = kb * bk + jax.lax.broadcasted_iota(
                     jnp.int32, (bq, bk), 1)
-                p = jnp.where(rows >= cols, p, 0.0)
+                p = jnp.where(_visible(rows, cols, window), p, 0.0)
             dp = jax.lax.dot_general(
                 do, v, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)         # [bq, bk]
@@ -504,7 +617,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                 ds, k, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
 
-    @pl.when(kb == nk - 1)
+    @pl.when(ik == nk - 1)
     def _finish():
         for t in range(ht):
             dq_ref[0, t] = dq_acc[t * bq:(t + 1) * bq].astype(dq_ref.dtype)
@@ -516,7 +629,11 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-                scale, causal, bq, bk, nq, ht, has_bias=False, rel=None):
+                scale, causal, bq, bk, nq, ht, has_bias=False, rel=None,
+                window=None, group=1, nqh=0, steps=0):
+    """``nq``: the q dimension's grid steps, ``group * steps`` of them
+    where the q axis is folded or banded: ``steps`` a head, over its
+    ``nqh`` q blocks or the part of them in the kv block's band."""
     bias_ref = rel_ref = None
     if has_bias:
         bias_ref, dk_ref, dv_ref, dk_acc, dv_acc = rest
@@ -524,16 +641,23 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         rel_ref, dk_ref, dv_ref, dk_acc, dv_acc = rest
     else:
         dk_ref, dv_ref, dk_acc, dv_acc = rest
-    qb = pl.program_id(3)
+    iq = qb = pl.program_id(3)
     kb = pl.program_id(2)
     ih = pl.program_id(1)     # outside pl.when (see _fwd_kernel)
+    if group > 1:
+        qb = qb % steps
+    if window is not None:
+        qb = (kb * bk) // bq + qb
 
-    @pl.when(qb == 0)
+    @pl.when(iq == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
     run = True if not causal else (kb * bk <= qb * bq + bq - 1)
+    if window is not None:    # inside the head, and the band reaches kb
+        run = jnp.logical_and(run, jnp.logical_and(
+            qb < nqh, qb * bq - (window - 1) <= kb * bk + bk - 1))
 
     @pl.when(run)
     def _block():
@@ -561,7 +685,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                     jnp.int32, (bq, bk), 0)
                 cols = kb * bk + jax.lax.broadcasted_iota(
                     jnp.int32, (bq, bk), 1)
-                p = jnp.where(rows >= cols, p, 0.0)
+                p = jnp.where(_visible(rows, cols, window), p, 0.0)
             pt = p.astype(do.dtype)
             r = slice(t * bk, (t + 1) * bk)
             dv_acc[r] += jax.lax.dot_general(
@@ -575,7 +699,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                 ds, q, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale  # [bk, d]
 
-    @pl.when(qb == nq - 1)
+    @pl.when(iq == nq - 1)
     def _finish():
         for t in range(ht):
             r = slice(t * bk, (t + 1) * bk)
@@ -584,7 +708,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 
 
 def _dqkv_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, *rest,
-                       scale, causal, bq, bk, ht, has_delta):
+                       scale, causal, bq, bk, ht, has_delta, window=None,
+                       group=1):
     """Single-block-pair fused backward: when the whole sequence is one
     (bq, bk) block per (b, head) — the flagship seq-512 geometry — the
     split dq / dkv kernels each recompute s, p and dp just to emit
@@ -601,6 +726,18 @@ def _dqkv_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, *rest,
     delta pass over out/do. Ring callers pass their hoisted GLOBAL
     delta instead (has_delta=True): a local p·dp sum cannot span the
     other kv shards' contributions."""
+    dk_acc = dv_acc = None
+    if group > 1:
+        # grouped kv heads: one query head of the kv head's group a grid
+        # step (``ht`` is 1), dk and dv summed over the steps in scratch
+        *rest, dk_acc, dv_acc = rest
+        ig = pl.program_id(2)
+
+        @pl.when(ig == 0)
+        def _init():
+            dk_acc[...] = jnp.zeros_like(dk_acc)
+            dv_acc[...] = jnp.zeros_like(dv_acc)
+
     if has_delta:
         delta_ref, dq_ref, dk_ref, dv_ref = rest
     else:
@@ -618,11 +755,15 @@ def _dqkv_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, *rest,
         if causal:
             rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            p = jnp.where(rows >= cols, p, 0.0)
+            p = jnp.where(_visible(rows, cols, window), p, 0.0)
         pt = p.astype(do.dtype)
-        dv_ref[0, t] = jax.lax.dot_general(
+        dv = jax.lax.dot_general(
             pt, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(dv_ref.dtype)
+            preferred_element_type=jnp.float32)
+        if group == 1:
+            dv_ref[0, t] = dv.astype(dv_ref.dtype)
+        else:
+            dv_acc[...] += dv
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)             # [bq, bk]
@@ -636,52 +777,83 @@ def _dqkv_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, *rest,
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
             * scale).astype(dq_ref.dtype)
-        dk_ref[0, t] = (jax.lax.dot_general(
+        dk = jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-            * scale).astype(dk_ref.dtype)
+            preferred_element_type=jnp.float32) * scale
+        if group == 1:
+            dk_ref[0, t] = dk.astype(dk_ref.dtype)
+        else:
+            dk_acc[...] += dk
+
+    if group > 1:
+        @pl.when(ig == group - 1)
+        def _finish():
+            dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
+            dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _flash_bwd_fused(q, k, v, lse, do, delta, causal, scale, bq, bk,
-                     interpret, ht):
+                     interpret, ht, window=None, group=1):
     """One pallas_call emitting (dq, dk, dv); caller guarantees
-    nq == nk == 1 and no bias/rel_table. ``lse`` and ``delta`` are
-    [b,h,sq]; ``delta=None`` computes it in-kernel (see
-    _dqkv_fused_kernel) — the no-``out``-input form."""
-    b, h, sq, d = q.shape
-    sk = k.shape[2]
-    spec_q = pl.BlockSpec((1, ht, bq, d), lambda ib, ih: (ib, ih, 0, 0))
-    spec_k = pl.BlockSpec((1, ht, bk, d), lambda ib, ih: (ib, ih, 0, 0))
-    spec_stat = pl.BlockSpec((1, ht, 1, bq), lambda ib, ih: (ib, ih, 0, 0))
+    nq == nk == 1 a head and no bias/rel_table. ``lse`` and ``delta``
+    are [b,h,sq]; ``delta=None`` computes it in-kernel (see
+    _dqkv_fused_kernel) — the no-``out``-input form. With ``group`` > 1
+    q, do and the statistics come folded and the grid gains the group as
+    its carried dimension."""
+    b, h, sk, d = k.shape
     has_delta = delta is not None
+    kernel = functools.partial(_dqkv_fused_kernel, scale=scale,
+                               causal=causal, bq=bq, bk=bk, ht=ht,
+                               has_delta=has_delta,
+                               **_band_args(window, group))
+    if group == 1:
+        grid, semantics, scratch = (b, h // ht), ("parallel", "parallel"), []
+        spec_q = pl.BlockSpec((1, ht, bq, d), lambda ib, ih: (ib, ih, 0, 0))
+        spec_k = pl.BlockSpec((1, ht, bk, d), lambda ib, ih: (ib, ih, 0, 0))
+        spec_stat = pl.BlockSpec((1, ht, 1, bq),
+                                 lambda ib, ih: (ib, ih, 0, 0))
+    else:
+        grid = (b, h, group)
+        semantics = ("parallel", "parallel", "arbitrary")
+        scratch = [pltpu.VMEM((bk, d), jnp.float32)] * 2
+        spec_q = pl.BlockSpec((1, 1, bq, d),
+                              lambda ib, ih, ig: (ib, ih, ig, 0))
+        spec_k = pl.BlockSpec((1, 1, bk, d),
+                              lambda ib, ih, ig: (ib, ih, 0, 0))
+        spec_stat = pl.BlockSpec((1, 1, 1, bq),
+                                 lambda ib, ih, ig: (ib, ih, 0, ig))
     in_specs = [spec_q, spec_k, spec_k, spec_q, spec_stat]
     inputs = [q, k, v, do, lse[:, :, None]]
     if has_delta:
         in_specs.append(spec_stat)
         inputs.append(delta[:, :, None])
     return pl.pallas_call(
-        functools.partial(_dqkv_fused_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, ht=ht, has_delta=has_delta),
-        grid=(b, h // ht),
+        kernel,
+        grid=grid,
         in_specs=in_specs,
         out_specs=[spec_q, spec_k, spec_k],
-        out_shape=[jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct((b, h, sk, d), k.dtype),
                    jax.ShapeDtypeStruct((b, h, sk, d), v.dtype)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=interpret,
         name="bps_flash_bwd_fused",
     )(*inputs)
 
 
 def _flash_bwd(q, k, v, out, lse, do, causal, scale, bq, bk, interpret,
-               delta=None, bias=None, rel_table=None, rel=None):
+               delta=None, bias=None, rel_table=None, rel=None, window=None):
     """(dq, dk, dv, dbias, drel). ``lse`` and a caller's ``delta`` are
-    [b,h,sq] fp32, like every row statistic outside the kernels."""
-    b, h, sq, d = q.shape
-    sk = k.shape[2]
-    nq, nk = sq // bq, sk // bk
+    [b,h,sq] fp32, like every row statistic outside the kernels. k and v
+    may have fewer heads than q (grouped), ``window`` as in _flash_fwd."""
+    b, hq, sq, d = q.shape
+    h, sk = k.shape[1], k.shape[2]
+    group = hq // h
+    nq, nk = sq // bq, sk // bk           # blocks a head
+    q, out, lse, do, delta = (None if x is None else _fold(x, group)
+                              for x in (q, out, lse, do, delta))
+    band = _band_args(window, group)
 
     has_bias = bias is not None
     has_rel = rel is not None
@@ -692,16 +864,17 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, bq, bk, interpret,
         # compute it in-kernel (dropping `out` from the backward's
         # inputs — under remat the recompute's p·V matmul DCEs away);
         # ring callers' hoisted GLOBAL delta is honored
-        ht_f = _head_tile(h, nq, nk, bq, bk, d, interpret, mats=4)
+        ht_f = (1 if group > 1 else
+                _head_tile(h, nq, nk, bq, bk, d, interpret, mats=4))
         dq, dk, dv = _flash_bwd_fused(q, k, v, lse, do, delta, causal,
-                                      scale, bq, bk, interpret, ht_f)
-        return dq, dk, dv, None, None
+                                      scale, bq, bk, interpret, ht_f, **band)
+        return _unfold(dq, group), dk, dv, None, None
 
     if delta is None:      # ring callers hoist this loop-invariant reduction
         delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                         axis=-1)                            # [b,h,s]
     lse, delta = lse[:, :, None], delta[:, :, None]         # [b,h,1,s]
-    ht = _head_tile(h, nq, nk, bq, bk, d, interpret,
+    ht = _head_tile(h, group * nq, nk, bq, bk, d, interpret,
                     mats=5 if has_rel else (4 if has_bias else 3))
     if has_rel:
         # the dtable scratch and output tiles are hard-sized to
@@ -709,13 +882,14 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, bq, bk, interpret,
         # write out of bounds and break the drel reshape
         ht = _clamp_ht(ht, h)
     qspec = pl.BlockSpec((1, ht, bq, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0))
-    kspec = pl.BlockSpec((1, ht, bk, d), lambda ib, ih, iq, ik: (ib, ih, ik, 0))
+    kspec = _kv_spec(ht, bq, bk, d, nq, group, window)
     stat = pl.BlockSpec((1, ht, 1, bq), lambda ib, ih, iq, ik: (ib, ih, 0, iq))
+    steps_k = nk if window is None else _band_steps_k(nq, bq, bk, window)
 
     in_specs = [qspec, kspec, kspec, qspec, stat, stat]
     inputs = [q, k, v, do, lse, delta]
     out_specs = qspec
-    out_shape = jax.ShapeDtypeStruct((b, h, sq, d), q.dtype)
+    out_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
     scratches = [pltpu.VMEM((ht * bq, d), jnp.float32)]
     params = _DIM_SEMANTICS
     if has_bias:
@@ -747,9 +921,9 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, bq, bk, interpret,
             "parallel", "parallel", "arbitrary", "arbitrary"))
     res = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, nk=nk, ht=ht, has_bias=has_bias,
-                          rel=rel, nq=nq),
-        grid=(b, h // ht, nq, nk),
+                          bq=bq, bk=bk, nk=steps_k, ht=ht, has_bias=has_bias,
+                          rel=rel, nq=nq, **(band and dict(band, nqh=nq))),
+        grid=(b, h // ht, group * nq, steps_k),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
@@ -773,6 +947,19 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, bq, bk, interpret,
     qspec2 = pl.BlockSpec((1, ht, bq, d), lambda ib, ih, ik, iq: (ib, ih, iq, 0))
     kspec2 = pl.BlockSpec((1, ht, bk, d), lambda ib, ih, ik, iq: (ib, ih, ik, 0))
     stat2 = pl.BlockSpec((1, ht, 1, bq), lambda ib, ih, ik, iq: (ib, ih, 0, iq))
+    steps_q = nq
+    if window is not None:
+        # a kv block's q blocks: from its diagonal block to the end of
+        # its band, the same walk in every head of the fold
+        steps_q = _band_steps_q(nq, nk, bq, bk, window)
+
+        def q_block(ik, iq):
+            qb = (ik * bk) // bq + iq % steps_q
+            return iq // steps_q * nq + jnp.minimum(qb, nq - 1)
+        qspec2 = pl.BlockSpec(
+            (1, ht, bq, d), lambda ib, ih, ik, iq: (ib, ih, q_block(ik, iq), 0))
+        stat2 = pl.BlockSpec(
+            (1, ht, 1, bq), lambda ib, ih, ik, iq: (ib, ih, 0, q_block(ik, iq)))
     in_specs2 = [qspec2, kspec2, kspec2, qspec2, stat2, stat2]
     inputs2 = [q, k, v, do, lse, delta]
     if has_bias:
@@ -785,9 +972,10 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, bq, bk, interpret,
         inputs2.append(rel_table)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, nq=nq, ht=ht, has_bias=has_bias,
-                          rel=rel),
-        grid=(b, h // ht, nk, nq),
+                          bq=bq, bk=bk, nq=group * steps_q, ht=ht,
+                          has_bias=has_bias, rel=rel,
+                          **(band and dict(band, nqh=nq, steps=steps_q))),
+        grid=(b, h // ht, nk, group * steps_q),
         in_specs=in_specs2,
         out_specs=[kspec2, kspec2],
         out_shape=[jax.ShapeDtypeStruct((b, h, sk, d), k.dtype),
@@ -798,18 +986,28 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, bq, bk, interpret,
         interpret=interpret,
         name="bps_flash_bwd_dkv",
     )(*inputs2)
-    return dq, dk, dv, dbias, drel
+    return _unfold(dq, group), dk, dv, dbias, drel
 
 
 # ------------------------------------------------------------ public API
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 11, 12))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 11, 12, 13))
 def flash_attention(q, k, v, causal=False, scale=None,
                     block_q=None, block_k=None, interpret=False,
                     fwd_xla=False, bias=None, rel_table=None,
-                    rel_bidirectional=True, rel_max_distance=128):
+                    rel_bidirectional=True, rel_max_distance=128,
+                    window=None):
     """Pallas flash attention. q: [b, sq, heads, d]; k,v: [b, sk, heads,
     d] → [b, sq, heads, d]. sq and sk may differ (cross-attention).
+
+    Grouped kv heads: k and v may carry ``kv_heads`` heads where
+    ``heads % kv_heads == 0``; query head i attends kv head
+    ``i // (heads // kv_heads)``, k and v are never repeated in HBM and
+    dk / dv come back summed over each group. ``window`` = w (causal
+    only) lets query i see key j where ``0 <= i - j < w``; kv blocks
+    outside the band are not visited. Neither goes with ``bias`` or
+    ``rel_table``.
 
     Each seq must be divisible by the (auto-shrunk) block sizes; a
     block size of None is the default (see ``_resolve``): 512, and in a
@@ -834,7 +1032,7 @@ def flash_attention(q, k, v, causal=False, scale=None,
     """
     out, _ = _fwd_rule(q, k, v, causal, scale, block_q, block_k, interpret,
                        fwd_xla, bias, rel_table, rel_bidirectional,
-                       rel_max_distance)
+                       rel_max_distance, window)
     return out
 
 
@@ -878,9 +1076,25 @@ def _rel_static(rel_table, bidirectional, max_distance):
             int(max_distance))
 
 
+def _check_band(q, k, causal, window, extra) -> None:
+    """What a window or grouped kv heads need of a call."""
+    heads, kv_heads = q.shape[2], k.shape[2]
+    if kv_heads < 1 or heads % kv_heads:
+        raise ValueError(f"{heads} query heads do not divide over "
+                         f"{kv_heads} kv heads")
+    if window is not None and not (causal and window >= 1):
+        raise ValueError("a window is a causal band of at least one key "
+                         f"(got window={window!r}, causal={causal})")
+    if extra and (window is not None or kv_heads != heads):
+        raise ValueError("bias / rel_table go with neither a window nor "
+                         "grouped kv heads")
+
+
 def _fwd_rule(q, k, v, causal, scale, block_q, block_k, interpret,
               fwd_xla=False, bias=None, rel_table=None,
-              rel_bidirectional=True, rel_max_distance=128):
+              rel_bidirectional=True, rel_max_distance=128, window=None):
+    _check_band(q, k, causal, window, bias is not None
+                or rel_table is not None)
     if rel_table is not None and rel_table.shape[1] > _DT_PAD[1]:
         raise ValueError(
             f"rel_table has {rel_table.shape[1]} buckets; the in-kernel "
@@ -905,10 +1119,12 @@ def _fwd_rule(q, k, v, causal, scale, block_q, block_k, interpret,
             from .relpos import relative_bias
             xbias = relative_bias(rel_table.T, q.shape[1], k.shape[1],
                                   rel[0], rel[1], rel[2])
-        out, lse = _xla_fwd(qt, kt, vt, causal, scale, bias=xbias)
+        out, lse = _xla_fwd(qt, kt, vt, causal, scale, bias=xbias,
+                            window=window)
     else:
         out, lse = _flash_fwd(qt, kt, vt, causal, scale, bq, bk, interpret,
-                              bias=bias, rel_table=rel_table, rel=rel)
+                              bias=bias, rel_table=rel_table, rel=rel,
+                              window=window)
     from jax.ad_checkpoint import checkpoint_name
     # named so a remat policy can pin the flash residuals while everything
     # around them recomputes (remat_policy="save_attn")
@@ -919,15 +1135,15 @@ def _fwd_rule(q, k, v, causal, scale, block_q, block_k, interpret,
 
 def _vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
              fwd_xla=False, bias=None, rel_table=None,
-             rel_bidirectional=True, rel_max_distance=128):
+             rel_bidirectional=True, rel_max_distance=128, window=None):
     out, res = _fwd_rule(q, k, v, causal, scale, block_q, block_k, interpret,
                          fwd_xla, bias, rel_table, rel_bidirectional,
-                         rel_max_distance)
+                         rel_max_distance, window)
     return out, res
 
 
 def _vjp_bwd(causal, scale, block_q, block_k, interpret, fwd_xla,
-             rel_bidirectional, rel_max_distance, res, g):
+             rel_bidirectional, rel_max_distance, window, res, g):
     qt, kt, vt, out, lse, bias, rel_table = res
     scale, bq, bk = _resolve(jnp.swapaxes(qt, 1, 2), jnp.swapaxes(kt, 1, 2),
                              scale, block_q, block_k)
@@ -935,7 +1151,7 @@ def _vjp_bwd(causal, scale, block_q, block_k, interpret, fwd_xla,
     do = jnp.swapaxes(g, 1, 2)
     dq, dk, dv, dbias, drel = _flash_bwd(
         qt, kt, vt, out, lse, do, causal, scale, bq, bk,
-        interpret, bias=bias, rel_table=rel_table, rel=rel)
+        interpret, bias=bias, rel_table=rel_table, rel=rel, window=window)
     return (jnp.swapaxes(dq, 1, 2), jnp.swapaxes(dk, 1, 2),
             jnp.swapaxes(dv, 1, 2), dbias, drel)
 
@@ -957,7 +1173,7 @@ _warned_fallback = set()
 
 def attention(q, k, v, causal=False, scale=None, impl="auto", bias=None,
               rel_table=None, rel_bidirectional=True,
-              rel_max_distance=128):
+              rel_max_distance=128, window=None):
     """Dispatcher: Pallas flash kernels on TPU, blockwise JAX elsewhere.
 
     impl: "auto" | "flash" | "hybrid" | "naive". "hybrid" = XLA-fused
@@ -971,6 +1187,9 @@ def attention(q, k, v, causal=False, scale=None, impl="auto", bias=None,
     computed in-kernel on the flash path (no materialized [h, sq, sk]
     bias); materialized only on the naive/hybrid fallbacks. ``bias``
     [heads, sq, sk]: arbitrary materialized bias. Mutually exclusive.
+
+    ``window`` and grouped kv heads (k, v with fewer heads than q) as in
+    ``flash_attention``, on every path.
     """
     if impl not in ("auto", "flash", "hybrid", "naive"):
         raise ValueError(
@@ -987,7 +1206,7 @@ def attention(q, k, v, causal=False, scale=None, impl="auto", bias=None,
         # named so that a fall-back from the kernels shows in a trace
         with jax.named_scope("bps_attn_xla"):
             return local_attention(q, k, v, causal=causal, scale=scale,
-                                   bias=b)
+                                   bias=b, window=window)
 
     if impl == "naive":
         return _naive()
@@ -997,12 +1216,14 @@ def attention(q, k, v, causal=False, scale=None, impl="auto", bias=None,
                                fwd_xla=True, bias=bias,
                                rel_table=rel_table,
                                rel_bidirectional=rel_bidirectional,
-                               rel_max_distance=rel_max_distance)
+                               rel_max_distance=rel_max_distance,
+                               window=window)
     if impl == "flash" or (on_tpu and supported(q.shape, k.shape)):
         return flash_attention(q, k, v, causal=causal, scale=scale,
                                bias=bias, rel_table=rel_table,
                                rel_bidirectional=rel_bidirectional,
-                               rel_max_distance=rel_max_distance)
+                               rel_max_distance=rel_max_distance,
+                               window=window)
     if on_tpu and tuple(q.shape) not in _warned_fallback:
         # a silent fall-through here once cost 28x at seq 8k (an s-1 shift
         # broke seq % 128) — make the downgrade loud, once per shape

@@ -1,0 +1,203 @@
+"""Grouped matrix products as Pallas TPU kernels: the experts' products
+of a routed feed-forward layer (``models/moe.py::routed_ffn``).
+
+The rows of ``lhs`` [rows, k] come sorted by group (expert), each group's
+rows padded to whole row tiles of ``tile`` rows, so that a row tile
+belongs to ONE group: ``tile_group[t]`` names it. Only the first
+``num_tiles`` tiles hold rows; the buffer behind them is sized for the
+worst routing (no row is ever dropped) and is never touched: a grid step
+past ``num_tiles`` skips its body, and its index maps stay on the last
+tile that ran, so it moves nothing either. Device time OF THESE KERNELS
+follows the rows that are there, not the buffer; what surrounds them in
+``routed_ffn`` (XLA's gathers to and from the buffer, the gated SiLU)
+still walks the whole buffer or every chosen pair.
+
+  - ``bps_gmm``     out[r] = lhs[r] @ w[group(r)]          [rows, n]
+  - ``bps_gmm_dx``  out[r] = lhs[r] @ w[group(r)]^T        [rows, k]
+  - ``bps_gmm_dw``  out[g] = lhs[rows of g]^T @ dout[rows of g]   [g, k, n]
+
+bf16 (or the inputs' dtype) in, fp32 accumulation on the MXU. The
+contraction of the first two is one block (k, n <= a few thousand: an
+expert's widths); ``bps_gmm_dw`` carries an fp32 accumulator over a
+group's row tiles. Rows of tiles that did not run hold whatever the
+buffer held: callers read only the rows they routed.
+
+``grouped_matmul`` is the differentiable entry: the kernels on the TPU,
+``lax.ragged_dot`` elsewhere (CPU tests), like ``ops.flash_attention
+.attention``.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .flash_attention import _pick_block as _pick
+
+# a step past the rows revisits the last tile's blocks: nothing may be
+# reordered around it, so the tile dimension is never "parallel"
+_GMM_SEMANTICS = pltpu.CompilerParams(
+    dimension_semantics=("arbitrary", "arbitrary"))
+_DW_SEMANTICS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+def _gmm_kernel(group_ref, num_ref, lhs_ref, rhs_ref, out_ref, *, transpose):
+    del group_ref
+    t = pl.program_id(1)
+
+    @pl.when(t < num_ref[0])
+    def _tile():
+        dims = (((1,), (1,)), ((), ())) if transpose else (
+            ((1,), (0,)), ((), ()))
+        out_ref[...] = jax.lax.dot_general(
+            lhs_ref[...], rhs_ref[0], dims,
+            preferred_element_type=jnp.float32).astype(out_ref.dtype)
+
+
+def _gmm(lhs, w, tile_group, num_tiles, tile, transpose, interpret):
+    """``lhs`` [rows, c] against ``w`` [g, k, n]: c = k and out [rows, n],
+    or with ``transpose`` c = n and out [rows, k]."""
+    rows, c = lhs.shape
+    g, k, n = w.shape
+    width = k if transpose else n
+    tn = _pick(width, 512)
+
+    def last(t, num):           # a step past the rows stays on the last tile
+        return jnp.minimum(t, num[0] - 1)
+
+    if transpose:
+        rhs_spec = pl.BlockSpec(
+            (1, tn, n), lambda j, t, grp, num: (grp[last(t, num)], j, 0))
+    else:
+        rhs_spec = pl.BlockSpec(
+            (1, k, tn), lambda j, t, grp, num: (grp[last(t, num)], 0, j))
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, transpose=transpose),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(width // tn, rows // tile),
+            in_specs=[
+                pl.BlockSpec((tile, c),
+                             lambda j, t, grp, num: (last(t, num), 0)),
+                rhs_spec],
+            out_specs=pl.BlockSpec(
+                (tile, tn), lambda j, t, grp, num: (last(t, num), j))),
+        out_shape=jax.ShapeDtypeStruct((rows, width), lhs.dtype),
+        compiler_params=_GMM_SEMANTICS,
+        interpret=interpret,
+        name="bps_gmm_dx" if transpose else "bps_gmm",
+    )(tile_group, num_tiles, lhs, w)
+
+
+def _gmm_dw_kernel(group_ref, num_ref, lhs_ref, dout_ref, out_ref, acc):
+    t = pl.program_id(2)
+    num = num_ref[0]
+    here = group_ref[t]
+    first = jnp.logical_or(t == 0, group_ref[jnp.maximum(t - 1, 0)] != here)
+    final = jnp.logical_or(
+        t == num - 1,
+        group_ref[jnp.minimum(t + 1, pl.num_programs(2) - 1)] != here)
+
+    @pl.when(t < num)
+    def _tile():
+        @pl.when(first)
+        def _zero():
+            acc[...] = jnp.zeros_like(acc)
+
+        acc[...] += jax.lax.dot_general(
+            lhs_ref[...], dout_ref[...], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+        @pl.when(final)
+        def _write():
+            out_ref[0] = acc[...].astype(out_ref.dtype)
+
+
+def _gmm_dw(lhs, dout, tile_group, num_tiles, groups, tile, interpret):
+    """[groups, k, n]: each group's ``lhs^T @ dout`` over its own rows.
+    Every group has a tile (``moe.py`` pads an empty one to a tile of
+    zero rows), so every block of the result is written."""
+    rows, k = lhs.shape
+    n = dout.shape[1]
+    tk, tn = _pick(k, 512), _pick(n, 1024)
+
+    def last(t, num):
+        return jnp.minimum(t, num[0] - 1)
+
+    return pl.pallas_call(
+        _gmm_dw_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(k // tk, n // tn, rows // tile),
+            in_specs=[
+                pl.BlockSpec((tile, tk),
+                             lambda i, j, t, grp, num: (last(t, num), i)),
+                pl.BlockSpec((tile, tn),
+                             lambda i, j, t, grp, num: (last(t, num), j))],
+            out_specs=pl.BlockSpec(
+                (1, tk, tn),
+                lambda i, j, t, grp, num: (grp[last(t, num)], i, j)),
+            scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((groups, k, n), lhs.dtype),
+        compiler_params=_DW_SEMANTICS,
+        interpret=interpret,
+        name="bps_gmm_dw",
+    )(tile_group, num_tiles, lhs, dout)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _gmm_vjp(lhs, w, tile_group, num_tiles, tile, interpret):
+    return _gmm(lhs, w, tile_group, num_tiles, tile, False, interpret)
+
+
+def _gmm_vjp_fwd(lhs, w, tile_group, num_tiles, tile, interpret):
+    out = _gmm(lhs, w, tile_group, num_tiles, tile, False, interpret)
+    return out, (lhs, w, tile_group, num_tiles)
+
+
+def _gmm_vjp_bwd(tile, interpret, res, dout):
+    lhs, w, tile_group, num_tiles = res
+    dlhs = _gmm(dout, w, tile_group, num_tiles, tile, True, interpret)
+    dw = _gmm_dw(lhs, dout, tile_group, num_tiles, w.shape[0], tile,
+                 interpret)
+    return dlhs, dw, None, None
+
+
+_gmm_vjp.defvjp(_gmm_vjp_fwd, _gmm_vjp_bwd)
+
+
+def supported(lhs_shape, w_shape, tile: int) -> bool:
+    """Shapes the kernels take: widths in whole 128-lane tiles, rows in
+    whole row tiles of a multiple of 128."""
+    rows, _ = lhs_shape
+    _, k, n = w_shape
+    return (tile % 128 == 0 and rows % tile == 0 and k % 128 == 0
+            and n % 128 == 0)
+
+
+def grouped_matmul(lhs, w, tile_group, num_tiles, group_rows, tile: int,
+                   impl: str = "auto"):
+    """``out[r] = lhs[r] @ w[group of r]`` for the rows of the first
+    ``num_tiles`` row tiles; ``tile_group`` [rows // tile] int32 names
+    each tile's group (tiles past ``num_tiles`` repeat the last one's),
+    ``num_tiles`` is [1] int32, ``group_rows`` [groups] int32 the padded
+    rows of each group (what ``lax.ragged_dot`` is given off the TPU).
+
+    impl: "auto" (the kernels on the TPU where ``supported``), "gmm",
+    "gmm_interpret" (the kernels in Pallas' interpreter: tests) or
+    "ragged" (``lax.ragged_dot``)."""
+    if impl not in ("auto", "gmm", "gmm_interpret", "ragged"):
+        raise ValueError(f"grouped_matmul impl {impl!r}")
+    if impl == "auto":
+        impl = ("gmm" if jax.default_backend() == "tpu"
+                and supported(lhs.shape, w.shape, tile) else "ragged")
+    if impl == "ragged":
+        return jax.lax.ragged_dot(lhs, w, group_rows,
+                                  preferred_element_type=lhs.dtype)
+    return _gmm_vjp(lhs, w, tile_group, num_tiles, tile,
+                    impl == "gmm_interpret")

@@ -92,6 +92,24 @@ def test_flash_fwd_bwd_compiles_for_v5e(compile_for_chip, shape, causal,
         *[(shape, jnp.bfloat16)] * 3, *extra_shape)
 
 
+@pytest.mark.parametrize("seq,window", [(8192, 2048), (8192, None),
+                                        (512, 128)],
+                         ids=["band_8k", "triangle_8k", "one_block_band"])
+def test_flash_grouped_window_compiles_for_v5e(compile_for_chip, seq, window):
+    """One chip's share of a grouped-kv decoder: 8 query heads of 128 on
+    one kv head, a causal band or the whole triangle at 8k (the online
+    forward, the split backward), and the one-block pair at 512."""
+    from byteps_tpu.ops.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        return (flash_attention(q, k, v, True, window=window)
+                .astype(jnp.float32) ** 2).sum()
+
+    compile_for_chip(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                     ((2, seq, 8, 128), jnp.bfloat16),
+                     *[((2, seq, 1, 128), jnp.bfloat16)] * 2)
+
+
 def test_onebit_pack_unpack_compile_for_v5e(compile_for_chip, mosaic):
     n = BUCKET_ELEMS
     chunks = (n + mosaic.PACK - 1) // mosaic.PACK

@@ -15,7 +15,7 @@ import optax
 import pytest
 
 from byteps_tpu.data import prefetch_to_mesh
-from byteps_tpu.models import bert, gpt2, transformer
+from byteps_tpu.models import bert, decoder, gpt2, transformer
 from byteps_tpu.parallel.mesh import make_mesh
 from byteps_tpu.training import DistributedTrainer
 
@@ -24,6 +24,10 @@ from byteps_tpu.training import DistributedTrainer
 SCOPES = ("bps.model", "bps.optimizer", "bps.exchange", "bps.exchange.pack",
           "bps.exchange.reduce", "bps.exchange.unpack", "bps.embed",
           "bps.attn", "bps.mlp", "bps.head", "bps_attn_xla")
+# what the decoder of several kinds of layer adds (ISSUE 29 section 5):
+# the routed feed-forward's parts inside bps.mlp
+MOE_SCOPES = ("bps.moe", "bps.moe.route", "bps.moe.experts",
+              "bps.moe.shared")
 
 
 def _trainer(model: str, mesh):
@@ -31,6 +35,13 @@ def _trainer(model: str, mesh):
         cfg = bert.bert_tiny()
         loss = lambda p, b: bert.mlm_loss(p, cfg, b, max_predictions=8)  # noqa: E731
         make = lambda rng: bert.synth_mlm_batch(rng, 8, 32, 128)  # noqa: E731
+    elif model == "afmoe_tiny":
+        cfg = decoder.afmoe_tiny()
+        loss = lambda p, b: decoder.causal_lm_loss(p, cfg, b)  # noqa: E731
+        make = lambda rng: gpt2.synth_lm_batch(rng, 8, 32, 128)  # noqa: E731
+        params = decoder.init_params(jax.random.PRNGKey(0), cfg)
+        return DistributedTrainer(loss, params, optax.adamw(1e-3), mesh=mesh,
+                                  partition_bytes=1 << 16), make
     else:
         cfg = gpt2.gpt2_tiny()
         loss = lambda p, b: gpt2.causal_lm_loss(p, cfg, b)  # noqa: E731
@@ -47,7 +58,7 @@ def lowered():
     """The step's lowering with its locations, once a model."""
     mesh = make_mesh({"data": 4}, devices=jax.devices()[:4])
     texts = {}
-    for model in ("bert_tiny", "gpt2_tiny"):
+    for model in ("bert_tiny", "afmoe_tiny", "gpt2_tiny"):
         trainer, make = _trainer(model, mesh)
         step = trainer._step_fn.lower(trainer.params, trainer.opt_state,
                                       make(np.random.RandomState(0)))
@@ -60,7 +71,8 @@ def lowered():
 
 
 @pytest.mark.parametrize("model,scope", list(itertools.product(
-    ("bert_tiny", "gpt2_tiny"), SCOPES)))
+    ("bert_tiny", "gpt2_tiny"), SCOPES)) + [
+        ("afmoe_tiny", scope) for scope in SCOPES + MOE_SCOPES])
 def test_lowered_step_holds_the_scope(lowered, model, scope):
     names = set(re.findall(r"bps[._][A-Za-z_.]+", lowered[model]))
     assert scope in names
@@ -81,6 +93,25 @@ def test_scopes_nest_as_the_phases_do(lowered):
     assert some(r"shard_map/bps\.optimizer/")
     assert not some(r"bps\.optimizer/.*bps\.exchange")
     assert not some(r"bps\.model/.*bps\.optimizer")
+
+
+def test_the_routed_layers_scopes_nest_inside_the_feed_forward():
+    """``bps.moe`` lies inside ``bps.mlp``; routing, the experts' products
+    and the shared expert inside it; forward and backward alike (the
+    gathers' hand-written transposes keep the scope of their forward)."""
+    mesh = make_mesh({"data": 2}, devices=jax.devices()[:2])
+    trainer, make = _trainer("afmoe_tiny", mesh)
+    step = trainer._step_fn.lower(trainer.params, trainer.opt_state,
+                                  make(np.random.RandomState(0)))
+    paths = set(re.findall(r'op_name="([^"]*)"', step.compile().as_text()))
+
+    def some(pattern):
+        return any(re.search(pattern, p) for p in paths)
+
+    for inner in ("route", "experts", "shared"):
+        assert some(rf"bps\.model/jvp\(bps\.mlp\)/bps\.moe/bps\.moe\.{inner}")
+        assert some(rf"bps\.model/transpose\(.*bps\.moe\.{inner}")
+    assert not some(r"bps\.attn/.*bps\.moe")
 
 
 @pytest.mark.parametrize("seq,kernels", [

@@ -1,0 +1,234 @@
+"""The routed feed-forward layer of one chip's share (``moe.routed_ffn``)
+and its grouped products (``ops/grouped_matmul.py``): against a dense
+masked product over the held experts; no row dropped under an imbalance
+forced by a biased router; and THE TEST THAT TIES THE SHARE TO THE MODEL:
+the shares of the experts, with the shared expert counted once, add up to
+the uncut reference's layer."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from byteps_tpu.models import moe
+from byteps_tpu.ops import grouped_matmul as gm
+
+from benchmark.reference import afmoe_share as ref
+
+T, H, M, E, K = 96, 128, 128, 16, 4
+
+
+def _layer(seed, held, shared=True):
+    rng = np.random.RandomState(seed)
+    normal = lambda *s: jnp.asarray(rng.randn(*s) * 0.05, jnp.float32)  # noqa: E731
+    blk = {"router": normal(H, E),
+           "experts": {"gate_up": normal(len(held), H, 2 * M),
+                       "down": normal(len(held), M, H)}}
+    if shared:
+        blk["shared"] = {"gate_up": normal(H, 2 * M), "down": normal(M, H)}
+    return blk, jnp.asarray(rng.randn(T, H), jnp.float32)
+
+
+def _dense(f, blk, cfg):
+    """Every held expert over every row, masked by the router's choice."""
+    w, chosen = moe.route(f, blk["router"], cfg)
+    out = (moe.gated_silu(f @ blk["shared"]["gate_up"])
+           @ blk["shared"]["down"]) if "shared" in blk else 0.0
+    for g, e in enumerate(cfg.held):
+        mine = jnp.where(chosen == e, w, 0.0).sum(-1)
+        out = out + mine[:, None] * (
+            moe.gated_silu(f @ blk["experts"]["gate_up"][g])
+            @ blk["experts"]["down"][g])
+    return out
+
+
+@pytest.mark.parametrize("impl", ["ragged", "gmm_interpret"])
+@pytest.mark.parametrize("held", [(0, 1, 2, 3), (5, 9), tuple(range(16)),
+                                  (15,)], ids=str)
+def test_routed_layer_and_every_gradient_match_the_dense_product(held, impl):
+    cfg = moe.RoutedConfig(E, held, K, 2.8, row_tile=128, impl=impl)
+    blk, f = _layer(0, held)
+
+    def loss(fn):
+        return lambda f, blk: jnp.sum(jnp.sin(fn(f, blk, cfg)))
+
+    (a, ga), (b, gb) = (jax.value_and_grad(loss(fn), (0, 1))(f, blk)
+                        for fn in (moe.routed_ffn, _dense))
+    np.testing.assert_allclose(float(a), float(b), rtol=1e-5)
+    for x, y in zip(jax.tree_util.tree_leaves(ga),
+                    jax.tree_util.tree_leaves(gb)):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), rtol=1e-4,
+                                   atol=1e-6)
+
+
+@pytest.mark.parametrize("impl", ["ragged", "gmm_interpret"])
+def test_no_row_is_dropped_when_the_router_favours_two_experts(impl):
+    """A router biased toward experts 1 and 2 sends them nearly every row
+    (far beyond any capacity factor); each row's result still holds both."""
+    held = (0, 1, 2, 3)
+    bias = np.zeros((H, E), np.float32)
+    cfg = moe.RoutedConfig(E, held, 2, 1.0, row_tile=128, impl=impl)
+    blk, f = _layer(1, held, shared=False)
+    f = jnp.abs(f)                      # so that a positive column wins
+    bias[:, 1:3] = 0.5
+    blk["router"] = blk["router"] + bias
+    _, chosen = moe.route(f, blk["router"], cfg)
+    plan = moe.plan_rows(chosen, cfg)
+    counts = np.asarray(plan["counts"])
+    assert counts[1] == T and counts[2] == T and counts.sum() == 2 * T
+    # every chosen pair has a row of its own, and every such row its pair
+    dest = np.asarray(plan["dest"]).reshape(-1)
+    assert len(set(dest)) == 2 * T and dest.max() < plan["row_pair"].shape[0]
+    np.testing.assert_array_equal(np.asarray(plan["row_pair"])[dest],
+                                  np.arange(2 * T))
+    np.testing.assert_allclose(np.asarray(moe.routed_ffn(f, blk, cfg)),
+                               np.asarray(_dense(f, blk, cfg)), rtol=1e-4,
+                               atol=1e-6)
+
+
+def test_the_plan_puts_every_expert_on_whole_tiles():
+    held = (3, 7, 8, 12)
+    cfg = moe.RoutedConfig(E, held, K, row_tile=8)
+    blk, f = _layer(2, held)
+    _, chosen = moe.route(f, blk["router"], cfg)
+    plan = {k: np.asarray(v) for k, v in moe.plan_rows(chosen, cfg).items()}
+    chosen = np.asarray(chosen)
+    for g, e in enumerate(held):
+        assert plan["counts"][g] == (chosen == e).sum()
+    assert (plan["group_rows"] % 8 == 0).all() and (
+        plan["group_rows"] >= np.maximum(plan["counts"], 1)).all()
+    assert plan["num_tiles"][0] * 8 == plan["group_rows"].sum()
+    # a tile holds one expert's rows: the rows' pairs chose that expert
+    pairs = chosen.reshape(-1)
+    for r, p in enumerate(plan["row_pair"]):
+        if p < pairs.size:
+            assert held[plan["tile_group"][r // 8]] == pairs[p]
+    # the buffer is sized for the worst routing: all k choices held
+    assert plan["row_pair"].shape[0] >= T * K
+
+
+@pytest.mark.parametrize("balanced", [False, True],
+                         ids=["by_score", "balanced"])
+def test_the_shares_of_the_experts_add_up_to_the_uncut_layer(balanced):
+    """8 chips hold 2 of 16 experts each. Each computes the shared expert
+    and its own experts' part; the routed parts of all shares and the
+    shared expert ONCE are the uncut reference's layer (the reference of
+    the benchmark, given all 16 experts)."""
+    rng = np.random.RandomState(3)
+    normal = lambda *s: jnp.asarray(rng.randn(*s) * 0.05, jnp.float32)  # noqa: E731
+    whole = {"router": normal(H, E),
+             "experts": {"gate_up": normal(E, H, 2 * M),
+                         "down": normal(E, M, H)},
+             "shared": {"gate_up": normal(H, 2 * M), "down": normal(M, H)}}
+    f = jnp.asarray(rng.randn(T, H), jnp.float32)
+    z = {"top_k": K, "route_scale": 2.8, "held": tuple(range(E)),
+         "balanced": balanced}
+    dot = lambda spec, a, b: jnp.einsum(spec, a, b, precision="highest")  # noqa: E731
+    uncut = ref._routed(f, whole, z, dot)
+    shared = moe.gated_silu(f @ whole["shared"]["gate_up"]) @ whole[
+        "shared"]["down"]
+    total = shared
+    for chip in range(8):
+        held = (2 * chip, 2 * chip + 1)
+        cfg = moe.RoutedConfig(E, held, K, 2.8, row_tile=8,
+                               balanced=balanced)
+        share = dict(whole, experts=jax.tree_util.tree_map(
+            lambda w: w[2 * chip:2 * chip + 2], whole["experts"]))
+        # the reference is given the same share and gives the same part
+        mine = moe.routed_ffn(f, share, cfg)
+        np.testing.assert_allclose(
+            np.asarray(mine), np.asarray(ref._routed(
+                f, share, dict(z, held=held), dot)), rtol=1e-4, atol=1e-6)
+        total = total + (mine - shared)
+    np.testing.assert_allclose(np.asarray(total), np.asarray(uncut),
+                               rtol=1e-4, atol=2e-6)
+
+
+@pytest.mark.parametrize("rows_in_groups", [(8, 24, 0, 16), (0, 0, 0, 8)],
+                         ids=str)
+def test_grouped_products_skip_what_lies_behind_the_rows(rows_in_groups):
+    """The three kernels in the interpreter against ``lax.ragged_dot``,
+    on a buffer longer than its rows: tiles past ``num_tiles`` are not
+    read (NaNs there do no harm) and not written."""
+    tile, k, n = 8, 128, 256
+    padded = np.maximum(-(-np.asarray(rows_in_groups) // tile), 1) * tile
+    used, tiles = int(padded.sum()), int(padded.sum()) // tile + 3
+    rng = np.random.RandomState(4)
+    lhs = np.full((tiles * tile, k), np.nan, np.float32)
+    lhs[:used] = rng.randn(used, k)
+    w = jnp.asarray(rng.randn(len(padded), k, n), jnp.float32)
+    group = np.repeat(np.arange(len(padded)), padded // tile)
+    group = np.concatenate([group, np.full(tiles - len(group), group[-1])])
+    args = (jnp.asarray(group, jnp.int32), jnp.asarray([used // tile],
+                                                       jnp.int32))
+    sizes = jnp.asarray(padded, jnp.int32)
+
+    def kernels(lhs, w):
+        return gm.grouped_matmul(lhs, w, *args, sizes, tile, "gmm_interpret")
+
+    def ragged(lhs, w):
+        return gm.grouped_matmul(lhs, w, *args, sizes, tile, "ragged")
+
+    lhs = jnp.asarray(lhs)
+    got = kernels(lhs, w)[:used]
+    want = ragged(jnp.nan_to_num(lhs), w)[:used]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
+                               atol=1e-5)
+    ct = jnp.asarray(rng.randn(tiles * tile, n), jnp.float32)
+    loss = lambda fn: lambda a, b: jnp.sum(fn(a, b)[:used] * ct[:used])  # noqa: E731
+    (da, dw), (ea, ew) = (jax.grad(loss(fn), (0, 1))(jnp.nan_to_num(lhs), w)
+                          for fn in (kernels, ragged))
+    np.testing.assert_allclose(np.asarray(da[:used]), np.asarray(ea[:used]),
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(dw), np.asarray(ew), rtol=1e-4,
+                               atol=1e-4)
+
+
+def test_grouped_products_carry_their_names():
+    import re
+    tile = 128
+    args = (jnp.zeros((2,), jnp.int32), jnp.ones((1,), jnp.int32),
+            jnp.asarray([128], jnp.int32))
+    loss = lambda a, w: gm.grouped_matmul(a, w, *args, tile, "gmm").sum()  # noqa: E731
+    jaxpr = str(jax.make_jaxpr(jax.grad(loss, (0, 1)))(
+        jnp.zeros((256, 128)), jnp.zeros((1, 128, 128))))
+    assert set(re.findall(r"name=(bps_gmm\w*)", jaxpr)) == {
+        "bps_gmm", "bps_gmm_dx", "bps_gmm_dw"}
+
+
+@pytest.mark.parametrize("impl", ["ragged", "gmm_interpret"])
+def test_a_balanced_choice_gives_every_expert_its_share_of_the_rows(impl):
+    """A router whose outputs sit far apart (experts 1 and 2 chosen by
+    every token, others by none) chooses, on its outputs standardised an
+    expert, every expert about ``T k / E`` times; the weights stay the
+    chosen experts' own scores, normalised; and the layer and its
+    gradients are the reference's, told the same."""
+    held = (0, 1, 2, 3)
+    blk, f = _layer(5, held)
+    f = jnp.abs(f)
+    blk["router"] = blk["router"].at[:, 1:3].add(0.5).at[:, 5:9].add(-0.5)
+    plain = moe.RoutedConfig(E, held, K, 2.8, row_tile=128, impl=impl)
+    cfg = moe.RoutedConfig(E, held, K, 2.8, row_tile=128, impl=impl,
+                           balanced=True)
+    count = lambda c: np.bincount(  # noqa: E731
+        np.asarray(moe.route(f, blk["router"], c)[1]).ravel(), minlength=E)
+    assert count(plain)[1] == T and count(plain)[5:9].sum() == 0
+    mean = T * K / E
+    assert np.abs(count(cfg) - mean).max() <= 0.4 * mean
+    weights, chosen = moe.route(f, blk["router"], cfg)
+    scores = jax.nn.sigmoid(jnp.dot(f, blk["router"], precision="highest"))
+    top = np.take_along_axis(np.asarray(scores), np.asarray(chosen), -1)
+    np.testing.assert_allclose(np.asarray(weights),
+                               2.8 * top / top.sum(-1, keepdims=True),
+                               rtol=1e-5)
+    z = {"top_k": K, "route_scale": 2.8, "held": held, "balanced": True}
+    dot = lambda spec, a, b: jnp.einsum(spec, a, b, precision="highest")  # noqa: E731
+    loss = lambda fn: lambda f, blk: jnp.sum(jnp.sin(fn(f, blk)))  # noqa: E731
+    (a, ga), (b, gb) = (jax.value_and_grad(loss(fn), (0, 1))(f, blk)
+                        for fn in (lambda f, blk: moe.routed_ffn(f, blk, cfg),
+                                   lambda f, blk: ref._routed(f, blk, z, dot)))
+    np.testing.assert_allclose(float(a), float(b), rtol=1e-5)
+    for x, y in zip(jax.tree_util.tree_leaves(ga),
+                    jax.tree_util.tree_leaves(gb)):
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), rtol=1e-4,
+                                   atol=1e-6)
