@@ -26,7 +26,9 @@ chooses over all the router's outputs, sorts the rows chosen for held
 experts into per-expert runs of whole row tiles (static shapes sized
 for the worst routing, no capacity), runs the grouped products over the
 runs that are there (``ops/grouped_matmul.py``) and gathers the weighted
-results back. What the experts held elsewhere would add is left out.
+results back; rows travel both ways in time that follows the rows routed
+(``ops/routed_rows.py``). What the experts held elsewhere would add is
+left out.
 """
 
 from __future__ import annotations
@@ -40,6 +42,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops.grouped_matmul import grouped_matmul
+from ..ops.routed_rows import (combine_rows, resolve as resolve_rows,
+                               take_rows, take_xla, tile_bounds)
 from .transformer import (TransformerConfig, _attention, _layernorm,
                           embed_lookup)
 
@@ -320,7 +324,10 @@ def plan_rows(experts, cfg: RoutedConfig):
     ``row_pair`` [buffer]: the row's pair t * k + j, or T * k (a pad row),
     and ``row_token`` [buffer]: that pair's token t, or T;
     ``tile_group`` [tiles], ``num_tiles`` [1], ``group_rows`` [held]: what
-    ``grouped_matmul`` reads; ``counts`` [held]: rows routed to each."""
+    ``grouped_matmul`` reads; ``counts`` [held]: rows routed to each;
+    ``lo``, ``hi``, ``lanes``, ``live``: where a tile of tokens has its
+    rows in each expert's run (``routed_rows.tile_bounds``: what
+    ``bps_moe_combine`` reads)."""
     t, k = experts.shape
     held, tile = len(cfg.held), cfg.row_tile
     pairs = t * k
@@ -349,58 +356,55 @@ def plan_rows(experts, cfg: RoutedConfig):
             "row_token": jnp.where(row_pair < pairs, row_pair // k, t),
             "tile_group": tile_group,
             "num_tiles": (padded.sum() // tile).astype(jnp.int32)[None],
-            "group_rows": padded, "counts": counts}
-
-
-def _take(x, index):
-    """Rows of ``x`` by ``index``; zeros where the index is past the end."""
-    return jnp.take(x, index, axis=0, mode="fill", fill_value=0)
+            "group_rows": padded, "counts": counts,
+            **tile_bounds(local.reshape(t, k), padded)}
 
 
 # Dispatch and combine are each other's transposes, and both are written
 # as GATHERS (rows by token, tokens by row): autodiff's transpose of a
-# gather is a scatter-add, which serialises on the TPU.
+# gather is a scatter-add, which serialises on the TPU. ``move``: how the
+# rows travel (``ops/routed_rows.py``): its kernels, or "ragged" for XLA's
+# gathers over the whole buffer and every chosen pair.
 
-@jax.custom_vjp
-def _dispatch(x, row_token, dest):
+@partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _dispatch(x, plan, tile, move):
     """[buffer, h]: token ``row_token[r]``'s row of ``x``, zeros in pad
     rows."""
-    return _take(x, row_token)
+    return take_rows(x, plan["row_token"], plan["num_tiles"], tile,
+                     impl=move)
 
 
-def _dispatch_fwd(x, row_token, dest):
-    return _take(x, row_token), dest
+def _dispatch_fwd(x, plan, tile, move):
+    return _dispatch(x, plan, tile, move), plan
 
 
-def _dispatch_bwd(dest, d_rows):
-    return _take(d_rows, dest.reshape(-1)).reshape(
-        dest.shape + d_rows.shape[1:]).sum(1), None, None
+def _dispatch_bwd(tile, move, plan, d_rows):
+    return combine_rows(d_rows, plan["dest"], None, plan,
+                        impl=move), None
 
 
 _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@jax.custom_vjp
-def _combine(y, weights, row_pair, row_token, dest):
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _combine(y, weights, plan, tile, move):
     """[T, h]: sum over a token's pairs of weight * the pair's row of
     ``y``; a pair with no row here (its expert is not held) adds zero."""
-    rows = _take(y, dest.reshape(-1)).reshape(dest.shape + y.shape[1:])
-    return jnp.einsum("tkh,tk->th", rows, weights.astype(y.dtype))
+    return combine_rows(y, plan["dest"], weights, plan, impl=move)
 
 
-def _combine_fwd(y, weights, row_pair, row_token, dest):
-    return (_combine(y, weights, row_pair, row_token, dest),
-            (y, weights, row_pair, row_token, dest))
+def _combine_fwd(y, weights, plan, tile, move):
+    return _combine(y, weights, plan, tile, move), (y, weights, plan)
 
 
-def _combine_bwd(res, d_out):
-    y, weights, row_pair, row_token, dest = res
-    row_weight = _take(weights.reshape(-1), row_pair)
-    d_y = _take(d_out, row_token) * row_weight[:, None].astype(d_out.dtype)
-    rows = _take(y, dest.reshape(-1)).reshape(dest.shape + y.shape[1:])
-    d_w = jnp.einsum("tkh,th->tk", rows, d_out,
-                     preferred_element_type=jnp.float32)
-    return d_y, d_w.astype(weights.dtype), None, None, None
+def _combine_bwd(tile, move, res, d_out):
+    y, weights, plan = res
+    row_weight = take_xla(weights.reshape(-1), plan["row_pair"])
+    d_y = take_rows(d_out, plan["row_token"], plan["num_tiles"], tile,
+                    scale=row_weight, impl=move)
+    d_w = combine_rows(y, plan["dest"], None, plan, d_out=d_out,
+                       impl=move)
+    return d_y, d_w.astype(weights.dtype), None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
@@ -417,26 +421,29 @@ def routed_ffn(f, blk, cfg: RoutedConfig, sequences: int = 1):
     ``gate_up`` [h, 2 ms], ``down`` [ms, h].
 
     No row is dropped, whatever the imbalance: the buffer of rows is sized
-    for the worst routing. Only the grouped products skip the part of it
-    that holds no row. The gathers do not: those by row walk the whole
-    buffer and those by pair all ``T * top_k`` chosen pairs, held or not
-    (most of the layer's device time: PERF.md section 5)."""
+    for the worst routing. The grouped products and the movement of rows
+    (to the buffer by live row tile, back by the rows a tile of tokens has
+    here: ``ops/routed_rows.py``) take time by the rows routed this step;
+    the gated SiLU between the products and the plan still walk the whole
+    buffer and every chosen pair (PERF.md section 5)."""
     dt = f.dtype
+    tile = cfg.row_tile
+    move = resolve_rows(cfg.impl, *f.shape, blk["experts"]["down"].shape[1],
+                        len(cfg.held), dt, tile)
     with jax.named_scope("bps.moe"):
         with jax.named_scope("bps.moe.route"):
             weights, experts = route(f, blk["router"], cfg, sequences)
             plan = plan_rows(experts, cfg)
-            rows = _dispatch(f, plan["row_token"], plan["dest"])
+            rows = _dispatch(f, plan, tile, move)
         with jax.named_scope("bps.moe.experts"):
             def product(lhs, w):
                 return grouped_matmul(
                     lhs, w.astype(dt), plan["tile_group"], plan["num_tiles"],
-                    plan["group_rows"], cfg.row_tile, cfg.impl)
+                    plan["group_rows"], tile, cfg.impl)
             y = product(gated_silu(product(rows, blk["experts"]["gate_up"])),
                         blk["experts"]["down"])
         with jax.named_scope("bps.moe.route"):
-            out = _combine(y, weights, plan["row_pair"], plan["row_token"],
-                           plan["dest"])
+            out = _combine(y, weights, plan, tile, move)
         if "shared" in blk:
             with jax.named_scope("bps.moe.shared"):
                 out = out + gated_silu(

@@ -7,10 +7,11 @@ belongs to ONE group: ``tile_group[t]`` names it. Only the first
 ``num_tiles`` tiles hold rows; the buffer behind them is sized for the
 worst routing (no row is ever dropped) and is never touched: a grid step
 past ``num_tiles`` skips its body, and its index maps stay on the last
-tile that ran, so it moves nothing either. Device time OF THESE KERNELS
-follows the rows that are there, not the buffer; what surrounds them in
-``routed_ffn`` (XLA's gathers to and from the buffer, the gated SiLU)
-still walks the whole buffer or every chosen pair.
+tile that ran, so it moves nothing either. Device time of these kernels
+follows the rows that are there, not the buffer, and so does the movement
+of rows to and from the buffer (``ops/routed_rows.py``); of what
+surrounds them in ``routed_ffn`` the gated SiLU between the products
+still walks the whole buffer.
 
   - ``bps_gmm``     out[r] = lhs[r] @ w[group(r)]          [rows, n]
   - ``bps_gmm_dx``  out[r] = lhs[r] @ w[group(r)]^T        [rows, k]
@@ -59,6 +60,10 @@ def _gmm_kernel(group_ref, num_ref, lhs_ref, rhs_ref, out_ref, *, transpose):
             preferred_element_type=jnp.float32).astype(out_ref.dtype)
 
 
+# jitted, like the kernels of ops/routed_rows.py: a step calls each some
+# dozen times, and one traced and lowered function serves every call of a
+# shape (a cell's set-up pays the lowering on every run, cached or not)
+@functools.partial(jax.jit, static_argnames=("tile", "transpose", "interpret"))
 def _gmm(lhs, w, tile_group, num_tiles, tile, transpose, interpret):
     """``lhs`` [rows, c] against ``w`` [g, k, n]: c = k and out [rows, n],
     or with ``transpose`` c = n and out [rows, k]."""
@@ -118,6 +123,7 @@ def _gmm_dw_kernel(group_ref, num_ref, lhs_ref, dout_ref, out_ref, acc):
             out_ref[0] = acc[...].astype(out_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=("groups", "tile", "interpret"))
 def _gmm_dw(lhs, dout, tile_group, num_tiles, groups, tile, interpret):
     """[groups, k, n]: each group's ``lhs^T @ dout`` over its own rows.
     Every group has a tile (``moe.py`` pads an empty one to a tile of

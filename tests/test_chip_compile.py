@@ -135,3 +135,33 @@ def test_fp8_sr_quantize_compiles_for_v5e(compile_for_chip, mosaic, kind):
         lambda x, s, seed: mosaic.fp8_sr_quantize(x, s, seed, k),
         ((BUCKET_ELEMS,), jnp.float32), ((), jnp.float32),
         ((), jnp.uint32))
+
+
+def test_routed_row_movement_compiles_for_v5e(compile_for_chip):
+    """``bps_moe_take`` (plain and scaled) and ``bps_moe_combine`` (the
+    weighted sum, the plain sum, the products with ``d_out``) at the
+    routed cell's shapes: 16,384 tokens of 2,048 in bf16, 8 choices, 16
+    experts held, a worst-case buffer of 272 row tiles of 512."""
+    from byteps_tpu.ops import routed_rows as rr
+
+    tokens, hidden, k, held, tile, tiles = 16384, 2048, 8, 16, 512, 272
+    assert rr.resolve("gmm", tokens, hidden, 1024, held,
+                      jnp.bfloat16, tile) == "gmm"
+    bounds = (tokens // 512 * held,)
+
+    def move(x, y, index, num, scale, dest, w, lo, hi, live, lanes):
+        bounds = {"lo": lo, "hi": hi, "live": live, "lanes": lanes}
+        rows = rr.take_rows(x, index, num, tile, impl="gmm")
+        d_y = rr.take_rows(x, index, num, tile, scale=scale, impl="gmm")
+        both = [rr.combine_rows(y, dest, weights, bounds, impl="gmm")
+                for weights in (w, None)]
+        d_w = rr.combine_rows(y, dest, None, bounds, d_out=x, impl="gmm")
+        return rows, d_y, both, d_w
+
+    compile_for_chip(
+        move, ((tokens, hidden), jnp.bfloat16),
+        ((tiles * tile, hidden), jnp.bfloat16),
+        ((tiles * tile,), jnp.int32), ((1,), jnp.int32),
+        ((tiles * tile,), jnp.float32), ((tokens, k), jnp.int32),
+        ((tokens, k), jnp.float32), (bounds, jnp.int32), (bounds, jnp.int32), ((1,), jnp.int32),
+        ((tokens // 512, 2, held * 64), jnp.int32))

@@ -1,5 +1,6 @@
-"""The routed feed-forward layer of one chip's share (``moe.routed_ffn``)
-and its grouped products (``ops/grouped_matmul.py``): against a dense
+"""The routed feed-forward layer of one chip's share (``moe.routed_ffn``),
+its grouped products (``ops/grouped_matmul.py``) and the kernels that move
+its rows (``ops/routed_rows.py``): against a dense
 masked product over the held experts; no row dropped under an imbalance
 forced by a biased router; and THE TEST THAT TIES THE SHARE TO THE MODEL:
 the shares of the experts, with the shared expert counted once, add up to
@@ -12,6 +13,7 @@ import pytest
 
 from byteps_tpu.models import moe
 from byteps_tpu.ops import grouped_matmul as gm
+from byteps_tpu.ops import routed_rows as rr
 
 from benchmark.reference import afmoe_share as ref
 
@@ -232,3 +234,179 @@ def test_a_balanced_choice_gives_every_expert_its_share_of_the_rows(impl):
                     jax.tree_util.tree_leaves(gb)):
         np.testing.assert_allclose(np.asarray(x), np.asarray(y), rtol=1e-4,
                                    atol=1e-6)
+
+
+# ---- the movement of rows (ops/routed_rows.py): bps_moe_take, bps_moe_combine
+
+@pytest.mark.parametrize("impl", ["ragged", "gmm_interpret"])
+@pytest.mark.parametrize("routing", ["worst", "none"])
+def test_layer_and_gradients_under_worst_and_under_no_routing(routing, impl):
+    """WORST: the router has as many outputs as a token chooses and all
+    are held, so every token sends all ``k`` rows here. NONE: no token
+    chooses a held expert: the layer is its shared expert, the experts'
+    gradients are zero, nothing is NaN."""
+    if routing == "worst":
+        e, held = K, tuple(range(K))
+    else:
+        e, held = E, (3, 12)
+    cfg = moe.RoutedConfig(e, held, K, 2.8, row_tile=128, impl=impl)
+    blk, f = _layer(6, held)
+    blk["router"] = blk["router"][:, :e]
+    if routing == "none":       # the held outputs are every token's lowest
+        f = jnp.abs(f)
+        blk["router"] = blk["router"].at[:, jnp.asarray(held)].add(-2.0)
+    plan = moe.plan_rows(moe.route(f, blk["router"], cfg)[1], cfg)
+    assert int(plan["counts"].sum()) == (T * K if routing == "worst" else 0)
+
+    def loss(fn):
+        return lambda f, blk: jnp.sum(jnp.sin(fn(f, blk, cfg)))
+
+    (a, ga), (b, gb) = (jax.value_and_grad(loss(fn), (0, 1))(f, blk)
+                        for fn in (moe.routed_ffn, _dense))
+    np.testing.assert_allclose(float(a), float(b), rtol=1e-5)
+    for x, y in zip(jax.tree_util.tree_leaves(ga),
+                    jax.tree_util.tree_leaves(gb)):
+        assert np.isfinite(np.asarray(x)).all()
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), rtol=1e-4,
+                                   atol=1e-6)
+    if routing == "none":
+        shared = moe.gated_silu(f @ blk["shared"]["gate_up"]) @ blk[
+            "shared"]["down"]
+        np.testing.assert_allclose(
+            np.asarray(moe.routed_ffn(f, blk, cfg)), np.asarray(shared),
+            rtol=1e-6, atol=1e-7)
+        for leaf in jax.tree_util.tree_leaves(ga[1]["experts"]):
+            assert not np.asarray(leaf).any()
+
+
+def _plan_with_a_run_that_ends_on_a_tile(tile):
+    """128 tokens of 2 choices, experts 0, 1, 2 of 8 held; expert 2 gets
+    62 rows of its 64-row tile, the last live one: a window over its last
+    rows that ran on would read the buffer behind the rows."""
+    experts = np.full((128, 2), 5, np.int32)
+    experts[:, 1] = 6
+    experts[:40, 0], experts[40:60, 0], experts[60:122, 1] = 0, 1, 2
+    cfg = moe.RoutedConfig(8, (0, 1, 2), 2, row_tile=tile)
+    return moe.plan_rows(jnp.asarray(experts), cfg)
+
+
+def test_the_take_leaves_what_lies_behind_the_rows_and_zeroes_pad_rows():
+    tile, h = 64, 128
+    plan = _plan_with_a_run_that_ends_on_a_tile(tile)
+    live = int(plan["num_tiles"][0]) * tile
+    rng = np.random.RandomState(7)
+    src = jnp.asarray(rng.randn(128, h), jnp.float32)
+    index = np.asarray(plan["row_token"]).copy()
+    assert live < index.size and (index[:live] == 128).any()
+    index[live:] = 2 ** 30          # behind the rows: never looked at
+    scale = jnp.asarray(rng.rand(index.size), jnp.float32)
+    for factor in (None, scale):
+        got = np.asarray(rr.take_rows(src, jnp.asarray(index),
+                                      plan["num_tiles"], tile, scale=factor,
+                                      impl="gmm_interpret"))
+        want = np.asarray(rr.take_rows(src, jnp.asarray(index),
+                                       plan["num_tiles"], tile, scale=factor,
+                                       impl="ragged"))
+        np.testing.assert_allclose(got[:live], want[:live], rtol=1e-6)
+        assert not got[:live][index[:live] == 128].any()    # pad rows
+        # the interpreter hands out NaN for what a kernel never wrote
+        assert np.isnan(got[live:]).all()
+
+
+def test_the_combine_reads_nothing_behind_the_rows(monkeypatch):
+    monkeypatch.setattr(rr, "_TOKEN_TILE", 32)      # four tiles of tokens
+    tile, h = 64, 128
+    plan = _plan_with_a_run_that_ends_on_a_tile(tile)
+    live = int(plan["num_tiles"][0]) * tile
+    rng = np.random.RandomState(8)
+    y = np.full((plan["row_pair"].shape[0], h), np.nan, np.float32)
+    y[:live] = rng.randn(live, h)
+    w = jnp.asarray(rng.rand(128, 2), jnp.float32)
+    d_out = jnp.asarray(rng.randn(128, h), jnp.float32)
+    for weights, ct in ((w, None), (None, None), (None, d_out)):
+        got, want = (rr.combine_rows(buf, plan["dest"], weights,
+                                     plan, d_out=ct, impl=impl)
+                     for buf, impl in ((jnp.asarray(y), "gmm_interpret"),
+                                       (jnp.nan_to_num(y), "ragged")))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("impl", ["ragged", "gmm_interpret"])
+def test_combine_gradients_match_autodiff_of_the_plain_gathers(impl):
+    """``d_w`` of the weights and ``d_y`` of the rows, by the hand-written
+    rule, against autodiff through ``jnp.take`` and an einsum."""
+    held = (0, 1, 2, 3)
+    cfg = moe.RoutedConfig(E, held, K, 2.8, row_tile=128)
+    blk, f = _layer(9, held)
+    weights, chosen = moe.route(f, blk["router"], cfg)
+    plan = moe.plan_rows(chosen, cfg)
+    live = int(plan["num_tiles"][0]) * 128
+    rng = np.random.RandomState(9)
+    y = jnp.zeros((plan["row_pair"].shape[0], H)).at[:live].set(
+        rng.randn(live, H))
+    ct = jnp.asarray(rng.randn(T, H), jnp.float32)
+
+    def plain(y, w):
+        rows = jnp.take(y, plan["dest"].reshape(-1), axis=0, mode="fill",
+                        fill_value=0).reshape(T, K, H)
+        return jnp.sum(jnp.einsum("tkh,tk->th", rows, w) * ct)
+
+    def ours(y, w):
+        return jnp.sum(moe._combine(y, w, plan, 128, impl) * ct)
+
+    (dy, dw), (ey, ew) = (jax.grad(fn, (0, 1))(y, weights)
+                          for fn in (ours, plain))
+    np.testing.assert_allclose(np.asarray(dw), np.asarray(ew), rtol=1e-4,
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(dy[:live]), np.asarray(ey[:live]),
+                               rtol=1e-5, atol=1e-6)
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside its equations."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for inner in (value if isinstance(value, (list, tuple))
+                          else [value]):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner)
+
+
+def test_with_the_kernels_no_gather_walks_the_buffer_or_every_pair():
+    """The layer and its gradients traced with the kernels: no XLA gather
+    reads ``hidden``-wide rows by all ``T * top_k`` chosen pairs or by the
+    buffer's rows (with ``ragged`` there are five); and the kernels that
+    move the rows carry names of their own, not ``bps_gmm*`` (which the
+    benchmark adds into ``kernels.gmm_ms``)."""
+    import re
+    tokens, hidden, held = 256, 1024, (0, 1, 2, 3)
+    rng = np.random.RandomState(10)
+    normal = lambda *s: jnp.asarray(rng.randn(*s) * 0.05, jnp.float32)  # noqa: E731
+    blk = {"router": normal(hidden, E),
+           "experts": {"gate_up": normal(len(held), hidden, 2 * M),
+                       "down": normal(len(held), M, hidden)}}
+    f = normal(tokens, hidden)
+
+    def wide_gathers(impl):
+        cfg = moe.RoutedConfig(E, held, K, 2.8, row_tile=128, impl=impl)
+        buffer = moe.plan_rows(jnp.zeros((tokens, K), jnp.int32),
+                               cfg)["row_pair"].shape[0]
+        traced = jax.make_jaxpr(jax.grad(
+            lambda f, blk: moe.routed_ffn(f, blk, cfg).sum(), (0, 1)))(f, blk)
+        found = [eqn for eqn in _equations(traced.jaxpr)
+                 if eqn.primitive.name == "gather"
+                 and eqn.invars[0].aval.shape[1:] == (hidden,)
+                 and eqn.invars[1].aval.shape[0] in (tokens * K, buffer)]
+        return found, str(traced)
+
+    found, text = wide_gathers("gmm")
+    assert not found, found
+    names = set(re.findall(r"name=(bps_\w+)", text))
+    assert {"bps_moe_take", "bps_moe_combine"} <= names
+    assert {n for n in names if n.startswith("bps_gmm")} == {
+        "bps_gmm", "bps_gmm_dx", "bps_gmm_dw"}
+    # the test sees them: two forward and three backward (none recomputed)
+    assert len(wide_gathers("ragged")[0]) == 5
