@@ -1,25 +1,15 @@
-"""Benchmark entry point. Prints ONE JSON line:
-  {"metric": ..., "value": N, "unit": ..., "vs_baseline": N}
+"""Host-rig entry point: `bench.py <name>` runs ONE standalone
+breakdown (ps_tail, ps_hier, ps_embed, ...) and prints one JSON line
+`{"<name>": {...}}`; with no name it prints the usage and exits 2.
 
-Flagship benchmark: BERT-large MLM training throughput (the reference's
-headline config — README.md:37-44: BERT-large, batch 64/GPU, mixed
-precision). On the single driver-provided chip the honest comparable is
-samples/sec/chip; vs_baseline is the ratio against a plain-JAX training
-step of the identical model with no framework wrapper (≥ 1.0 means the
-framework's distribution layer adds no single-chip overhead; the
-reference's multi-worker scaling numbers need multiple hosts). It needs
-the chip: a run that finds no TPU exits non-zero unless the caller set
-``JAX_PLATFORMS=cpu``, and the line names platform, kind and count.
+The list is single-sourced from the `_BREAKDOWNS` dispatch table — run
+`python bench.py --help` for the current set with one-line summaries;
+this docstring deliberately does NOT enumerate them (it drifted once).
 
-The measurement scaffold (`mlm_setup`, `time_plain_steps`) is shared
-with examples/perf_lab.py so A/B lab numbers stay comparable to this
-headline bench.
-
-Besides the flagship, `bench.py <name>` runs one standalone breakdown
-(ps_tail, ps_hier, ps_embed, ...). The list is single-sourced from the
-`_BREAKDOWNS` dispatch table — run `python bench.py --help` for the
-current set with one-line summaries; this docstring deliberately does
-NOT enumerate them (it drifted once).
+The chip benchmark is `BENCHMARK.json` with the harness under
+`benchmark/` (`python3 benchmark/run.py --workload <cell>`); the chip's
+smoke is `chip_smoke.py`, which borrows `mlm_setup`, `make_plain_step`
+and `verify_kernels` from here.
 """
 
 from __future__ import annotations
@@ -34,7 +24,7 @@ import jax
 
 # --stats: attach the obs metrics-registry summary (per-stage latency
 # histograms with p50/p95/p99, counters, step/wall_s StepStats rollup)
-# to the JSON line for the headline run AND every PS-breakdown variant
+# to the JSON line of every PS-breakdown variant
 # (docs/observability.md). The line stays single-line JSON.
 STATS = "--stats" in sys.argv
 
@@ -83,7 +73,9 @@ import optax
 
 
 def mlm_setup(cfg, batch: int, seq: int):
-    """(params, batch data, loss_fn) for the flagship MLM config."""
+    """(params, batch data, loss_fn) for an MLM config. A smoke's batch
+    (a binomial mask capped at 0.2·seq targets), no measurement's
+    traffic: the instrument's is ``benchmark/generator.py``."""
     from byteps_tpu.models import bert, transformer
 
     params = transformer.init_params(jax.random.PRNGKey(0), cfg)
@@ -101,9 +93,8 @@ def mlm_setup(cfg, batch: int, seq: int):
 
 def make_plain_step(loss_fn, tx):
     """The baseline arm: a donated, jitted plain-JAX train step with no
-    framework wrapper. ONE definition shared by the headline bench's
-    alternating windows, the dh128 variant and examples/perf_lab.py, so
-    the arms can never silently diverge."""
+    framework wrapper. ONE definition shared by ``chip_smoke.py`` and
+    ``ps_tail``, so the arms can never silently diverge."""
 
     @partial(jax.jit, donate_argnums=(0, 1))
     def step(p, s, b):
@@ -114,32 +105,15 @@ def make_plain_step(loss_fn, tx):
     return step
 
 
-def time_plain_steps(params, data, loss_fn, batch: int, iters: int,
-                     warm: int) -> float:
-    """samples/sec of the plain baseline step (one timed window).
-    Consumes ``params`` (donation)."""
-    tx = optax.adamw(1e-4)
-    step = make_plain_step(loss_fn, tx)
-    state = tx.init(params)
-    jb = jax.tree_util.tree_map(np.asarray, data)
-    for _ in range(warm):
-        params, state, l = step(params, state, jb)
-    jax.block_until_ready(l)
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        params, state, l = step(params, state, jb)
-    jax.block_until_ready(l)
-    return batch * iters / (time.perf_counter() - t0)
-
-
 def verify_kernels() -> bool:
     """TPU-mode numerical check of the Pallas kernels vs naive XLA
     attention ON THE REAL CHIP (VERDICT r1: interpret-mode CI alone left
     real-TPU numerics unproven). Raises on any mismatch, which is fatal
     to the run; returns True so the line records that the check ran."""
     import jax.numpy as jnp
-    from byteps_tpu.ops.flash_attention import flash_attention
-    from byteps_tpu.parallel.ring import local_attention, ring_attention
+    from byteps_tpu.ops.flash_attention import (flash_attention,
+                                                local_attention)
+    from byteps_tpu.parallel.ring import ring_attention
 
     key = jax.random.PRNGKey(7)
     b, s, h, d = 2, 512, 4, 64
@@ -1151,7 +1125,7 @@ def pp_breakdown(iters: int = 8, warm: int = 2, dim: int = 512,
     from byteps_tpu.pipeline import (ActivationExchange,
                                      PipelineStageDriver,
                                      StagePartitioner)
-    from byteps_tpu.server import sched as wire_sched
+    from byteps_tpu.server import admission as wire_sched
     from byteps_tpu.server.engine import PSServer
     from byteps_tpu.server.throttle import Nic
     from byteps_tpu.server.transport import (PSTransportServer,
@@ -1281,7 +1255,7 @@ def pp_breakdown(iters: int = 8, warm: int = 2, dim: int = 512,
         / statistics.median(walls["pipelined"]), 4)
 
     # ---- scheduler demo: act frame vs grad burst on one throttled NIC
-    wire_sched.configure(credit)
+    wire_sched.configure_send(credit)
     eng = srv = cli = None
     try:
         nic = Nic(8e6)
@@ -1304,7 +1278,7 @@ def pp_breakdown(iters: int = 8, warm: int = 2, dim: int = 512,
         cli.act_push((1 << 40) | 7, 1, act_payload)
         for t in gts:
             t.join()
-        tr = wire_sched.current().trace()
+        tr = wire_sched.send_scheduler().trace()
         acts_tr = [e for e in tr if e["class"] == "act"]
         out["sched"] = {
             "credit": credit,
@@ -1315,7 +1289,7 @@ def pp_breakdown(iters: int = 8, warm: int = 2, dim: int = 512,
                                             and acts_tr[0]["overtook"]),
         }
     finally:
-        wire_sched.configure(0)
+        wire_sched.configure_send(0)
         for closer in (cli, srv, eng):
             if closer is not None:
                 closer.close()
@@ -2826,10 +2800,7 @@ def _usage() -> str:
     taken from the callable's own docstring — the dispatch table IS the
     documentation, so the two cannot drift."""
     lines = [
-        "usage: python bench.py [<breakdown>] [--stats] [--fleet-stats]",
-        "",
-        "With no <breakdown>: the flagship BERT-large MLM training-",
-        "throughput bench (one JSON line; see the module docstring).",
+        "usage: python bench.py <breakdown> [--stats] [--fleet-stats]",
         "",
         "Breakdowns (bench.py <name> runs exactly one and prints",
         '{"<name>": {...}}):',
@@ -2853,181 +2824,14 @@ def main() -> None:
     if "--help" in sys.argv[1:] or "-h" in sys.argv[1:]:
         print(_usage())
         return
-    # standalone breakdown dispatch: `bench.py ps_comp [--stats]` runs
-    # ONE A/B and prints its JSON line, skipping the flagship run (the
-    # form the CI smoke lanes and the ISSUE win conditions invoke)
+    # `bench.py ps_comp [--stats]` runs ONE A/B and prints its JSON line
+    # (the form the CI smoke lanes and the ISSUE win conditions invoke)
     for name, fn in _BREAKDOWNS.items():
         if name in sys.argv[1:]:
             print(json.dumps({name: fn()}))
             return
-    import byteps_tpu as bps
-    from byteps_tpu.common.config import enable_compile_cache
-    from byteps_tpu.models import bert
-    from byteps_tpu.models.flops import (chip_peak_flops,
-                                         transformer_train_flops_per_sample)
-    from byteps_tpu.training import DistributedTrainer
-
-    enable_compile_cache()
-    dev = jax.devices()[0]
-    on_tpu = dev.platform == "tpu"
-    if not on_tpu and os.environ.get("JAX_PLATFORMS", "").lower() != "cpu":
-        # a measurement path that finds no chip fails; the CPU is used
-        # only when the caller asked for it by name
-        sys.exit(f"bench.py: JAX found no TPU (platform {dev.platform}); "
-                 f"set JAX_PLATFORMS=cpu to run on the CPU on purpose")
-    peak = chip_peak_flops() if on_tpu else None   # unknown kind raises
-    bps.init()
-    if on_tpu:
-        verify_kernels()          # a numerics failure on the chip is fatal
-    cfg = bert.bert_large(max_seq=512)
-    batch, seq = 64, 512      # reference headline config: batch 64/chip
-    iters = 6                 # per WINDOW; windows interleave the two
-                              # arms so slow drift cancels — more,
-                              # shorter windows tighten the ratio at
-                              # the same total timed-step count
-
-    params, data, loss_fn = mlm_setup(cfg, batch, seq)
-
-    # The first seconds of execution on a fresh process run a few
-    # percent slow, and speed drifts on the scale of a phase (±0.05%
-    # swung vs_baseline across whole runs on the earlier setup). So
-    # instead of one long window per arm, the two arms ALTERNATE short
-    # timed windows (A-B-A-B-A-B): slow drift hits both arms equally and
-    # cancels in the ratio. The arms still can't hold params+adam state
-    # resident simultaneously (two BERT-large copies + activations
-    # don't fit HBM), so each window re-inits its arm's state and
-    # del/gc's it after — the jitted executables stay cached, only the
-    # ~1 GB state init is repaid, outside the timed region.
-    warm = 3
-    windows = 6     # EVEN: the lead-arm alternation below needs a
-                    # balanced split to cancel the within-pair order bias
-    import gc
-
-    tx = optax.adamw(1e-4)
-    plain_step = make_plain_step(loss_fn, tx)
-
-    jb = jax.tree_util.tree_map(np.asarray, data)
-    trainer = DistributedTrainer(loss_fn, params, optax.adamw(1e-4))
-    tr_params0, tr_ostate0 = trainer.params, trainer.opt_state
-    # the trainer holds its own copy; keeping the construction copy
-    # resident would press on HBM through every timed window
-    del params
-    gc.collect()
-
-    # per-window re-seed runs ON DEVICE (the jitted init recomputes the
-    # same params from the seed) — a host-side stash would re-cross the
-    # host link with >1 GB per window and dominate the bench wall clock
-    from byteps_tpu.models import transformer as _transformer
-    reinit = jax.jit(
-        lambda: _transformer.init_params(jax.random.PRNGKey(0), cfg))
-
-    def plain_window(first: bool) -> float:
-        p = reinit()
-        s = tx.init(p)
-        for _ in range(warm if first else 1):
-            p, s, l = plain_step(p, s, jb)
-        jax.block_until_ready(l)
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            p, s, l = plain_step(p, s, jb)
-        jax.block_until_ready(l)
-        dt = time.perf_counter() - t0
-        del p, s
-        gc.collect()
-        return dt
-
-    def fw_window(first: bool) -> float:
-        if first:
-            trainer.params, trainer.opt_state = tr_params0, tr_ostate0
-        else:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            rep = NamedSharding(trainer.mesh, P())
-            trainer.params = jax.tree_util.tree_map(
-                lambda x: jax.device_put(x, rep), reinit())
-            from byteps_tpu.parallel.sharding import init_sharded_state
-            trainer.opt_state = init_sharded_state(
-                trainer.tx, trainer.params, trainer._ostate_spec,
-                trainer.mesh)
-        for _ in range(warm if first else 1):
-            loss = trainer.step(data)
-        jax.block_until_ready(loss)
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            loss = trainer.step(data)
-        jax.block_until_ready(loss)         # chained deps -> full timing
-        dt = time.perf_counter() - t0
-        trainer.params = trainer.opt_state = None
-        gc.collect()
-        return dt
-
-    # Pair w=0 MUST run the framework arm first: the trainer's
-    # construction-time param+adam state is still resident until its
-    # first window frees it, and a plain window sharing HBM with it
-    # measured 2.4x slow. Every later window frees its own arm's state
-    # before returning, so from w=1 on the lead arm ALTERNATES — a
-    # monotone speed trend within a pair otherwise favors whichever
-    # arm runs second (measured as a systematic ~0.1-0.2% ratio bias);
-    # the even window count keeps the lead split balanced
-    plain_t = fw_t = 0.0
-    pair_ratios = []
-    for w in range(windows):
-        if w % 2 == 0:
-            ft = fw_window(first=w == 0)
-            pt = plain_window(first=w == 0)
-        else:
-            pt = plain_window(first=False)
-            ft = fw_window(first=False)
-        fw_t += ft
-        plain_t += pt
-        pair_ratios.append(pt / ft)
-    plain_sps = batch * iters * windows / plain_t
-    fw_sps = batch * iters * windows / fw_t
-    # headline ratio = total throughput ratio (what a user experiences);
-    # the per-pair MEDIAN rides along as a drift-robust cross-check —
-    # the two agree within ±0.15% run noise at true parity
-    vs_baseline = fw_sps / plain_sps
-    import statistics
-    vs_baseline_median = statistics.median(pair_ratios)
-
-    # absolute chip accountability: analytic model FLOPs (no remat
-    # recompute counted) against the chip's bf16 peak — "1.0 vs baseline"
-    # alone can't hide an underutilized chip
-    fps = transformer_train_flops_per_sample(
-        cfg, seq, lm_positions=max(1, int(0.2 * seq)))
-    line = {
-        "metric": "bert_large_mlm_train_throughput",
-        "value": round(fw_sps, 2),
-        "unit": "samples/sec/chip",
-        "vs_baseline": round(vs_baseline, 4),
-        "vs_baseline_median_pair": round(vs_baseline_median, 4),
-        "tflops": round(fw_sps * fps / 1e12, 2),
-        "platform": dev.platform,
-        "device_kind": dev.device_kind,
-        "device_count": len(jax.devices()),
-    }
-    if on_tpu:
-        line["mfu"] = round(fw_sps * fps / peak, 4)
-        line["kernels_verified"] = True   # flash fwd/bwd + ring, this run
-
-        # higher-arithmetic-intensity flagship variant: same hidden/
-        # layers/FLOPs, 8 heads × d_head 128 instead of 16 × 64. The
-        # MXU's 128-lane contraction is exactly filled, confirming the
-        # plateau analysis: the d-64 gap is head-geometry, not kernel
-        # quality (docs/performance.md "Where the other 61% goes")
-        import dataclasses
-        del trainer, data
-        gc.collect()
-        cfg128 = dataclasses.replace(cfg, heads=8)
-        p128, d128, lf128 = mlm_setup(cfg128, batch, seq)
-        sps128 = time_plain_steps(p128, d128, lf128, batch, iters, warm)
-        fps128 = transformer_train_flops_per_sample(
-            cfg128, seq, lm_positions=max(1, int(0.2 * seq)))
-        line["dh128_sps"] = round(sps128, 2)
-        line["dh128_mfu"] = round(sps128 * fps128 / peak, 4)
-    if STATS:
-        line["metrics"] = _metrics_summary()
-    bps.shutdown()
-    print(json.dumps(line))
+    print(_usage(), file=sys.stderr)
+    sys.exit(2)
 
 
 if __name__ == "__main__":

@@ -86,11 +86,11 @@ class GlobalState:
         # env, exactly like the metrics master switch above
         from ..obs import watchtower as obs_watchtower
         obs_watchtower.configure()
-        # two-class wire send scheduler (server/sched.py): resolve the
+        # two-class wire send scheduler (server/admission.py): resolve the
         # byte credit for THIS init, before any backend is constructed,
         # so every transport client sees the same gate
-        from ..server import sched as wire_sched
-        wire_sched.configure(config.scheduling_credit)
+        from ..server.admission import configure_send
+        configure_send(config.scheduling_credit)
         self.stats = None
         if config.stats_on:
             from ..obs.stats import StepStatsEmitter

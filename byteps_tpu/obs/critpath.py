@@ -368,9 +368,9 @@ def step_attribution(events: List[dict], step: Optional[int],
     the current wire scheduler. The StepStats/slow-step entry point —
     the chain listing is TRIMMED (the rolling BPS_STATS_FILE must not
     carry hundreds of segments per step; the CLI keeps the full walk)."""
-    from ..server import sched as _sched
+    from ..server.admission import send_scheduler
     from . import spans as _spans
-    sch = _sched.current()
+    sch = send_scheduler()
     res = attribute(events, server_spans=_spans.collected(),
                     sched_trace=sch.trace() if sch is not None else None,
                     step=step, t0=t0_s)

@@ -83,7 +83,7 @@ SHARD_COUNTERS: Tuple[str, ...] = ("ps/param_put_bytes",
                                    "ps/param_fetch_bytes")
 
 # Pipeline-parallel plane (byteps_tpu.pipeline, docs/pipeline-
-# parallelism.md) + the two-class wire scheduler (server/sched.py):
+# parallelism.md) + the two-class wire scheduler (server/admission.py):
 # pre-registered so "is the pipeline / scheduler doing anything" is
 # answerable before any traffic.
 PP_COUNTERS: Tuple[str, ...] = (

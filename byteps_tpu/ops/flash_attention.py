@@ -29,7 +29,6 @@ shapes allow, pure-JAX blockwise otherwise (CPU tests, odd shapes).
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -202,9 +201,9 @@ _DT_PAD = (8, 128)
 def _clamp_ht(ht: int, h: int) -> int:
     """Clamp a head tile to the dtable row bound (_DT_PAD[0]) while
     keeping h % ht == 0. A plain min() can break divisibility — e.g. a
-    BPS_FLASH_HT=12 override with h=12 clamps to 8, the grid covers only
-    heads 0-7, and the kernel silently emits garbage for the rest — so
-    fall back to the largest divisor of h that fits the bound."""
+    tile of 12 with h=12 clamps to 8, the grid covers only heads 0-7,
+    and the kernel silently emits garbage for the rest — so fall back
+    to the largest divisor of h that fits the bound."""
     clamped = min(ht, _DT_PAD[0])
     while clamped > 1 and h % clamped != 0:
         clamped -= 1
@@ -225,6 +224,13 @@ def _table_grad(ds32, bucket, nb):
     return jnp.pad(g, (0, _DT_PAD[1] - nb))
 
 
+# scoped-VMEM budget for the tile chooser (heuristic: real usage exceeds
+# the estimate by the io double-buffers; 10M of estimate keeps Mosaic's
+# 16M limit safe). 11M admits ht=8 for the d64 fwd — measured NEUTRAL
+# (80.22 vs 80.2 sps), so the validated value stands
+_HT_VMEM_BUDGET = 10 << 20
+
+
 def _head_tile(h: int, nq: int, nk: int, bq: int, bk: int, d: int,
                interpret: bool, mats: int = 1) -> int:
     """Heads per kernel program. Short sequences (one block pair per
@@ -234,39 +240,15 @@ def _head_tile(h: int, nq: int, nk: int, bq: int, bk: int, d: int,
     multiply the VMEM footprint, so keep 1. ``mats`` = number of
     [bq, bk] fp32 temporaries live per unrolled head (1 fwd; 3 bwd —
     the Mosaic stack allocator keeps each unrolled iteration's
-    temporaries live, and the scoped-vmem limit is 16M). BPS_FLASH_HT
-    overrides (0 = auto)."""
-    import os as _os
+    temporaries live, and the scoped-vmem limit is 16M)."""
+    if interpret or nq != 1 or nk != 1:
+        return 1
 
     def _vmem(cand: int) -> int:
         return cand * (mats * bq * bk * 4 + 8 * max(bq, bk) * d)
 
-    # scoped-VMEM budget for the tile chooser (heuristic: real usage
-    # exceeds the estimate by the io double-buffers; 10M of estimate
-    # keeps Mosaic's 16M limit safe). Raising it to 11M admits ht=8
-    # for the d64 fwd — measured NEUTRAL (80.22 vs 80.2 sps), so the
-    # validated default stands and the knob exists for experiments
-    budget = int(_os.environ.get("BPS_FLASH_VMEM_BUDGET",
-                                 str(10 << 20)))
-    env = int(_os.environ.get("BPS_FLASH_HT", "0"))
-    if env:
-        if h % env != 0:
-            return 1
-        if _vmem(env) >= budget:
-            # an oversized override would blow the 16M scoped-vmem limit
-            # and fail Mosaic compilation at runtime — clamp to the same
-            # budget the auto path enforces
-            from ..common.logging import get_logger
-            get_logger().warning(
-                "BPS_FLASH_HT=%d exceeds the VMEM budget for this shape "
-                "(bq=%d bk=%d d=%d mats=%d); falling back to auto tiling",
-                env, bq, bk, d, mats)
-        else:
-            return env
-    if interpret or nq != 1 or nk != 1:
-        return 1
     for cand in (8, 4, 2):
-        if h % cand == 0 and _vmem(cand) < budget:
+        if h % cand == 0 and _vmem(cand) < _HT_VMEM_BUDGET:
             return cand
     return 1
 
@@ -498,40 +480,6 @@ def _band_args(window, group, **more):
     if window is None and group == 1:
         return {}
     return dict(window=window, group=group, **more)
-
-
-@jax.named_scope("bps_attn_xla")
-def _xla_fwd(qt, kt, vt, causal, scale, out_dtype=None, bias=None,
-             window=None):
-    """[b,h,s,d] → (out, lse [b,h,s] fp32) with plain XLA ops.
-
-    At moderate sequence lengths the XLA-fused softmax-attention forward
-    beats the Pallas forward kernel (measured: BERT-large seq 512 fwd
-    261→239 ms — the [s,s] scores fit HBM easily and XLA's fusion wins),
-    while the flash BACKWARD kernels still beat XLA's backward (which
-    must materialize softmax gradients). The hybrid uses this forward +
-    the same (out, lse) residual contract the Pallas backward needs.
-    Grouped kv heads: the queries are folded as the kernels fold them."""
-    group, sq = qt.shape[1] // kt.shape[1], qt.shape[2]
-    qt = _fold(qt, group)
-    s = jax.lax.dot_general(qt, kt, (((3,), (3,)), ((0, 1), (0, 1))),
-                            preferred_element_type=jnp.float32) * scale
-    if bias is not None:
-        s = s + bias[None].astype(jnp.float32)
-    if causal:
-        rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        if group > 1:                     # a fold's row: position row % sq
-            rows = rows % sq
-        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 3)
-        s = jnp.where(_visible(rows, cols, window), s, _NEG_INF)
-    m = jnp.max(s, -1)
-    p = jnp.exp(s - m[..., None])
-    l = jnp.sum(p, -1)
-    out = jax.lax.dot_general((p / l[..., None]).astype(vt.dtype), vt,
-                              (((3,), (2,)), ((0, 1), (0, 1))),
-                              preferred_element_type=jnp.float32)
-    return (_unfold(out.astype(out_dtype or qt.dtype), group),
-            _unfold(m + jnp.log(l), group))
 
 
 # -------------------------------------------------------------- backward
@@ -857,8 +805,7 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, bq, bk, interpret,
 
     has_bias = bias is not None
     has_rel = rel is not None
-    if (not has_bias and not has_rel and nq == 1 and nk == 1
-            and os.environ.get("BPS_FLASH_FUSED_BWD", "1") != "0"):
+    if not has_bias and not has_rel and nq == 1 and nk == 1:
         # mats=4: p, dp, ds32 and the cast ds are live per unrolled
         # head. delta passes through as given: None lets the kernel
         # compute it in-kernel (dropping `out` from the backward's
@@ -878,8 +825,8 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, bq, bk, interpret,
                     mats=5 if has_rel else (4 if has_bias else 3))
     if has_rel:
         # the dtable scratch and output tiles are hard-sized to
-        # _DT_PAD rows — a BPS_FLASH_HT override above that would
-        # write out of bounds and break the drel reshape
+        # _DT_PAD rows — a tile above that would write out of bounds
+        # and break the drel reshape
         ht = _clamp_ht(ht, h)
     qspec = pl.BlockSpec((1, ht, bq, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0))
     kspec = _kv_spec(ht, bq, bk, d, nq, group, window)
@@ -992,12 +939,11 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, bq, bk, interpret,
 # ------------------------------------------------------------ public API
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 11, 12, 13))
+                   nondiff_argnums=(3, 4, 5, 6, 7, 10, 11, 12))
 def flash_attention(q, k, v, causal=False, scale=None,
                     block_q=None, block_k=None, interpret=False,
-                    fwd_xla=False, bias=None, rel_table=None,
-                    rel_bidirectional=True, rel_max_distance=128,
-                    window=None):
+                    bias=None, rel_table=None, rel_bidirectional=True,
+                    rel_max_distance=128, window=None):
     """Pallas flash attention. q: [b, sq, heads, d]; k,v: [b, sk, heads,
     d] → [b, sq, heads, d]. sq and sk may differ (cross-attention).
 
@@ -1015,9 +961,7 @@ def flash_attention(q, k, v, causal=False, scale=None,
     Differentiable via the flash backward kernels. 512 blocks measured
     ~29% faster than 256 on BERT-large seq-512 (fewer grid steps,
     full-width MXU tiles); VMEM stays comfortable through d=256
-    (p-block 1MB + acc 512KB). ``fwd_xla`` swaps the forward for the
-    XLA-fused one (see ``_xla_fwd``) while keeping the flash backward —
-    the "hybrid" impl.
+    (p-block 1MB + acc 512KB).
 
     Two additive-score-bias forms (mutually exclusive):
 
@@ -1031,8 +975,8 @@ def flash_attention(q, k, v, causal=False, scale=None,
       lengths only.
     """
     out, _ = _fwd_rule(q, k, v, causal, scale, block_q, block_k, interpret,
-                       fwd_xla, bias, rel_table, rel_bidirectional,
-                       rel_max_distance, window)
+                       bias, rel_table, rel_bidirectional, rel_max_distance,
+                       window)
     return out
 
 
@@ -1050,14 +994,6 @@ def _resolve(q, k, scale, block_q, block_k, whole_kv=False, causal=False):
     sk = k.shape[1]
     if scale is None:
         scale = d ** -0.5
-    # sweep/tuning overrides (examples/flash_block_sweep.py): applied
-    # before the shape-shrink so every call site is covered uniformly
-    env_q = int(os.environ.get("BPS_FLASH_BQ", "0"))
-    env_k = int(os.environ.get("BPS_FLASH_BK", "0"))
-    if env_q:
-        block_q = env_q
-    if env_k:
-        block_k = env_k
     if whole_kv and block_k is None and sk <= _WHOLE_KV:
         block_k = sk
         if causal and block_q is None:
@@ -1091,8 +1027,8 @@ def _check_band(q, k, causal, window, extra) -> None:
 
 
 def _fwd_rule(q, k, v, causal, scale, block_q, block_k, interpret,
-              fwd_xla=False, bias=None, rel_table=None,
-              rel_bidirectional=True, rel_max_distance=128, window=None):
+              bias=None, rel_table=None, rel_bidirectional=True,
+              rel_max_distance=128, window=None):
     _check_band(q, k, causal, window, bias is not None
                 or rel_table is not None)
     if rel_table is not None and rel_table.shape[1] > _DT_PAD[1]:
@@ -1113,18 +1049,9 @@ def _fwd_rule(q, k, v, causal, scale, block_q, block_k, interpret,
     qt = jnp.swapaxes(q, 1, 2)       # [b, h, s, d]
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
-    if fwd_xla:
-        xbias = bias
-        if rel is not None:
-            from .relpos import relative_bias
-            xbias = relative_bias(rel_table.T, q.shape[1], k.shape[1],
-                                  rel[0], rel[1], rel[2])
-        out, lse = _xla_fwd(qt, kt, vt, causal, scale, bias=xbias,
-                            window=window)
-    else:
-        out, lse = _flash_fwd(qt, kt, vt, causal, scale, bq, bk, interpret,
-                              bias=bias, rel_table=rel_table, rel=rel,
-                              window=window)
+    out, lse = _flash_fwd(qt, kt, vt, causal, scale, bq, bk, interpret,
+                          bias=bias, rel_table=rel_table, rel=rel,
+                          window=window)
     from jax.ad_checkpoint import checkpoint_name
     # named so a remat policy can pin the flash residuals while everything
     # around them recomputes (remat_policy="save_attn")
@@ -1134,15 +1061,15 @@ def _fwd_rule(q, k, v, causal, scale, block_q, block_k, interpret,
 
 
 def _vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-             fwd_xla=False, bias=None, rel_table=None,
-             rel_bidirectional=True, rel_max_distance=128, window=None):
+             bias=None, rel_table=None, rel_bidirectional=True,
+             rel_max_distance=128, window=None):
     out, res = _fwd_rule(q, k, v, causal, scale, block_q, block_k, interpret,
-                         fwd_xla, bias, rel_table, rel_bidirectional,
+                         bias, rel_table, rel_bidirectional,
                          rel_max_distance, window)
     return out, res
 
 
-def _vjp_bwd(causal, scale, block_q, block_k, interpret, fwd_xla,
+def _vjp_bwd(causal, scale, block_q, block_k, interpret,
              rel_bidirectional, rel_max_distance, window, res, g):
     qt, kt, vt, out, lse, bias, rel_table = res
     scale, bq, bk = _resolve(jnp.swapaxes(qt, 1, 2), jnp.swapaxes(kt, 1, 2),
@@ -1168,6 +1095,42 @@ def supported(q_shape, k_shape=None) -> bool:
     return sq % 128 == 0 and sk % 128 == 0 and d <= 256
 
 
+def local_attention(q, k, v, causal: bool = False,
+                    scale: float | None = None, bias=None, window=None):
+    """Single-device reference attention, same layout [b, s, h, d]
+    (q and kv lengths may differ; ``bias`` [h, sq, sk] adds to the
+    scores — the T5 relative-position contract). k and v may carry
+    fewer heads than q (query head i attends kv head i // group);
+    ``window`` = w cuts the causal triangle to ``0 <= i - j < w``."""
+    b, s, h, d = q.shape
+    hk = k.shape[2]
+    if scale is None:
+        scale = d ** -0.5
+    if window is not None and not causal:
+        raise ValueError("a window is a causal band")
+    sc = jnp.einsum("bqkgd,bckd->bkgqc", q.reshape(b, s, hk, h // hk, d), k,
+                    preferred_element_type=jnp.float32) * scale
+    sc = sc.reshape(b, h, s, k.shape[1])
+    if bias is not None:
+        sc = sc + bias[None].astype(jnp.float32)
+    if causal:
+        if k.shape[1] != s:
+            # same contract (and message) as the flash path
+            raise ValueError(
+                "causal masking requires equal q/kv lengths (got "
+                f"{s} vs {k.shape[1]}); cross-attention is "
+                "bidirectional")
+        mask = jnp.tril(jnp.ones((s, s), bool))
+        if window is not None:
+            mask = jnp.logical_and(mask, ~jnp.tril(mask, -window))
+        sc = jnp.where(mask[None, None], sc, -jnp.inf)
+    p = jax.nn.softmax(sc, axis=-1)
+    out = jnp.einsum("bkgqc,bckd->bqkgd",
+                     p.astype(v.dtype).reshape(b, hk, h // hk, s, -1), v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, s, h, d).astype(q.dtype)
+
+
 _warned_fallback = set()
 
 
@@ -1176,25 +1139,20 @@ def attention(q, k, v, causal=False, scale=None, impl="auto", bias=None,
               rel_max_distance=128, window=None):
     """Dispatcher: Pallas flash kernels on TPU, blockwise JAX elsewhere.
 
-    impl: "auto" | "flash" | "hybrid" | "naive". "hybrid" = XLA-fused
-    forward + flash backward kernels: wins on FORWARD-dominated work
-    (inference/eval: BERT-large seq-512 fwd measured 261→239 ms) but
-    loses on the rematted train step (69.0 vs 73.7 samples/s — the
-    recompute re-materializes the [s,s] scores inside the backward),
-    so "auto" stays pure flash and hybrid is opt-in.
+    impl: "auto" | "flash" | "naive" ("flash" takes the kernels whatever
+    the platform and shape, "naive" ``local_attention``).
 
     ``rel_table`` [heads, num_buckets]: T5 relative-position bias,
     computed in-kernel on the flash path (no materialized [h, sq, sk]
-    bias); materialized only on the naive/hybrid fallbacks. ``bias``
+    bias); materialized only on the naive fall-back. ``bias``
     [heads, sq, sk]: arbitrary materialized bias. Mutually exclusive.
 
     ``window`` and grouped kv heads (k, v with fewer heads than q) as in
     ``flash_attention``, on every path.
     """
-    if impl not in ("auto", "flash", "hybrid", "naive"):
+    if impl not in ("auto", "flash", "naive"):
         raise ValueError(
-            f"attn impl must be auto|flash|hybrid|naive, got {impl!r}")
-    from ..parallel.ring import local_attention
+            f"attn impl must be auto|flash|naive, got {impl!r}")
 
     def _naive():
         b = bias
@@ -1211,13 +1169,6 @@ def attention(q, k, v, causal=False, scale=None, impl="auto", bias=None,
     if impl == "naive":
         return _naive()
     on_tpu = jax.default_backend() == "tpu"
-    if impl == "hybrid":
-        return flash_attention(q, k, v, causal=causal, scale=scale,
-                               fwd_xla=True, bias=bias,
-                               rel_table=rel_table,
-                               rel_bidirectional=rel_bidirectional,
-                               rel_max_distance=rel_max_distance,
-                               window=window)
     if impl == "flash" or (on_tpu and supported(q.shape, k.shape)):
         return flash_attention(q, k, v, causal=causal, scale=scale,
                                bias=bias, rel_table=rel_table,

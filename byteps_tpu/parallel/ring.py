@@ -302,39 +302,3 @@ def _ring_flash_vjp_bwd(axis_name, causal, scale, interpret, res, g):
 
 
 _ring_flash.defvjp(_ring_flash_vjp_fwd, _ring_flash_vjp_bwd)
-
-
-def local_attention(q, k, v, causal: bool = False,
-                    scale: Optional[float] = None, bias=None, window=None):
-    """Single-device reference attention, same layout [b, s, h, d]
-    (q and kv lengths may differ; ``bias`` [h, sq, sk] adds to the
-    scores — the T5 relative-position contract). k and v may carry
-    fewer heads than q (query head i attends kv head i // group);
-    ``window`` = w cuts the causal triangle to ``0 <= i - j < w``."""
-    b, s, h, d = q.shape
-    hk = k.shape[2]
-    if scale is None:
-        scale = d ** -0.5
-    if window is not None and not causal:
-        raise ValueError("a window is a causal band")
-    sc = jnp.einsum("bqkgd,bckd->bkgqc", q.reshape(b, s, hk, h // hk, d), k,
-                    preferred_element_type=jnp.float32) * scale
-    sc = sc.reshape(b, h, s, k.shape[1])
-    if bias is not None:
-        sc = sc + bias[None].astype(jnp.float32)
-    if causal:
-        if k.shape[1] != s:
-            # same contract (and message) as the flash path
-            raise ValueError(
-                "causal masking requires equal q/kv lengths (got "
-                f"{s} vs {k.shape[1]}); cross-attention is "
-                "bidirectional")
-        mask = jnp.tril(jnp.ones((s, s), bool))
-        if window is not None:
-            mask = jnp.logical_and(mask, ~jnp.tril(mask, -window))
-        sc = jnp.where(mask[None, None], sc, -jnp.inf)
-    p = jax.nn.softmax(sc, axis=-1)
-    out = jnp.einsum("bkgqc,bckd->bqkgd",
-                     p.astype(v.dtype).reshape(b, hk, h // hk, s, -1), v,
-                     preferred_element_type=jnp.float32)
-    return out.reshape(b, s, h, d).astype(q.dtype)

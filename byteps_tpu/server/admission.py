@@ -11,12 +11,11 @@ everything else wants"):
      (``_enqueue_pull`` / ``_pull_next``),
   3. the staged-segment launcher's cross-step epoch gate
      (``cross_step``'s ``wait_epoch(e - 1)``),
-  4. the two-class wire send scheduler (``server/sched.py``).
+  4. the two-class wire send scheduler (``SendScheduler`` below).
 
 They now live here as one plane with one contract. ``KeyGate`` is the
 per-key apply-order gate, ``PullQueue`` is the pull scheduler,
-``SendScheduler`` is the wire gate (``server/sched.py`` remains as a
-compatibility shim re-exporting it), and ``AdmissionPlane`` is the
+``SendScheduler`` is the wire gate, and ``AdmissionPlane`` is the
 facade an exchange owns. The external surfaces are unchanged at the
 default configuration: same metrics (``ps/admission_*``, ``sched/*``),
 same key-less ``send_admit`` flight events, same scheduler trace shape

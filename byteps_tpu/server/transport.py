@@ -1831,7 +1831,7 @@ class RemotePSBackend:
                     _time.sleep(0.2)
 
     # payload-bearing ops the wire scheduler gates (the bandwidth
-    # class; OP_ACT_PUSH is the latency class — see server/sched.py).
+    # class; OP_ACT_PUSH is the latency class — see server/admission.py).
     # OP_REPL_PUT is included: a replication forward-log upload is a
     # merged-round-sized payload — unscheduled it would saturate the
     # NIC outside the credit and nothing could overtake it
@@ -1852,8 +1852,8 @@ class RemotePSBackend:
         # two dict lookups.
         ticket = scheduler = None
         if payload is not None:
-            from . import sched as _sched
-            scheduler = _sched.current()
+            from . import admission as _sched
+            scheduler = _sched.send_scheduler()
             if scheduler is not None:
                 plen = (sum(len(p) for p in payload)
                         if isinstance(payload, (tuple, list))

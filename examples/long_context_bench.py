@@ -138,8 +138,8 @@ def measure_cross(enc_len: int, dec_len: int, heads: int, d: int,
     long-encoder seq2seq enabler (summarization at 8k+ source)."""
     import jax.numpy as jnp
 
-    from byteps_tpu.ops.flash_attention import flash_attention
-    from byteps_tpu.parallel.ring import local_attention
+    from byteps_tpu.ops.flash_attention import (flash_attention,
+                                                local_attention)
 
     key = jax.random.PRNGKey(0)
     q = jax.random.normal(key, (1, dec_len, heads, d), jnp.bfloat16)

@@ -340,6 +340,18 @@ def test_ground_truth_compute_bound():
     assert r["agg"]["dominant"] == "compute", r["agg"]["fracs"]
 
 
+def test_bench_without_a_rig_prints_the_usage(monkeypatch, capsys):
+    """``python bench.py`` measures nothing by itself: no rig named, the
+    usage with every rig and exit code 2."""
+    import bench
+    monkeypatch.setattr("sys.argv", ["bench.py"])
+    with pytest.raises(SystemExit) as e:
+        bench.main()
+    assert e.value.code == 2
+    err = capsys.readouterr().err
+    assert all(f"  {name} " in err for name in bench._BREAKDOWNS), err
+
+
 @pytest.mark.slow
 def test_bench_critpath_smoke():
     """The full acceptance breakdown (three asserted rigs + CLI smoke)
@@ -515,7 +527,7 @@ def test_export_cli_flight_flag(capsys):
 def test_sched_admission_records_flight_event():
     """Send-admission grants land in the flight ring KEY-LESS (context
     for every key's postmortem) with class + overtake flag."""
-    from byteps_tpu.server.sched import CLASS_GRAD, SendScheduler
+    from byteps_tpu.server.admission import CLASS_GRAD, SendScheduler
     sc = SendScheduler(credit_bytes=1 << 20)
     t = sc.acquire(CLASS_GRAD, 3, 42, 8192)
     sc.release(t)

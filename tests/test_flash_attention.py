@@ -5,13 +5,17 @@ kernel logic (online softmax, block masking, backward recompute) is what
 is validated — forward values and all three input gradients, causal and
 bidirectional, fp32 and bf16."""
 
+import ast
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from byteps_tpu.ops.flash_attention import attention, flash_attention
-from byteps_tpu.parallel.ring import local_attention
+import byteps_tpu.ops.flash_attention as fa
+from byteps_tpu.ops.flash_attention import (attention, flash_attention,
+                                            local_attention)
 
 
 def make_qkv(rng, b, s, h, d, dtype):
@@ -84,7 +88,6 @@ def test_naive_fallback_warns_once_per_shape(monkeypatch):
     """On TPU, silently downgrading to O(s^2) attention must be loud."""
     import logging
 
-    import byteps_tpu.ops.flash_attention as fa
     from byteps_tpu.common.logging import get_logger
 
     monkeypatch.setattr(fa.jax, "default_backend", lambda: "tpu")
@@ -105,21 +108,6 @@ def test_naive_fallback_warns_once_per_shape(monkeypatch):
     finally:
         logger.setLevel(prev_level)
         logger.removeHandler(handler)
-
-
-def test_flash_ht_override_clamped_by_vmem(monkeypatch):
-    """BPS_FLASH_HT beyond the scoped-VMEM budget must fall back to auto
-    tiling instead of failing Mosaic compilation at runtime (ADVICE r2)."""
-    from byteps_tpu.ops.flash_attention import _head_tile
-    # a shape where ht=64 would need ~64*(3*512*512*4) bytes >> 10M
-    monkeypatch.setenv("BPS_FLASH_HT", "64")
-    ht = _head_tile(h=64, nq=1, nk=1, bq=512, bk=512, d=64,
-                    interpret=False, mats=3)
-    assert ht in (8, 4, 2, 1) and ht != 64
-    # a modest override inside budget is honored
-    monkeypatch.setenv("BPS_FLASH_HT", "2")
-    assert _head_tile(h=64, nq=1, nk=1, bq=128, bk=128, d=64,
-                      interpret=False) == 2
 
 
 # ---------------------------------------------------------------------------
@@ -170,7 +158,7 @@ def test_bias_forward_backward_exact(causal):
     b, s, h, d = 2, 256, 2, 64
     q, k, v = make_qkv(rng, b, s, h, d, np.float32)
     bias = jnp.asarray(rng.randn(h, s, s).astype(np.float32))
-    out = flash_attention(q, k, v, causal, None, 128, 128, True, False,
+    out = flash_attention(q, k, v, causal, None, 128, 128, True,
                           bias=bias)
     ref = local_attention(q, k, v, causal=causal, bias=bias)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -178,7 +166,7 @@ def test_bias_forward_backward_exact(causal):
 
     def f_loss(q, k, v, bb):
         return (flash_attention(q, k, v, causal, None, 128, 128, True,
-                                False, bias=bb) ** 2).sum()
+                                bias=bb) ** 2).sum()
 
     def n_loss(q, k, v, bb):
         return (local_attention(q, k, v, causal=causal, bias=bb)
@@ -201,7 +189,7 @@ def test_mismatched_bias_cross():
     k = jnp.asarray(rng.randn(1, 384, 2, 64).astype(np.float32))
     v = jnp.asarray(rng.randn(1, 384, 2, 64).astype(np.float32))
     bias = jnp.asarray(rng.randn(2, 128, 384).astype(np.float32))
-    out = flash_attention(q, k, v, False, None, 128, 128, True, False,
+    out = flash_attention(q, k, v, False, None, 128, 128, True,
                           bias=bias)
     ref = local_attention(q, k, v, bias=bias)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -209,10 +197,11 @@ def test_mismatched_bias_cross():
 
 
 @pytest.mark.parametrize("causal", [False, True])
-def test_fused_bwd_matches_split(monkeypatch, causal):
+def test_fused_bwd_matches_split(causal):
     """VERDICT r4 #1: the single-block-pair fused backward (one kernel,
     shared p/dp recompute, 5 matmuls) must produce the same dq/dk/dv as
-    the split dq + dkv kernels (7 matmuls) it replaces.
+    the split dq + dkv kernels (7 matmuls), which the same tensors take
+    in blocks of 128 (two a side).
 
     Tolerance is float-level, not bitwise: the fused kernel computes
     the softmax correction IN-KERNEL as sum_j p_ij*dp_ij while the
@@ -220,30 +209,31 @@ def test_fused_bwd_matches_split(monkeypatch, causal):
     fp32 summation order differs (~1e-5 absolute on unit-scale
     inputs)."""
     rng = np.random.RandomState(11)
-    q = jnp.asarray(rng.randn(2, 256, 4, 64).astype(np.float32))
-    k = jnp.asarray(rng.randn(2, 256, 4, 64).astype(np.float32))
-    v = jnp.asarray(rng.randn(2, 256, 4, 64).astype(np.float32))
+    q, k, v = make_qkv(rng, 2, 256, 4, 64, np.float32)
 
-    def grads():
+    def grads(block):
         def loss(q, k, v):
-            return (flash_attention(q, k, v, causal, None, 512, 512,
+            return (flash_attention(q, k, v, causal, None, block, block,
                                     True).astype(jnp.float32) ** 2).sum()
-        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+        grad = jax.grad(loss, argnums=(0, 1, 2))
+        names = set(re.findall(r"name=(bps_flash_bwd\w+)",
+                               str(jax.make_jaxpr(grad)(q, k, v))))
+        return names, grad(q, k, v)
 
-    monkeypatch.setenv("BPS_FLASH_FUSED_BWD", "1")
-    fused = grads()
-    monkeypatch.setenv("BPS_FLASH_FUSED_BWD", "0")
-    split = grads()
+    fused_names, fused = grads(512)
+    split_names, split = grads(128)
+    assert fused_names == {"bps_flash_bwd_fused"}
+    assert split_names == {"bps_flash_bwd_dq", "bps_flash_bwd_dkv"}
     for a, b_, nm in zip(fused, split, "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    rtol=1e-4, atol=2e-5, err_msg=nm)
 
 
 def test_rel_table_ht_clamp_keeps_divisibility(monkeypatch):
-    """ADVICE r4 (medium): clamping a BPS_FLASH_HT override to the
-    dtable row bound must re-check h % ht — BPS_FLASH_HT=12 with h=12
-    clamped to min(12, 8)=8 would cover only heads 0-7 and silently
-    emit garbage for the rest. The clamp must land on a divisor (6)."""
+    """ADVICE r4 (medium): clamping a head tile to the dtable row bound
+    must re-check h % ht — a tile of 12 with h=12 clamped to
+    min(12, 8)=8 would cover only heads 0-7 and silently emit garbage
+    for the rest. The clamp must land on a divisor (6)."""
     from byteps_tpu.ops.flash_attention import _clamp_ht
     assert _clamp_ht(12, 12) == 6
     assert _clamp_ht(8, 16) == 8
@@ -253,12 +243,12 @@ def test_rel_table_ht_clamp_keeps_divisibility(monkeypatch):
     assert _clamp_ht(13, 13) == 1    # prime > bound: no divisor fits
 
     from byteps_tpu.ops.relpos import relative_bias
-    monkeypatch.setenv("BPS_FLASH_HT", "12")
+    monkeypatch.setattr(fa, "_head_tile", lambda *a, **kw: 12)
     rng = np.random.RandomState(7)
     b, s, h, d, nb = 1, 128, 12, 8, 16
     q, k, v = make_qkv(rng, b, s, h, d, np.float32)
     table = jnp.asarray(rng.randn(h, nb).astype(np.float32))
-    out = flash_attention(q, k, v, False, 1.0, 128, 128, True, False,
+    out = flash_attention(q, k, v, False, 1.0, 128, 128, True,
                           rel_table=table)
     mat = relative_bias(table.T, s, s, True, nb, 128)
     ref = local_attention(q, k, v, causal=False, scale=1.0, bias=mat)
@@ -280,8 +270,7 @@ def test_rel_table_in_kernel_exact(causal, bidir):
 
     def flash(q, k, v, t):
         return flash_attention(q, k, v, causal, 1.0, 128, 128, True,
-                               False, rel_table=t,
-                               rel_bidirectional=bidir)
+                               rel_table=t, rel_bidirectional=bidir)
 
     def ref(q, k, v, t):
         mat = relative_bias(t.T, s, s, bidir, nb, 128)
@@ -313,7 +302,7 @@ def test_rel_table_no_materialized_bias_in_jaxpr():
 
     def loss(q, t):
         return (flash_attention(q, q, q, False, 1.0, 512, 512, True,
-                                False, rel_table=t).astype(jnp.float32)
+                                rel_table=t).astype(jnp.float32)
                 ** 2).sum()
 
     jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(q, table)
@@ -398,7 +387,7 @@ def test_no_padded_statistic_crosses_a_kernel(sq, sk, h, causal, blocks,
                "rel_table": (jnp.zeros((h, 32), jnp.float32),)}[extra]
 
     def loss(q, k, v, *e):
-        return (flash_attention(q, k, v, causal, None, *blocks, False, False,
+        return (flash_attention(q, k, v, causal, None, *blocks, False,
                                 **dict(zip([extra], e)))
                 .astype(jnp.float32) ** 2).sum()
 
@@ -407,27 +396,44 @@ def test_no_padded_statistic_crosses_a_kernel(sq, sk, h, causal, blocks,
     assert_statistics_lane_dense(jaxpr, b * h * sq, calls)
 
 
+def _plain_lse(q, k, causal, scale):
+    """[b, h, s] log-sum-exp of the scores as ``local_attention`` masks
+    them (q, k: [b, h, s, d])."""
+    sc = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                    preferred_element_type=jnp.float32) * scale
+    if causal:
+        sc = jnp.where(jnp.tril(jnp.ones(sc.shape[-2:], bool)), sc, -jnp.inf)
+    return jax.scipy.special.logsumexp(sc, axis=-1)
+
+
+def _plain_out(q, k, v, causal, scale):
+    """``local_attention`` on [b, h, s, d] operands."""
+    return jnp.swapaxes(local_attention(
+        *(jnp.swapaxes(x, 1, 2) for x in (q, k, v)), causal=causal,
+        scale=scale), 1, 2)
+
+
 @pytest.mark.parametrize("ht", [1, 8])
 @pytest.mark.parametrize("s", [128, 384, 1024])
 def test_lse_matches_xla_forward(monkeypatch, s, ht):
     """The kernel's lse, converted to rows 128 at a time, is the plain
     forward's [b, h, s] log-sum-exp: one block (128, 384) and two (1024),
     one head a program and eight."""
-    from byteps_tpu.ops.flash_attention import _flash_fwd, _xla_fwd
-    monkeypatch.setenv("BPS_FLASH_HT", str(ht))
+    monkeypatch.setattr(fa, "_head_tile", lambda *a, **kw: ht)
     rng = np.random.RandomState(13)
     b, h, d = 1, 8, 16
     q, k, v = (jnp.asarray(rng.randn(b, h, s, d).astype(np.float32))
                for _ in range(3))
     bq = min(s, 512)
-    out, lse = _flash_fwd(q, k, v, True, d ** -0.5, bq, bq, True)
-    want_out, want = _xla_fwd(q, k, v, True, d ** -0.5)
+    out, lse = fa._flash_fwd(q, k, v, True, d ** -0.5, bq, bq, True)
+    want = _plain_lse(q, k, True, d ** -0.5)
     assert lse.shape == want.shape == (b, h, s)
     assert lse.dtype == jnp.float32
     np.testing.assert_allclose(np.asarray(lse), np.asarray(want),
                                rtol=1e-6, atol=1e-6)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(want_out),
-                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(_plain_out(q, k, v, True, d ** -0.5)),
+        rtol=2e-5, atol=2e-5)
 
 
 # ---- the single-block forward (one kv block holds the row's keys)
@@ -449,7 +455,7 @@ def test_single_block_forward_matches_online(s, causal, blocks):
     """With the whole kv row in one block the forward is a plain softmax
     (no carried scratch state); out and lse are the online kernel's (128
     blocks) and the plain XLA forward's."""
-    from byteps_tpu.ops.flash_attention import _flash_fwd, _xla_fwd
+    from byteps_tpu.ops.flash_attention import _flash_fwd
     rng = np.random.RandomState(17)
     b, h, d = 1, 2, 32
     q, k, v = (_bhsd(rng, b, h, s, d) for _ in range(3))
@@ -457,7 +463,8 @@ def test_single_block_forward_matches_online(s, causal, blocks):
     out, lse = _flash_fwd(q, k, v, causal, scale, *blocks, True)
     online_out, online_lse = _flash_fwd(q, k, v, causal, scale, 128, 128,
                                         True)
-    ref_out, ref_lse = _xla_fwd(q, k, v, causal, scale)
+    ref_out = _plain_out(q, k, v, causal, scale)
+    ref_lse = _plain_lse(q, k, causal, scale)
     for got, want in ((out, online_out), (out, ref_out)):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
@@ -515,3 +522,47 @@ def test_default_forward_is_single_block_at_1024():
     for a, b_, name in zip(gf, gr, "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    rtol=2e-4, atol=2e-4, err_msg=name)
+
+
+# ---- what chooses the path: arguments and shapes, nothing else
+
+@pytest.mark.parametrize("name,value", [
+    ("BPS_FLASH_FUSED_BWD", "0"), ("BPS_FLASH_BQ", "128"),
+    ("BPS_FLASH_BK", "128"), ("BPS_FLASH_HT", "2"),
+    ("BPS_FLASH_VMEM_BUDGET", "1")])
+def test_no_environment_variable_steers_the_kernels(monkeypatch, name, value):
+    """The five retired variables: set or not, a call traces the same
+    kernels, blocks and head tile (BERT's shape class: one block pair, the
+    fused backward, eight heads a program)."""
+    q = jnp.zeros((1, 256, 8, 64), jnp.bfloat16)
+
+    def trace():
+        def loss(q, k, v):
+            return flash_attention(q, k, v).astype(jnp.float32).sum()
+        return str(jax.make_jaxpr(jax.value_and_grad(
+            loss, argnums=(0, 1, 2)))(q, q, q))
+
+    monkeypatch.delenv(name, raising=False)
+    clean = trace()
+    monkeypatch.setenv(name, value)
+    assert trace() == clean
+    assert "name=bps_flash_bwd_fused" in clean and "grid=(1, 1, 1, 1)" in clean
+
+
+def test_hybrid_is_no_impl():
+    q = jnp.zeros((1, 128, 2, 8), jnp.float32)
+    with pytest.raises(ValueError, match="got 'hybrid'"):
+        attention(q, q, q, impl="hybrid")
+
+
+def test_the_kernels_import_nothing_from_parallel():
+    """``parallel/ring.py`` imports the kernels; the arrow points one way."""
+    with open(fa.__file__) as f:
+        tree = ast.parse(f.read())
+    imported = [(n.module or "", n.level) for n in ast.walk(tree)
+                if isinstance(n, ast.ImportFrom)]
+    imported += [(a.name, 0) for n in ast.walk(tree)
+                 if isinstance(n, ast.Import) for a in n.names]
+    assert not [m for m, level in imported
+                if m.startswith("byteps_tpu.parallel")
+                or (level == 2 and m.split(".")[0] == "parallel")], imported

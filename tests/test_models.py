@@ -306,20 +306,6 @@ def test_chunked_lm_head_composes_with_sequence_parallel():
     np.testing.assert_allclose(got, want, rtol=1e-5)
 
 
-def test_flops_accounting():
-    """Analytic FLOPs: hand-computed BERT-large seq-512 numbers."""
-    from byteps_tpu.models import bert
-    from byteps_tpu.models.flops import (transformer_fwd_flops_per_sample,
-                                         transformer_train_flops_per_sample)
-    cfg = bert.bert_large(max_seq=512)
-    s, h, m = 512, 1024, 4096
-    per_layer = 8 * s * h * h + 4 * s * h * m + 4 * s * s * h
-    head = 2 * 102 * h * cfg.vocab_size
-    want = 24 * per_layer + head
-    assert transformer_fwd_flops_per_sample(cfg, 512, 102) == want
-    assert transformer_train_flops_per_sample(cfg, 512, 102) == 3.0 * want
-
-
 def test_remat_layers_validation_and_exactness():
     """remat_layers must be gated on remat=True; partial remat computes
     the same loss/grads as full remat."""

@@ -319,7 +319,7 @@ from byteps_tpu.pipeline import (ActivationExchange, LocalActPeer,
                                  one_f_one_b, sequential_schedule,
                                  split_microbatches)
 from byteps_tpu.pipeline.exchange import ActStore, PeerDead, act_key
-from byteps_tpu.server import sched as wire_sched
+from byteps_tpu.server import admission as wire_sched
 
 
 def _mlp_case(dim=32, depth=4, batch=8, micro=2, seed=0):
@@ -660,7 +660,7 @@ def test_act_frame_overtakes_grad_burst_under_throttle():
     from byteps_tpu.server.throttle import Nic
     from byteps_tpu.server.transport import (PSTransportServer,
                                              RemotePSBackend)
-    wire_sched.configure(512 << 10)
+    wire_sched.configure_send(512 << 10)
     eng = PSServer(num_workers=1, engine_threads=2)
     srv = PSTransportServer(eng, host="127.0.0.1", port=0)
     cli = RemotePSBackend([f"127.0.0.1:{srv.port}"], nic=Nic(8e6))
@@ -686,7 +686,7 @@ def test_act_frame_overtakes_grad_burst_under_throttle():
             t.join()
         # the act frame beat at least one earlier-enqueued grad both in
         # admission (trace) and in delivery (wall order)
-        tr = wire_sched.current().trace()
+        tr = wire_sched.send_scheduler().trace()
         acts = [e for e in tr if e["class"] == "act"]
         assert acts and acts[0]["overtook"]
         finish = [tag for tag, _ in sorted(done, key=lambda d: d[1])]
@@ -694,7 +694,7 @@ def test_act_frame_overtakes_grad_burst_under_throttle():
         # the mailbox really got the frame
         assert srv.act_store().take(act_key(7), 1, timeout_ms=2000)
     finally:
-        wire_sched.configure(0)
+        wire_sched.configure_send(0)
         cli.close()
         srv.close()
         eng.close()

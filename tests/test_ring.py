@@ -6,8 +6,9 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from byteps_tpu.ops.flash_attention import local_attention
 from byteps_tpu.parallel.mesh import make_mesh
-from byteps_tpu.parallel.ring import local_attention, ring_attention
+from byteps_tpu.parallel.ring import ring_attention
 
 
 @pytest.mark.parametrize("causal", [False, True])

@@ -510,10 +510,10 @@ def test_param_frame_overtakes_grad_burst_under_throttle():
     """A param frame enqueued AFTER a large grad burst is admitted
     ahead of the queued grads (CLASS_ACT base + first-use priority) —
     trace-asserted through the real transport under a throttled NIC."""
-    from byteps_tpu.server import sched as wire_sched
+    from byteps_tpu.server import admission as wire_sched
     from byteps_tpu.server.throttle import Nic
 
-    wire_sched.configure(512 << 10)
+    wire_sched.configure_send(512 << 10)
     eng = PSServer(num_workers=1, engine_threads=2)
     srv = PSTransportServer(eng, host="127.0.0.1", port=0)
     cli = RemotePSBackend([f"127.0.0.1:{srv.port}"], nic=Nic(8e6))
@@ -536,7 +536,7 @@ def test_param_frame_overtakes_grad_burst_under_throttle():
         cli.param_put(pkey, 1, b"p" * (256 << 10))
         for t in gts:
             t.join()
-        tr = wire_sched.current().trace()
+        tr = wire_sched.send_scheduler().trace()
         params = [e for e in tr if e["class"] == "act"
                   and e["key"] == pkey]
         assert params, tr
@@ -545,7 +545,7 @@ def test_param_frame_overtakes_grad_burst_under_throttle():
         # the mailbox really got the frame
         assert srv.param_store().get(pkey, 1, timeout_ms=2000)
     finally:
-        wire_sched.configure(0)
+        wire_sched.configure_send(0)
         cli.close()
         srv.close()
         eng.close()
