@@ -213,10 +213,22 @@ def _layernorm(x, scale, bias, eps=1e-5):
 
 
 def _attention(x, blk, cfg: TransformerConfig, tp_size: int):
+    """Self-attention of a block. q, k and v each leave their own product
+    as [b, s, heads*head_dim], heads side by side on the minor axis, and
+    attention's output enters ``attn_out`` so: the layout in which the
+    flash kernels read and write HBM where a head is narrower than a
+    lane tile (``ops/flash_attention.py``, the note on narrow heads), so
+    that nothing is copied between a product and a kernel. The
+    [b, s, heads, head_dim] the kernels' API, the ring and tensor
+    parallelism see is a reshape of it. (One product to
+    [b, s, 3, heads, head_dim] and three slices, as before PR 33, left
+    every q, k, v a strided slice to copy out.) The weight is stored
+    [h, 3, heads, head_dim] as ever."""
     b, s, _ = x.shape
     local_heads = cfg.heads // tp_size
-    qkv = jnp.einsum("bsh,hcnd->bscnd", x, blk["qkv"].astype(x.dtype))
-    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]  # [b, s, lh, hd]
+    w = blk["qkv"].astype(x.dtype)
+    q, k, v = ((x @ w[:, c].reshape(w.shape[0], -1))
+               .reshape(b, s, local_heads, cfg.head_dim) for c in range(3))
     if cfg.sp_axis is not None:
         out = ring_attention(q, k, v, cfg.sp_axis, causal=cfg.causal,
                              impl=cfg.attn_impl)

@@ -18,9 +18,12 @@ kernel pair:
     the lane axis, never as [.., s, 1] columns (padded 128x there)
 
 Layout contract matches the rest of the stack: [batch, seq, heads,
-head_dim] in, same out. Kernels run per (batch, head) over a grid of
-sequence blocks; the kv-block loop is the innermost grid dimension so
-the accumulator scratch lives in VMEM across it.
+head_dim] in, same out. Kernels run per (batch, head tile) over a grid
+of sequence blocks; the kv-block loop is the innermost grid dimension so
+the accumulator scratch lives in VMEM across it. Across HBM the operands
+travel head-major, [b, heads, s, d], or where a head's width divides a
+128-lane tile lane-dense, [b, s, heads*d]: ``_lane_dense`` chooses from
+the call's shapes (the note on narrow heads, below).
 
 `attention()` is the dispatcher the models call: Pallas on TPU when
 shapes allow, pure-JAX blockwise otherwise (CPU tests, odd shapes).
@@ -104,6 +107,86 @@ def _unfold(x, group):
         return x
     b, hk, gs = x.shape[:3]
     return x.reshape((b, hk * group, gs // group) + x.shape[3:])
+
+
+# ---- heads narrower than a lane tile: [b, s, heads*d] across HBM ----
+# An HBM tile is 128 lanes wide. Head-major [b, h, s, d] puts d on the
+# lanes: at d = 128 every tile is full, at BERT's and GPT-2's 64 every
+# tile of q, k, v, out and their cotangents is half empty, and the
+# swapaxes to and from the projections' [b, s, heads, d] are copies
+# between two padded layouts (PR 32: 138 of a 712 ms BERT-large step).
+# So where d divides 128 those eight cross HBM as the projections write
+# and read them, [b, s, heads*d], a reshape of the public layout: a
+# block is (1, rows, ht*d) with ht*d whole lane tiles, the head tile on
+# the lane axis. Inside a kernel a head is the lanes t*d to (t+1)*d of
+# its block, and no lane moves: an operand is the whole 128-lane tile
+# the head lies in, its neighbours beside it (``_take``), a product
+# over the tile's lanes costs the MXU what one over d of them does
+# (either half-fills a 128 x 128 pass), and a result's own d lanes are
+# stored where they already sit (``_put``). Handing a product a head's
+# [rows, d] slice instead has Mosaic rotate every odd head to lane 0
+# and back (measured, PR 33, the kernels alone: BERT-large's fused
+# backward 2.25 ms a call against 2.07, seq 128's forward 0.90 against
+# 0.66). The rows' statistics keep their own layout (below). The bodies
+# are the head-major ones: mask, softmax and the five products do not
+# know which layout handed them a head. ``_lane_dense`` says which
+# calls take it; every other call, d = 128 first, traces as it did
+# before the layout existed.
+
+def _take(ref, t, lanes=None, rows=None, alone=False):
+    """Head ``t`` of a block of q, k, v or do as a product's operand.
+    Head-major, block (1, ht, rows, d): its [rows, d]. Lane-dense
+    (``lanes`` = d), block (1, rows, ht*d): the [rows, 128] lane tile
+    the head lies in. ``alone`` zeroes the neighbours' lanes there: an
+    operand contracted over its lanes with the neighbours still in the
+    other one (q in q k', do in do v') must be; a product that keeps the
+    lanes (p v, ds k, ds' q, p' do) gives the neighbours' columns beside
+    the head's own, which ``_put`` leaves behind. ``rows``: a slice of
+    the block's rows, default all."""
+    if lanes is None:
+        return ref[(0, t) if rows is None else (0, t, rows)]
+    lo = t * lanes // 128 * 128
+    x = ref[0, slice(None) if rows is None else rows, lo:lo + 128]
+    if alone:
+        lane = lo + jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
+        own = jnp.logical_and(lane >= t * lanes, lane < (t + 1) * lanes)
+        x = jnp.where(own, x, jnp.zeros_like(x))
+    return x
+
+
+def _put(ref, t, value, lanes=None, rows=None):
+    """Head ``t``'s result into its place in a block of out, dq, dk or
+    dv (laid out as in ``_take``), cast to the block's dtype; lane-dense,
+    ``value`` is [rows, 128] and its lanes outside the head's are not
+    stored."""
+    if lanes is None:
+        ref[(0, t) if rows is None else (0, t, rows)] = value.astype(
+            ref.dtype)
+        return
+    lo = t * lanes % 128
+    ref[0, slice(None) if rows is None else rows,
+        t * lanes:(t + 1) * lanes] = value[:, lo:lo + lanes].astype(ref.dtype)
+
+
+def _rows_spec(ht, rows, d, dense, at):
+    """BlockSpec of ``ht`` heads' ``rows`` rows of such an operand;
+    ``at`` maps the grid's indices to (batch, head tile, row block)."""
+    if dense:
+        def lane_block(*grid):
+            ib, ih, ir = at(*grid)
+            return ib, ir, ih
+        return pl.BlockSpec((1, rows, ht * d), lane_block)
+    return pl.BlockSpec((1, ht, rows, d), lambda *grid: (*at(*grid), 0))
+
+
+def _dims(q, k, heads):
+    """(b, query heads, kv heads, sq, sk, d) of head-major q and k, or
+    with ``heads`` given of lane-dense ones."""
+    if heads is None:
+        b, hq, sq, d = q.shape
+        return b, hq, k.shape[1], sq, k.shape[2], d
+    b, sq, lanes = q.shape
+    return b, heads, heads, sq, k.shape[1], lanes // heads
 
 
 # ---- row statistics (lse, delta): lane-dense across HBM ----
@@ -243,14 +326,34 @@ def _head_tile(h: int, nq: int, nk: int, bq: int, bk: int, d: int,
     temporaries live, and the scoped-vmem limit is 16M)."""
     if interpret or nq != 1 or nk != 1:
         return 1
-
-    def _vmem(cand: int) -> int:
-        return cand * (mats * bq * bk * 4 + 8 * max(bq, bk) * d)
-
     for cand in (8, 4, 2):
-        if h % cand == 0 and _vmem(cand) < _HT_VMEM_BUDGET:
+        if (h % cand == 0
+                and _tile_vmem(cand, mats, bq, bk, d) < _HT_VMEM_BUDGET):
             return cand
     return 1
+
+
+def _tile_vmem(ht: int, mats: int, bq: int, bk: int, d: int) -> int:
+    return ht * (mats * bq * bk * 4 + 8 * max(bq, bk) * d)
+
+
+def _dense_tile(h: int, nq: int, nk: int, bq: int, bk: int, d: int,
+                interpret: bool, mats: int):
+    """Heads per program of a lane-dense call (see the note on narrow
+    heads), or None where there is none: ``_head_tile``'s choice if its
+    ht*d lanes are whole tiles, else the least tile whose are (2 at
+    width 64: the split backward and the interpreter, where
+    ``_head_tile`` keeps 1), if it divides the heads and fits the same
+    VMEM count. Only widths that divide a lane tile: a head of 96 would
+    lie across two."""
+    if d >= 128 or 128 % d:
+        return None
+    least = 128 // d
+    ht = _head_tile(h, nq, nk, bq, bk, d, interpret, mats)
+    if ht % least:
+        ht = least
+    fits = interpret or _tile_vmem(ht, mats, bq, bk, d) < _HT_VMEM_BUDGET
+    return ht if h % ht == 0 and fits else None
 
 
 # --------------------------------------------------------------- forward
@@ -342,7 +445,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, bq, bk, nk,
 
 
 def _fwd_single_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
-                       causal, bq, bk, nq, ht, window=None, group=1):
+                       causal, bq, bk, nq, ht, window=None, group=1,
+                       lanes=None):
     """Forward when ONE kv block holds every key of the row (nk == 1;
     caller guarantees no bias/rel_table): a plain softmax a q row, no
     state carried from grid step to grid step. The online form's round
@@ -368,10 +472,10 @@ def _fwd_single_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
                 jnp.int32, (rc, keys), 0)
             cols = jax.lax.broadcasted_iota(jnp.int32, (rc, keys), 1)
             visible = _visible(rows, cols, window)
-        for t in range(ht):                  # heads per program (see
-            q = q_ref[0, t, r0:r0 + rc]      # _fwd_kernel)   [rc, d]
-            k = k_ref[0, t, :keys]                          # [keys, d]
-            v = v_ref[0, t, :keys]
+        for t in range(ht):          # heads per program (see _fwd_kernel)
+            q = _take(q_ref, t, lanes, slice(r0, r0 + rc), alone=True)
+            k = _take(k_ref, t, lanes, slice(keys))         # [keys, d]
+            v = _take(v_ref, t, lanes, slice(keys))
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale   # [rc, keys]
@@ -383,12 +487,12 @@ def _fwd_single_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
             pv = jax.lax.dot_general(
                 p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)           # [rc, d]
-            o_ref[0, t, r0:r0 + rc] = (pv / l).astype(o_ref.dtype)
+            _put(o_ref, t, pv / l, lanes, slice(r0, r0 + rc))
             _store_stat(lse_ref, t, m + jnp.log(l), r0)
 
 
 def _flash_fwd(q, k, v, causal, scale, bq, bk, interpret, out_dtype=None,
-               bias=None, rel_table=None, rel=None, window=None):
+               bias=None, rel_table=None, rel=None, window=None, heads=None):
     """q: [b, h, sq, d]; k,v: [b, hkv, sk, d] → (out [b,h,sq,d],
     lse [b,h,sq] fp32). sq and sk may DIFFER (cross-attention: the
     decoder's queries over the encoder's keys) — the kernels only ever
@@ -396,17 +500,21 @@ def _flash_fwd(q, k, v, causal, scale, bq, bk, interpret, out_dtype=None,
     divide ``h`` (grouped kv heads) and ``window`` cut the causal
     triangle to a band (see the note at the top).
 
+    With ``heads`` given (a call ``_lane_dense`` admits) q, k, v and out
+    are lane-dense, [b, s, heads*d]; lse as ever.
+
     out_dtype overrides the output dtype (default q.dtype) — ring
     attention requests fp32 partials so the per-step LSE combine does
     not accumulate one bf16 rounding per ring step."""
-    b, hq, sq, d = q.shape
-    h, sk = k.shape[1], k.shape[2]
+    dense = heads is not None
+    b, hq, h, sq, sk, d = _dims(q, k, heads)
     group = hq // h
     q = _fold(q, group)
     nq, nk = sq // bq, sk // bk           # blocks a head
     steps = nk if window is None else _band_steps_k(nq, bq, bk, window)
-    ht = _head_tile(h, group * nq, nk, bq, bk, d, interpret,
-                    mats=3 if rel is not None else 1)
+    ht = (_dense_tile if dense else _head_tile)(
+        h, group * nq, nk, bq, bk, d, interpret,
+        mats=3 if rel is not None else 1)
     if rel is not None:
         ht = _clamp_ht(ht, h)   # matches the bwd dtable tile bound
     grid = (b, h // ht, group * nq, steps)
@@ -414,6 +522,7 @@ def _flash_fwd(q, k, v, causal, scale, bq, bk, interpret, out_dtype=None,
     if nk == 1 and not has_bias and rel is None:
         kernel = functools.partial(_fwd_single_kernel, scale=scale,
                                    causal=causal, bq=bq, bk=bk, nq=nq, ht=ht,
+                                   lanes=d if dense else None,
                                    **_band_args(window, group))
         scratch = []
     else:
@@ -425,11 +534,10 @@ def _flash_fwd(q, k, v, causal, scale, bq, bk, interpret, out_dtype=None,
                    pltpu.VMEM((ht * bq, 128), jnp.float32),
                    pltpu.VMEM((ht * bq, 128), jnp.float32)]
     kv_spec = _kv_spec(ht, bq, bk, d, nq, group,
-                       None if nk == 1 else window)
-    in_specs = [
-        pl.BlockSpec((1, ht, bq, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-        kv_spec, kv_spec,
-    ]
+                       None if nk == 1 else window, dense)
+    q_spec = _rows_spec(ht, bq, d, dense,
+                        lambda ib, ih, iq, ik: (ib, ih, iq))
+    in_specs = [q_spec, kv_spec, kv_spec]
     inputs = [q, k, v]
     if has_bias:
         in_specs.append(pl.BlockSpec(
@@ -444,12 +552,12 @@ def _flash_fwd(q, k, v, causal, scale, bq, bk, interpret, out_dtype=None,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, ht, bq, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
+            q_spec,
             pl.BlockSpec((1, ht, 1, bq), lambda ib, ih, iq, ik: (ib, ih, 0, iq)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, out_dtype or q.dtype),
-            jax.ShapeDtypeStruct((b, h, 1, q.shape[2]), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, 1, group * sq), jnp.float32),
         ],
         scratch_shapes=scratch,
         compiler_params=_DIM_SEMANTICS,
@@ -459,13 +567,13 @@ def _flash_fwd(q, k, v, causal, scale, bq, bk, interpret, out_dtype=None,
     return _unfold(out, group), _unfold(lse[:, :, 0], group)
 
 
-def _kv_spec(ht, bq, bk, d, nq, group, window):
+def _kv_spec(ht, bq, bk, d, nq, group, window, dense=False):
     """k's and v's BlockSpec on a (b, heads, q blocks, kv steps) grid: the
     step's own block, or under a band the block the step stands for,
     held at the q block's diagonal once the band has passed it."""
     if window is None:
-        return pl.BlockSpec((1, ht, bk, d),
-                            lambda ib, ih, iq, ik: (ib, ih, ik, 0))
+        return _rows_spec(ht, bk, d, dense,
+                          lambda ib, ih, iq, ik: (ib, ih, ik))
 
     def kv_block(ib, ih, iq, ik):
         qb = iq % nq if group > 1 else iq
@@ -486,7 +594,7 @@ def _band_args(window, group, **more):
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                scale, causal, bq, bk, nk, ht, has_bias=False, rel=None,
-               nq=0, window=None, group=1, nqh=0):
+               nq=0, window=None, group=1, nqh=0, lanes=None):
     """``nk``: the kv dimension's grid steps; ``nqh``: q blocks a head
     (folded q axis), as in ``_fwd_kernel``."""
     bias_ref = dbias_ref = rel_ref = dt_ref = dt_scr = None
@@ -529,10 +637,10 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         if rel is not None:
             bucket = _bucket_block(qb, kb, bq, bk, rel[0], rel[1], rel[2])
         for t in range(ht):                  # heads per program (see fwd)
-            q = q_ref[0, t]
-            k = k_ref[0, t]
-            v = v_ref[0, t]
-            do = do_ref[0, t]
+            q = _take(q_ref, t, lanes, alone=True)
+            k = _take(k_ref, t, lanes)
+            v = _take(v_ref, t, lanes)
+            do = _take(do_ref, t, lanes, alone=True)
             lse = _load_stat(lse_ref, t)                    # [bq, 1]
             delta = _load_stat(delta_ref, t)                # [bq, 1]
             s = jax.lax.dot_general(
@@ -568,7 +676,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     @pl.when(ik == nk - 1)
     def _finish():
         for t in range(ht):
-            dq_ref[0, t] = dq_acc[t * bq:(t + 1) * bq].astype(dq_ref.dtype)
+            _put(dq_ref, t, dq_acc[t * bq:(t + 1) * bq], lanes)
 
     if rel is not None:
         @pl.when(jnp.logical_and(kb == nk - 1, qb == nq - 1))
@@ -578,7 +686,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                 scale, causal, bq, bk, nq, ht, has_bias=False, rel=None,
-                window=None, group=1, nqh=0, steps=0):
+                window=None, group=1, nqh=0, steps=0, lanes=None):
     """``nq``: the q dimension's grid steps, ``group * steps`` of them
     where the q axis is folded or banded: ``steps`` a head, over its
     ``nqh`` q blocks or the part of them in the kv block's band."""
@@ -612,10 +720,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         if rel is not None:
             bucket = _bucket_block(qb, kb, bq, bk, rel[0], rel[1], rel[2])
         for t in range(ht):                  # heads per program (see fwd)
-            q = q_ref[0, t]                                 # [bq, d]
-            k = k_ref[0, t]                                 # [bk, d]
-            v = v_ref[0, t]
-            do = do_ref[0, t]                               # [bq, d]
+            q = _take(q_ref, t, lanes, alone=True)          # [bq, d]
+            k = _take(k_ref, t, lanes)                      # [bk, d]
+            v = _take(v_ref, t, lanes)
+            do = _take(do_ref, t, lanes, alone=True)        # [bq, d]
             lse = _load_stat(lse_ref, t)                    # [bq, 1]
             delta = _load_stat(delta_ref, t)
             s = jax.lax.dot_general(
@@ -651,13 +759,13 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     def _finish():
         for t in range(ht):
             r = slice(t * bk, (t + 1) * bk)
-            dk_ref[0, t] = dk_acc[r].astype(dk_ref.dtype)
-            dv_ref[0, t] = dv_acc[r].astype(dv_ref.dtype)
+            _put(dk_ref, t, dk_acc[r], lanes)
+            _put(dv_ref, t, dv_acc[r], lanes)
 
 
 def _dqkv_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, *rest,
                        scale, causal, bq, bk, ht, has_delta, window=None,
-                       group=1):
+                       group=1, lanes=None):
     """Single-block-pair fused backward: when the whole sequence is one
     (bq, bk) block per (b, head) — the flagship seq-512 geometry — the
     split dq / dkv kernels each recompute s, p and dp just to emit
@@ -691,10 +799,10 @@ def _dqkv_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, *rest,
     else:
         dq_ref, dk_ref, dv_ref = rest
     for t in range(ht):
-        q = q_ref[0, t]                                     # [bq, d]
-        k = k_ref[0, t]                                     # [bk, d]
-        v = v_ref[0, t]
-        do = do_ref[0, t]
+        q = _take(q_ref, t, lanes, alone=True)              # [bq, d]
+        k = _take(k_ref, t, lanes)                          # [bk, d]
+        v = _take(v_ref, t, lanes)
+        do = _take(do_ref, t, lanes, alone=True)
         lse = _load_stat(lse_ref, t)                        # [bq, 1]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
@@ -709,7 +817,7 @@ def _dqkv_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, *rest,
             pt, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         if group == 1:
-            dv_ref[0, t] = dv.astype(dv_ref.dtype)
+            _put(dv_ref, t, dv, lanes)
         else:
             dv_acc[...] += dv
         dp = jax.lax.dot_general(
@@ -721,15 +829,14 @@ def _dqkv_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, *rest,
             delta = jnp.sum(p * dp, -1, keepdims=True)      # [bq, 1]
         ds32 = p * (dp - delta)
         ds = ds32.astype(q.dtype)
-        dq_ref[0, t] = (jax.lax.dot_general(
+        _put(dq_ref, t, jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-            * scale).astype(dq_ref.dtype)
+            preferred_element_type=jnp.float32) * scale, lanes)
         dk = jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
         if group == 1:
-            dk_ref[0, t] = dk.astype(dk_ref.dtype)
+            _put(dk_ref, t, dk, lanes)
         else:
             dk_acc[...] += dk
 
@@ -741,23 +848,26 @@ def _dqkv_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, *rest,
 
 
 def _flash_bwd_fused(q, k, v, lse, do, delta, causal, scale, bq, bk,
-                     interpret, ht, window=None, group=1):
+                     interpret, ht, window=None, group=1, heads=None):
     """One pallas_call emitting (dq, dk, dv); caller guarantees
     nq == nk == 1 a head and no bias/rel_table. ``lse`` and ``delta``
     are [b,h,sq]; ``delta=None`` computes it in-kernel (see
     _dqkv_fused_kernel) — the no-``out``-input form. With ``group`` > 1
     q, do and the statistics come folded and the grid gains the group as
-    its carried dimension."""
-    b, h, sk, d = k.shape
+    its carried dimension. ``heads`` as in ``_flash_fwd``: q, k, v, do
+    and the three gradients lane-dense."""
+    dense = heads is not None
+    b, _, h, _, _, d = _dims(q, k, heads)
     has_delta = delta is not None
     kernel = functools.partial(_dqkv_fused_kernel, scale=scale,
                                causal=causal, bq=bq, bk=bk, ht=ht,
                                has_delta=has_delta,
+                               lanes=d if dense else None,
                                **_band_args(window, group))
     if group == 1:
         grid, semantics, scratch = (b, h // ht), ("parallel", "parallel"), []
-        spec_q = pl.BlockSpec((1, ht, bq, d), lambda ib, ih: (ib, ih, 0, 0))
-        spec_k = pl.BlockSpec((1, ht, bk, d), lambda ib, ih: (ib, ih, 0, 0))
+        spec_q = _rows_spec(ht, bq, d, dense, lambda ib, ih: (ib, ih, 0))
+        spec_k = _rows_spec(ht, bk, d, dense, lambda ib, ih: (ib, ih, 0))
         spec_stat = pl.BlockSpec((1, ht, 1, bq),
                                  lambda ib, ih: (ib, ih, 0, 0))
     else:
@@ -781,8 +891,8 @@ def _flash_bwd_fused(q, k, v, lse, do, delta, causal, scale, bq, bk,
         in_specs=in_specs,
         out_specs=[spec_q, spec_k, spec_k],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct((b, h, sk, d), k.dtype),
-                   jax.ShapeDtypeStruct((b, h, sk, d), v.dtype)],
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(k.shape, v.dtype)],
         scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=interpret,
@@ -791,17 +901,21 @@ def _flash_bwd_fused(q, k, v, lse, do, delta, causal, scale, bq, bk,
 
 
 def _flash_bwd(q, k, v, out, lse, do, causal, scale, bq, bk, interpret,
-               delta=None, bias=None, rel_table=None, rel=None, window=None):
+               delta=None, bias=None, rel_table=None, rel=None, window=None,
+               heads=None):
     """(dq, dk, dv, dbias, drel). ``lse`` and a caller's ``delta`` are
     [b,h,sq] fp32, like every row statistic outside the kernels. k and v
-    may have fewer heads than q (grouped), ``window`` as in _flash_fwd."""
-    b, hq, sq, d = q.shape
-    h, sk = k.shape[1], k.shape[2]
+    may have fewer heads than q (grouped), ``window`` as in _flash_fwd;
+    so ``heads``: q, k, v, out, do, dq, dk and dv lane-dense."""
+    dense = heads is not None
+    b, hq, h, sq, sk, d = _dims(q, k, heads)
     group = hq // h
     nq, nk = sq // bq, sk // bk           # blocks a head
     q, out, lse, do, delta = (None if x is None else _fold(x, group)
                               for x in (q, out, lse, do, delta))
     band = _band_args(window, group)
+    lanes = d if dense else None
+    tile = _dense_tile if dense else _head_tile
 
     has_bias = bias is not None
     has_rel = rel is not None
@@ -812,24 +926,29 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, bq, bk, interpret,
         # inputs — under remat the recompute's p·V matmul DCEs away);
         # ring callers' hoisted GLOBAL delta is honored
         ht_f = (1 if group > 1 else
-                _head_tile(h, nq, nk, bq, bk, d, interpret, mats=4))
+                tile(h, nq, nk, bq, bk, d, interpret, mats=4))
         dq, dk, dv = _flash_bwd_fused(q, k, v, lse, do, delta, causal,
-                                      scale, bq, bk, interpret, ht_f, **band)
+                                      scale, bq, bk, interpret, ht_f,
+                                      heads=heads, **band)
         return _unfold(dq, group), dk, dv, None, None
 
     if delta is None:      # ring callers hoist this loop-invariant reduction
-        delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                        axis=-1)                            # [b,h,s]
+        delta = do.astype(jnp.float32) * out.astype(jnp.float32)
+        if dense:          # a head's lanes summed, then [b,s,h] -> [b,h,s]
+            delta = jnp.swapaxes(
+                jnp.sum(delta.reshape(b, sq, h, d), axis=-1), 1, 2)
+        else:
+            delta = jnp.sum(delta, axis=-1)                 # [b,h,s]
     lse, delta = lse[:, :, None], delta[:, :, None]         # [b,h,1,s]
-    ht = _head_tile(h, group * nq, nk, bq, bk, d, interpret,
-                    mats=5 if has_rel else (4 if has_bias else 3))
+    ht = tile(h, group * nq, nk, bq, bk, d, interpret,
+              mats=5 if has_rel else (4 if has_bias else 3))
     if has_rel:
         # the dtable scratch and output tiles are hard-sized to
         # _DT_PAD rows — a tile above that would write out of bounds
         # and break the drel reshape
         ht = _clamp_ht(ht, h)
-    qspec = pl.BlockSpec((1, ht, bq, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0))
-    kspec = _kv_spec(ht, bq, bk, d, nq, group, window)
+    qspec = _rows_spec(ht, bq, d, dense, lambda ib, ih, iq, ik: (ib, ih, iq))
+    kspec = _kv_spec(ht, bq, bk, d, nq, group, window, dense)
     stat = pl.BlockSpec((1, ht, 1, bq), lambda ib, ih, iq, ik: (ib, ih, 0, iq))
     steps_k = nk if window is None else _band_steps_k(nq, bq, bk, window)
 
@@ -837,7 +956,8 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, bq, bk, interpret,
     inputs = [q, k, v, do, lse, delta]
     out_specs = qspec
     out_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
-    scratches = [pltpu.VMEM((ht * bq, d), jnp.float32)]
+    acc_lanes = 128 if dense else d     # lane-dense: a head's whole tile
+    scratches = [pltpu.VMEM((ht * bq, acc_lanes), jnp.float32)]
     params = _DIM_SEMANTICS
     if has_bias:
         bspec = pl.BlockSpec((ht, bq, bk), lambda ib, ih, iq, ik: (ih, iq, ik))
@@ -869,7 +989,8 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, bq, bk, interpret,
     res = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, nk=steps_k, ht=ht, has_bias=has_bias,
-                          rel=rel, nq=nq, **(band and dict(band, nqh=nq))),
+                          rel=rel, nq=nq, lanes=lanes,
+                          **(band and dict(band, nqh=nq))),
         grid=(b, h // ht, group * nq, steps_k),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -891,8 +1012,8 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, bq, bk, interpret,
         dq = res
 
     # dk/dv: kv block is the outer (carried) grid dim, q block inner
-    qspec2 = pl.BlockSpec((1, ht, bq, d), lambda ib, ih, ik, iq: (ib, ih, iq, 0))
-    kspec2 = pl.BlockSpec((1, ht, bk, d), lambda ib, ih, ik, iq: (ib, ih, ik, 0))
+    qspec2 = _rows_spec(ht, bq, d, dense, lambda ib, ih, ik, iq: (ib, ih, iq))
+    kspec2 = _rows_spec(ht, bk, d, dense, lambda ib, ih, ik, iq: (ib, ih, ik))
     stat2 = pl.BlockSpec((1, ht, 1, bq), lambda ib, ih, ik, iq: (ib, ih, 0, iq))
     steps_q = nq
     if window is not None:
@@ -920,15 +1041,15 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, bq, bk, interpret,
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, nq=group * steps_q, ht=ht,
-                          has_bias=has_bias, rel=rel,
+                          has_bias=has_bias, rel=rel, lanes=lanes,
                           **(band and dict(band, nqh=nq, steps=steps_q))),
         grid=(b, h // ht, nk, group * steps_q),
         in_specs=in_specs2,
         out_specs=[kspec2, kspec2],
-        out_shape=[jax.ShapeDtypeStruct((b, h, sk, d), k.dtype),
-                   jax.ShapeDtypeStruct((b, h, sk, d), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((ht * bk, d), jnp.float32),
-                        pltpu.VMEM((ht * bk, d), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(k.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((ht * bk, acc_lanes), jnp.float32),
+                        pltpu.VMEM((ht * bk, acc_lanes), jnp.float32)],
         compiler_params=_DIM_SEMANTICS,
         interpret=interpret,
         name="bps_flash_bwd_dkv",
@@ -954,6 +1075,21 @@ def flash_attention(q, k, v, causal=False, scale=None,
     only) lets query i see key j where ``0 <= i - j < w``; kv blocks
     outside the band are not visited. Neither goes with ``bias`` or
     ``rel_table``.
+
+    Which layout crosses HBM (``_fwd_rule`` chooses, once a call; no
+    argument does): q, k, v, out and in the backward do, dq, dk, dv go
+    lane-dense, [b, s, heads*d], a free reshape of what is passed and
+    returned, where head_dim divides a 128-lane tile (64, 32), a head
+    tile of whole lane tiles divides the heads (an even number of heads
+    at width 64) within the kernels' VMEM count, k and v have as many
+    heads as q, there is no ``bias``, ``rel_table``
+    or ``window``, and one forward block holds a row's keys (up to 1024
+    by default; explicit blocks that split the keys do not). Unequal q
+    and kv lengths are covered (T5's cross-attention at width 64). Every
+    other call (width 128, T5's biased self-attention, grouped kv heads,
+    a window, the online forward at long rows, three heads of 64) is
+    head-major, [b, heads, s, d], behind a swapaxes each way, and so are
+    the ring's own calls of ``_flash_fwd`` / ``_flash_bwd``.
 
     Each seq must be divisible by the (auto-shrunk) block sizes; a
     block size of None is the default (see ``_resolve``): 512, and in a
@@ -1026,9 +1162,34 @@ def _check_band(q, k, causal, window, extra) -> None:
                          "grouped kv heads")
 
 
+def _lane_dense(q, k, bq, bk, bwd_blocks, interpret) -> bool:
+    """Whether a call with no bias, rel_table or window sends its
+    operands across HBM as [b, s, heads*d] (the note on narrow heads):
+    a head width that divides a lane tile, as many kv heads as query heads,
+    every key of a row in the forward's one block (``_fwd_single_kernel``)
+    and a head tile of whole lane tiles for the forward and for the
+    backward. q and kv lengths may differ (T5's cross-attention)."""
+    _, sq, heads, d = q.shape
+    sk = k.shape[1]
+    if k.shape[2] != heads or bk != sk:
+        return False
+    bq_b, bk_b = bwd_blocks
+    nq, nk = sq // bq_b, sk // bk_b
+    return None not in (
+        _dense_tile(heads, sq // bq, 1, bq, bk, d, interpret, mats=1),
+        _dense_tile(heads, nq, nk, bq_b, bk_b, d, interpret,
+                    mats=4 if nq == 1 and nk == 1 else 3))
+
+
 def _fwd_rule(q, k, v, causal, scale, block_q, block_k, interpret,
               bias=None, rel_table=None, rel_bidirectional=True,
               rel_max_distance=128, window=None):
+    """The forward and its residuals. The layout in which q, k, v, out
+    (and in the backward their cotangents) cross HBM is chosen here,
+    once a call, from what the call shows (``_lane_dense``): lane-dense
+    [b, s, heads*d], a free reshape of the arguments, or head-major
+    [b, heads, s, d] behind a swapaxes each way. The residuals carry the
+    layout to ``_vjp_bwd`` in their rank."""
     _check_band(q, k, causal, window, bias is not None
                 or rel_table is not None)
     if rel_table is not None and rel_table.shape[1] > _DT_PAD[1]:
@@ -1046,18 +1207,28 @@ def _fwd_rule(q, k, v, causal, scale, block_q, block_k, interpret,
     scale, bq, bk = _resolve(q, k, scale, block_q, block_k,
                              whole_kv=bias is None and rel is None,
                              causal=causal)
-    qt = jnp.swapaxes(q, 1, 2)       # [b, h, s, d]
-    kt = jnp.swapaxes(k, 1, 2)
-    vt = jnp.swapaxes(v, 1, 2)
+    heads = None
+    if (bias is None and rel is None and window is None and _lane_dense(
+            q, k, bq, bk, _resolve(q, k, scale, block_q, block_k)[1:],
+            interpret)):
+        heads = q.shape[2]
+        qt, kt, vt = (x.reshape(*x.shape[:2], -1) for x in (q, k, v))
+    else:
+        qt = jnp.swapaxes(q, 1, 2)       # [b, h, s, d]
+        kt = jnp.swapaxes(k, 1, 2)
+        vt = jnp.swapaxes(v, 1, 2)
     out, lse = _flash_fwd(qt, kt, vt, causal, scale, bq, bk, interpret,
                           bias=bias, rel_table=rel_table, rel=rel,
-                          window=window)
+                          window=window, heads=heads)
     from jax.ad_checkpoint import checkpoint_name
     # named so a remat policy can pin the flash residuals while everything
     # around them recomputes (remat_policy="save_attn")
     out = checkpoint_name(out, "flash_out")
     lse = checkpoint_name(lse, "flash_lse")                 # [b,h,sq]
-    return jnp.swapaxes(out, 1, 2), (qt, kt, vt, out, lse, bias, rel_table)
+    res = (qt, kt, vt, out, lse, bias, rel_table)
+    if heads is not None:
+        return out.reshape(q.shape), res
+    return jnp.swapaxes(out, 1, 2), res
 
 
 def _vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
@@ -1072,6 +1243,16 @@ def _vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
 def _vjp_bwd(causal, scale, block_q, block_k, interpret,
              rel_bidirectional, rel_max_distance, window, res, g):
     qt, kt, vt, out, lse, bias, rel_table = res
+    if qt.ndim == 3:                 # lane-dense residuals (_fwd_rule)
+        heads = lse.shape[1]
+        kv = jax.ShapeDtypeStruct((*kt.shape[:2], heads, g.shape[3]),
+                                  kt.dtype)
+        scale, bq, bk = _resolve(g, kv, scale, block_q, block_k)
+        dq, dk, dv, _, _ = _flash_bwd(
+            qt, kt, vt, out, lse, g.reshape(qt.shape), causal, scale, bq, bk,
+            interpret, heads=heads)
+        return (dq.reshape(g.shape), dk.reshape(kv.shape),
+                dv.reshape(kv.shape), None, None)
     scale, bq, bk = _resolve(jnp.swapaxes(qt, 1, 2), jnp.swapaxes(kt, 1, 2),
                              scale, block_q, block_k)
     rel = _rel_static(rel_table, rel_bidirectional, rel_max_distance)

@@ -49,6 +49,7 @@ def compile_for_chip(one_chip):
                 for s, d in shapes]
         text = jax.jit(fn).lower(*args).compile().as_text()
         assert "tpu_custom_call" in text
+        return text
 
     yield compile_
     jax.config.update("jax_enable_compilation_cache", prev)
@@ -65,21 +66,27 @@ def mosaic(monkeypatch):
     return pk
 
 
-@pytest.mark.parametrize("shape,causal,extra", [
-    ((64, 512, 16, 64), False, None),     # BERT-large, batch 64 x seq 512
-    ((64, 512, 8, 128), False, None),     # its d_head-128 twin
-    ((1, 32768, 12, 64), True, None),     # GPT-2-small at 32k, causal
-    # GPT-2-medium's cell: split backward, ht = 1, nq = 2
-    ((8, 1024, 16, 64), True, None),
-    ((256, 128, 16, 64), False, None),    # BERT phase 1: ht = 8
-    ((8, 512, 8, 64), False, "bias"),     # T5's two score-bias forms
-    ((8, 1024, 8, 64), False, "rel_table"),
+@pytest.mark.parametrize("shape,causal,extra,lane_dense", [
+    # BERT-large, batch 64 x seq 512: heads as 64-lane slices, ht 4 and 2
+    ((64, 512, 16, 64), False, None, True),
+    ((64, 512, 8, 128), False, None, False),    # its d_head-128 twin
+    # GPT-2-small at 32k, causal: the online forward keeps [b, h, s, d]
+    ((1, 32768, 12, 64), True, None, False),
+    # GPT-2-medium's cell: split backward, nq = 2, ht = 2 (the least
+    # tile that fills the lanes)
+    ((8, 1024, 16, 64), True, None, True),
+    ((256, 128, 16, 64), False, None, True),    # BERT phase 1: ht = 8
+    ((8, 512, 8, 64), False, "bias", False),    # T5's two score-bias forms
+    ((8, 1024, 8, 64), False, "rel_table", False),
 ], ids=["bert_large", "dh128", "gpt2_32k_causal", "gpt2_medium_causal",
         "bert_s128", "bias", "rel_table"])
 def test_flash_fwd_bwd_compiles_for_v5e(compile_for_chip, shape, causal,
-                                        extra):
+                                        extra, lane_dense):
+    """Mosaic takes every flash call of the main paths, and each in the
+    layout its shape chooses: [b, s, heads*d] operands where the heads
+    are 64 wide and the forward is one block, [b, h, s, d] elsewhere."""
     from byteps_tpu.ops.flash_attention import flash_attention
-    _, s, h, _ = shape
+    b, s, h, d = shape
     extra_shape = {None: [], "bias": [((h, s, s), jnp.float32)],
                    "rel_table": [((h, 32), jnp.float32)]}[extra]
 
@@ -87,9 +94,16 @@ def test_flash_fwd_bwd_compiles_for_v5e(compile_for_chip, shape, causal,
         return (flash_attention(q, k, v, causal, **dict(zip([extra], e)))
                 .astype(jnp.float32) ** 2).sum()
 
-    compile_for_chip(
+    text = compile_for_chip(
         jax.value_and_grad(loss, argnums=tuple(range(3 + len(extra_shape)))),
         *[(shape, jnp.bfloat16)] * 3, *extra_shape)
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "bps_flash" in line]
+    assert calls
+    dense, major = f"bf16[{b},{s},{h * d}]", f"bf16[{b},{h},{s},{d}]"
+    for line in calls:
+        assert (dense in line, major in line) == (lane_dense,
+                                                  not lane_dense), line
 
 
 @pytest.mark.parametrize("seq,window", [(8192, 2048), (8192, None),

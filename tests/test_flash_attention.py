@@ -566,3 +566,247 @@ def test_the_kernels_import_nothing_from_parallel():
     assert not [m for m, level in imported
                 if m.startswith("byteps_tpu.parallel")
                 or (level == 2 and m.split(".")[0] == "parallel")], imported
+
+
+# ---- heads narrower than a lane tile cross HBM as [b, s, heads*d] (PR 33)
+
+KERNELS = {"bps_flash_fwd", "bps_flash_bwd_fused", "bps_flash_bwd_dq",
+           "bps_flash_bwd_dkv"}
+
+
+def flash_calls(fn, *args):
+    """(the pallas_call equations, the transpose equations) of ``fn``'s
+    jaxpr, kernel bodies not entered."""
+    jaxpr = jax.make_jaxpr(fn)(*args)
+    return equations(jaxpr, "pallas_call"), equations(jaxpr, "transpose")
+
+
+def wide_operands(eqn):
+    """Shapes of a kernel's q, k, v, out, do, dq, dk, dv: its operands and
+    results that are no float32 statistic, bias or table."""
+    return [v.aval.shape for v in list(eqn.invars) + list(eqn.outvars)
+            if v.aval.ndim >= 3 and v.aval.shape[-2] != 1
+            and v.aval.dtype != jnp.float32]
+
+
+@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("s,h,d,causal,kernels,tile", [
+    (512, 16, 64, False, ("bps_flash_fwd", "bps_flash_bwd_fused"), 2),
+    (1024, 4, 64, True,
+     ("bps_flash_fwd", "bps_flash_bwd_dq", "bps_flash_bwd_dkv"), 2),
+    (256, 8, 32, True, ("bps_flash_fwd", "bps_flash_bwd_fused"), 4),
+], ids=["bert_s512_fused", "gpt2_s1024_split", "width32_tile4"])
+def test_lane_dense_matches_reference(s, h, d, causal, kernels, tile, dtype):
+    """Forward and the three gradients of the lane-dense path against
+    ``local_attention`` at the blocks a plain call takes: BERT's one
+    block pair, GPT-2's single forward over 1024 keys with the backward
+    split at 512, and width 32. Every kernel's blocks of q, k, v, out and
+    the cotangents are (1, rows, tile * d): whole 128-lane tiles."""
+    rng = np.random.RandomState(33)
+    q, k, v = (x.astype(dtype) for x in make_qkv(rng, 1, s, h, d, np.float32))
+
+    def flash(q, k, v):
+        return jnp.sum(jnp.sin(flash_attention(
+            q, k, v, causal, None, None, None, True).astype(jnp.float32)))
+
+    def naive(q, k, v):
+        return jnp.sum(jnp.sin(local_attention(
+            q, k, v, causal=causal).astype(jnp.float32)))
+
+    calls, _ = flash_calls(jax.value_and_grad(flash, (0, 1, 2)), q, k, v)
+    assert {str(e.params["name"]) for e in calls} == set(kernels)
+    for eqn in calls:
+        blocks = [bm.block_shape for bm in
+                  eqn.params["grid_mapping"].block_mappings]
+        wide = [tuple(int(getattr(n, "block_size", n)) for n in bs)
+                for bs in blocks if len(bs) == 3]
+        assert len(wide) >= 4, (eqn.params["name"], blocks)
+        assert all(bs[0] == 1 and bs[2] == tile * d for bs in wide), wide
+    (lf, gf), (ln, gn) = (jax.value_and_grad(f, (0, 1, 2))(q, k, v)
+                          for f in (flash, naive))
+    tol = 3e-4 if dtype == np.float32 else 6e-2
+    np.testing.assert_allclose(float(lf), float(ln),
+                               rtol=1e-5 if dtype == np.float32 else 2e-2)
+    for a, b_, name in zip(gf, gn, "qkv"):
+        assert a.shape == b_.shape and a.dtype == b_.dtype
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b_, np.float32), rtol=tol,
+                                   atol=tol, err_msg=name)
+
+
+@pytest.mark.parametrize("causal,bwd_blocks", [(False, 256), (True, 128)],
+                         ids=["fused", "causal_split"])
+def test_lane_dense_equals_head_major_on_the_same_tensors(causal, bwd_blocks):
+    """One set of mathematics: out, lse, dq, dk and dv of the two layouts
+    are equal, a head's lanes against a head's rows, to the rounding of
+    float32 sums taken in another order (a lane-dense product runs over
+    the head's whole lane tile, its neighbour's lanes zero or dropped)."""
+    rng = np.random.RandomState(34)
+    b, s, h, d = 2, 256, 4, 64
+    q, k, v = make_qkv(rng, b, s, h, d, np.float32)
+    do = jnp.asarray(rng.randn(b, s, h, d).astype(np.float32))
+    scale = d ** -0.5
+
+    def dense(x):
+        return x.reshape(b, s, h * d)
+
+    def major(x):
+        return jnp.swapaxes(x, 1, 2)
+
+    out_d, lse_d = fa._flash_fwd(dense(q), dense(k), dense(v), causal, scale,
+                                 s, s, True, heads=h)
+    out_m, lse_m = fa._flash_fwd(major(q), major(k), major(v), causal, scale,
+                                 s, s, True)
+    assert out_d.shape == (b, s, h * d) and out_m.shape == (b, h, s, d)
+    np.testing.assert_allclose(np.asarray(lse_d), np.asarray(lse_m),
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(out_d.reshape(b, s, h, d)), np.asarray(major(out_m)),
+        rtol=1e-5, atol=1e-6)
+    grads_d = fa._flash_bwd(dense(q), dense(k), dense(v), out_d, lse_d,
+                            dense(do), causal, scale, bwd_blocks, bwd_blocks,
+                            True, heads=h)[:3]
+    grads_m = fa._flash_bwd(major(q), major(k), major(v), out_m, lse_m,
+                            major(do), causal, scale, bwd_blocks, bwd_blocks,
+                            True)[:3]
+    for gd, gm, name in zip(grads_d, grads_m, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(
+            np.asarray(gd.reshape(b, s, h, d)), np.asarray(major(gm)),
+            rtol=1e-5, atol=2e-6, err_msg=name)
+
+
+def _squared_loss(**kwargs):
+    def loss(q, k, v, *extra):
+        named = dict(zip(kwargs.get("extra", ()), extra))
+        return (flash_attention(
+            q, k, v, kwargs.get("causal", False), None, None, None, True,
+            window=kwargs.get("window"), **named)
+            .astype(jnp.float32) ** 2).sum()
+    return loss
+
+
+def test_narrow_heads_cross_no_transpose():
+    """At [2, 256, 16, 64] neither the forward nor the backward holds a
+    ``transpose``: q, k, v, out and the cotangents reach the kernels as
+    [b, s, heads*d], a reshape of the arguments. At 1024 causal keys the
+    split backward joins, and the one transpose left is delta's
+    [b, s, heads] -> [b, heads, s], 4 bytes a row of attention."""
+    q = jnp.zeros((2, 256, 16, 64), jnp.bfloat16)
+    calls, transposes = flash_calls(
+        jax.value_and_grad(_squared_loss(), (0, 1, 2)), q, q, q)
+    assert {str(e.params["name"]) for e in calls} == {
+        "bps_flash_fwd", "bps_flash_bwd_fused"}
+    assert transposes == []
+    for eqn in calls:
+        assert set(wide_operands(eqn)) == {(2, 256, 16 * 64)}, eqn.params[
+            "name"]
+
+    q = jnp.zeros((1, 1024, 4, 64), jnp.bfloat16)
+    more, transposes = flash_calls(
+        jax.value_and_grad(_squared_loss(causal=True), (0, 1, 2)), q, q, q)
+    names = {str(e.params["name"]) for e in calls + more}
+    assert names == KERNELS
+    assert [t.invars[0].aval.shape for t in transposes] == [(1, 1024, 4)]
+    for eqn in more:
+        assert set(wide_operands(eqn)) == {(1, 1024, 4 * 64)}
+
+
+@pytest.mark.parametrize("heads,kv_heads,d,kwargs", [
+    (8, 1, 128, dict(causal=True)),
+    (8, 1, 128, dict(causal=True, window=100)),
+    (8, 8, 128, dict()),
+    (4, 4, 64, dict(extra=("bias",))),
+    (4, 4, 64, dict(extra=("rel_table",))),
+    (4, 2, 64, dict()),
+    (3, 3, 64, dict()),
+], ids=["width128_grouped", "width128_grouped_window", "width128",
+        "bias", "rel_table", "grouped_width64", "three_heads"])
+def test_every_other_call_keeps_the_head_major_path(heads, kv_heads, d,
+                                                    kwargs):
+    """Width 128, a bias, a table, grouped kv heads, a window, a head
+    count no lane tile divides: the kernels' operands are [b, h, s, d]
+    (q and its kin folded over the group) behind a swapaxes each: three
+    in and one out, in the backward two for the shapes, do in and three
+    out, as before the lane-dense layout existed."""
+    b, s = 2, 256
+    q = jnp.zeros((b, s, heads, d), jnp.bfloat16)
+    k = jnp.zeros((b, s, kv_heads, d), jnp.bfloat16)
+    extra = {"bias": jnp.zeros((heads, s, s), jnp.float32),
+             "rel_table": jnp.zeros((heads, 32), jnp.float32)}
+    operands = tuple(extra[e] for e in kwargs.get("extra", ()))
+    calls, transposes = flash_calls(
+        jax.value_and_grad(_squared_loss(**kwargs),
+                           tuple(range(3 + len(operands)))),
+        q, k, k, *operands)
+    assert {str(e.params["name"]) for e in calls} <= KERNELS
+    assert len(calls) >= 2
+    group = heads // kv_heads
+    for eqn in calls:
+        assert set(wide_operands(eqn)) <= {(b, kv_heads, group * s, d),
+                                           (b, kv_heads, s, d)}, (
+            eqn.params["name"], wide_operands(eqn))
+    swaps = [t for t in transposes
+             if tuple(t.params["permutation"]) == (0, 2, 1, 3)]
+    assert len(swaps) == 10, len(swaps)
+
+
+def test_three_heads_of_64_are_right_on_the_head_major_path():
+    """Three heads of 64 fill no whole number of lane tiles two at a
+    time: the call keeps today's path (above) and its gradients are the
+    reference's."""
+    rng = np.random.RandomState(35)
+    q, k, v = make_qkv(rng, 1, 256, 3, 64, np.float32)
+    assert fa._dense_tile(3, 1, 1, 256, 256, 64, True, mats=1) is None
+
+    def flash(q, k, v):
+        return jnp.sum(jnp.sin(flash_attention(q, k, v, True, None, None,
+                                               None, True)))
+
+    def naive(q, k, v):
+        return jnp.sum(jnp.sin(local_attention(q, k, v, causal=True)))
+
+    (lf, gf), (ln, gn) = (jax.value_and_grad(f, (0, 1, 2))(q, k, v)
+                          for f in (flash, naive))
+    np.testing.assert_allclose(float(lf), float(ln), rtol=1e-5)
+    for a, b_, name in zip(gf, gn, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), rtol=3e-4,
+                                   atol=3e-4, err_msg=name)
+
+
+def test_the_ring_hands_the_kernels_head_major_blocks():
+    """``parallel/ring.py`` calls ``_flash_fwd`` itself, on [b, h, s, d]
+    blocks it has transposed once for the whole ring: width 64 or not,
+    it stays head-major."""
+    from byteps_tpu.parallel.ring import _flash_blk_fwd
+    q = jnp.zeros((2, 16, 256, 64), jnp.bfloat16)
+    calls, _ = flash_calls(
+        lambda q, k, v: _flash_blk_fwd(q, k, v, None, 0.125, True), q, q, q)
+    assert [str(e.params["name"]) for e in calls] == ["bps_flash_fwd"]
+    assert set(wide_operands(calls[0])) == {(2, 16, 256, 64)}
+
+
+@pytest.mark.parametrize("h,d,nq,nk,bq,bk,mats,want", [
+    (16, 64, 1, 1, 512, 512, 1, 4),       # BERT s512 forward
+    (16, 64, 1, 1, 512, 512, 4, 2),       # its fused backward
+    (16, 64, 1, 1, 128, 128, 4, 8),       # BERT s128
+    (16, 64, 1, 1, 1024, 1024, 1, 2),     # GPT-2's forward
+    (16, 64, 2, 2, 512, 512, 3, 2),       # its split backward: 1 -> 2
+    (16, 32, 1, 1, 512, 512, 4, None),    # width 32 needs 4: over the budget
+    (16, 32, 1, 1, 256, 256, 4, 8),
+    (3, 64, 1, 1, 256, 256, 1, None),
+    (12, 96, 1, 1, 128, 128, 1, None),    # a head of 96 lies across two tiles
+    (16, 128, 1, 1, 128, 128, 1, None),   # wide enough already
+], ids=["bert_fwd", "bert_bwd", "s128_bwd", "gpt2_fwd", "gpt2_split",
+        "width32_s512", "width32_s256", "three_heads", "width96",
+        "width128"])
+def test_dense_tile_fills_whole_lane_tiles(h, d, nq, nk, bq, bk, mats, want):
+    """The chip's head tile of a lane-dense call: ``_head_tile``'s where
+    that fills whole 128-lane tiles, else the least that does, or none
+    (the call then keeps the head-major path): no tile within the VMEM
+    count, a head count none divides, a width that divides no lane tile."""
+    got = fa._dense_tile(h, nq, nk, bq, bk, d, False, mats)
+    assert got == want
+    if got is not None:
+        assert got * d % 128 == 0 and h % got == 0
+        assert fa._tile_vmem(got, mats, bq, bk, d) < fa._HT_VMEM_BUDGET

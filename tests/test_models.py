@@ -335,3 +335,73 @@ def test_remat_layers_validation_and_exactness():
     jax.tree_util.tree_map(
         lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6),
         g_full, g_part)
+
+
+def _block_eqns(cfg, b, s):
+    """(pallas_call, transpose) equations of one block's value and
+    gradient, the flash kernels in the interpreter."""
+    from test_flash_attention import equations
+    params = transformer.init_params(jax.random.PRNGKey(5), cfg)
+    blk = jax.tree_util.tree_map(lambda x: x[0], params["blocks"])
+    x = jnp.zeros((b, s, cfg.hidden), jnp.bfloat16)
+
+    def loss(x, blk):
+        return transformer._block(x, blk, cfg, 1).astype(jnp.float32).sum()
+
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(loss, (0, 1)))(x, blk)
+    return equations(jaxpr, "pallas_call"), equations(jaxpr, "transpose")
+
+
+def test_projection_hands_the_kernels_what_they_read(monkeypatch):
+    """Four heads of 64: q, k and v leave their products as [b, s, 256]
+    and reach the flash kernels so, out enters ``attn_out`` so, and no
+    transpose of an activation stands between a product and a kernel
+    (the ones left turn weights for their gradients)."""
+    import byteps_tpu.ops.flash_attention as fa
+    flash = fa.flash_attention
+    monkeypatch.setattr(fa, "flash_attention", lambda *a, **kw: flash(
+        *a, **dict(kw, interpret=True)))
+    cfg = transformer.TransformerConfig(
+        vocab_size=64, hidden=256, layers=1, heads=4, mlp_dim=512,
+        max_seq=128, remat=False, attn_impl="flash")
+    calls, transposes = _block_eqns(cfg, 2, 128)
+    assert [str(e.params["name"]) for e in calls] == [
+        "bps_flash_fwd", "bps_flash_bwd_fused"]
+    for eqn in calls:
+        wide = [v.aval.shape for v in list(eqn.invars) + list(eqn.outvars)
+                if v.aval.dtype == jnp.bfloat16]
+        assert wide and set(wide) == {(2, 128, 256)}, wide
+    assert all(t.invars[0].aval.ndim == 2 for t in transposes), [
+        t.invars[0].aval.shape for t in transposes]
+
+
+def test_three_products_are_the_one_product():
+    """``_attention``'s three products on slices of the stored
+    [h, 3, heads, head_dim] weight compute what one einsum to
+    [b, s, 3, heads, head_dim] and three slices did, gradient to the
+    weight (one leaf, stored shape) included."""
+    from byteps_tpu.ops.flash_attention import local_attention
+    cfg = bert.bert_tiny()
+    params = transformer.init_params(jax.random.PRNGKey(6), cfg)
+    blk = jax.tree_util.tree_map(lambda x: x[0], params["blocks"])
+    x = jnp.asarray(np.random.RandomState(6).randn(2, 16, cfg.hidden),
+                    jnp.float32)
+
+    def one_product(x, blk):
+        qkv = jnp.einsum("bsh,hcnd->bscnd", x, blk["qkv"])
+        out = local_attention(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2])
+        return out.reshape(*x.shape[:2], -1) @ blk["attn_out"]
+
+    def three(x, blk):
+        return transformer._attention(x, blk, cfg, 1)
+
+    np.testing.assert_allclose(np.asarray(three(x, blk)),
+                               np.asarray(one_product(x, blk)),
+                               rtol=1e-5, atol=1e-6)
+    g3, g1 = (jax.grad(lambda x, blk: jnp.sum(jnp.sin(f(x, blk))), (0, 1))(
+        x, blk) for f in (three, one_product))
+    assert g3[1]["qkv"].shape == (cfg.hidden, 3, cfg.heads, cfg.head_dim)
+    for a, b_ in ((g3[0], g1[0]), (g3[1]["qkv"], g1[1]["qkv"]),
+                  (g3[1]["attn_out"], g1[1]["attn_out"])):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=1e-4, atol=1e-6)
