@@ -1,14 +1,26 @@
-"""A causal decoder whose layers are a LIST OF KINDS, and the ``afmoe``
-family (arcee-ai Trinity) built on it.
+"""A causal decoder whose layers are a LIST OF KINDS, and the two
+families built on it: ``afmoe`` (arcee-ai Trinity) and ``nemotron_h``
+(NVIDIA Nemotron 3).
 
 ``transformer.py`` is one kind of block under one ``lax.scan``. Here each
-layer is data: whether its attention is a causal band (``window``) or the
-whole triangle, whether it rotates q and k (RoPE) or has no positions,
-and whether its feed-forward is a dense gated-SiLU MLP or the routed
-layer of ``moe.py::routed_ffn`` (experts held here, a shared expert).
-Every layer has four RMSNorms (before and after each half), RMSNorm on
-q and k a head, grouped kv heads, and a sigmoid gate on the attention
-output. The embedding is scaled by sqrt(hidden) and the head is untied.
+layer is data, and a kind says everything about its layer:
+
+* ``dense_sliding``, ``dense_full``, ``moe_sliding``, ``moe_full``
+  (afmoe): an attention half and a feed-forward half. The attention is a
+  causal band (``window``) that rotates q and k (RoPE), or the whole
+  triangle without positions; the feed-forward a dense gated-SiLU MLP or
+  the routed layer of ``moe.py::routed_ffn`` (experts held here, a shared
+  expert). Four RMSNorms (before and after each half), RMSNorm on q and
+  k a head, grouped kv heads, a sigmoid gate on the attention output.
+* ``ssm``, ``attn``, ``moe`` (nemotron_h): ONE mixer a layer,
+  ``x + mixer(RMSNorm(x))``, one norm. ``ssm``: the Mamba-2 mixer of
+  ``mamba2.py`` (``cfg.ssm``); ``attn``: q, k, v and the output
+  projection over grouped kv heads, causal, with no positions, no norm
+  on q or k and no gate; ``moe``: the routed layer, its experts' function
+  and shared width ``cfg.routed``'s.
+
+The embedding is scaled by sqrt(hidden) where ``scale_embedding`` (afmoe)
+and the head is untied.
 
 One chip's SHARE of a model is a configuration like any other: ``heads``
 and ``kv_heads`` are the heads held here, ``vocab_size`` the rows of the
@@ -32,10 +44,15 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.flash_attention import attention
+from .mamba2 import SSMConfig, init_mixer, mixer as ssm_mixer
 from .moe import RoutedConfig, gated_silu, routed_ffn
 from .transformer import _chunked_nll_sum, embed_lookup
 
-KINDS = ("dense_sliding", "dense_full", "moe_sliding", "moe_full")
+# a layer of two halves (attention, feed-forward) ...
+HALVES = ("dense_sliding", "dense_full", "moe_sliding", "moe_full")
+# ... or of one mixer: state-space, attention, routed feed-forward
+MIXERS = ("ssm", "attn", "moe")
+KINDS = HALVES + MIXERS
 
 
 @dataclasses.dataclass(frozen=True)
@@ -51,6 +68,8 @@ class DecoderConfig:
     moe_dim: int = 0              # an expert's width
     shared_experts: int = 0       # shared experts, each of moe_dim
     routed: Optional[RoutedConfig] = None
+    ssm: Optional[SSMConfig] = None     # an ``ssm`` layer's mixer
+    scale_embedding: bool = True  # the embedding times sqrt(hidden)
     max_seq: int = 1 << 17        # positions RoPE is defined for
     rope_theta: float = 10000.0
     norm_eps: float = 1e-5
@@ -62,12 +81,17 @@ class DecoderConfig:
     def __post_init__(self):
         bad = [k for k in self.layer_kinds if k not in KINDS]
         if bad:
-            raise ValueError(f"layer kinds {bad} are none of {KINDS}")
+            raise ValueError(
+                f"layer kinds {bad} are none of {KINDS}: a layer of two "
+                f"halves is one of {HALVES}, a layer of one mixer one of "
+                f"{MIXERS}")
         if self.heads % self.kv_heads:
             raise ValueError(f"{self.heads} heads over {self.kv_heads} kv")
         if any(k.startswith("moe") for k in self.layer_kinds) and (
                 self.routed is None or not self.moe_dim):
             raise ValueError("a routed layer needs `routed` and `moe_dim`")
+        if "ssm" in self.layer_kinds and self.ssm is None:
+            raise ValueError("a state-space layer needs `ssm`")
 
 
 def afmoe_config(vocab_size, hidden, heads, kv_heads, head_dim, mlp_dim,
@@ -93,6 +117,48 @@ def afmoe_config(vocab_size, hidden, heads, kv_heads, head_dim, mlp_dim,
         **kw)
 
 
+def nemotron_h_config(vocab_size, hidden, heads, kv_heads, head_dim,
+                      moe_dim, shared_dim, layer_kinds: Sequence[str], top_k,
+                      router_outputs, held: Sequence[int], ssm_heads,
+                      ssm_head_dim, ssm_groups, ssm_state, conv_kernel=4,
+                      chunk=128, route_scale=1.0, max_seq=1 << 18,
+                      norm_eps=1e-5, balanced=False, routed_kw=None,
+                      **kw) -> DecoderConfig:
+    """The nemotron_h family (NVIDIA Nemotron 3) from its sizes, as the
+    benchmark's configuration gives them: layers of ONE mixer each
+    (``layer_kinds`` of ``ssm`` / ``attn`` / ``moe``, the published
+    pattern's ``M`` / ``*`` / ``E``), experts that are not gated
+    (``down(relu(up x)^2)``, width ``moe_dim``) with one shared expert of
+    ``shared_dim``, attention without positions, an embedding that is not
+    scaled. ``balanced``, ``routed_kw`` and further keywords as
+    ``afmoe_config``'s."""
+    return DecoderConfig(
+        vocab_size=vocab_size, hidden=hidden, heads=heads, kv_heads=kv_heads,
+        head_dim=head_dim, mlp_dim=0, moe_dim=moe_dim,
+        layer_kinds=tuple(layer_kinds), window=0, max_seq=max_seq,
+        norm_eps=norm_eps, scale_embedding=False,
+        ssm=SSMConfig(ssm_heads, ssm_head_dim, ssm_groups, ssm_state,
+                      conv_kernel, chunk),
+        routed=RoutedConfig(router_outputs, tuple(held), top_k, route_scale,
+                            balanced=balanced, act="relu2",
+                            shared_dim=shared_dim, **(routed_kw or {})),
+        **kw)
+
+
+def nemotron_h_tiny(**kw) -> DecoderConfig:
+    """Test-sized: every kind of one-mixer layer, 2 query heads a kv head,
+    2 state-space heads a group, 4 of 8 experts held, a shared expert of
+    a width of its own."""
+    sizes = dict(vocab_size=128, hidden=64, heads=4, kv_heads=2, head_dim=16,
+                 moe_dim=24, shared_dim=40, top_k=2, router_outputs=8,
+                 held=(0, 1, 2, 3), route_scale=2.5, ssm_heads=4,
+                 ssm_head_dim=8, ssm_groups=2, ssm_state=16, chunk=8,
+                 routed_kw={"row_tile": 8},
+                 layer_kinds=("ssm", "moe", "ssm", "attn", "moe"),
+                 dtype="float32", remat=False)
+    return nemotron_h_config(**{**sizes, **kw})
+
+
 def afmoe_tiny(**kw) -> DecoderConfig:
     """Test-sized: every kind of layer, 2 query heads a kv head, a window
     shorter than the sequence, 4 of 8 experts held."""
@@ -108,7 +174,8 @@ def afmoe_tiny(**kw) -> DecoderConfig:
 # ----------------------------------------------------------------- params
 
 def init_params(rng, cfg: DecoderConfig):
-    """The parameter tree: N(0, 0.02) matrices, unit norm scales, fp32."""
+    """The parameter tree: N(0, 0.02) matrices, unit norm scales, fp32;
+    a state-space mixer's leaves as ``mamba2.init_mixer`` seeds them."""
     h, d = cfg.hidden, cfg.head_dim
     keys = iter(jax.random.split(rng, 16 * len(cfg.layer_kinds) + 2))
 
@@ -119,7 +186,26 @@ def init_params(rng, cfg: DecoderConfig):
         return {"gate_up": normal(*lead, h, 2 * width),
                 "down": normal(*lead, width, h)}
 
+    def mixer_layer(kind):
+        blk = {"norm": jnp.ones((h,))}
+        if kind == "ssm":
+            blk.update(init_mixer(next(keys), h, cfg.ssm))
+        elif kind == "attn":
+            blk.update(q=normal(h, cfg.heads, d), k=normal(h, cfg.kv_heads, d),
+                       v=normal(h, cfg.kv_heads, d), o=normal(cfg.heads, d, h))
+        else:
+            held = len(cfg.routed.held)
+            blk["router"] = normal(h, cfg.routed.num_experts)
+            blk["experts"] = {"up": normal(held, h, cfg.moe_dim),
+                              "down": normal(held, cfg.moe_dim, h)}
+            if cfg.routed.shared_dim:
+                blk["shared"] = {"up": normal(h, cfg.routed.shared_dim),
+                                 "down": normal(cfg.routed.shared_dim, h)}
+        return blk
+
     def layer(kind):
+        if kind in MIXERS:
+            return mixer_layer(kind)
         attn = {"norm_in": jnp.ones((h,)), "q": normal(h, cfg.heads, d),
                 "k": normal(h, cfg.kv_heads, d),
                 "v": normal(h, cfg.kv_heads, d),
@@ -193,7 +279,28 @@ def _ffn_half(x, blk, cfg: DecoderConfig, routed: bool):
     return rmsnorm(m, blk["norm_post"], cfg.norm_eps)
 
 
+def _mixer_layer(x, blk, cfg: DecoderConfig, kind: str):
+    """A layer that is one mixer: ``x + mixer(RMSNorm(x))``."""
+    dt = x.dtype
+    scope = {"ssm": "bps.ssm", "attn": "bps.attn", "moe": "bps.mlp"}[kind]
+    with jax.named_scope(scope):
+        a = rmsnorm(x, blk["norm"], cfg.norm_eps)
+        if kind == "ssm":
+            return x + ssm_mixer(a, blk, cfg.ssm, cfg.norm_eps)
+        if kind == "moe":
+            b, s, h = a.shape
+            return x + routed_ffn(a.reshape(b * s, h), blk, cfg.routed,
+                                  sequences=b).reshape(b, s, h)
+        q = jnp.einsum("bsh,hnd->bsnd", a, blk["q"].astype(dt))
+        k = jnp.einsum("bsh,hnd->bsnd", a, blk["k"].astype(dt))
+        v = jnp.einsum("bsh,hnd->bsnd", a, blk["v"].astype(dt))
+        return x + jnp.einsum("bsnd,ndh->bsh", attention(q, k, v, causal=True),
+                              blk["o"].astype(dt))
+
+
 def _layer(x, blk, cfg: DecoderConfig, kind: str):
+    if kind in MIXERS:
+        return _mixer_layer(x, blk, cfg, kind)
     with jax.named_scope("bps.attn"):
         x = x + _attention_half(x, blk["attn"], cfg,
                                 kind.endswith("sliding"))
@@ -208,8 +315,10 @@ def apply(params, cfg: DecoderConfig, tokens) -> jnp.ndarray:
         raise ValueError(f"{tokens.shape[1]} positions, the model has "
                          f"{cfg.max_seq}")
     with jax.named_scope("bps.embed"):
-        x = (embed_lookup(params["embed"], tokens)
-             * math.sqrt(cfg.hidden)).astype(dt)
+        x = embed_lookup(params["embed"], tokens)
+        if cfg.scale_embedding:
+            x = x * math.sqrt(cfg.hidden)
+        x = x.astype(dt)
     for kind, blk in zip(cfg.layer_kinds, params["layers"]):
         layer = functools.partial(_layer, cfg=cfg, kind=kind)
         if cfg.remat:

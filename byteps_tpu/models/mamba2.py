@@ -1,0 +1,133 @@
+"""The Mamba-2 mixer of a ``nemotron_h`` decoder (``models/decoder.py``,
+kind ``ssm``): what stands in a layer where attention would.
+
+``in_proj`` [hidden, inner + (inner + 2 groups n) + heads] gives ``z``
+(the gate), ``xBC`` and a head's ``dt`` from the normed input; ``xBC``
+passes a depthwise causal convolution of ``conv_kernel`` taps with bias,
+then SiLU, and splits into ``x`` [s, heads, p], ``B`` and ``C``
+[s, groups, n]; ``dt = softplus(dt + dt_bias)``, ``A = -exp(A_log)``; the
+recurrence runs in chunks (``ops/ssd.py``); ``y * silu(z)`` is RMS-normed
+in ``groups`` groups of channels and ``out_proj`` [inner, hidden] ends
+it. No biases but the convolution's. ``inner = heads * head_dim`` (the
+published code takes it from there, not from ``expand``).
+
+docs/state-space.md has the equations and what is float32: ``dt``, ``A``,
+the decays, their running sums, the carried state and the group norm;
+the projections, the convolution's operands and the scan's products are
+in the compute dtype.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+
+import jax
+import jax.numpy as jnp
+
+from ..ops.ssd import CHUNK, ssd
+
+# the seeded dt_bias: the inverse softplus of a step drawn log-uniform in
+# [DT_MIN, DT_MAX] and floored (the published time_step_min / _max / _floor)
+DT_MIN, DT_MAX, DT_FLOOR = 0.001, 0.1, 1e-4
+
+
+@dataclasses.dataclass(frozen=True)
+class SSMConfig:
+    """A Mamba-2 mixer's sizes."""
+    heads: int                    # mamba_num_heads
+    head_dim: int                 # mamba_head_dim
+    groups: int                   # n_groups: heads share B and C a group
+    state: int                    # ssm_state_size
+    conv_kernel: int = 4
+    chunk: int = CHUNK
+
+    def __post_init__(self):
+        if self.heads % self.groups:
+            raise ValueError(f"{self.heads} heads over {self.groups} groups")
+
+    @property
+    def inner(self) -> int:
+        return self.heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        return self.inner + 2 * self.groups * self.state
+
+
+def init_mixer(key, hidden: int, cfg: SSMConfig, std: float = 0.02):
+    """A mixer's leaves, float32: matrices N(0, ``std``); the
+    convolution as ``torch.nn.Conv1d`` starts it (uniform within
+    1 / sqrt(taps), weight and bias); ``dt_bias`` the inverse softplus of
+    a step drawn log-uniform in [DT_MIN, DT_MAX] and floored; ``A_log`` the
+    log of uniform [1, 16]; ``D`` one; unit norm scale."""
+    k = jax.random.split(key, 6)
+    bound = 1.0 / math.sqrt(cfg.conv_kernel)
+    step = jnp.maximum(jnp.exp(
+        jax.random.uniform(k[3], (cfg.heads,), jnp.float32)
+        * (math.log(DT_MAX) - math.log(DT_MIN)) + math.log(DT_MIN)), DT_FLOOR)
+    return {
+        "in_proj": jax.random.normal(
+            k[0], (hidden, cfg.inner + cfg.conv_dim + cfg.heads),
+            jnp.float32) * std,
+        "conv_w": jax.random.uniform(k[1], (cfg.conv_kernel, cfg.conv_dim),
+                                     jnp.float32, -bound, bound),
+        "conv_b": jax.random.uniform(k[2], (cfg.conv_dim,), jnp.float32,
+                                     -bound, bound),
+        "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+        "A_log": jnp.log(jax.random.uniform(k[4], (cfg.heads,), jnp.float32,
+                                            1.0, 16.0)),
+        "D": jnp.ones((cfg.heads,), jnp.float32),
+        "gated_norm": jnp.ones((cfg.inner,), jnp.float32),
+        "out_proj": jax.random.normal(k[5], (cfg.inner, hidden),
+                                      jnp.float32) * std,
+    }
+
+
+def causal_conv(x, w, bias):
+    """Depthwise: ``out_t = bias + sum_k w[k] x_{t - (taps - 1) + k}`` over
+    [b, s, channels], zeros before the first position; float32 sums."""
+    taps, s = w.shape[0], x.shape[1]
+    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
+    out = bias.astype(jnp.float32)
+    for k in range(taps):
+        out = out + w[k].astype(jnp.float32) * padded[:, k:k + s]
+    return out
+
+
+def group_rmsnorm(x, scale, groups: int, eps: float):
+    """RMSNorm over each of ``groups`` runs of channels, in float32."""
+    g = x.astype(jnp.float32).reshape(x.shape[:-1] + (groups, -1))
+    g = g * jax.lax.rsqrt(jnp.mean(g * g, -1, keepdims=True) + eps)
+    return g.reshape(x.shape) * scale
+
+
+def mixer(a, blk, cfg: SSMConfig, eps: float):
+    """[b, s, hidden] (normed) -> [b, s, hidden]."""
+    dt_ = a.dtype
+    bsz, s, _ = a.shape
+    inner, gn = cfg.inner, cfg.groups * cfg.state
+    w = blk["in_proj"].astype(dt_)
+    with jax.named_scope("bps.ssm.proj"):       # three products of slices
+        z = a @ w[:, :inner]                    # of the weight: no copy of
+        xbc = a @ w[:, inner:inner + cfg.conv_dim]          # an activation
+        step = jnp.dot(a, w[:, inner + cfg.conv_dim:],
+                       preferred_element_type=jnp.float32)
+    with jax.named_scope("bps.ssm.conv"):
+        xbc = jax.nn.silu(causal_conv(xbc, blk["conv_w"],
+                                      blk["conv_b"])).astype(dt_)
+    with jax.named_scope("bps.ssm.scan"):
+        y = ssd(xbc[..., :inner].reshape(bsz, s, cfg.heads, cfg.head_dim),
+                jax.nn.softplus(step + blk["dt_bias"]),
+                -jnp.exp(blk["A_log"]),
+                xbc[..., inner:inner + gn].reshape(bsz, s, cfg.groups,
+                                                   cfg.state),
+                xbc[..., inner + gn:].reshape(bsz, s, cfg.groups, cfg.state),
+                blk["D"], cfg.chunk)
+    with jax.named_scope("bps.ssm.norm"):
+        y = group_rmsnorm(
+            y.reshape(bsz, s, inner).astype(jnp.float32)
+            * jax.nn.silu(z.astype(jnp.float32)),
+            blk["gated_norm"], cfg.groups, eps).astype(dt_)
+    with jax.named_scope("bps.ssm.proj"):
+        return y @ blk["out_proj"].astype(dt_)

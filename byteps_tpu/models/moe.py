@@ -261,6 +261,9 @@ class RoutedConfig:
     row_tile: int = 512           # rows a tile of the grouped products
     impl: str = "auto"            # ops.grouped_matmul.grouped_matmul's
     balanced: bool = False        # choose on standardised outputs (route)
+    act: str = "gated_silu"       # the experts' function: one of ACTS
+    shared_dim: int = 0           # the shared expert's width, where the
+    # layer states one of its own (the weights' shapes say it besides)
 
     def __post_init__(self):
         held = tuple(self.held)
@@ -270,6 +273,9 @@ class RoutedConfig:
                              f"of a router with {self.num_experts}")
         if not 1 <= self.top_k <= self.num_experts:
             raise ValueError(f"top_k {self.top_k} of {self.num_experts}")
+        if self.act not in ACTS:
+            raise ValueError(f"experts' function {self.act!r} is none of "
+                             f"{sorted(ACTS)}")
 
     @property
     def rows(self):
@@ -281,6 +287,17 @@ def gated_silu(h):
     """``silu(gate) * up`` of a fused [.., 2 m] gate|up projection."""
     m = h.shape[-1] // 2
     return jax.nn.silu(h[..., :m]) * h[..., m:]
+
+
+def relu2(h):
+    """``relu(up)^2`` of a plain [.., m] up projection (nemotron_h)."""
+    return jnp.square(jax.nn.relu(h))
+
+
+# an expert is ``down(act(first x))``: the function between its two
+# products, and the name of the first one's weights ([h, 2 m] fused gate
+# and up under ``gated_silu``, [h, m] under ``relu2``)
+ACTS = {"gated_silu": (gated_silu, "gate_up"), "relu2": (relu2, "up")}
 
 
 def route(f, router_w, cfg: RoutedConfig, sequences: int = 1):
@@ -415,21 +432,25 @@ def routed_ffn(f, blk, cfg: RoutedConfig, sequences: int = 1):
     ``f`` [T, h] -> [T, h] (``sequences`` of them end to end: what a
     balanced choice is balanced over, see ``route``):
     ``shared(f) + sum over the token's chosen
-    experts that are held here of weight * expert(f)``, every expert a
-    gated-SiLU MLP. ``blk``: ``router`` [h, num_experts]; ``experts``
-    ``gate_up`` [held, h, 2 m], ``down`` [held, m, h]; optional ``shared``
-    ``gate_up`` [h, 2 ms], ``down`` [ms, h].
+    experts that are held here of weight * expert(f)``, every expert
+    ``down(act(first f))`` with ``cfg.act`` the layer's function (``ACTS``:
+    a gated SiLU over a fused ``gate_up`` [h, 2 m], or ``relu(.)^2`` over
+    ``up`` [h, m]). ``blk``: ``router`` [h, num_experts]; ``experts``
+    ``gate_up`` [held, h, 2 m] or ``up`` [held, h, m], ``down``
+    [held, m, h]; optional ``shared``, the same MLP once at a width of its
+    own, ``gate_up`` [h, 2 ms] or ``up`` [h, ms], ``down`` [ms, h].
 
     No row is dropped, whatever the imbalance: the buffer of rows is sized
     for the worst routing. The grouped products and the movement of rows
     (to the buffer by live row tile, back by the rows a tile of tokens has
     here: ``ops/routed_rows.py``) take time by the rows routed this step;
-    the gated SiLU between the products and the plan still walk the whole
+    the function between the products and the plan still walk the whole
     buffer and every chosen pair (PERF.md section 5)."""
     dt = f.dtype
     tile = cfg.row_tile
+    act, first = ACTS[cfg.act]
     move = resolve_rows(cfg.impl, *f.shape, blk["experts"]["down"].shape[1],
-                        len(cfg.held), dt, tile)
+                        len(cfg.held), tile)
     with jax.named_scope("bps.moe"):
         with jax.named_scope("bps.moe.route"):
             weights, experts = route(f, blk["router"], cfg, sequences)
@@ -440,13 +461,13 @@ def routed_ffn(f, blk, cfg: RoutedConfig, sequences: int = 1):
                 return grouped_matmul(
                     lhs, w.astype(dt), plan["tile_group"], plan["num_tiles"],
                     plan["group_rows"], tile, cfg.impl)
-            y = product(gated_silu(product(rows, blk["experts"]["gate_up"])),
+            y = product(act(product(rows, blk["experts"][first])),
                         blk["experts"]["down"])
         with jax.named_scope("bps.moe.route"):
             out = _combine(y, weights, plan, tile, move)
         if "shared" in blk:
             with jax.named_scope("bps.moe.shared"):
-                out = out + gated_silu(
-                    f @ blk["shared"]["gate_up"].astype(dt)
+                out = out + act(
+                    f @ blk["shared"][first].astype(dt)
                 ) @ blk["shared"]["down"].astype(dt)
     return out

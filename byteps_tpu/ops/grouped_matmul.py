@@ -10,7 +10,7 @@ past ``num_tiles`` skips its body, and its index maps stay on the last
 tile that ran, so it moves nothing either. Device time of these kernels
 follows the rows that are there, not the buffer, and so does the movement
 of rows to and from the buffer (``ops/routed_rows.py``); of what
-surrounds them in ``routed_ffn`` the gated SiLU between the products
+surrounds them in ``routed_ffn`` the experts' function between the products
 still walks the whole buffer.
 
   - ``bps_gmm``     out[r] = lhs[r] @ w[group(r)]          [rows, n]
@@ -22,6 +22,16 @@ contraction of the first two is one block (k, n <= a few thousand: an
 expert's widths); ``bps_gmm_dw`` carries an fp32 accumulator over a
 group's row tiles. Rows of tiles that did not run hold whatever the
 buffer held: callers read only the rows they routed.
+
+Which widths run the kernels (``supported``): ``k`` and ``n`` in whole
+lane tiles of 128 OR ending in a half one (1856 = 14.5 tiles, an
+expert's width in nemotron_h). A width that a power-of-two block of
+whole lane tiles divides is cut into such blocks, as ever; any other is
+cut into blocks of whole lane tiles that leave the least over
+(``_cols``: 2688 -> 7 x 384, 1856 -> 4 x 384 + 320), and the last block
+hangs over the array's edge: what it reads there is garbage that only
+reaches results past the edge, which are not written. A contraction is
+never cut, so it takes the whole width, half tile and all.
 
 ``grouped_matmul`` is the differentiable entry: the kernels on the TPU,
 ``lax.ragged_dot`` elsewhere (CPU tests), like ``ops.flash_attention
@@ -39,12 +49,28 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import _pick_block as _pick
 
+HALF_LANES = 64     # a width is whole lane tiles, or ends in half a one
+
 # a step past the rows revisits the last tile's blocks: nothing may be
 # reordered around it, so the tile dimension is never "parallel"
 _GMM_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("arbitrary", "arbitrary"))
 _DW_SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
+def _cols(width: int, want: int) -> int:
+    """Columns a block of a dimension ``width`` long. ``_pick``'s where
+    that is a power-of-two number of lane tiles, at least two (every
+    width of whole 256s: the blocks those widths always had). Else the
+    multiple of 128 up to ``want`` (and the width) whose blocks hang
+    least over the edge, the larger of equals; a width under a lane tile
+    is one block."""
+    b = _pick(width, want)
+    if 256 <= b <= want or width < 128:
+        return b
+    return min(range(128, min(want, width) + 1, 128),
+               key=lambda c: (-(-width // c) * c - width, -c))
 
 
 def _gmm_kernel(group_ref, num_ref, lhs_ref, rhs_ref, out_ref, *, transpose):
@@ -70,7 +96,7 @@ def _gmm(lhs, w, tile_group, num_tiles, tile, transpose, interpret):
     rows, c = lhs.shape
     g, k, n = w.shape
     width = k if transpose else n
-    tn = _pick(width, 512)
+    tn = _cols(width, 512)
 
     def last(t, num):           # a step past the rows stays on the last tile
         return jnp.minimum(t, num[0] - 1)
@@ -85,7 +111,7 @@ def _gmm(lhs, w, tile_group, num_tiles, tile, transpose, interpret):
         functools.partial(_gmm_kernel, transpose=transpose),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(width // tn, rows // tile),
+            grid=(pl.cdiv(width, tn), rows // tile),
             in_specs=[
                 pl.BlockSpec((tile, c),
                              lambda j, t, grp, num: (last(t, num), 0)),
@@ -130,7 +156,7 @@ def _gmm_dw(lhs, dout, tile_group, num_tiles, groups, tile, interpret):
     zero rows), so every block of the result is written."""
     rows, k = lhs.shape
     n = dout.shape[1]
-    tk, tn = _pick(k, 512), _pick(n, 1024)
+    tk, tn = _cols(k, 512), _cols(n, 1024)
 
     def last(t, num):
         return jnp.minimum(t, num[0] - 1)
@@ -139,7 +165,7 @@ def _gmm_dw(lhs, dout, tile_group, num_tiles, groups, tile, interpret):
         _gmm_dw_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(k // tk, n // tn, rows // tile),
+            grid=(pl.cdiv(k, tk), pl.cdiv(n, tn), rows // tile),
             in_specs=[
                 pl.BlockSpec((tile, tk),
                              lambda i, j, t, grp, num: (last(t, num), i)),
@@ -178,12 +204,12 @@ _gmm_vjp.defvjp(_gmm_vjp_fwd, _gmm_vjp_bwd)
 
 
 def supported(lhs_shape, w_shape, tile: int) -> bool:
-    """Shapes the kernels take: widths in whole 128-lane tiles, rows in
-    whole row tiles of a multiple of 128."""
+    """Shapes the kernels take: widths in whole 128-lane tiles or ending
+    in a half one, rows in whole row tiles of a multiple of 128."""
     rows, _ = lhs_shape
     _, k, n = w_shape
-    return (tile % 128 == 0 and rows % tile == 0 and k % 128 == 0
-            and n % 128 == 0)
+    return (tile % 128 == 0 and rows % tile == 0 and k % HALF_LANES == 0
+            and n % HALF_LANES == 0)
 
 
 def grouped_matmul(lhs, w, tile_group, num_tiles, group_rows, tile: int,

@@ -15,6 +15,9 @@ gathers cannot know that: by row they walk the whole buffer, by pair all
     (8, 128) tiles (16 rows of bf16), so ``src`` is first laid out a row
     a tile (``[n * h / 128, 128]``: a reshape of ``tokens`` rows, not of
     the buffer), and the copied tile is put back as ``[tile, h]`` in VMEM.
+    A row that is no whole number of such tiles (hidden 2688 in bf16: 21
+    rows of 128 lanes, a tile holds 16) is padded to the next whole one
+    in that copy of ``src`` (32: 4096 lanes), and the pad stays in VMEM.
   - ``bps_moe_combine``  out[t] = sum_j weights[t, j] * y[dest[t, j]]
     over the pairs that have a row here, or with ``d_out`` the products
     ``y[dest[t, j]] . d_out[t]``. A grid step a tile of tokens. An
@@ -30,6 +33,11 @@ gathers cannot know that: by row they walk the whole buffer, by pair all
 Pad rows INSIDE a live tile are written as zeros: ``bps_gmm_dw`` sums
 over every row of a live tile. Off the TPU both are ``jnp.take`` with
 fill (``impl`` "ragged"), as ``grouped_matmul`` is ``lax.ragged_dot``.
+
+Which widths run the kernels (``resolve``): the hidden size in whole
+128-lane tiles; the experts' width as ``grouped_matmul.supported`` takes
+it (whole lane tiles or ending in a half one), since these kernels leave
+unwritten what only the grouped kernels know not to read.
 """
 
 from __future__ import annotations
@@ -40,6 +48,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .grouped_matmul import HALF_LANES
 
 _LANES = 128
 _TOKEN_TILE = 512       # tokens a grid step of bps_moe_combine
@@ -63,22 +73,21 @@ def _token_tile(tokens: int) -> int:
 
 
 def resolve(impl: str, tokens: int, hidden: int, width: int, held: int,
-            dtype, tile: int) -> str:
+            tile: int) -> str:
     """How the rows of a layer travel under ``grouped_matmul``'s ``impl``:
     "gmm" or "gmm_interpret" (the two kernels; "auto": on the TPU) where
     they take the shapes, else "ragged" (XLA's gathers). Never the kernels
     around ``lax.ragged_dot``, which may read what they leave unwritten:
-    so ``width``, the experts', and ``tile`` in whole 128s, as the grouped
-    kernels want them. The compiled kernels move a row as whole (8, 128)
-    tiles; the interpreter does not care."""
+    so ``width``, the experts', as the grouped kernels take it (whole lane
+    tiles or ending in a half one) and ``tile`` in whole 128s. The
+    compiled kernels move a row as whole (8, 128) tiles (``_take`` pads a
+    row that is none); the interpreter does not care."""
     if impl == "auto":
         impl = "gmm" if jax.default_backend() == "tpu" else "ragged"
     tt = _token_tile(tokens)
-    fits = (hidden % _LANES == 0 and width % _LANES == 0
+    fits = (hidden % _LANES == 0 and width % HALF_LANES == 0
             and tile % _LANES == 0 and tt <= _TOKEN_TILE and tt % 8 == 0
-            and held * _WINDOW <= _MAX_SPAN
-            and (impl == "gmm_interpret"
-                 or (hidden // _LANES) % _row_align(dtype) == 0))
+            and held * _WINDOW <= _MAX_SPAN)
     return impl if fits else "ragged"
 
 
@@ -127,10 +136,12 @@ def _take_kernel(num_ref, idx_ref, *rest, sub, n_src, scaled):
             return carry
 
         jax.lax.fori_loop(0, issued, wait, 0)
-        rows = stage[...].reshape(tile, h)
+        rows = stage[...].reshape(tile, sub * _LANES)
+        if sub * _LANES != h:   # a row padded to whole tiles: drop the pad
+            rows = rows[:, :h]
         if scaled:              # [1, tile] along lanes -> a row's own lanes
             by_row = jnp.broadcast_to(scale_ref[0], (_LANES, tile)).T
-            for c in range(sub):
+            for c in range(h // _LANES):
                 lanes = slice(c * _LANES, (c + 1) * _LANES)
                 out_ref[:, lanes] = (rows[:, lanes].astype(jnp.float32)
                                      * by_row).astype(out_ref.dtype)
@@ -144,7 +155,10 @@ def _take_kernel(num_ref, idx_ref, *rest, sub, n_src, scaled):
 def _take(src, index, num_tiles, tile, scale, interpret):
     rows = index.shape[0]
     n_src, h = src.shape
-    sub = h // _LANES
+    align = _row_align(src.dtype)
+    sub = -(-h // (_LANES * align)) * align     # whole (8, 128) tiles a row
+    if sub * _LANES != h:
+        src = jnp.pad(src, ((0, 0), (0, sub * _LANES - h)))
     scaled = scale is not None
 
     def last(t, num):           # a step past the rows stays on the last tile

@@ -151,16 +151,22 @@ def test_fp8_sr_quantize_compiles_for_v5e(compile_for_chip, mosaic, kind):
         ((), jnp.uint32))
 
 
-def test_routed_row_movement_compiles_for_v5e(compile_for_chip):
+@pytest.mark.parametrize("hidden,width,k,held,tiles", [
+    (2048, 1024, 8, 16, 272), (2688, 1856, 6, 8, 200)],
+    ids=["trinity_mini", "nemotron3_nano"])
+def test_routed_row_movement_compiles_for_v5e(compile_for_chip, hidden, width,
+                                              k, held, tiles):
     """``bps_moe_take`` (plain and scaled) and ``bps_moe_combine`` (the
-    weighted sum, the plain sum, the products with ``d_out``) at the
-    routed cell's shapes: 16,384 tokens of 2,048 in bf16, 8 choices, 16
-    experts held, a worst-case buffer of 272 row tiles of 512."""
+    weighted sum, the plain sum, the products with ``d_out``) at the two
+    routed cells' shapes: 16,384 tokens in bf16, a worst-case buffer of
+    row tiles of 512. Trinity: hidden 2,048 (a row is one (16, 128) tile),
+    8 choices, 16 experts held. Nemotron 3 Nano: hidden 2,688 (21 rows of
+    128 lanes, padded to 32 in the take's source), experts of 1,856, 6
+    choices, 8 held."""
     from byteps_tpu.ops import routed_rows as rr
 
-    tokens, hidden, k, held, tile, tiles = 16384, 2048, 8, 16, 512, 272
-    assert rr.resolve("gmm", tokens, hidden, 1024, held,
-                      jnp.bfloat16, tile) == "gmm"
+    tokens, tile = 16384, 512
+    assert rr.resolve("gmm", tokens, hidden, width, held, tile) == "gmm"
     bounds = (tokens // 512 * held,)
 
     def move(x, y, index, num, scale, dest, w, lo, hi, live, lanes):
@@ -179,3 +185,28 @@ def test_routed_row_movement_compiles_for_v5e(compile_for_chip):
         ((tiles * tile,), jnp.float32), ((tokens, k), jnp.int32),
         ((tokens, k), jnp.float32), (bounds, jnp.int32), (bounds, jnp.int32), ((1,), jnp.int32),
         ((tokens // 512, 2, held * 64), jnp.int32))
+
+
+@pytest.mark.parametrize("k,n", [(2688, 1856), (1856, 2688)],
+                         ids=["up", "down"])
+def test_grouped_products_off_the_lane_tile_compile_for_v5e(compile_for_chip,
+                                                            k, n):
+    """``bps_gmm``, ``bps_gmm_dx`` and ``bps_gmm_dw`` at Nemotron 3 Nano's
+    expert width of 1,856 (14.5 lane tiles) against a hidden size of 2,688
+    (21): blocks of 384 or 640 columns whose last hangs over the edge, and
+    a contraction over a width that ends in half a tile."""
+    from byteps_tpu.ops import grouped_matmul as gm
+
+    held, tile, tiles = 8, 512, 200
+    assert gm.supported((tiles * tile, k), (held, k, n), tile)
+
+    def grads(lhs, w, group, num):
+        return jax.grad(lambda lhs, w: gm.grouped_matmul(
+            lhs, w, group, num, None, tile, "gmm").astype(
+                jnp.float32).sum(), (0, 1))(lhs, w)
+
+    text = compile_for_chip(
+        grads, ((tiles * tile, k), jnp.bfloat16), ((held, k, n), jnp.bfloat16),
+        ((tiles,), jnp.int32), ((1,), jnp.int32))
+    for kernel in ("bps_gmm_dx", "bps_gmm_dw"):
+        assert kernel in text

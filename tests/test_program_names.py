@@ -28,6 +28,10 @@ SCOPES = ("bps.model", "bps.optimizer", "bps.exchange", "bps.exchange.pack",
 # the routed feed-forward's parts inside bps.mlp
 MOE_SCOPES = ("bps.moe", "bps.moe.route", "bps.moe.experts",
               "bps.moe.shared")
+# what the decoder's one-mixer layers add (ISSUE 34): a state-space mixer
+# and its parts; its attention and routed layers keep bps.attn, bps.mlp
+SSM_SCOPES = ("bps.ssm", "bps.ssm.proj", "bps.ssm.conv", "bps.ssm.scan",
+              "bps.ssm.norm")
 
 
 def _trainer(model: str, mesh):
@@ -35,8 +39,8 @@ def _trainer(model: str, mesh):
         cfg = bert.bert_tiny()
         loss = lambda p, b: bert.mlm_loss(p, cfg, b, max_predictions=8)  # noqa: E731
         make = lambda rng: bert.synth_mlm_batch(rng, 8, 32, 128)  # noqa: E731
-    elif model == "afmoe_tiny":
-        cfg = decoder.afmoe_tiny()
+    elif model in ("afmoe_tiny", "nemotron_h_tiny"):
+        cfg = getattr(decoder, model)()
         loss = lambda p, b: decoder.causal_lm_loss(p, cfg, b)  # noqa: E731
         make = lambda rng: gpt2.synth_lm_batch(rng, 8, 32, 128)  # noqa: E731
         params = decoder.init_params(jax.random.PRNGKey(0), cfg)
@@ -58,7 +62,7 @@ def lowered():
     """The step's lowering with its locations, once a model."""
     mesh = make_mesh({"data": 4}, devices=jax.devices()[:4])
     texts = {}
-    for model in ("bert_tiny", "afmoe_tiny", "gpt2_tiny"):
+    for model in ("bert_tiny", "afmoe_tiny", "nemotron_h_tiny", "gpt2_tiny"):
         trainer, make = _trainer(model, mesh)
         step = trainer._step_fn.lower(trainer.params, trainer.opt_state,
                                       make(np.random.RandomState(0)))
@@ -72,7 +76,9 @@ def lowered():
 
 @pytest.mark.parametrize("model,scope", list(itertools.product(
     ("bert_tiny", "gpt2_tiny"), SCOPES)) + [
-        ("afmoe_tiny", scope) for scope in SCOPES + MOE_SCOPES])
+        ("afmoe_tiny", scope) for scope in SCOPES + MOE_SCOPES] + [
+        ("nemotron_h_tiny", scope)
+        for scope in SCOPES + MOE_SCOPES + SSM_SCOPES])
 def test_lowered_step_holds_the_scope(lowered, model, scope):
     names = set(re.findall(r"bps[._][A-Za-z_.]+", lowered[model]))
     assert scope in names
@@ -112,6 +118,28 @@ def test_the_routed_layers_scopes_nest_inside_the_feed_forward():
         assert some(rf"bps\.model/jvp\(bps\.mlp\)/bps\.moe/bps\.moe\.{inner}")
         assert some(rf"bps\.model/transpose\(.*bps\.moe\.{inner}")
     assert not some(r"bps\.attn/.*bps\.moe")
+
+
+def test_a_state_space_mixers_parts_nest_inside_it():
+    """``bps.ssm`` holds the mixer's norm and its four parts, forward and
+    backward; the routed layer of a one-mixer decoder lies in ``bps.mlp``
+    as afmoe's does, and nothing of it in ``bps.ssm``."""
+    mesh = make_mesh({"data": 2}, devices=jax.devices()[:2])
+    trainer, make = _trainer("nemotron_h_tiny", mesh)
+    step = trainer._step_fn.lower(trainer.params, trainer.opt_state,
+                                  make(np.random.RandomState(0)))
+    paths = set(re.findall(r'op_name="([^"]*)"', step.compile().as_text()))
+
+    def some(pattern):
+        return any(re.search(pattern, p) for p in paths)
+
+    for inner in ("proj", "conv", "scan", "norm"):
+        assert some(rf"bps\.model/jvp\(bps\.ssm\)/bps\.ssm\.{inner}")
+        assert some(rf"bps\.model/transpose\(.*bps\.ssm\.{inner}")
+    assert some(r"bps\.model/jvp\(bps\.mlp\)/bps\.moe/bps\.moe\.experts")
+    assert some(r"bps\.model/jvp\(\)/.*bps\.attn|jvp\(bps\.attn\)")
+    assert not some(r"bps\.ssm/.*bps\.moe") and not some(
+        r"bps\.attn/.*bps\.ssm")
 
 
 @pytest.mark.parametrize("seq,kernels", [
