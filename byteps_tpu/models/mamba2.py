@@ -25,7 +25,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ..ops.ssd import CHUNK, ssd
+from ..ops.ssd import CHUNK, ssd_packed
 
 # the seeded dt_bias: the inverse softplus of a step drawn log-uniform in
 # [DT_MIN, DT_MAX] and floored (the published time_step_min / _max / _floor)
@@ -104,9 +104,7 @@ def group_rmsnorm(x, scale, groups: int, eps: float):
 
 def mixer(a, blk, cfg: SSMConfig, eps: float):
     """[b, s, hidden] (normed) -> [b, s, hidden]."""
-    dt_ = a.dtype
-    bsz, s, _ = a.shape
-    inner, gn = cfg.inner, cfg.groups * cfg.state
+    dt_, inner = a.dtype, cfg.inner
     w = blk["in_proj"].astype(dt_)
     with jax.named_scope("bps.ssm.proj"):       # three products of slices
         z = a @ w[:, :inner]                    # of the weight: no copy of
@@ -116,18 +114,13 @@ def mixer(a, blk, cfg: SSMConfig, eps: float):
     with jax.named_scope("bps.ssm.conv"):
         xbc = jax.nn.silu(causal_conv(xbc, blk["conv_w"],
                                       blk["conv_b"])).astype(dt_)
-    with jax.named_scope("bps.ssm.scan"):
-        y = ssd(xbc[..., :inner].reshape(bsz, s, cfg.heads, cfg.head_dim),
-                jax.nn.softplus(step + blk["dt_bias"]),
-                -jnp.exp(blk["A_log"]),
-                xbc[..., inner:inner + gn].reshape(bsz, s, cfg.groups,
-                                                   cfg.state),
-                xbc[..., inner + gn:].reshape(bsz, s, cfg.groups, cfg.state),
-                blk["D"], cfg.chunk)
+    with jax.named_scope("bps.ssm.scan"):   # x, B and C where they lie
+        y = ssd_packed(xbc, jax.nn.softplus(step + blk["dt_bias"]),
+                       -jnp.exp(blk["A_log"]), blk["D"], cfg.groups,
+                       cfg.state, cfg.chunk)
     with jax.named_scope("bps.ssm.norm"):
         y = group_rmsnorm(
-            y.reshape(bsz, s, inner).astype(jnp.float32)
-            * jax.nn.silu(z.astype(jnp.float32)),
+            y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32)),
             blk["gated_norm"], cfg.groups, eps).astype(dt_)
     with jax.named_scope("bps.ssm.proj"):
         return y @ blk["out_proj"].astype(dt_)

@@ -210,3 +210,30 @@ def test_grouped_products_off_the_lane_tile_compile_for_v5e(compile_for_chip,
         ((tiles,), jnp.int32), ((1,), jnp.int32))
     for kernel in ("bps_gmm_dx", "bps_gmm_dw"):
         assert kernel in text
+
+
+def test_state_space_scan_compiles_for_v5e(compile_for_chip):
+    """``bps_ssd_fwd`` (with and without the states it saves) and
+    ``bps_ssd_bwd`` at Nemotron 3 Nano's shape: 2 x 8,192 positions, 64
+    heads of 64 in 8 groups, a state of 128, chunks of 128, bf16. A lane
+    tile holds two heads: a column of the group's steps brought to a
+    head's lanes, a head's lanes kept by a select, the state transposed
+    in scratch."""
+    from byteps_tpu.ops import ssd as S
+
+    bsz, s, heads, p, groups, n = 2, 8192, 64, 64, 8, 128
+    assert S.supported((bsz, s, heads, p), (bsz, s, groups, n))
+
+    def both(*args):
+        y, pull = jax.vjp(lambda *a: S.ssd_kernels(*a, S.CHUNK, False), *args)
+        return S.ssd_kernels(*args, S.CHUNK, False), pull(y)
+
+    text = compile_for_chip(
+        both, ((bsz, s, heads, p), jnp.bfloat16),
+        ((bsz, s, heads), jnp.float32), ((heads,), jnp.float32),
+        ((bsz, s, groups, n), jnp.bfloat16),
+        ((bsz, s, groups, n), jnp.bfloat16), ((heads,), jnp.float32))
+    for kernel in ("bps_ssd_fwd", "bps_ssd_bwd"):
+        assert kernel in text
+    # the states before each chunk, float32, leave the forward that saves
+    assert f"f32[{bsz},{s // S.CHUNK},{n},{heads * p}]" in text
