@@ -29,8 +29,15 @@ embedding and of the head held here, ``held`` the experts held here of
 
 The parameter tree (``init_params``) is a list of per-layer dicts (the
 layers differ in shape, so they are not stacked); the layers run as a
-Python loop, each under ``jax.checkpoint``. The embedding lookup, the
-chunked head and the target convention are ``transformer.py``'s.
+Python loop, each under ``jax.checkpoint``. A layer's checkpoint keeps
+its input, the flash kernel's output and row statistics (``flash_out``,
+``flash_lse``: [b, s, heads * head_dim] bf16 + [b, heads, s] fp32, 34 MB
+at 2 x 8192 x 8 heads of 128, 135 MB at 32 heads) and the routed layer's
+plan (``moe.PLAN_NAME``: the choice, its scores and the int32 arrays of the
+chosen pairs and the buffer's rows, 3 MB at 16,384 tokens x 8); everything
+else is recomputed in the backward, so the kernel's forward and the plan's
+top-k, sort and gathers run once a layer. The embedding lookup, the chunked head and the target
+convention are ``transformer.py``'s.
 """
 
 from __future__ import annotations
@@ -43,9 +50,9 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops.flash_attention import attention
+from ..ops.flash_attention import SAVED_NAMES, attention
 from .mamba2 import SSMConfig, init_mixer, mixer as ssm_mixer
-from .moe import RoutedConfig, gated_silu, routed_ffn
+from .moe import PLAN_NAME, RoutedConfig, gated_silu, routed_ffn
 from .transformer import _chunked_nll_sum, embed_lookup
 
 # a layer of two halves (attention, feed-forward) ...
@@ -319,10 +326,14 @@ def apply(params, cfg: DecoderConfig, tokens) -> jnp.ndarray:
         if cfg.scale_embedding:
             x = x * math.sqrt(cfg.hidden)
         x = x.astype(dt)
+    # a layer's checkpoint keeps its input, the flash kernel's output and
+    # row statistics and the routed layer's plan; the rest is recomputed
+    policy = jax.checkpoint_policies.save_only_these_names(
+        *SAVED_NAMES, PLAN_NAME)
     for kind, blk in zip(cfg.layer_kinds, params["layers"]):
         layer = functools.partial(_layer, cfg=cfg, kind=kind)
         if cfg.remat:
-            layer = jax.checkpoint(layer)
+            layer = jax.checkpoint(layer, policy=policy)
         x = layer(x, blk)
     with jax.named_scope("bps.head"):    # the final norm feeds the head
         return rmsnorm(x, params["final_norm"], cfg.norm_eps)

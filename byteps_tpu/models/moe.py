@@ -39,6 +39,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ..ops.grouped_matmul import grouped_matmul
@@ -300,6 +301,17 @@ def relu2(h):
 ACTS = {"gated_silu": (gated_silu, "gate_up"), "relu2": (relu2, "up")}
 
 
+# the name a ``jax.checkpoint`` policy keeps the routed layer's plan
+# under: every int32 array of ``plan_rows``, the choice of experts it is
+# made from and the chosen scores (``route``). They are a few MB a layer
+# and making them (top-k, a sort, searchsorted, gathers of int32 over the
+# whole buffer and of the scores by pair, a millisecond each at 131,072
+# pairs) is dear: ``decoder.apply``'s checkpoint saves them, so the
+# backward's recompute holds none of it. The int32 arrays carry no
+# gradient; the scores' flows through the name as through an identity.
+PLAN_NAME = "moe_plan"
+
+
 def route(f, router_w, cfg: RoutedConfig, sequences: int = 1):
     """(weights [T, k] fp32, experts [T, k] int32): sigmoid scores over
     ALL the router's outputs in fp32, the k largest, their scores
@@ -317,16 +329,18 @@ def route(f, router_w, cfg: RoutedConfig, sequences: int = 1):
     logits = jnp.dot(f.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     scores = jax.nn.sigmoid(logits)
-    if not cfg.balanced:
-        top, experts = jax.lax.top_k(scores, cfg.top_k)
-    else:
+    ranked = jax.lax.stop_gradient(scores)
+    if cfg.balanced:
         by_seq = jax.lax.stop_gradient(logits).reshape(
             sequences, -1, logits.shape[-1])
         centred = by_seq - by_seq.mean(1, keepdims=True)
         centred *= jax.lax.rsqrt(
             jnp.mean(centred * centred, 1, keepdims=True) + 1e-12)
-        _, experts = jax.lax.top_k(centred.reshape(logits.shape), cfg.top_k)
-        top = jnp.take_along_axis(scores, experts, axis=-1)
+        ranked = centred.reshape(logits.shape)
+    # the scores are a gather by the NAMED choice: neither is made again
+    experts = checkpoint_name(jax.lax.top_k(ranked, cfg.top_k)[1], PLAN_NAME)
+    top = checkpoint_name(jnp.take_along_axis(scores, experts, axis=-1),
+                          PLAN_NAME)
     return cfg.route_scale * top / top.sum(-1, keepdims=True), experts
 
 
@@ -344,7 +358,7 @@ def plan_rows(experts, cfg: RoutedConfig):
     ``grouped_matmul`` reads; ``counts`` [held]: rows routed to each;
     ``lo``, ``hi``, ``lanes``, ``live``: where a tile of tokens has its
     rows in each expert's run (``routed_rows.tile_bounds``: what
-    ``bps_moe_combine`` reads)."""
+    ``bps_moe_combine`` reads). Every one is named ``PLAN_NAME``."""
     t, k = experts.shape
     held, tile = len(cfg.held), cfg.row_tile
     pairs = t * k
@@ -369,12 +383,13 @@ def plan_rows(experts, cfg: RoutedConfig):
     at = row - run[rg]
     row_pair = jnp.where(at < counts[rg],
                          order[jnp.clip(start[rg] + at, 0, pairs - 1)], pairs)
-    return {"dest": dest.reshape(t, k), "row_pair": row_pair,
-            "row_token": jnp.where(row_pair < pairs, row_pair // k, t),
-            "tile_group": tile_group,
-            "num_tiles": (padded.sum() // tile).astype(jnp.int32)[None],
-            "group_rows": padded, "counts": counts,
-            **tile_bounds(local.reshape(t, k), padded)}
+    return checkpoint_name(
+        {"dest": dest.reshape(t, k), "row_pair": row_pair,
+         "row_token": jnp.where(row_pair < pairs, row_pair // k, t),
+         "tile_group": tile_group,
+         "num_tiles": (padded.sum() // tile).astype(jnp.int32)[None],
+         "group_rows": padded, "counts": counts,
+         **tile_bounds(local.reshape(t, k), padded)}, PLAN_NAME)
 
 
 # Dispatch and combine are each other's transposes, and both are written

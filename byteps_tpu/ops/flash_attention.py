@@ -35,10 +35,15 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
+# what the kernels' backward reads of their forward, by the names a
+# ``jax.checkpoint`` policy keeps them under (``_fwd_rule``): the output
+# and the rows' log-sum-exp. The models' checkpoints save them by default.
+SAVED_NAMES = ("flash_out", "flash_lse")
 
 # every kernel's grid is (outer..., carried): only the innermost dim
 # carries scratch state across iterations; the rest are independent
@@ -1220,11 +1225,9 @@ def _fwd_rule(q, k, v, causal, scale, block_q, block_k, interpret,
     out, lse = _flash_fwd(qt, kt, vt, causal, scale, bq, bk, interpret,
                           bias=bias, rel_table=rel_table, rel=rel,
                           window=window, heads=heads)
-    from jax.ad_checkpoint import checkpoint_name
     # named so a remat policy can pin the flash residuals while everything
-    # around them recomputes (remat_policy="save_attn")
-    out = checkpoint_name(out, "flash_out")
-    lse = checkpoint_name(lse, "flash_lse")                 # [b,h,sq]
+    # around them recomputes (SAVED_NAMES; remat_policy="save_attn")
+    out, lse = map(checkpoint_name, (out, lse), SAVED_NAMES)  # lse [b,h,sq]
     res = (qt, kt, vt, out, lse, bias, rel_table)
     if heads is not None:
         return out.reshape(q.shape), res

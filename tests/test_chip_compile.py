@@ -10,6 +10,8 @@ may load the TPU library, so nothing here touches it at import or
 collection time (xdist workers must collect the same tests).
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -237,3 +239,62 @@ def test_state_space_scan_compiles_for_v5e(compile_for_chip):
         assert kernel in text
     # the states before each chunk, float32, leave the forward that saves
     assert f"f32[{bsz},{s // S.CHUNK},{n},{heads * p}]" in text
+
+
+def _flash_forwards(text):
+    """(``bps_flash_fwd`` calls in a compiled step, those of them in a
+    checkpoint's recompute) by the ``op_name`` each carries, the name
+    stack a trace of the chip reads."""
+    paths = [m.group(1) for line in text.splitlines()
+             if "tpu_custom_call" in line
+             for m in [re.search(r'op_name="([^"]*bps_flash_fwd[^"]*)"',
+                                 line)] if m]
+    return len(paths), sum("rematted_computation" in p for p in paths)
+
+
+@pytest.mark.parametrize("family,policy,forwards,again", [
+    ("transformer", "save_attn", 1, 0), ("transformer", None, 2, 1),
+    ("decoder", "save_attn", 3, 0)],
+    ids=["transformer", "transformer_minimum_memory", "decoder"])
+def test_the_checkpoint_keeps_the_flash_output_on_v5e(
+        compile_for_chip, monkeypatch, family, policy, forwards, again):
+    """A gradient step's compiled text for the chip (ISSUE 36). Under the
+    default the flash forward is there once an attending layer (once in
+    the body of the transformer's scan) and never under
+    ``rematted_computation``; ``remat_policy=None`` still runs it again
+    in the recompute; the decoder's routed layers choose and sort once."""
+    from byteps_tpu.models import decoder, gpt2, transformer
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if family == "transformer":
+        cfg = transformer.TransformerConfig(
+            vocab_size=512, hidden=256, layers=2, heads=4, mlp_dim=512,
+            max_seq=128, causal=True, remat_policy=policy)
+        params = jax.eval_shape(
+            lambda: transformer.init_params(jax.random.PRNGKey(0), cfg))
+        loss = gpt2.causal_lm_loss
+    else:
+        cfg = decoder.afmoe_config(
+            vocab_size=512, hidden=256, heads=2, kv_heads=1, head_dim=128,
+            mlp_dim=512, moe_dim=128, window=128, top_k=2, router_outputs=8,
+            held=(0, 1, 2, 3), balanced=True, routed_kw={"row_tile": 128},
+            layer_kinds=("dense_sliding", "moe_full", "moe_sliding"))
+        params = jax.eval_shape(
+            lambda: decoder.init_params(jax.random.PRNGKey(0), cfg))
+        loss = decoder.causal_lm_loss
+    assert cfg.remat
+    leaves, tree = jax.tree_util.tree_flatten(params)
+
+    def grads(tokens, *leaves):
+        return jax.grad(lambda p: loss(p, cfg, tokens))(
+            jax.tree_util.tree_unflatten(tree, leaves))
+
+    text = compile_for_chip(grads, ((2, 256 if family == "decoder" else 128),
+                                    jnp.int32),
+                            *((x.shape, x.dtype) for x in leaves))
+    assert _flash_forwards(text) == (forwards, again)
+    if family == "decoder":
+        sorts = [line for line in text.splitlines()
+                 if " sort(" in line and "bps.moe.route" in line]
+        # two a routed layer: the choice's top-k and the plan's sort
+        assert len(sorts) == 4, sorts
+        assert not any("rematted_computation" in s for s in sorts)
