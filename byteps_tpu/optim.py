@@ -7,8 +7,9 @@ with gradient accumulation via ``backward_passes_per_step``
 (torch/__init__.py:83-113).
 
 The TPU-native equivalent is an ``optax.GradientTransformation`` that
-inserts a bucketed cross-replica allreduce in front of the inner
-transformation. It must be applied *inside* a shard_map'd train step, where
+inserts a cross-replica allreduce in front of the inner transformation
+(``parallel.collectives.tree_allreduce``: the leaves as they are on an
+ICI-only mesh, flat buckets where a reducer needs a flat buffer). It must be applied *inside* a shard_map'd train step, where
 the mesh data axes are live — that is the idiomatic JAX seam, exactly where
 autodiff hands you raw per-replica gradients (the same seam the reference
 hooks with grad-accumulator callbacks).
@@ -21,7 +22,7 @@ from typing import Sequence, Tuple
 import jax
 import optax
 
-from .parallel.collectives import Reducer, bucketed_allreduce, psum_reducer
+from .parallel.collectives import Reducer, psum_reducer, tree_allreduce
 
 
 def _make(inner: optax.GradientTransformation, axes: Tuple[str, ...],
@@ -31,9 +32,9 @@ def _make(inner: optax.GradientTransformation, axes: Tuple[str, ...],
 
     def update_fn(grads, state, params=None, **extra):
         with jax.named_scope("bps.exchange"):
-            grads = bucketed_allreduce(grads, axes=axes,
-                                       partition_bytes=partition_bytes,
-                                       average=average, reducer=reducer)
+            grads = tree_allreduce(grads, axes=axes,
+                                   partition_bytes=partition_bytes,
+                                   average=average, reducer=reducer)
         with jax.named_scope("bps.optimizer"):
             return inner.update(grads, state, params, **extra)
 
@@ -116,6 +117,11 @@ def distributed_optimizer(inner: optax.GradientTransformation,
     torch/__init__.py:83-113) — implemented with optax.MultiSteps so the
     allreduce itself sits under the every-k branch and no bandwidth is
     spent on intermediate passes.
+
+    ``partition_bytes`` sizes the flat buckets where the exchange runs in
+    buckets: ``compression``, a custom ``reducer``, a ``"dcn"`` axis among
+    ``axes``. With the default ``psum_reducer`` on ICI axes each leaf is
+    reduced in its own shape and the value has no meaning (and no effect).
 
     ``compression`` is a string-kwargs dict in the reference's format
     (docs/gradient-compression.md "Interface"), e.g.

@@ -4,22 +4,36 @@ The reference moves every gradient through a 12-stage pipeline of priority
 queues and background threads (NCCL reduce-scatter → D2H → push → server
 sum → pull → H2D → all-gather; reference: common.h:88-102 QueueType,
 core_loops.cc). On TPU, all of those stages collapse into XLA collectives
-over a device mesh; what survives of the design — because it is what the
-design was *for* — is:
+over a device mesh. What is left of the design is decided by what a
+reduction needs, not by what the reference did:
 
-  1. **Bucketing**: many small gradients fused into few fixed-byte buckets
-     (reference: tensor partitioning, operations.cc:140-180 — inverted, see
-     byteps_tpu/common/partition.py).
-  2. **Priority order**: buckets communicated in reverse layer order so the
-     earliest-ready gradients go first (reference: scheduled_queue.cc:82-102).
-  3. **Overlap**: bucket collectives issued as separate async dispatches (or
-     as independent ops inside one jit program, where XLA's latency-hiding
-     scheduler overlaps them with compute).
+  1. **The leaves as they are** (``leaf_allreduce``): on an ICI-only mesh
+     the lossless exchange inside the jitted step is one ``psum`` a
+     gradient leaf, in the leaf's own shape and layout, and XLA's
+     all-reduce combiner does what bucketing did by hand. A stacked leaf
+     ``f32[24, 1024, 4096]`` in the TPU's tiled layout is not the bytes of
+     a flat ``f32[100663296]``: the ravel, the slices into 4 MB buffers
+     and the way back are copies, and the reshape from flat fuses into
+     the optimizer as a relayout. On BERT-large at dp=4 on four v5e chips
+     that cost 44.8 ms of a 635.4 ms step (PERF.md section 6, PR 37).
+  2. **Bucketing** (``bucketed_allreduce``; reference: tensor partitioning,
+     operations.cc:140-180 — inverted, see byteps_tpu/common/partition.py)
+     stays where a reducer needs a flat buffer: ``psum_reducer``'s
+     dcn × ici hierarchy (reduce-scatter, all-reduce of the shard,
+     all-gather over a 1-D buffer), a custom ``reducer`` (its contract is
+     ``(flat buffer, axes)``), compression (``optim._make_compressed``),
+     and the eager engine below. Buckets are planned in reverse layer
+     order (reference: scheduled_queue.cc:82-102), which orders the eager
+     engine's dispatches; inside one jitted step whose layers run under
+     ``lax.scan`` every gradient is ready at once, so neither form
+     overlaps the backward yet (ROADMAP A3(b)).
 
-Two forms are provided:
+``exchange_form`` is the one place that decides between the two, from the
+reducer's identity and the axes' names. Entry points:
 
-  - ``bucketed_allreduce`` — call *inside* your shard_map'd train step.
-    This is the primary, fully-jitted path.
+  - ``tree_allreduce`` — call *inside* your shard_map'd train step; takes
+    the form ``exchange_form`` names. This is the primary, fully-jitted
+    path (``optim.distributed_optimizer`` calls it).
   - ``PushPullEngine`` — an eager, Horovod-style engine: per-bucket jitted
     programs dispatched in priority order. This is the analogue of the
     reference's ``EnqueueTensor`` API and supports cross-barrier-style
@@ -122,9 +136,12 @@ def bucketed_allreduce(tree, axes: Sequence[str], partition_bytes: int = 4 << 20
 
     Flattens the grad pytree, packs leaves into ~partition_bytes buckets in
     reverse declaration order, reduces each bucket with ``reducer``, and
-    scatters back. Bucket reduces are independent ops in the XLA graph, so
-    the latency-hiding scheduler can overlap them with backward compute —
-    the jit-native version of the reference's pipelined queues.
+    scatters back: the form for a reducer that needs a flat buffer
+    (``exchange_form``). The pack and the way back are copies on the TPU
+    (a tiled leaf is not its ravel), which is why the default ICI path is
+    ``leaf_allreduce``; and the bucket reduces, independent ops though they
+    are, all become ready together when the layers run under ``lax.scan``,
+    so nothing here hides behind the backward (ROADMAP A3(b)).
     """
     axes = tuple(ax for ax in axes if ax)
     leaves, treedef = jax.tree_util.tree_flatten(tree)
@@ -148,6 +165,50 @@ def bucketed_allreduce(tree, axes: Sequence[str], partition_bytes: int = 4 << 20
             _unpack_bucket(buf, b, flat)
     out = [f.reshape(s) for f, s in zip(flat, shapes)]
     return jax.tree_util.tree_unflatten(treedef, out)
+
+
+def leaf_allreduce(tree, axes: Sequence[str], average: bool = True):
+    """Lossless gradient allreduce for use inside a shard_map'd step: one
+    ``psum`` a leaf, each in its own shape and layout, the mean a leaf's
+    own division. No ravel, slice, concatenate or update-slice: combining
+    small all-reduces is left to XLA."""
+    axes = tuple(ax for ax in axes if ax)
+    with jax.named_scope("bps.exchange.reduce"):
+        return jax.tree_util.tree_map(
+            lambda x: allreduce(x, axes, average), tree)
+
+
+def exchange_form(axes: Sequence[str], reducer: Reducer = psum_reducer,
+                  compression: Optional[dict] = None) -> Tuple[str, str]:
+    """Which form the in-jit exchange over ``axes`` takes, and why:
+    ``("leaves", reason)`` or ``("buckets", reason)``. Decided by what
+    makes a flat buffer necessary and by nothing else: the reducer's
+    identity, the axes' names, whether the gradients are compressed."""
+    if compression:
+        return "buckets", "compression encodes and reduces flat buckets"
+    if reducer is not psum_reducer:
+        return "buckets", "a custom reducer takes (flat buffer, axes)"
+    if "dcn" in axes:
+        return "buckets", ("psum_reducer's dcn x ici hierarchy is written "
+                           "over a 1-D buffer")
+    if not tuple(ax for ax in axes if ax):
+        return "leaves", "no axis to reduce over: the gradients pass through"
+    return "leaves", ("lossless psum on an ICI-only mesh: each leaf in its "
+                      "own shape and layout")
+
+
+def tree_allreduce(tree, axes: Sequence[str], partition_bytes: int = 4 << 20,
+                   average: bool = True, reducer: Reducer = psum_reducer):
+    """The in-jit gradient exchange, in the form ``exchange_form`` names:
+    ``leaf_allreduce`` on an ICI-only mesh with the default reducer,
+    ``bucketed_allreduce`` for a ``dcn`` axis or a custom reducer.
+    ``partition_bytes`` sizes the buckets where there are buckets and means
+    nothing on the leaf path."""
+    form, _ = exchange_form(axes, reducer)
+    if form == "leaves":
+        return leaf_allreduce(tree, axes, average=average)
+    return bucketed_allreduce(tree, axes, partition_bytes=partition_bytes,
+                              average=average, reducer=reducer)
 
 
 # ---------------------------------------------------------------------------

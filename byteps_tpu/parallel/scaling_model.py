@@ -15,9 +15,12 @@ on besides link speed:
    collectives. ``verify_dp_schedule`` then asserts the invariants the
    analytic model (and the performance story) relies on:
 
-   - exactly ONE reduction collective per gradient bucket — a
-     regression that splits buckets into per-leaf collectives, or
-     serializes an extra hop, fails the pinned counts;
+   - on an ICI-only mesh with the default reducer (the leaf form,
+     ``collectives.leaf_allreduce``): nothing but all-reduces over the
+     data axes, every gradient leaf reduced exactly once in its own
+     size — a regression that re-packs the gradients, drops a leaf or
+     adds a hop of another kind fails; wherever buckets run (a custom
+     reducer, a dcn mesh) exactly ONE reduction collective per bucket;
    - on hybrid ``dcn × ici`` meshes, the hierarchical schedule of
      ``psum_reducer``: per bucket one in-slice reduce_scatter, one
      cross-slice all_reduce over the 1/ici shard, one in-slice
@@ -208,13 +211,15 @@ def lower_flagship_step(n_devices: int, dcn: int = 1, cfg=None,
     ``distributed_optimizer``-wrapped optax inside a ``shard_map`` —
     but from ``ShapeDtypeStruct``s, so no arrays, devices, or compiles
     are involved. Returns ``(lowered, info)`` where ``info`` has the
-    bucket plan and gradient byte totals the invariant checks need.
+    exchange's form (``collectives.exchange_form``), the buckets it ran
+    (none on the leaf form), the leaves' sizes and the gradient byte
+    totals the invariant checks need.
     """
     import optax
     from ..common.partition import plan_buckets
     from ..models import bert, transformer
     from ..optim import distributed_optimizer
-    from .collectives import leaf_specs_of_tree
+    from .collectives import exchange_form, leaf_specs_of_tree
 
     if cfg is None:
         cfg = bert.bert_large(max_seq=seq)
@@ -261,8 +266,12 @@ def lower_flagship_step(n_devices: int, dcn: int = 1, cfg=None,
     buckets = plan_buckets(specs, partition_bytes, reverse_order=True)
     grad_bytes = sum(sp.size * np.dtype(sp.dtype).itemsize
                      for sp in specs)
-    info = {"n_buckets": len(buckets), "grad_bytes": grad_bytes,
-            "axes": axes, "ici": n_devices // max(dcn, 1), "dcn": dcn}
+    form, _ = exchange_form(axes, **kw)
+    # the plan that RAN: the leaf form packs no bucket
+    info = {"n_buckets": len(buckets) if form == "buckets" else 0,
+            "grad_bytes": grad_bytes,
+            "axes": axes, "ici": n_devices // max(dcn, 1), "dcn": dcn,
+            "form": form, "leaf_elems": [sp.size for sp in specs]}
     return lowered, info
 
 
@@ -459,9 +468,11 @@ def verify_dp_schedule(schedule: Sequence[Collective], info: Dict,
                        small_bytes: int = 4096) -> Dict[str, int]:
     """Assert the collective schedule of a lowered DP step.
 
-    Pins, per the module docstring: one reduction collective per bucket,
-    hierarchical rs/ar/ag shape on hybrid meshes, no full-size bulk
-    collective across the dcn tier, and gradient byte totals. Raises
+    Pins, per the module docstring: on the leaf form every gradient leaf
+    in exactly one all-reduce and no collective of another kind, where
+    buckets run one reduction collective per bucket, hierarchical
+    rs/ar/ag shape on hybrid meshes, no full-size bulk collective across
+    the dcn tier, and gradient byte totals. Raises
     ``AssertionError`` with a diagnostic on any violation; returns
     summary counts on success."""
     n_buckets = info["n_buckets"]
@@ -470,16 +481,38 @@ def verify_dp_schedule(schedule: Sequence[Collective], info: Dict,
     small = [c for c in schedule if c.operand_bytes <= small_bytes]
 
     if dcn <= 1:
-        ars = [c for c in bulk if c.kind == "all_reduce"]
-        assert len(ars) == n_buckets, (
-            f"expected exactly one all_reduce per bucket "
-            f"({n_buckets}), lowered program has {len(ars)}: a "
-            f"regression de-bucketed or serialized the exchange\n"
-            f"{bulk}")
+        # ICI-only, either form: nothing bulk but all-reduces, every
+        # all-reduce over the whole data axis
         assert not [c for c in bulk if c.kind != "all_reduce"], bulk
+        ars = [c for c in schedule if c.kind == "all_reduce"]
         for c in ars:
             assert c.group_size == ici * dcn, c
-        reduced = sum(c.operand_bytes for c in ars)
+    if dcn <= 1 and info.get("form") == "leaves":
+        # the leaf form (collectives.leaf_allreduce): every gradient leaf
+        # is reduced as it is. What matters of the old one-per-bucket pin
+        # stays: each leaf exactly once, together exactly the gradient
+        # bytes. Matched as multisets of element counts; what is left
+        # over must be small (the loss's mean).
+        left = sorted(info["leaf_elems"])
+        reduced = 0
+        for c in sorted(ars, key=lambda c: -c.operand_elems):
+            if c.operand_elems in left:
+                left.remove(c.operand_elems)
+                reduced += c.operand_bytes
+            else:
+                assert c.operand_bytes <= small_bytes, (
+                    "a bulk all_reduce that is no gradient leaf: the "
+                    "exchange re-packed or reduced something twice", c)
+        assert not left, (
+            f"gradient leaves of {left} elements reach no all_reduce: "
+            f"the exchange dropped or re-packed them")
+    elif dcn <= 1:
+        assert len(bulk) == n_buckets, (
+            f"expected exactly one all_reduce per bucket "
+            f"({n_buckets}), lowered program has {len(bulk)}: a "
+            f"regression de-bucketed or serialized the exchange\n"
+            f"{bulk}")
+        reduced = sum(c.operand_bytes for c in bulk)
     else:
         rs = [c for c in bulk if c.kind == "reduce_scatter"]
         ar = [c for c in bulk if c.kind == "all_reduce"]
@@ -557,10 +590,12 @@ def model_step_time(schedule: Sequence[Collective], compute_s: float,
 
     ``no_overlap``: compute then serial comm (pessimal). ``overlap``:
     XLA's latency-hiding scheduler hides comm under backward compute —
-    comm only shows once it exceeds the compute window (what the
-    per-bucket independent reduces are FOR, collectives.py docstring).
-    Reality lands between; the reference's measured 90% @ 256 sits at
-    the overlap end."""
+    comm only shows once it exceeds the compute window. Reality lands
+    between; the reference's measured 90% @ 256 sits at the overlap
+    end. On the v5e at dp=4 today's step sits at the no-overlap end:
+    under ``lax.scan`` every gradient is ready at once and the
+    all-reduces run exposed after the backward (PERF.md section 5,
+    ROADMAP A3(b))."""
     t_comm = sum(comm.time(c) for c in schedule
                  if c.operand_bytes > small_bytes)
     return {

@@ -5,11 +5,13 @@ mxnet/__init__.py:164-345) owns the optimizer, rescales gradients by
 batch-size×world-size, push_pulls every parameter, and steps locally. The
 TPU-native analogue owns the whole jitted train step: it shard_maps the
 user's loss over the mesh (batch split on the data axes, params
-replicated), computes per-replica grads, runs the bucketed allreduce via
-``distributed_optimizer``, and applies updates identically on every
-replica. One compiled XLA program per step — XLA's latency-hiding
-scheduler overlaps bucket collectives with backward compute, which is the
-whole point of the reference's pipeline.
+replicated), computes per-replica grads, reduces them via
+``distributed_optimizer`` (each leaf as it is on an ICI mesh, flat
+buckets where a reducer needs a flat buffer:
+``parallel.collectives.exchange_form``), and applies updates identically
+on every replica. One compiled XLA program per step. The all-reduces run
+after the backward, exposed (23 ms of BERT-large's step at dp=4 on the
+v5e, PERF.md section 5): hiding them behind it is ROADMAP A3(b).
 """
 
 from __future__ import annotations
@@ -27,9 +29,18 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from .common.global_state import GlobalState
 from .obs.metrics import observe_stage
 from .optim import distributed_optimizer
-from .parallel.collectives import Reducer, psum_reducer
+from .parallel.collectives import Reducer, exchange_form, psum_reducer
 from .parallel.mesh import data_axes, make_mesh
 from .parallel.sharding import spec_axes as _spec_axes
+
+
+def _log_exchange_form(axes, reducer, compression) -> None:
+    """One line a trainer, beside GlobalState's ``BPS init``: which form
+    the jitted step's gradient exchange takes, and why."""
+    from .common.logging import get_logger
+    form, why = exchange_form(axes, reducer, compression)
+    get_logger().info("BPS exchange: form=%s axes=%s (%s)", form,
+                      tuple(axes), why)
 
 
 def _batch_samples(batch) -> Optional[int]:
@@ -51,10 +62,18 @@ class DistributedTrainer:
         construction: the same host value is replicated to every device).
       tx: inner optax transformation (e.g. ``optax.adamw(1e-3)``).
       mesh: device mesh; defaults to the global one from ``bps.init()``.
+      partition_bytes: bucket size (default ``BPS_PARTITION_BYTES``, 4 MB)
+        wherever the exchange runs in buckets: the PS path, a ``dcn``
+        mesh, a custom ``reducer``, ``compression``. On the default path
+        (ICI-only mesh, ``psum_reducer``) each gradient leaf is reduced as
+        it is and the value changes nothing; it is accepted all the same.
+        The constructor logs which form was taken (``BPS exchange:``).
       backward_passes_per_step: local gradient accumulation (reference:
         torch/__init__.py:83-113).
-      reducer: collective strategy — plain psum by default, a compressing
-        reducer from byteps_tpu.ops.compression otherwise.
+      reducer: collective strategy over ``(flat bucket, axes)`` — plain
+        psum by default (and then, on an ICI-only mesh, no bucket is
+        built), a compressing reducer from byteps_tpu.ops.compression
+        otherwise.
       name: stable tensor-declaration name for the PS exchange; defaults
         to a hash of the parameter tree's structure+shapes+dtypes (stable
         across restarts, unlike a bare creation counter). When several
@@ -329,11 +348,12 @@ class DistributedTrainer:
                           "backward_passes_per_step>1 (the sharded "
                           "tail is the chunked tail)")
             return
-        # Size-1 data axes reduce to identity psums; dropping them skips the
-        # whole bucket pack/unpack (pure HBM overhead on a single chip).
+        # Size-1 data axes reduce to identity psums; dropping them leaves
+        # the exchange nothing to do on a single chip (the gradients pass
+        # through, whatever form the axes' names would have chosen).
         # Lossy paths keep them — compression and custom reducers must see
         # the gradient even at world 1 (reference: BYTEPS_FORCE_DISTRIBUTED
-        # tests run 1-worker compressed).
+        # tests run 1-worker compressed) — and so keep their buckets.
         lossless = compression is None and reducer is psum_reducer
         comm_axes = (tuple(a for a in self.axes if mesh.shape[a] > 1)
                      if lossless else self.axes)
@@ -348,6 +368,7 @@ class DistributedTrainer:
                                         min_compress_bytes=min_compress_bytes,
                                         compression_state_world=mesh.size,
                                         compression_reduce_world=reduce_world)
+        _log_exchange_form(comm_axes, reducer, compression)
         replicated = NamedSharding(mesh, P())
         # Copy (not alias) into the trainer: the step donates its param
         # buffers, and device_put aliases when the sharding already matches —
@@ -1122,8 +1143,9 @@ class ShardedTrainer:
     synchronization is derived from the param spec: a gradient must be
     summed over every mesh axis its computation was sharded on *except*
     the axes that shard the leaf itself (those grads are owned per-shard).
-    The data-axis allreduce then runs through the bucketed
-    distributed_optimizer like the pure-DP path.
+    The data-axis allreduce then runs through ``distributed_optimizer``
+    like the pure-DP path: per-shard leaves as they are on an ICI mesh,
+    flat buckets across ``dcn`` or under compression.
 
       - params sharded per ``param_specs`` (TP axes inside the spec)
       - batch sharded over (data..., seq) with leading batch dim on data
@@ -1170,6 +1192,7 @@ class ShardedTrainer:
             compression_leaf_specs=comp_specs,
             compression_state_world=mesh.size,
             compression_reduce_world=reduce_world)
+        _log_exchange_form(comm_axes, psum_reducer, compression)
         self.pspec = param_spec_tree
         self.ospec = opt_state_specs(
             self.tx, params, param_spec_tree,

@@ -320,8 +320,9 @@ def phase_ps(train_loss1: float) -> None:
 
 def phase_dp(chips: int) -> None:
     """Data parallel across the chips of one host: the trainer's
-    bucketed push_pull on ICI against one plain jitted step on the same
-    mesh (per-shard grads, one tree pmean — XLA's own all-reduce)."""
+    exchange on ICI (one psum a gradient leaf, the default path) against
+    one plain jitted step on the same mesh (per-shard grads, one tree
+    pmean — XLA's own all-reduce)."""
     import jax
     import optax
     from jax.sharding import NamedSharding, PartitionSpec as P

@@ -187,3 +187,222 @@ def test_scheduling_credit_bounds_inflight():
         jax.block_until_ready = restore
     eng.synchronize(h)
     bps.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# The in-jit exchange's two forms (ISSUE 37): the leaves as they are on an
+# ICI-only mesh with the default reducer, flat buckets where a reducer
+# needs a flat buffer.
+# ---------------------------------------------------------------------------
+
+def _grad_tree(rng):
+    """Per-rank gradients [DP, ...]: a stacked [L, a, b] leaf, a bfloat16
+    leaf, a scalar, a bias."""
+    return {
+        "stack": rng.randn(DP, 3, 8, 16).astype(np.float32),
+        "half": rng.randn(DP, 33).astype(jnp.bfloat16),
+        "scalar": rng.randn(DP).astype(np.float32),
+        "bias": rng.randn(DP, 7).astype(np.float32),
+    }
+
+
+@pytest.mark.parametrize("average", [True, False], ids=["mean", "sum"])
+@pytest.mark.parametrize("empty", [False, True], ids=["tree", "empty_tree"])
+def test_leaf_form_equals_bucketed_form_and_the_per_rank_mean(
+        mesh8, average, empty):
+    from byteps_tpu.parallel.collectives import leaf_allreduce, tree_allreduce
+    tree = {} if empty else _grad_tree(np.random.RandomState(3))
+
+    def run(fn):
+        def step(t):
+            # a rank's row of every leaf, reduced, and handed back a row
+            local = jax.tree_util.tree_map(lambda x: x[0], t)
+            out = fn(local)
+            return jax.tree_util.tree_map(lambda x: x[None], out)
+        return jax.jit(jax.shard_map(step, mesh=mesh8, in_specs=P("data"),
+                                     out_specs=P("data"), check_vma=False))(
+            {k: stacked(mesh8, v) for k, v in tree.items()})
+
+    leaf = run(lambda t: leaf_allreduce(t, ("data",), average=average))
+    auto = run(lambda t: tree_allreduce(t, ("data",), partition_bytes=256,
+                                        average=average))
+    bucket = run(lambda t: bucketed_allreduce(
+        t, ("data",), partition_bytes=256, average=average))
+    assert set(leaf) == set(tree) == set(auto) == set(bucket)
+    for k, x in tree.items():
+        want = np.asarray(x, np.float64).sum(0) / (DP if average else 1)
+        tol = 1e-5 if x.dtype == np.float32 else 5e-2
+        for got in (leaf[k], auto[k], bucket[k]):
+            assert got.shape == x.shape and got.dtype == x.dtype
+            for r in range(DP):
+                np.testing.assert_allclose(
+                    np.asarray(got, np.float64)[r], want, rtol=tol, atol=tol)
+        # the same float sum over the same ranks, then the same division
+        np.testing.assert_array_equal(np.asarray(leaf[k], np.float64),
+                                      np.asarray(auto[k], np.float64))
+        np.testing.assert_allclose(np.asarray(leaf[k], np.float64),
+                                   np.asarray(bucket[k], np.float64),
+                                   rtol=tol / 10, atol=tol / 10)
+
+
+def _exchange_ops(lowered):
+    """(op name, location, operand types, enclosing op names) of every op
+    of a lowering that lies under the scope ``bps.exchange``."""
+    out = []
+
+    def walk(op, parents):
+        for region in op.regions:
+            for block in region.blocks:
+                for o in block.operations:
+                    name = o.operation.name
+                    loc = str(o.location)
+                    if "bps.exchange" in loc:
+                        out.append((name, loc,
+                                    [str(x.type) for x in o.operands],
+                                    parents))
+                    walk(o, parents + (name,))
+
+    walk(lowered.compiler_ir().operation, ())
+    return out
+
+
+_PACKING = ("stablehlo.concatenate", "stablehlo.dynamic_slice",
+            "stablehlo.dynamic_update_slice")
+
+
+def _tiny_trainer(mesh, **kw):
+    from byteps_tpu.models import bert, transformer
+    from byteps_tpu.training import DistributedTrainer
+    import optax
+    cfg = bert.bert_tiny()
+    loss = lambda p, b: bert.mlm_loss(p, cfg, b, max_predictions=8)  # noqa: E731
+    params = transformer.init_params(jax.random.PRNGKey(0), cfg)
+    trainer = DistributedTrainer(loss, params, optax.adamw(1e-3), mesh=mesh,
+                                 **kw)
+    batch = bert.synth_mlm_batch(np.random.RandomState(0), 16, 32, 128)
+    return trainer, batch
+
+
+def _lower(trainer, batch):
+    return trainer._step_fn.lower(trainer.params, trainer.opt_state, batch)
+
+
+def test_ici_step_reduces_the_leaves_as_they_are(mesh8):
+    """No ravel, slice, concatenate or update-slice of a gradient under
+    ``bps.exchange``, and every all-reduce operand has a leaf's shape."""
+    trainer, batch = _tiny_trainer(mesh8, partition_bytes=1 << 14)
+    ops = _exchange_ops(_lower(trainer, batch))
+    names = {name for name, _, _, _ in ops}
+    assert "stablehlo.all_reduce" in names
+    assert not names & set(_PACKING), names
+    assert "stablehlo.reshape" not in names
+    leaves = jax.tree_util.tree_leaves(trainer.params)
+    want = sorted("tensor<" + "x".join(map(str, l.shape + ("f32",))) + ">"
+                  for l in leaves)
+    got = sorted(t for name, _, types, _ in ops
+                 if name == "stablehlo.all_reduce" for t in types)
+    assert got == want
+    assert all("bps.exchange.reduce" in loc for name, loc, _, _ in ops
+               if name == "stablehlo.all_reduce")
+
+
+def _flat_psum(x, axes):
+    return jax.lax.psum(x, axes)
+
+
+@pytest.mark.parametrize("case", ["custom_reducer", "dcn_mesh",
+                                  "compression"])
+def test_buckets_stay_where_a_reducer_needs_a_flat_buffer(case):
+    from byteps_tpu.parallel.collectives import exchange_form, psum_reducer
+    if case == "custom_reducer":
+        mesh, kw = make_mesh({"data": 8}), {"reducer": _flat_psum}
+        form = exchange_form(("data",), _flat_psum)
+    elif case == "dcn_mesh":
+        mesh, kw = make_mesh({"dcn": 2, "data": 4}), {}
+        form = exchange_form(("dcn", "data"), psum_reducer)
+    else:
+        mesh = make_mesh({"data": 8})
+        kw = {"compression": {"compressor_type": "onebit"},
+              "min_compress_bytes": 0}
+        form = exchange_form(("data",), psum_reducer, kw["compression"])
+    assert form[0] == "buckets"
+    trainer, batch = _tiny_trainer(mesh, partition_bytes=1 << 14, **kw)
+    ops = _exchange_ops(_lower(trainer, batch))
+    names = {name for name, _, _, _ in ops}
+    # the flat buffers: packed by concatenation, written back by slices
+    assert {"stablehlo.concatenate", "stablehlo.dynamic_update_slice"} <= names
+    # and every collective's operand is a flat buffer, no leaf's shape
+    collectives = [types[0] for name, _, types, _ in ops
+                   if name in ("stablehlo.all_reduce",
+                               "stablehlo.reduce_scatter",
+                               "stablehlo.all_gather")]
+    assert collectives
+    if case != "compression":     # whose payloads are packed words
+        assert all(t.count("x") <= 1 for t in collectives), collectives
+    if case == "dcn_mesh":
+        assert {"stablehlo.reduce_scatter", "stablehlo.all_gather"} <= names
+
+
+def test_exchange_form_reads_the_reducer_and_the_axes_alone():
+    from byteps_tpu.parallel.collectives import exchange_form, psum_reducer
+    assert exchange_form(("data",))[0] == "leaves"
+    assert exchange_form(("data", "model"), psum_reducer)[0] == "leaves"
+    assert exchange_form(())[0] == "leaves"
+    assert exchange_form(("dcn", "data"))[0] == "buckets"
+    assert exchange_form(("data",), _flat_psum)[0] == "buckets"
+    assert exchange_form((), _flat_psum)[0] == "buckets"
+    assert exchange_form(("data",), psum_reducer,
+                         {"compressor_type": "onebit"})[0] == "buckets"
+    for axes in (("data",), ("dcn", "data")):
+        assert exchange_form(axes)[1]       # a reason, for the log
+
+
+def test_partition_bytes_changes_nothing_on_the_leaf_path(mesh8):
+    texts = []
+    for pb in (1 << 10, 4 << 20, None):
+        kw = {} if pb is None else {"partition_bytes": pb}
+        trainer, batch = _tiny_trainer(mesh8, **kw)
+        texts.append(_lower(trainer, batch).as_text())
+    assert texts[0] == texts[1] == texts[2]
+
+
+def test_trainer_logs_its_exchange_form_once(mesh8, caplog):
+    import logging
+    from byteps_tpu.common.logging import get_logger
+    logger = get_logger()
+    logger.addHandler(caplog.handler)
+    try:
+        with caplog.at_level(logging.INFO, logger=logger.name):
+            _tiny_trainer(mesh8)
+            _tiny_trainer(mesh8, reducer=_flat_psum)
+    finally:
+        logger.removeHandler(caplog.handler)
+    lines = [r.getMessage() for r in caplog.records
+             if r.getMessage().startswith("BPS exchange:")]
+    assert len(lines) == 2
+    assert "form=leaves" in lines[0] and "ICI" in lines[0]
+    assert "form=buckets" in lines[1] and "custom reducer" in lines[1]
+
+
+def test_accumulation_communicates_every_second_step_only(mesh8):
+    """``backward_passes_per_step=2``: the all-reduces lie under the
+    every-k branch, and a step that only accumulates moves no weight."""
+    trainer, batch = _tiny_trainer(mesh8, backward_passes_per_step=2,
+                                   donate=False)
+    ops = _exchange_ops(_lower(trainer, batch))
+    reduces = [parents for name, _, _, parents in ops
+               if name == "stablehlo.all_reduce"]
+    assert reduces
+    assert all("stablehlo.case" in parents or "stablehlo.if" in parents
+               for parents in reduces)
+    assert not {name for name, _, _, _ in ops} & set(_PACKING)
+    before = jax.tree_util.tree_map(np.asarray, trainer.params)
+    trainer.step(batch)
+    mid = jax.tree_util.tree_map(np.asarray, trainer.params)
+    trainer.step(batch)
+    after = jax.tree_util.tree_map(np.asarray, trainer.params)
+    moved = lambda a, b: max(  # noqa: E731
+        float(np.abs(x - y).max()) for x, y in zip(
+            jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)))
+    assert moved(before, mid) == 0.0
+    assert moved(mid, after) > 0.0

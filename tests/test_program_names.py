@@ -21,9 +21,13 @@ from byteps_tpu.training import DistributedTrainer
 
 # every scope of the table in ISSUE 25 section 1 that a CPU lowering can
 # hold (the kernels' own names need the Mosaic path: see the jaxpr tests)
-SCOPES = ("bps.model", "bps.optimizer", "bps.exchange", "bps.exchange.pack",
-          "bps.exchange.reduce", "bps.exchange.unpack", "bps.embed",
+SCOPES = ("bps.model", "bps.optimizer", "bps.exchange",
+          "bps.exchange.reduce", "bps.embed",
           "bps.attn", "bps.mlp", "bps.head", "bps_attn_xla")
+# the exchange's parts that only a bucketed step opens (a custom reducer,
+# a dcn mesh, compression: ISSUE 37); the default ICI path reduces the
+# leaves as they are, under bps.exchange / bps.exchange.reduce alone
+BUCKET_SCOPES = ("bps.exchange.pack", "bps.exchange.unpack")
 # what the decoder of several kinds of layer adds (ISSUE 29 section 5):
 # the routed feed-forward's parts inside bps.mlp
 MOE_SCOPES = ("bps.moe", "bps.moe.route", "bps.moe.experts",
@@ -34,7 +38,20 @@ SSM_SCOPES = ("bps.ssm", "bps.ssm.proj", "bps.ssm.conv", "bps.ssm.scan",
               "bps.ssm.norm")
 
 
+def _flat_psum(x, axes):
+    return jax.lax.psum(x, axes)
+
+
 def _trainer(model: str, mesh):
+    if model == "bert_tiny_buckets":
+        # a custom reducer takes (flat buffer, axes): the bucketed form
+        cfg = bert.bert_tiny()
+        loss = lambda p, b: bert.mlm_loss(p, cfg, b, max_predictions=8)  # noqa: E731
+        make = lambda rng: bert.synth_mlm_batch(rng, 8, 32, 128)  # noqa: E731
+        params = transformer.init_params(jax.random.PRNGKey(0), cfg)
+        return DistributedTrainer(loss, params, optax.adamw(1e-3), mesh=mesh,
+                                  partition_bytes=1 << 16,
+                                  reducer=_flat_psum), make
     if model == "bert_tiny":
         cfg = bert.bert_tiny()
         loss = lambda p, b: bert.mlm_loss(p, cfg, b, max_predictions=8)  # noqa: E731
@@ -62,11 +79,15 @@ def lowered():
     """The step's lowering with its locations, once a model."""
     mesh = make_mesh({"data": 4}, devices=jax.devices()[:4])
     texts = {}
-    for model in ("bert_tiny", "afmoe_tiny", "nemotron_h_tiny", "gpt2_tiny"):
+    for model in ("bert_tiny_buckets", "bert_tiny", "afmoe_tiny",
+                  "nemotron_h_tiny", "gpt2_tiny"):
         trainer, make = _trainer(model, mesh)
         step = trainer._step_fn.lower(trainer.params, trainer.opt_state,
                                       make(np.random.RandomState(0)))
         texts[model] = step.as_text(debug_info=True)
+        if model == "bert_tiny_buckets":
+            texts["bucket_paths"] = set(re.findall(
+                r'op_name="([^"]*)"', step.compile().as_text()))
     # the last model's compiled module: its op_name metadata holds the
     # whole scope path of an instruction, as a trace of the chip does
     texts["paths"] = set(re.findall(r'op_name="([^"]*)"',
@@ -84,6 +105,18 @@ def test_lowered_step_holds_the_scope(lowered, model, scope):
     assert scope in names
 
 
+@pytest.mark.parametrize("model,scope,there", [
+    ("bert_tiny_buckets", scope, True)
+    for scope in ("bps.exchange", "bps.exchange.reduce") + BUCKET_SCOPES] + [
+    (model, scope, False) for model in (
+        "bert_tiny", "gpt2_tiny", "afmoe_tiny", "nemotron_h_tiny")
+    for scope in BUCKET_SCOPES])
+def test_exchange_scopes_follow_its_form(lowered, model, scope, there):
+    """``.pack`` / ``.unpack`` where buckets run, and only there."""
+    names = set(re.findall(r"bps[._][A-Za-z_.]+", lowered[model]))
+    assert (scope in names) == there
+
+
 def test_scopes_nest_as_the_phases_do(lowered):
     paths = lowered["paths"]
 
@@ -95,7 +128,10 @@ def test_scopes_nest_as_the_phases_do(lowered):
     assert some(r"bps\.model/jvp\(\)/.*bps\.attn")
     assert some(r"bps\.model/transpose\(.*bps\.mlp")
     assert some(r"bps\.model/.*jvp\(bps\.head\)")
-    assert some(r"bps\.exchange/bps\.exchange\.pack")
+    assert some(r"bps\.exchange/bps\.exchange\.reduce")
+    assert not some(r"bps\.exchange\.(pack|unpack)")
+    assert any(re.search(r"bps\.exchange/bps\.exchange\.pack", p)
+               for p in lowered["bucket_paths"])
     assert some(r"shard_map/bps\.optimizer/")
     assert not some(r"bps\.optimizer/.*bps\.exchange")
     assert not some(r"bps\.model/.*bps\.optimizer")

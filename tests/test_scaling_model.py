@@ -5,7 +5,8 @@ The multi-chip north star (BASELINE.md: ≥90% scaling efficiency
 these tests pin what the curve depends on that IS checkable without
 hardware: the compiled data-parallel step's communication structure,
 AOT-lowered over an AbstractMesh (see parallel/scaling_model.py
-docstring). A regression that de-buckets, serializes an extra hop, or
+docstring). A regression that drops or re-packs a gradient leaf on the
+ICI path, de-buckets where buckets run, serializes an extra hop, or
 ships full-size buckets across the dcn tier fails here.
 """
 
@@ -29,14 +30,53 @@ def _lower(n, dcn=1, **kw):
                                partition_bytes=PB, **kw)
 
 
-def test_ici_only_one_allreduce_per_bucket():
-    lowered, info = _lower(8)
+def _flat_psum(x, axes):
+    return jax.lax.psum(x, axes)
+
+
+@pytest.mark.parametrize("form", ["leaves", "buckets"])
+def test_ici_only_one_allreduce_per_bucket(form):
+    """ICI-only: nothing but all-reduces over the data axes, together
+    exactly the gradient bytes. On the default path each gradient leaf
+    once, in its own size (no bucket is packed); under a custom reducer,
+    whose contract is a flat buffer, one a bucket as before."""
+    kw = {} if form == "leaves" else {"reducer": _flat_psum}
+    lowered, info = _lower(8, **kw)
+    assert info["form"] == form
     sched = collective_schedule(lowered, 8)
     counts = verify_dp_schedule(sched, info)
-    assert info["n_buckets"] > 1, "config must exercise multi-bucket"
-    assert counts["bulk"] == info["n_buckets"]
+    assert {c.kind for c in sched} == {"all_reduce"}
+    assert all(c.group_size == 8 for c in sched)
+    if form == "buckets":
+        assert info["n_buckets"] > 1, "config must exercise multi-bucket"
+        assert counts["bulk"] == info["n_buckets"]
+    else:
+        assert info["n_buckets"] == 0
+        bulk = sorted(c.operand_elems for c in sched
+                      if c.operand_bytes > 4096)
+        assert bulk == sorted(n for n in info["leaf_elems"] if n > 1024)
+        assert counts["bulk"] == len(bulk) > 1
     # byte volume: collectives carry exactly the gradient bytes
     assert counts["reduced_bytes"] == info["grad_bytes"]
+
+
+@pytest.mark.parametrize("fault", ["dropped_leaf", "repacked"])
+def test_leaf_form_regressions_fail_the_ici_invariants(fault):
+    """What the one-per-bucket pin was for, on the form that has no
+    bucket: a leaf that reaches no all-reduce, or gradients re-packed
+    into buffers that are no leaf, must FAIL verification."""
+    import dataclasses
+    lowered, info = _lower(8)
+    sched = collective_schedule(lowered, 8)
+    big = max(sched, key=lambda c: c.operand_elems)
+    if fault == "dropped_leaf":
+        bad = [c for c in sched if c is not big]
+    else:
+        half = dataclasses.replace(big, operand_elems=big.operand_elems // 2,
+                                   result_elems=big.result_elems // 2)
+        bad = [c for c in sched if c is not big] + [half, half]
+    with pytest.raises(AssertionError):
+        verify_dp_schedule(bad, info)
 
 
 def test_hybrid_mesh_hierarchical_schedule():
