@@ -91,6 +91,9 @@ class GlobalState:
         # so every transport client sees the same gate
         from ..server.admission import configure_send
         configure_send(config.scheduling_credit)
+        # the newest trainer's record of its own start
+        # (common/setup_record.py), for a reader that is handed no trainer
+        self.setup_record = None
         self.stats = None
         if config.stats_on:
             from ..obs.stats import StepStatsEmitter

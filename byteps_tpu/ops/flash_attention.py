@@ -39,6 +39,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..common.setup_record import note_choice
+
 _NEG_INF = -1e30
 # what the kernels' backward reads of their forward, by the names a
 # ``jax.checkpoint`` policy keeps them under (``_fwd_rule``): the output
@@ -1315,9 +1317,6 @@ def local_attention(q, k, v, causal: bool = False,
     return out.reshape(b, s, h, d).astype(q.dtype)
 
 
-_warned_fallback = set()
-
-
 def attention(q, k, v, causal=False, scale=None, impl="auto", bias=None,
               rel_table=None, rel_bidirectional=True,
               rel_max_distance=128, window=None):
@@ -1350,21 +1349,18 @@ def attention(q, k, v, causal=False, scale=None, impl="auto", bias=None,
             return local_attention(q, k, v, causal=causal, scale=scale,
                                    bias=b, window=window)
 
-    if impl == "naive":
-        return _naive()
     on_tpu = jax.default_backend() == "tpu"
-    if impl == "flash" or (on_tpu and supported(q.shape, k.shape)):
+    flash = impl == "flash" or (impl == "auto" and on_tpu
+                                and supported(q.shape, k.shape))
+    # a silent fall-through here once cost 28x at seq 8k (an s-1 shift
+    # broke seq % 128): the record makes the downgrade loud, once a shape
+    note_choice("attention", "flash" if flash else "xla", tuple(q.shape),
+                "naive O(s^2): flash needs seq % 128 == 0 and head_dim <= 256",
+                asked=impl)
+    if flash:
         return flash_attention(q, k, v, causal=causal, scale=scale,
                                bias=bias, rel_table=rel_table,
                                rel_bidirectional=rel_bidirectional,
                                rel_max_distance=rel_max_distance,
                                window=window)
-    if on_tpu and tuple(q.shape) not in _warned_fallback:
-        # a silent fall-through here once cost 28x at seq 8k (an s-1 shift
-        # broke seq % 128) — make the downgrade loud, once per shape
-        _warned_fallback.add(tuple(q.shape))
-        from ..common.logging import get_logger
-        get_logger().warning(
-            "attention %s falls back to naive O(s^2) on TPU (flash needs "
-            "seq %% 128 == 0 and head_dim <= 256)", tuple(q.shape))
     return _naive()

@@ -47,6 +47,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..common.setup_record import note_choice
 from .flash_attention import _pick_block as _pick
 
 HALF_LANES = 64     # a width is whole lane tiles, or ends in half a one
@@ -225,9 +226,15 @@ def grouped_matmul(lhs, w, tile_group, num_tiles, group_rows, tile: int,
     "ragged" (``lax.ragged_dot``)."""
     if impl not in ("auto", "gmm", "gmm_interpret", "ragged"):
         raise ValueError(f"grouped_matmul impl {impl!r}")
+    asked = impl
     if impl == "auto":
         impl = ("gmm" if jax.default_backend() == "tpu"
                 and supported(lhs.shape, w.shape, tile) else "ragged")
+    note_choice("grouped_matmul", "ragged" if impl == "ragged" else "gmm",
+                (tuple(lhs.shape), tuple(w.shape), tile),
+                "lax.ragged_dot: the kernels need widths in whole or half "
+                "lane tiles and rows in whole row tiles of a multiple of 128",
+                asked=asked)
     if impl == "ragged":
         return jax.lax.ragged_dot(lhs, w, group_rows,
                                   preferred_element_type=lhs.dtype)

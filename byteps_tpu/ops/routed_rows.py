@@ -49,6 +49,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..common.setup_record import note_choice
 from .grouped_matmul import HALF_LANES
 
 _LANES = 128
@@ -82,13 +83,21 @@ def resolve(impl: str, tokens: int, hidden: int, width: int, held: int,
     tiles or ending in a half one) and ``tile`` in whole 128s. The
     compiled kernels move a row as whole (8, 128) tiles (``_take`` pads a
     row that is none); the interpreter does not care."""
+    asked = impl
     if impl == "auto":
         impl = "gmm" if jax.default_backend() == "tpu" else "ragged"
     tt = _token_tile(tokens)
     fits = (hidden % _LANES == 0 and width % HALF_LANES == 0
             and tile % _LANES == 0 and tt <= _TOKEN_TILE and tt % 8 == 0
             and held * _WINDOW <= _MAX_SPAN)
-    return impl if fits else "ragged"
+    impl = impl if fits else "ragged"
+    note_choice("routed_rows", "ragged" if impl == "ragged" else "gmm",
+                (tokens, hidden, width, held, tile),
+                "XLA's gathers: the kernels need a hidden size in whole lane "
+                "tiles, the experts' width as the grouped kernels take it and "
+                "tokens in whole tiles of 512 or one tile of whole 8s",
+                asked=asked)
+    return impl
 
 
 def take_xla(x, index):

@@ -66,6 +66,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..common.setup_record import note_choice
+
 CHUNK = 128
 LANES = 128
 
@@ -548,9 +550,6 @@ def supported(x_shape, b_shape, chunk: int = CHUNK) -> bool:
             and per % (LANES // p) == 0 and 2 * per <= LANES)
 
 
-_warned_fallback = set()
-
-
 def ssd(x, dt, a, b, c, d, chunk: int = CHUNK):
     """``y`` [batch, s, heads, p] of the recurrence above.
 
@@ -561,19 +560,17 @@ def ssd(x, dt, a, b, c, d, chunk: int = CHUNK):
     is zero. The kernels on the TPU where ``supported``, ``ssd_xla``
     elsewhere."""
     _sizes(x, b, chunk)
-    on_tpu = jax.default_backend() == "tpu"
-    if on_tpu and supported(x.shape, b.shape, chunk):
+    kernels = (jax.default_backend() == "tpu"
+               and supported(x.shape, b.shape, chunk))
+    note_choice("ssd", "kernels" if kernels else "xla",
+                (tuple(x.shape), tuple(b.shape), chunk),
+                "XLA products: the kernels need a head width that divides 128 "
+                "with a group's heads in whole lane tiles, and a state size "
+                "and chunk of whole lane tiles")
+    if kernels:
         f32 = jnp.float32
         return ssd_kernels(x, dt.astype(f32), a.astype(f32), b, c,
                            d.astype(f32), chunk, False)
-    shapes = (tuple(x.shape), tuple(b.shape), chunk)
-    if on_tpu and shapes not in _warned_fallback:
-        _warned_fallback.add(shapes)
-        from ..common.logging import get_logger
-        get_logger().warning(
-            "ssd %s falls back to XLA products on TPU (the kernels need a "
-            "head width that divides 128 with a group's heads in whole lane "
-            "tiles, and a state size and chunk of whole lane tiles)", shapes)
     # named so that a fall-back from the kernels shows in a trace
     with jax.named_scope("bps_ssd_xla"):
         return ssd_xla(x, dt, a, b, c, d, chunk)
@@ -592,6 +589,7 @@ def ssd_packed(xbc, dt, a, d, groups: int, n: int, chunk: int = CHUNK):
     x_shape, b_shape = (bsz, s, heads, inner // heads), (bsz, s, groups, n)
     if (jax.default_backend() == "tpu" and inner % n == 0
             and supported(x_shape, b_shape, chunk)):
+        note_choice("ssd", "kernels_packed", (x_shape, b_shape, chunk))
         f32 = jnp.float32
         return ssd_kernels_packed(xbc, dt.astype(f32), a.astype(f32),
                                   d.astype(f32), groups, n, chunk, False)

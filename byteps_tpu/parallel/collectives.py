@@ -52,6 +52,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..common.partition import Bucket, LeafSpec, plan_buckets
 from ..common.naming import NameRegistry
+from ..common.setup_record import note_choice
 from .mesh import data_axes, dp_size
 
 Reducer = Callable[[jnp.ndarray, Tuple[str, ...]], jnp.ndarray]
@@ -205,6 +206,7 @@ def tree_allreduce(tree, axes: Sequence[str], partition_bytes: int = 4 << 20,
     ``partition_bytes`` sizes the buckets where there are buckets and means
     nothing on the leaf path."""
     form, _ = exchange_form(axes, reducer)
+    note_choice("exchange", form, tuple(axes))
     if form == "leaves":
         return leaf_allreduce(tree, axes, average=average)
     return bucketed_allreduce(tree, axes, partition_bytes=partition_bytes,

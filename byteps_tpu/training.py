@@ -16,6 +16,7 @@ v5e, PERF.md section 5): hiding them behind it is ROADMAP A3(b).
 
 from __future__ import annotations
 
+import functools
 import os
 import time
 from typing import Callable, Optional
@@ -26,6 +27,7 @@ import numpy as np
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .common import setup_record as _record
 from .common.global_state import GlobalState
 from .obs.metrics import observe_stage
 from .optim import distributed_optimizer
@@ -41,6 +43,42 @@ def _log_exchange_form(axes, reducer, compression) -> None:
     form, why = exchange_form(axes, reducer, compression)
     get_logger().info("BPS exchange: form=%s axes=%s (%s)", form,
                       tuple(axes), why)
+
+
+def _recorded(init):
+    """Decorator of a trainer's ``__init__``: its set-up record
+    (``common/setup_record.py``) opens on entry, ``bps.setup.init`` spans
+    the constructor, and the first ``step`` call goes through an
+    instance-bound wrapper that spans it (``bps.setup.first_step``),
+    closes the record and removes itself: from the second call on
+    ``step`` is the class's own, and nothing of the record runs in it."""
+    @functools.wraps(init)
+    def __init__(self, *args, **kwargs):
+        rec = self._setup = _record.open_record()
+        try:
+            with _record.span(rec, "bps.setup.init"):
+                init(self, *args, **kwargs)
+        except BaseException:
+            _record.close(rec)
+            raise
+        rec["step_funs"] = tuple(
+            getattr(self, a).__name__
+            for a in ("_step_fn", "_grad_fn", "_apply_fn") if hasattr(self, a))
+
+        def first_step(batch):
+            if rec["closed"]:           # a caller kept the bound wrapper
+                return type(self).step(self, batch)
+            _record.open_record(rec)    # another trainer may have opened its
+            try:
+                with _record.span(rec, "bps.setup.first_step",
+                                  step_num=self.step_count):
+                    return type(self).step(self, batch)
+            finally:
+                _record.close(rec)
+                self.__dict__.pop("step", None)
+
+        self.step = first_step
+    return __init__
 
 
 def _batch_samples(batch) -> Optional[int]:
@@ -130,6 +168,7 @@ class DistributedTrainer:
             f"{getattr(l, 'dtype', type(l).__name__)}" for l in leaves)
         return "trainer-" + hashlib.sha1(sig.encode()).hexdigest()[:10]
 
+    @_recorded
     def __init__(self, loss_fn: Callable, params, tx: optax.GradientTransformation,
                  mesh: Optional[Mesh] = None, partition_bytes: Optional[int] = None,
                  backward_passes_per_step: int = 1,
@@ -214,15 +253,13 @@ class DistributedTrainer:
                     "server.cc:310-314) — drop BPS_ENABLE_ASYNC or the "
                     "compression kwargs")
             self.tx = tx
-            replicated = NamedSharding(mesh, P())
-            self.params = jax.tree_util.tree_map(
-                lambda x: jax.device_put(jnp.array(x), replicated), params)
+            self._place_params(params)
             self._ostate_spec = P()
-            from .parallel.sharding import init_sharded_state
-            self.opt_state = init_sharded_state(self.tx, self.params,
-                                                self._ostate_spec, mesh)
+            self._init_opt_state()
             self._loss_fn = loss_fn
-            self._grad_fn, self._apply_fn = self._build_ps_step(donate=False)
+            with _record.span(self._setup, "bps.setup.build_step"):
+                self._grad_fn, self._apply_fn = self._build_ps_step(
+                    donate=False)
             from .server.ps_mode import AsyncPSWorker
             # server-side init is idempotent (first init allocates, later
             # inits are no-ops — NOT a rendezvous), so every worker seeds
@@ -309,16 +346,13 @@ class DistributedTrainer:
             self._h2d_ex = None         # lazy single-thread H2D dispatcher
             self._opt_state_at_init = None   # set below: restore detection
             self.tx = tx          # plain inner optimizer: sync is the hop
-            replicated = NamedSharding(mesh, P())
-            self.params = jax.tree_util.tree_map(
-                lambda x: jax.device_put(jnp.array(x), replicated), params)
+            self._place_params(params)
             self._ostate_spec = P()
-            from .parallel.sharding import init_sharded_state
-            self.opt_state = init_sharded_state(self.tx, self.params,
-                                                self._ostate_spec, mesh)
+            self._init_opt_state()
             self._opt_state_at_init = self.opt_state
             self._loss_fn = loss_fn
-            self._grad_fn, self._apply_fn = self._build_ps_step(donate)
+            with _record.span(self._setup, "bps.setup.build_step"):
+                self._grad_fn, self._apply_fn = self._build_ps_step(donate)
             self._accum = None
             self.step_count = 0
             # ZeRO-style sharded weight update (BPS_SHARDED_UPDATE,
@@ -369,12 +403,7 @@ class DistributedTrainer:
                                         compression_state_world=mesh.size,
                                         compression_reduce_world=reduce_world)
         _log_exchange_form(comm_axes, reducer, compression)
-        replicated = NamedSharding(mesh, P())
-        # Copy (not alias) into the trainer: the step donates its param
-        # buffers, and device_put aliases when the sharding already matches —
-        # donation must never invalidate the caller's arrays.
-        self.params = jax.tree_util.tree_map(
-            lambda x: jax.device_put(jnp.array(x), replicated), params)
+        self._place_params(params)
         if compression:
             # compressor state (EF error, momentum) is per-device: leading
             # device axis sharded over the whole mesh (see _make_compressed)
@@ -385,12 +414,35 @@ class DistributedTrainer:
                 comp_axes=tuple(mesh.axis_names))
         else:
             self._ostate_spec = P()
-        from .parallel.sharding import init_sharded_state
-        self.opt_state = init_sharded_state(self.tx, self.params,
-                                            self._ostate_spec, mesh)
+        self._init_opt_state()
         self._loss_fn = loss_fn
-        self._step_fn = self._build_step(donate)
+        with _record.span(self._setup, "bps.setup.build_step"):
+            self._step_fn = self._build_step(donate)
         self.step_count = 0
+
+    def _place_params(self, params) -> None:
+        """A copy of the parameters on the mesh, not an alias: the step
+        donates its param buffers, and device_put aliases when the sharding
+        already matches — donation must never invalidate the caller's
+        arrays."""
+        from .data import _host_bytes
+        replicated = NamedSharding(self.mesh, P())
+        with _record.span(self._setup, "bps.setup.place_params",
+                          bytes=_host_bytes(params)):
+            self.params = jax.tree_util.tree_map(
+                lambda x: jax.device_put(jnp.array(x), replicated), params)
+
+    def _init_opt_state(self) -> None:
+        from .parallel.sharding import init_sharded_state
+        with _record.span(self._setup, "bps.setup.opt_init"):
+            self.opt_state = init_sharded_state(self.tx, self.params,
+                                                self._ostate_spec, self.mesh)
+
+    def setup_record(self) -> dict:
+        """What this trainer's start took, what JAX compiled for it and
+        which kernels its trace chose (``common/setup_record.py``,
+        docs/timeline.md)."""
+        return self._setup
 
     def _build_step(self, donate: bool):
         axes, mesh, loss_fn, tx = self.axes, self.mesh, self._loss_fn, self.tx
@@ -1160,6 +1212,7 @@ class ShardedTrainer:
     one replica's accumulators.
     """
 
+    @_recorded
     def __init__(self, loss_fn: Callable, params, param_spec_tree,
                  tx: optax.GradientTransformation, mesh: Mesh,
                  batch_spec: Optional[P] = None,
@@ -1201,8 +1254,13 @@ class ShardedTrainer:
             seq_ax = "seq" if "seq" in mesh.axis_names else None
             batch_spec = P(self.dp_axes if self.dp_axes else None, seq_ax)
         self.batch_spec = batch_spec
-        self.params = shard_tree(params, self.pspec, mesh)
-        self.opt_state = init_sharded_state(self.tx, params, self.ospec, mesh)
+        from .data import _host_bytes
+        with _record.span(self._setup, "bps.setup.place_params",
+                          bytes=_host_bytes(params)):
+            self.params = shard_tree(params, self.pspec, mesh)
+        with _record.span(self._setup, "bps.setup.opt_init"):
+            self.opt_state = init_sharded_state(self.tx, params, self.ospec,
+                                                mesh)
         loss_axes = tuple(ax for ax in mesh.axis_names
                           if ax in _spec_axes(batch_spec))
 
@@ -1246,9 +1304,12 @@ class ShardedTrainer:
             in_specs=(self.pspec, self.ospec, batch_spec),
             out_specs=(self.pspec, self.ospec, P()),
             check_vma=False)
-        self._step_fn = jax.jit(shard_fn,
-                                donate_argnums=(0, 1) if donate else ())
+        with _record.span(self._setup, "bps.setup.build_step"):
+            self._step_fn = jax.jit(shard_fn,
+                                    donate_argnums=(0, 1) if donate else ())
         self.step_count = 0
+
+    setup_record = DistributedTrainer.setup_record
 
     def shard_batch(self, batch):
         from .data import shard_batch

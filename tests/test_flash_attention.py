@@ -91,7 +91,8 @@ def test_naive_fallback_warns_once_per_shape(monkeypatch):
     from byteps_tpu.common.logging import get_logger
 
     monkeypatch.setattr(fa.jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(fa, "_warned_fallback", set())
+    from byteps_tpu.common import setup_record
+    monkeypatch.setattr(setup_record, "_warned", set())
     records = []
     handler = logging.Handler()
     handler.emit = lambda r: records.append(r.getMessage())
@@ -103,7 +104,8 @@ def test_naive_fallback_warns_once_per_shape(monkeypatch):
         q = jnp.zeros((1, 65, 2, 8), jnp.float32)   # 65 % 128 != 0
         fa.attention(q, q, q)
         fa.attention(q, q, q)                        # same shape: no repeat
-        warns = [m for m in records if "falls back to naive" in m]
+        warns = [m for m in records if "falls back to xla" in m
+                 and "naive O(s^2)" in m]
         assert len(warns) == 1, records
     finally:
         logger.setLevel(prev_level)

@@ -232,6 +232,45 @@ def test_host_spans_land_in_the_profilers_trace(tmp_path):
             if name == "bps.feed.h2d"} == {host_bytes}
 
 
+SETUP_SPANS = ("bps.setup.init", "bps.setup.place_params",
+               "bps.setup.opt_init", "bps.setup.build_step",
+               "bps.setup.first_step")
+
+
+@pytest.fixture(scope="module")
+def setup_events(tmp_path_factory):
+    """A profiler session an operator starts BEFORE building the trainer:
+    the set-up record's spans are host spans of that trace as well."""
+    trace_dir = str(tmp_path_factory.mktemp("setup_trace"))
+    mesh = make_mesh({"data": 2}, devices=jax.devices()[:2])
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+    try:
+        trainer, make = _trainer("bert_tiny", mesh)
+        batch = make(np.random.RandomState(0))
+        trainer.step(batch)
+        jax.block_until_ready(trainer.step(batch))
+    finally:
+        jax.profiler.stop_trace()
+    return _host_events(trace_dir), trainer
+
+
+@pytest.mark.parametrize("name", SETUP_SPANS)
+def test_setup_spans_land_in_the_profilers_trace(setup_events, name):
+    """Once each, with the arguments the record holds; ``first_step``
+    shares ``bps.step``'s identifier and the second step has none."""
+    events, trainer = setup_events
+    stats, = [stats for n, stats in events if n == name]
+    recorded, = [s for s in trainer.setup_record()["spans"]
+                 if s["name"] == name]
+    assert {k: stats[k] for k in recorded["args"]} == recorded["args"]
+    if name == "bps.setup.first_step":
+        assert stats["step_num"] == 0
+        assert [st["step_num"] for n, st in events
+                if n == "bps.step"] == [0, 1]
+
+
 class _CountingTime:
     """``time`` with its clock calls counted."""
 

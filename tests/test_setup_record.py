@@ -1,0 +1,300 @@
+"""The trainer's record of its own start (``common/setup_record.py``,
+``training._recorded``): where it opens and closes, what the one
+``jax.monitoring`` listener joins into it, what a trace's choices leave
+in it, and that nothing of it runs in a step. CPU, tiny sizes; what the
+record reads on the chip is the benchmark's business
+(``benchmark/metrics/trainer.step_*``)."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from byteps_tpu.common import setup_record
+from byteps_tpu.parallel.mesh import make_mesh
+from byteps_tpu.training import DistributedTrainer, ShardedTrainer
+
+SPANS = ("bps.setup.init", "bps.setup.place_params", "bps.setup.opt_init",
+         "bps.setup.build_step", "bps.setup.first_step")
+
+
+def _loss(params, batch):
+    return jnp.mean((batch["x"] @ params["w"] - batch["y"]) ** 2)
+
+
+def _batch(rows=8):
+    rng = np.random.RandomState(0)
+    return {"x": rng.randn(rows, 4).astype(np.float32),
+            "y": rng.randn(rows, 2).astype(np.float32)}
+
+
+def _trainer(kind="distributed"):
+    mesh = make_mesh({"data": 2}, devices=jax.devices()[:2])
+    params = {"w": jnp.ones((4, 2), jnp.float32)}
+    if kind == "sharded":
+        from jax.sharding import PartitionSpec as P
+        return ShardedTrainer(_loss, params, {"w": P()}, optax.sgd(0.1),
+                              mesh, batch_spec=P("data"))
+    return DistributedTrainer(_loss, params, optax.sgd(0.1), mesh=mesh)
+
+
+def _step_entries(rec):
+    return [e for e in rec["compiles"] if e["step"]]
+
+
+@pytest.mark.parametrize("kind", ["distributed", "sharded"])
+def test_the_record_opens_in_the_constructor_and_closes_after_one_step(kind):
+    trainer = _trainer(kind)
+    rec = trainer.setup_record()
+    assert not rec["closed"] and setup_record._current is rec
+    assert [s["name"] for s in rec["spans"]] == list(SPANS[:4])
+    assert "step" in vars(trainer)              # the one-call wrapper
+    trainer.step(_batch())
+    assert rec["closed"] and setup_record._current is None
+    assert "step" not in vars(trainer)          # from now on the class's own
+    assert [s["name"] for s in rec["spans"]] == list(SPANS)
+    trainer.step(_batch())
+    assert len(rec["spans"]) == len(SPANS)
+
+
+@pytest.mark.parametrize("name", SPANS)
+def test_every_span_names_its_parent_and_lies_inside_it(name):
+    trainer = _trainer()
+    trainer.step(_batch())
+    spans = {s["name"]: s for s in trainer.setup_record()["spans"]}
+    s = spans[name]
+    assert s["end"] >= s["start"]
+    top = name in ("bps.setup.init", "bps.setup.first_step")
+    assert s["parent"] == (None if top else "bps.setup.init")
+    if not top:
+        outer = spans[s["parent"]]
+        assert outer["start"] <= s["start"] and s["end"] <= outer["end"]
+    if name == "bps.setup.place_params":
+        assert s["args"] == {"bytes": 4 * 2 * 4}
+    if name == "bps.setup.first_step":
+        assert s["args"] == {"step_num": 0}
+
+
+@pytest.mark.parametrize("lower_first,lowerings", [
+    (None, 1), ("same_batch", 1), ("host_batch", 2)],
+    ids=["first_step_alone", "lowered_before_as_the_harness_does",
+         "lowered_before_on_another_placement"])
+def test_the_steps_compile_is_joined_from_jaxs_events(lower_first, lowerings):
+    """One entry a lowering of the step function, whoever called ``lower``.
+    The harness lowers ``trainer._step_fn`` itself before the first step, on
+    the device batch the step is then given: JAX hands the call that
+    lowering and its executable, and the step is lowered ONCE. A lowering
+    for another placement of the batch is a second one."""
+    trainer = _trainer()
+    placed = trainer.shard_batch(_batch())
+    if lower_first:
+        trainer._step_fn.lower(
+            trainer.params, trainer.opt_state,
+            placed if lower_first == "same_batch" else _batch()).compile()
+    trainer.step(placed)
+    rec = trainer.setup_record()
+    assert rec["step_funs"] == ("step",)
+    entries = _step_entries(rec)
+    assert sum(e["lower_s"] > 0 for e in entries) == lowerings
+    for e in entries:
+        assert e["fun_name"] == "step"
+        assert e["trace_s"] > 0 and e["lower_s"] > 0 and e["compile_s"] > 0
+        assert "inside" not in e
+    # between the constructor and the first step no span is open
+    assert entries[0]["span"] == (None if lower_first
+                                  else "bps.setup.first_step")
+    if lowerings == 2:
+        assert entries[1]["span"] == "bps.setup.first_step"
+    # the constructor's small programs are the others, under its spans
+    others = [e for e in rec["compiles"] if not e["step"]]
+    assert others and all(
+        e["span"].startswith("bps.setup.") for e in others)
+
+
+def test_a_new_batch_shape_after_the_close_is_a_recompile_with_its_step():
+    trainer = _trainer()
+    for _ in range(3):
+        trainer.step(_batch())
+    rec = trainer.setup_record()
+    compiles = len(rec["compiles"])
+    assert rec["recompiles"] == []
+    trainer.step(_batch(rows=4))                # a short last batch
+    entry, = rec["recompiles"]
+    assert entry["step_num"] == 3 and entry["step"]
+    assert entry["trace_s"] > 0 and entry["compile_s"] > 0
+    # an unrelated function compiled outside ``step`` belongs to nobody
+    jax.jit(lambda x: x * 3 + 1)(jnp.ones(5))
+    assert len(rec["recompiles"]) == 1 and len(rec["compiles"]) == compiles
+
+
+def test_the_listener_is_registered_once_over_two_trainers(monkeypatch):
+    from jax._src import monitoring
+    first, second = _trainer(), _trainer()
+    listeners = monitoring.get_event_duration_listeners()
+    assert listeners.count(setup_record._on_duration) == 1
+    # the newer trainer's record is the open one until the older steps
+    assert setup_record._current is second.setup_record()
+    first.step(_batch())
+    second.step(_batch())
+    for trainer in (first, second):
+        assert len(_step_entries(trainer.setup_record())) <= 1
+        assert trainer.setup_record()["closed"]
+    assert len(_step_entries(first.setup_record())) == 1
+
+
+def test_a_compile_nested_in_a_trace_is_marked_inside_it():
+    """An eager product on constants inside the step's trace compiles a
+    program of its own while the trace's clock runs."""
+    def loss(params, batch):
+        with jax.ensure_compile_time_eval():    # compiled at trace time
+            scale = float(jnp.tanh(jnp.ones(())) * 2.0)
+        return scale * _loss(params, batch)
+
+    mesh = make_mesh({"data": 2}, devices=jax.devices()[:2])
+    trainer = DistributedTrainer(loss, {"w": jnp.ones((4, 2))},
+                                 optax.sgd(0.1), mesh=mesh)
+    before = len(trainer.setup_record()["compiles"])
+    trainer.step(_batch())
+    during = trainer.setup_record()["compiles"][before:]
+    nested = [e for e in during if not e["step"]]
+    assert nested and all(e["inside"] == "step" for e in nested)
+    assert "inside" not in _step_entries(trainer.setup_record())[0]
+
+
+def _call_attention(shape):
+    from byteps_tpu.ops.flash_attention import attention
+    q = jnp.zeros(shape, jnp.float32)
+    jax.eval_shape(lambda q: attention(q, q, q), q)
+
+
+def _call_ssd(packed):
+    from byteps_tpu.ops import ssd as S
+    bsz, s, heads, p, groups, n = (1, 256, 4, 64, 2, 128) if packed != "odd" \
+        else (1, 64, 4, 8, 2, 16)
+    chunk = 128 if packed != "odd" else 16
+    f32 = jnp.float32
+    dt, a, d = (jnp.ones((bsz, s, heads), f32), -jnp.ones((heads,), f32),
+                jnp.ones((heads,), f32))
+    if packed == "packed":
+        xbc = jnp.zeros((bsz, s, heads * p + 2 * groups * n), jnp.bfloat16)
+        return jax.eval_shape(
+            lambda xbc: S.ssd_packed(xbc, dt, a, d, groups, n, chunk), xbc)
+    x = jnp.zeros((bsz, s, heads, p), jnp.bfloat16)
+    b = jnp.zeros((bsz, s, groups, n), jnp.bfloat16)
+    jax.eval_shape(lambda x, b: S.ssd(x, dt, a, b, b, d, chunk), x, b)
+
+
+def _call_grouped_matmul(width):
+    from byteps_tpu.ops.grouped_matmul import grouped_matmul
+    lhs = jnp.zeros((256, width), jnp.bfloat16)
+    w = jnp.zeros((2, width, width), jnp.bfloat16)
+    tiles = jnp.zeros((2,), jnp.int32)
+    jax.eval_shape(lambda lhs, w: grouped_matmul(
+        lhs, w, tiles, jnp.ones((1,), jnp.int32),
+        jnp.full((2,), 128, jnp.int32), 128), lhs, w)
+
+
+def _call_resolve(hidden):
+    from byteps_tpu.ops.routed_rows import resolve
+    resolve("auto", 512, hidden, 256, 4, 128)
+
+
+def _call_exchange(reducer):
+    from byteps_tpu.parallel.collectives import tree_allreduce
+    mesh = make_mesh({"data": 2}, devices=jax.devices()[:2])
+    from jax.sharding import PartitionSpec as P
+    kwargs = {} if reducer is None else {"reducer": reducer}
+    jax.eval_shape(jax.shard_map(
+        lambda x: tree_allreduce({"a": x}, ("data",), **kwargs),
+        mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False),
+        jnp.ones((8,), jnp.float32))
+
+
+def _flat_psum(x, axes):
+    return jax.lax.psum(x, axes)
+
+
+# (site, the call, what it took on a TPU, is that a fall-back)
+CHOICES = [
+    ("attention", lambda: _call_attention((1, 128, 2, 64)), "flash", False),
+    ("attention", lambda: _call_attention((1, 65, 2, 8)), "xla", True),
+    ("ssd", lambda: _call_ssd("plain"), "kernels", False),
+    ("ssd", lambda: _call_ssd("packed"), "kernels_packed", False),
+    ("ssd", lambda: _call_ssd("odd"), "xla", True),
+    ("grouped_matmul", lambda: _call_grouped_matmul(128), "gmm", False),
+    ("grouped_matmul", lambda: _call_grouped_matmul(100), "ragged", True),
+    ("routed_rows", lambda: _call_resolve(256), "gmm", False),
+    ("routed_rows", lambda: _call_resolve(100), "ragged", True),
+    ("exchange", lambda: _call_exchange(None), "leaves", False),
+    ("exchange", lambda: _call_exchange(_flat_psum), "buckets", False),
+]
+
+
+@pytest.mark.parametrize("site,call,took,fell_back", CHOICES, ids=[
+    f"{site}-{took}" for site, _, took, _ in CHOICES])
+def test_note_choice_counts_each_site_and_says_a_fall_back_once(
+        monkeypatch, site, call, took, fell_back):
+    warned = []
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(setup_record, "_warned", set())
+    monkeypatch.setattr(setup_record.get_logger(), "warning",
+                        lambda *a: warned.append(a))
+    rec = setup_record.open_record()
+    try:
+        call()
+        call()
+    finally:
+        setup_record.close(rec)
+    assert rec["choices"][site, took] == 2
+    assert set(rec["choices"]) == {(site, took)}
+    if fell_back:
+        (key, count), = rec["fallbacks"].items()
+        assert key[:2] == (site, took) and count == 2
+        assert len(warned) == 1 and "falls back" in warned[0][0]
+        assert warned[0][1:4] == (site, key[2], took)
+    else:
+        assert not rec["fallbacks"] and not warned
+
+
+@pytest.mark.parametrize("asked,backend,falls", [
+    ("auto", "tpu", True), ("gmm", "tpu", True), ("ragged", "tpu", False),
+    ("naive", "tpu", False), ("auto", "cpu", False)])
+def test_a_fall_back_is_xlas_form_on_a_tpu_that_nobody_asked_for(
+        monkeypatch, asked, backend, falls):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(setup_record, "_warned", set())
+    rec = setup_record.open_record()
+    try:
+        setup_record.note_choice("site", "ragged", (8, 100), "why",
+                                 asked=asked)
+    finally:
+        setup_record.close(rec)
+    assert rec["choices"]["site", "ragged"] == 1
+    assert bool(rec["fallbacks"]) == falls
+
+
+def test_ten_thousand_steps_add_nothing_to_the_record():
+    """From its second call on ``step`` is the class's own: no span, no
+    entry, no growth, and the listener is not called at all."""
+    import copy
+    trainer = _trainer()
+    batch = trainer.shard_batch(_batch())
+    trainer.step(batch)
+    trainer.step(batch)
+    rec = trainer.setup_record()
+    before = copy.deepcopy(rec)
+    calls = []
+    real = setup_record._on_duration
+    listeners = jax._src.monitoring.get_event_duration_listeners()
+    at = listeners.index(real)
+    listeners[at] = lambda *a, **kw: (calls.append(a), real(*a, **kw))
+    try:
+        for _ in range(10_000):
+            loss = trainer.step(batch)
+        jax.block_until_ready(loss)
+    finally:
+        listeners[at] = real
+    assert rec == before and not calls
+    assert type(trainer).step is DistributedTrainer.step
+    assert "step" not in vars(trainer)

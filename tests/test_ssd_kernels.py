@@ -247,7 +247,8 @@ def test_the_dispatcher_follows_platform_and_shape(monkeypatch):
 
     warned = []
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(S, "_warned_fallback", set())
+    from byteps_tpu.common import setup_record
+    monkeypatch.setattr(setup_record, "_warned", set())
     from byteps_tpu.common import logging as bps_logging
     monkeypatch.setattr(bps_logging.get_logger(), "warning",
                         lambda *a: warned.append(a))
