@@ -15,6 +15,12 @@ docs/state-space.md has the equations and what is float32: ``dt``, ``A``,
 the decays, their running sums, the carried state and the group norm;
 the projections, the convolution's operands and the scan's products are
 in the compute dtype.
+
+The two elementwise stages beside the scan are entries that choose as
+``ops.ssd.ssd`` does, by the platform and the shapes alone:
+``conv_silu`` and ``gated_norm`` take the kernels of
+``ops/mamba2_kernels.py`` on the TPU where the shapes allow and the XLA
+form (``causal_conv``, ``group_rmsnorm``) elsewhere.
 """
 
 from __future__ import annotations
@@ -25,6 +31,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ..common.setup_record import note_choice
+from ..ops import mamba2_kernels as K
 from ..ops.ssd import CHUNK, ssd_packed
 
 # the seeded dt_bias: the inverse softplus of a step drawn log-uniform in
@@ -102,6 +110,43 @@ def group_rmsnorm(x, scale, groups: int, eps: float):
     return g.reshape(x.shape) * scale
 
 
+def conv_silu(x, w, bias):
+    """``silu(causal_conv(x, w, bias))`` in ``x``'s dtype: on the TPU,
+    for the shapes ``ops.mamba2_kernels.conv_supported`` takes, the
+    kernels ``bps_ssm_conv_fwd`` / ``_bwd``; elsewhere the XLA form."""
+    kernels = (jax.default_backend() == "tpu"
+               and K.conv_supported(x.shape, w.shape))
+    note_choice("ssm_conv", "kernels" if kernels else "xla",
+                (tuple(x.shape), tuple(w.shape)),
+                "XLA's fusions over float32 copies: the kernels need "
+                f"channels in whole lane tiles, positions in blocks of "
+                f"{K.ROWS[-1]} and 2 to {K.SUB + 1} taps")
+    if kernels:
+        return K.conv_silu_kernels(x, w.astype(jnp.float32),
+                                   bias.astype(jnp.float32))
+    return jax.nn.silu(causal_conv(x, w, bias)).astype(x.dtype)
+
+
+def gated_norm(y, z, scale, groups: int, eps: float):
+    """``group_rmsnorm(y * silu(z), scale)`` in ``y``'s dtype: on the TPU,
+    for the shapes ``ops.mamba2_kernels.norm_supported`` takes, the
+    kernels ``bps_ssm_norm_fwd`` / ``_bwd``; elsewhere the XLA form."""
+    kernels = (jax.default_backend() == "tpu"
+               and K.norm_supported(y.shape, groups))
+    note_choice("ssm_norm", "kernels" if kernels else "xla",
+                (tuple(y.shape), groups),
+                "XLA's fusions over a [.., groups, width] relayout: the "
+                "kernels need a group's channels in whole lane tiles, at "
+                f"most {K.LANES_MOST}, and positions in blocks of "
+                f"{K.ROWS[-1]}")
+    if kernels:
+        return K.gated_norm_kernels(y, z, scale.astype(jnp.float32), groups,
+                                    eps)
+    f32 = jnp.float32
+    return group_rmsnorm(y.astype(f32) * jax.nn.silu(z.astype(f32)), scale,
+                         groups, eps).astype(y.dtype)
+
+
 def mixer(a, blk, cfg: SSMConfig, eps: float):
     """[b, s, hidden] (normed) -> [b, s, hidden]."""
     dt_, inner = a.dtype, cfg.inner
@@ -112,15 +157,12 @@ def mixer(a, blk, cfg: SSMConfig, eps: float):
         step = jnp.dot(a, w[:, inner + cfg.conv_dim:],
                        preferred_element_type=jnp.float32)
     with jax.named_scope("bps.ssm.conv"):
-        xbc = jax.nn.silu(causal_conv(xbc, blk["conv_w"],
-                                      blk["conv_b"])).astype(dt_)
+        xbc = conv_silu(xbc, blk["conv_w"], blk["conv_b"])
     with jax.named_scope("bps.ssm.scan"):   # x, B and C where they lie
         y = ssd_packed(xbc, jax.nn.softplus(step + blk["dt_bias"]),
                        -jnp.exp(blk["A_log"]), blk["D"], cfg.groups,
                        cfg.state, cfg.chunk)
     with jax.named_scope("bps.ssm.norm"):
-        y = group_rmsnorm(
-            y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32)),
-            blk["gated_norm"], cfg.groups, eps).astype(dt_)
+        y = gated_norm(y, z, blk["gated_norm"], cfg.groups, eps)
     with jax.named_scope("bps.ssm.proj"):
         return y @ blk["out_proj"].astype(dt_)

@@ -241,6 +241,36 @@ def test_state_space_scan_compiles_for_v5e(compile_for_chip):
     assert f"f32[{bsz},{s // S.CHUNK},{n},{heads * p}]" in text
 
 
+def test_the_stages_beside_the_scan_compile_for_v5e(compile_for_chip):
+    """``bps_ssm_conv_fwd`` / ``_bwd`` over ``xBC`` [2, 8192, 6144] (48
+    lane tiles in runs of 4, blocks of 512 positions with their halo
+    blocks, strips rotated along the sublanes) and ``bps_ssm_norm_fwd`` /
+    ``_bwd`` over [2, 8192, 4096] in 8 groups of 4 lane tiles, bf16, at
+    Nemotron 3 Nano's shape; nothing float32 of an activation's size
+    leaves a kernel."""
+    from byteps_tpu.ops import mamba2_kernels as K
+
+    bsz, s, inner, conv_dim, groups = 2, 8192, 4096, 6144, 8
+    assert K.conv_supported((bsz, s, conv_dim), (4, conv_dim))
+    assert K.norm_supported((bsz, s, inner), groups)
+
+    def both(x, w, bias, y, z, scale):
+        out, pull = jax.vjp(K.conv_silu_kernels, x, w, bias)
+        normed, pull_norm = jax.vjp(
+            lambda *a: K.gated_norm_kernels(*a, groups, 1e-5), y, z, scale)
+        return out, pull(out), normed, pull_norm(normed)
+
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    text = compile_for_chip(
+        both, ((bsz, s, conv_dim), bf16), ((4, conv_dim), f32),
+        ((conv_dim,), f32), ((bsz, s, inner), bf16), ((bsz, s, inner), bf16),
+        ((inner,), f32))
+    for kernel in ("bps_ssm_conv_fwd", "bps_ssm_conv_bwd", "bps_ssm_norm_fwd",
+                   "bps_ssm_norm_bwd"):
+        assert kernel in text
+    assert not re.search(rf"f32\[{bsz},{s},\d+\]", text)
+
+
 def _flash_forwards(text):
     """(``bps_flash_fwd`` calls in a compiled step, those of them in a
     checkpoint's recompute) by the ``op_name`` each carries, the name

@@ -178,6 +178,88 @@ def test_a_state_space_mixers_parts_nest_inside_it():
         r"bps\.attn/.*bps\.ssm")
 
 
+SSM_KERNELS = {     # stage -> (forward call, its kernel, backward call, ...)
+    "conv": ("_conv_fwd_call", "bps_ssm_conv_fwd",
+             "_conv_bwd_call", "bps_ssm_conv_bwd"),
+    "norm": ("_norm_fwd_call", "bps_ssm_norm_fwd",
+             "_norm_bwd_call", "bps_ssm_norm_bwd"),
+}
+
+
+@pytest.fixture(scope="module")
+def ssm_kernels_step():
+    """A checkpointed state-space layer's step at shapes the TPU's
+    kernels take, traced as on a TPU and lowered FOR one (no chip: the
+    lowering ends in Mosaic's serialised kernels): its text with
+    locations, its call sites' scope paths by callee, its set-up record."""
+    from byteps_tpu.ops import mamba2_kernels
+    cfg = decoder.nemotron_h_tiny(
+        ssm_head_dim=64, ssm_state=128, chunk=128, remat=True,
+        layer_kinds=("ssm", "ssm"), dtype="bfloat16")
+    params = decoder.init_params(jax.random.PRNGKey(0), cfg)
+    mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
+    real = jax.default_backend
+    jax.default_backend = lambda: "tpu"
+    try:
+        trainer = DistributedTrainer(
+            lambda p, b: decoder.causal_lm_loss(p, cfg, b), params,
+            optax.adamw(1e-3), mesh=mesh)
+        batch = jax.ShapeDtypeStruct((1, mamba2_kernels.ROWS[-1]),
+                                     jnp.int32)
+        text = trainer._step_fn.trace(
+            trainer.params, trainer.opt_state, batch).lower(
+                lowering_platforms=("tpu",)).as_text(debug_info=True)
+    finally:
+        jax.default_backend = real
+    from byteps_tpu.common import setup_record
+    setup_record.close(trainer.setup_record())
+    named = dict(re.findall(r'(#loc\d+) = loc\("([^"]*)"', text))
+    sites = {}
+    for callee, loc in re.findall(r"call @(\w+?)(?:_\d+)?\(.*loc\((#loc\d+)\)",
+                                  text):
+        sites.setdefault(callee, []).append(named.get(loc, ""))
+    return text, sites, trainer.setup_record()
+
+
+@pytest.mark.parametrize("stage", sorted(SSM_KERNELS))
+def test_the_stages_beside_the_scan_are_kernels_under_their_scopes(
+        ssm_kernels_step, stage):
+    """Forward, recompute and backward: every call of a stage's two
+    kernels lies under ``bps.ssm.<stage>`` inside ``bps.model``, none
+    under an empty path, and each kernel is lowered once for the two
+    layers."""
+    text, sites, _ = ssm_kernels_step
+    fwd_call, fwd, bwd_call, bwd = SSM_KERNELS[stage]
+    scope = rf"bps\.ssm/bps\.ssm\.{stage}/jit\({{}}\)$"
+    fwd_sites, bwd_sites = sites[fwd_call], sites[bwd_call]
+    assert len(fwd_sites) == 4 and len(bwd_sites) == 2      # two layers
+    forward = [p for p in fwd_sites if "transpose(" not in p]
+    recompute = [p for p in fwd_sites if "rematted_computation" in p]
+    assert len(forward) == 2 and len(recompute) == 2
+    for p in forward:
+        assert re.search(r"bps\.model/jvp\(bps\.ssm\)/bps\.ssm\."
+                         rf"{stage}/jit\({fwd_call}\)$", p), p
+    for p in recompute:
+        assert re.search(r"bps\.model/transpose\(.*rematted_computation/"
+                         + scope.format(fwd_call), p), p
+    for p in bwd_sites:
+        assert "rematted_computation" not in p and re.search(
+            r"bps\.model/transpose\(.*checkpoint/" + scope.format(bwd_call),
+            p), p
+    for call, kernel in ((fwd_call, fwd), (bwd_call, bwd)):
+        assert len(re.findall(rf"func\.func private @{call}\(", text)) == 1
+        assert f'loc("{kernel}/pallas_call' in text
+        assert f'kernel_name = "{kernel}"' in text
+
+
+def test_the_set_up_record_holds_the_state_space_sites(ssm_kernels_step):
+    _, _, rec = ssm_kernels_step
+    chose = {k: v for k, v in rec["choices"].items() if k[0] != "exchange"}
+    assert chose == {("ssm_conv", "kernels"): 2, ("ssm_norm", "kernels"): 2,
+                     ("ssd", "kernels_packed"): 2}
+    assert not rec["fallbacks"]
+
+
 @pytest.mark.parametrize("seq,kernels", [
     (128, ("bps_flash_fwd", "bps_flash_bwd_fused")),
     (256, ("bps_flash_fwd", "bps_flash_bwd_dq", "bps_flash_bwd_dkv")),
