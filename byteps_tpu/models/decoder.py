@@ -322,10 +322,8 @@ def apply(params, cfg: DecoderConfig, tokens) -> jnp.ndarray:
         raise ValueError(f"{tokens.shape[1]} positions, the model has "
                          f"{cfg.max_seq}")
     with jax.named_scope("bps.embed"):
-        x = embed_lookup(params["embed"], tokens)
-        if cfg.scale_embedding:
-            x = x * math.sqrt(cfg.hidden)
-        x = x.astype(dt)
+        x = embed_lookup(params["embed"], tokens, dt, math.sqrt(cfg.hidden)
+                         if cfg.scale_embedding else None)
     # a layer's checkpoint keeps its input, the flash kernel's output and
     # row statistics and the routed layer's plan; the rest is recomputed
     policy = jax.checkpoint_policies.save_only_these_names(

@@ -211,7 +211,7 @@ def moe_apply(params, cfg: MoEConfig, tokens: jnp.ndarray,
             offset = 0
         positions = offset + jnp.arange(s)
     tp_size = jax.lax.axis_size(cfg.tp_axis) if cfg.tp_axis else 1
-    x = embed_lookup(params["embed"]["tok"], tokens).astype(dt)
+    x = embed_lookup(params["embed"]["tok"], tokens, dt)
     x = x + params["embed"]["pos"][positions].astype(dt)
 
     blk_fn = partial(_moe_block, cfg=cfg, tp_size=tp_size)

@@ -273,7 +273,7 @@ def _dec_block(x, memory, blk, cfg: T5Config, rel_table=None):
 def _embed(params, cfg: T5Config, tokens):
     dt = jnp.dtype(cfg.dtype)
     s = tokens.shape[1]
-    x = embed_lookup(params["embed"]["tok"], tokens).astype(dt)
+    x = embed_lookup(params["embed"]["tok"], tokens, dt)
     if not cfg.relative:
         x = x + params["embed"]["pos"][:s].astype(dt)
     return x

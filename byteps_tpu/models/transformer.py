@@ -33,7 +33,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from ..common.setup_record import note_choice
+from ..ops import grouped_matmul as gm
 from ..ops.flash_attention import SAVED_NAMES
+from ..ops.routed_rows import take_xla
 from ..parallel.ring import ring_attention
 
 
@@ -178,42 +181,105 @@ def param_specs(cfg: TransformerConfig):
 
 # ----------------------------------------------------------------- layers
 
-def embed_lookup(table, tokens):
-    """Token-embedding lookup with an MXU backward.
+def embed_lookup(table, tokens, dtype=None, scale=None):
+    """``(table[tokens] * scale).astype(dtype)``: the token embedding, with
+    a backward that costs what the tokens cost.
 
-    Forward is the plain gather. The default backward — scatter-add of
-    [b·s, hid] rows into the [vocab, hid] table — serializes badly on
-    TPU: measured 115 ms/step for BERT-large (batch 64, seq 512) vs
-    29 ms when the same contraction runs as a one-hot matmul on the MXU
-    (~10% of the whole train step). The one-hot never materializes: XLA
-    fuses it into the dot."""
-    return _embed_lookup(table.shape[0], str(table.dtype), table, tokens)
+    Forward is the plain gather (``scale``, a Python number, in the
+    table's dtype before the cast, as a caller's own product would be).
+    The backward is ``scale * sum of the cotangent's rows by id``, summed
+    in float32 and scaled once. Three forms of that sum have been read
+    on the v5e:
+
+    - autodiff's scatter-add of [b*s, hid] rows into [vocab, hid], which
+      serialises: 115 ms a step in BERT-large (batch 64, seq 512; read
+      before PR 1, commit 7373b59);
+    - ``one_hot(tokens, vocab)^T @ ct`` on the MXU, tokens x vocab x hid
+      of which one term in vocab is not zero: 29 ms there then, 11.6 ms
+      since (2.05 TFLOP near the bf16 peak), and 27.5 ms in Trinity,
+      whose float32 scale outside this function made the cotangent a
+      float32 that is no bf16 (six passes; PERF.md, PR 36). It is the
+      form off the TPU (``embed_grad``);
+    - the grouped form (``ops.grouped_matmul.embed_dw``, PR 42): the ids
+      sorted, the cotangent's rows gathered into that order, and each
+      block of 256 vocabulary rows summed from its own run of tokens by
+      a one-hot made in VMEM: tokens x 256 x hid. PERF.md (PR 42) has its
+      readings at the cells' shapes.
+
+    ``dtype`` and ``scale`` are inside so that the backward sees the
+    cotangent in the dtype the model computes in: bf16 rows sum exactly
+    in one pass."""
+    out = jnp.dtype(dtype or table.dtype)
+    return _embed_lookup(table.shape[0], str(table.dtype), str(out), scale,
+                         table, tokens)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _embed_lookup(vocab: int, dt: str, table, tokens):
-    return table[tokens]
+def _embed_rows(out: str, scale, table, tokens):
+    x = table[tokens]
+    return (x if scale is None else x * scale).astype(out)
 
 
-def _embed_lookup_fwd(vocab, dt, table, tokens):
-    return table[tokens], tokens
+@partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _embed_lookup(vocab: int, dt: str, out: str, scale, table, tokens):
+    return _embed_rows(out, scale, table, tokens)
 
 
-def _embed_lookup_bwd(vocab, dt, tokens, ct):
-    flat_t = tokens.reshape(-1)
-    flat_ct = ct.reshape(-1, ct.shape[-1])
-    onehot = jax.nn.one_hot(flat_t, vocab, dtype=flat_ct.dtype)
-    # fp32 cotangents keep scatter-add exactness (TPU fp32 dots default
-    # to bf16 MXU passes); bf16 cotangents take the fast default
-    prec = (jax.lax.Precision.HIGHEST
-            if flat_ct.dtype == jnp.float32 else None)
-    grad = jax.lax.dot_general(onehot, flat_ct, (((0,), (0,)), ((), ())),
-                               precision=prec,
-                               preferred_element_type=jnp.float32)
-    return grad.astype(dt), None
+def _embed_lookup_fwd(vocab, dt, out, scale, table, tokens):
+    return _embed_rows(out, scale, table, tokens), tokens
+
+
+def _embed_lookup_bwd(vocab, dt, out, scale, tokens, ct):
+    return embed_grad(tokens.reshape(-1), ct.reshape(-1, ct.shape[-1]),
+                      vocab, scale, dt), None
 
 
 _embed_lookup.defvjp(_embed_lookup_fwd, _embed_lookup_bwd)
+
+
+def embed_grad(ids, ct, vocab: int, scale=None, dtype="float32",
+               impl: str = "auto"):
+    """[vocab, hid] in ``dtype``: ``scale * sum of ct[t] over the tokens t
+    with ids[t] == v``, for ``ids`` [T] and ``ct`` [T, hid]. An id outside
+    the vocabulary adds to no row.
+
+    impl: "auto": the grouped form on the TPU where the hidden size is
+    whole lane tiles (what the kernel's blocks take), else the one-hot
+    product; "kernels", "kernels_interpret" (Pallas' interpreter: tests),
+    "xla"."""
+    if impl not in ("auto", "kernels", "kernels_interpret", "xla"):
+        raise ValueError(f"embed_grad impl {impl!r}")
+    asked = impl
+    if impl == "auto":
+        impl = ("kernels" if jax.default_backend() == "tpu"
+                and ct.shape[1] % 128 == 0 else "xla")
+    note_choice("embed_bwd", "xla" if impl == "xla" else "kernels",
+                (tuple(ct.shape), vocab),
+                "the one-hot product over tokens x vocabulary: the grouped "
+                "form needs a hidden size in whole lane tiles", asked=asked)
+    if impl == "xla":
+        onehot = jax.nn.one_hot(ids, vocab, dtype=ct.dtype)
+        # fp32 cotangents keep scatter-add exactness (TPU fp32 dots default
+        # to bf16 MXU passes); bf16 cotangents take the fast default
+        prec = (jax.lax.Precision.HIGHEST
+                if ct.dtype == jnp.float32 else None)
+        grad = jax.lax.dot_general(onehot, ct, (((0,), (0,)), ((), ())),
+                                   precision=prec,
+                                   preferred_element_type=jnp.float32)
+        return (grad if scale is None else grad * scale).astype(dtype)
+    tile = gm.EMBED_TILE
+    rows = -(-ids.shape[0] // tile) * tile
+    # a stable sort: a row's tokens are summed in the same order every
+    # run; rows past the tokens sort last, under no id of the vocabulary
+    ids = jnp.pad(ids.astype(jnp.int32), (0, rows - ids.shape[0]),
+                  constant_values=jnp.iinfo(jnp.int32).max)
+    ids, order = jax.lax.sort((ids, jnp.arange(rows, dtype=jnp.int32)),
+                              num_keys=1, is_stable=True)
+    # a row of no token of the vocabulary is the gather's fill (an index
+    # past the tokens), not a product of what it holds with the one-hot's
+    order = jnp.where((ids >= 0) & (ids < vocab), order, rows)
+    moved = take_xla(ct, order)
+    return gm.embed_dw(ids, moved, vocab, scale, jnp.dtype(dtype).name,
+                       impl == "kernels_interpret")
 
 
 def _layernorm(x, scale, bias, eps=1e-5):
@@ -305,7 +371,7 @@ def apply(params, cfg: TransformerConfig, tokens: jnp.ndarray,
         positions = offset + jnp.arange(s)
     tp_size = jax.lax.axis_size(cfg.tp_axis) if cfg.tp_axis else 1
     with jax.named_scope("bps.embed"):
-        x = embed_lookup(params["embed"]["tok"], tokens).astype(dt)
+        x = embed_lookup(params["embed"]["tok"], tokens, dt)
         x = x + params["embed"]["pos"][positions].astype(dt)
 
     plain_fn = partial(_block, cfg=cfg, tp_size=tp_size)
@@ -381,6 +447,23 @@ def apply(params, cfg: TransformerConfig, tokens: jnp.ndarray,
     return x
 
 
+@jax.custom_vjp
+def _own_gradient(w):
+    """``w``, with a cotangent that is an op of its own: XLA may not fuse
+    what produces it into what consumes it. The tied head's gradient to
+    the table is consumed after the whole backward, where the embedding's
+    is added: fused into that sum (and Adam's update behind it) the
+    product waits there, and its operands with it: BERT-large's
+    ``f32[64, 80, 30522]`` logits' cotangent, 1.15 GB more at the step's
+    peak (compiled for the v5e, PR 42). While the embedding's backward
+    was a product itself the fusion's one product was taken."""
+    return w
+
+
+_own_gradient.defvjp(lambda w: (w, None),
+                     lambda _, ct: (jax.lax.optimization_barrier(ct),))
+
+
 def logits(params, cfg: TransformerConfig, hidden: jnp.ndarray) -> jnp.ndarray:
     """Tied-embedding LM head → [b, s, vocab] in fp32.
 
@@ -388,7 +471,7 @@ def logits(params, cfg: TransformerConfig, hidden: jnp.ndarray) -> jnp.ndarray:
     one op dominates the step) with fp32 accumulation."""
     dt = jnp.dtype(cfg.dtype)
     return jnp.einsum("bsh,vh->bsv", hidden.astype(dt),
-                      params["embed"]["tok"].astype(dt),
+                      _own_gradient(params["embed"]["tok"].astype(dt)),
                       preferred_element_type=jnp.float32)
 
 

@@ -16,6 +16,8 @@ still walks the whole buffer.
   - ``bps_gmm``     out[r] = lhs[r] @ w[group(r)]          [rows, n]
   - ``bps_gmm_dx``  out[r] = lhs[r] @ w[group(r)]^T        [rows, k]
   - ``bps_gmm_dw``  out[g] = lhs[rows of g]^T @ dout[rows of g]   [g, k, n]
+  - ``bps_embed_dw``  the same sum where ``lhs`` is a one-hot of ids: the
+    gradient of an embedding table (``embed_dw``, below)      [vocab, n]
 
 bf16 (or the inputs' dtype) in, fp32 accumulation on the MXU. The
 contraction of the first two is one block (k, n <= a few thousand: an
@@ -36,6 +38,19 @@ never cut, so it takes the whole width, half tile and all.
 ``grouped_matmul`` is the differentiable entry: the kernels on the TPU,
 ``lax.ragged_dot`` elsewhere (CPU tests), like ``ops.flash_attention
 .attention``.
+
+``embed_dw`` is the grouped product's second user, and no part of a
+routed layer (its kernel's name is outside ``bps_gmm*`` for that: what
+reads a trace by that prefix counts the experts' products alone). A
+group is a block of ``EMBED_BLOCK`` rows of the vocabulary, and the
+token ids come SORTED, so a block's tokens are one run of rows. The
+runs are not padded: a row tile that holds the end of one run and the
+start of the next is read once for each, and a row of another block's
+matches no column of this block's one-hot, which the kernel makes in
+VMEM from the tile's ids. ``tile_group`` and its companion ``tile_row``
+name, for each of a static ``rows // tile + blocks`` grid steps, the
+block and the row tile (every block has a step, so every block of the
+gradient is written, an empty one as zeros).
 """
 
 from __future__ import annotations
@@ -51,6 +66,13 @@ from ..common.setup_record import note_choice
 from .flash_attention import _pick_block as _pick
 
 HALF_LANES = 64     # a width is whole lane tiles, or ends in half a one
+# embed_dw: rows of the vocabulary a group, token rows a grid step. The
+# work is tokens x EMBED_BLOCK x hidden, the grid steps tokens / EMBED_TILE
+# + vocab / EMBED_BLOCK. Read at the four cells' shapes on the v5e (PERF.md,
+# PR 42): 256 / 256 is the fastest in all four, by 0.02-0.05 ms of 0.5-1.2
+# over 512 / 256 and 512 / 512; 1024 rows a block do not fit VMEM
+EMBED_BLOCK = 256
+EMBED_TILE = 256
 
 # a step past the rows revisits the last tile's blocks: nothing may be
 # reordered around it, so the tile dimension is never "parallel"
@@ -181,6 +203,94 @@ def _gmm_dw(lhs, dout, tile_group, num_tiles, groups, tile, interpret):
         interpret=interpret,
         name="bps_gmm_dw",
     )(tile_group, num_tiles, lhs, dout)
+
+
+def _embed_dw_kernel(group_ref, row_ref, num_ref, ids_ref, dout_ref, out_ref,
+                     acc, *, scale, precision):
+    del row_ref
+    t = pl.program_id(1)
+    num = num_ref[0]
+    here = group_ref[t]
+    first = jnp.logical_or(t == 0, group_ref[jnp.maximum(t - 1, 0)] != here)
+    final = jnp.logical_or(
+        t == num - 1,
+        group_ref[jnp.minimum(t + 1, pl.num_programs(1) - 1)] != here)
+
+    @pl.when(t < num)
+    def _tile():
+        block, tile = out_ref.shape[0], dout_ref.shape[0]
+        # [block, tile]: the tile's row r is a token of this block's row
+        # v where ids[r] == block * here + v; a row of another block (or
+        # of no token: an id outside the vocabulary) matches none
+        hit = (jax.lax.broadcasted_iota(jnp.int32, (block, tile), 0)
+               == ids_ref[0] - here * block)
+        part = jnp.dot(jnp.where(hit, 1.0, 0.0).astype(dout_ref.dtype),
+                       dout_ref[...], precision=precision,
+                       preferred_element_type=jnp.float32)
+
+        @pl.when(first)
+        def _start():
+            acc[...] = part
+
+        @pl.when(jnp.logical_not(first))
+        def _add():
+            acc[...] += part
+
+        @pl.when(final)
+        def _write():
+            total = acc[...] if scale is None else acc[...] * scale
+            out_ref[...] = total.astype(out_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "vocab", "scale", "out_dtype", "interpret"))
+def embed_dw(ids, dout, vocab, scale, out_dtype, interpret):
+    """[vocab, n] in ``out_dtype``: ``scale * sum of dout[r] over the rows
+    whose id is v``, summed in float32, for ``ids`` [rows] int32 in
+    ascending order and ``dout`` [rows, n] in the same. ``rows`` in whole
+    tiles of ``EMBED_TILE``; an id outside ``0 .. vocab - 1`` (a pad
+    row's) is in no sum, and its row of ``dout`` must still be finite.
+    bf16 rows are summed exactly; float32 rows by a product at fp32
+    contract precision, since a float32 is no bf16."""
+    rows, n = dout.shape
+    block, tile = EMBED_BLOCK, EMBED_TILE
+    groups, tiles = pl.cdiv(vocab, block), rows // tile
+    steps = tiles + groups
+    # block g's run of rows, the row tiles it lies in (an empty run: one),
+    # and the grid step at which g's tiles start
+    edge = jnp.searchsorted(ids, jnp.arange(groups + 1, dtype=jnp.int32)
+                            * block, side="left").astype(jnp.int32)
+    lo, hi = edge[:-1], edge[1:]
+    first = jnp.minimum(lo // tile, tiles - 1)
+    count = jnp.where(hi > lo, (hi - 1) // tile - first + 1, 1)
+    start = jnp.cumsum(count) - count
+    num_tiles = count.sum().astype(jnp.int32)[None]
+    # steps past the last stay on its tile and its block: nothing moves
+    step = jnp.minimum(jnp.arange(steps, dtype=jnp.int32), num_tiles - 1)
+    tile_group = (jnp.searchsorted(start, step, side="right") - 1).astype(
+        jnp.int32)
+    tile_row = first[tile_group] + step - start[tile_group]
+    tn = _cols(n, 1024)
+    precision = (jax.lax.Precision.HIGHEST if dout.dtype == jnp.float32
+                 else None)
+    return pl.pallas_call(
+        functools.partial(_embed_dw_kernel, scale=scale, precision=precision),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(pl.cdiv(n, tn), steps),
+            in_specs=[
+                pl.BlockSpec((1, 1, tile),
+                             lambda j, t, grp, row, num: (row[t], 0, 0)),
+                pl.BlockSpec((tile, tn),
+                             lambda j, t, grp, row, num: (row[t], j))],
+            out_specs=pl.BlockSpec(
+                (block, tn), lambda j, t, grp, row, num: (grp[t], j)),
+            scratch_shapes=[pltpu.VMEM((block, tn), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((vocab, n), out_dtype),
+        compiler_params=_GMM_SEMANTICS,
+        interpret=interpret,
+        name="bps_embed_dw",
+    )(tile_group, tile_row, num_tiles, ids.reshape(tiles, 1, tile), dout)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))

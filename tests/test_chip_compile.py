@@ -189,6 +189,33 @@ def test_routed_row_movement_compiles_for_v5e(compile_for_chip, hidden, width,
         ((tokens // 512, 2, held * 64), jnp.int32))
 
 
+@pytest.mark.parametrize("tokens,vocab,hidden,dtype,scale", [
+    (16384, 25024, 2048, jnp.bfloat16, 2048 ** 0.5),
+    (16384, 16384, 2688, jnp.bfloat16, None),
+    (32768, 30522, 1024, jnp.bfloat16, None),
+    (8192, 50257, 1024, jnp.bfloat16, None),
+    (8192, 50257, 1024, jnp.float32, None)],
+    ids=["trinity_mini", "nemotron3_nano", "bert_large", "gpt2_medium",
+         "float32"])
+def test_the_embeddings_backward_compiles_for_v5e(compile_for_chip, tokens,
+                                                  vocab, hidden, dtype,
+                                                  scale):
+    """The grouped form of ``embed_lookup``'s backward at the cells' four
+    shapes (and a float32 cotangent: the product at fp32 contract
+    precision): the sort, XLA's gather of the sorted rows and
+    ``bps_embed_dw``, whose last block of 256 vocabulary rows hangs over
+    a vocabulary that is no multiple of 256, of 128 or of 8."""
+    from byteps_tpu.models import transformer
+
+    def grad(ids, ct):
+        return transformer.embed_grad(ids, ct, vocab, scale, impl="kernels")
+
+    text = compile_for_chip(grad, ((tokens,), jnp.int32),
+                            ((tokens, hidden), dtype))
+    assert "bps_embed_dw" in text and "bps_moe_take" not in text
+    assert f"f32[{vocab},{hidden}]" in text
+
+
 @pytest.mark.parametrize("k,n", [(2688, 1856), (1856, 2688)],
                          ids=["up", "down"])
 def test_grouped_products_off_the_lane_tile_compile_for_v5e(compile_for_chip,

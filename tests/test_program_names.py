@@ -189,12 +189,13 @@ SSM_KERNELS = {     # stage -> (forward call, its kernel, backward call, ...)
 @pytest.fixture(scope="module")
 def ssm_kernels_step():
     """A checkpointed state-space layer's step at shapes the TPU's
-    kernels take, traced as on a TPU and lowered FOR one (no chip: the
-    lowering ends in Mosaic's serialised kernels): its text with
-    locations, its call sites' scope paths by callee, its set-up record."""
+    kernels take (a hidden size of one lane tile: the embedding's backward
+    too), traced as on a TPU and lowered FOR one (no chip: the lowering
+    ends in Mosaic's serialised kernels): its text with locations, its
+    call sites' scope paths by callee, its set-up record."""
     from byteps_tpu.ops import mamba2_kernels
     cfg = decoder.nemotron_h_tiny(
-        ssm_head_dim=64, ssm_state=128, chunk=128, remat=True,
+        hidden=128, ssm_head_dim=64, ssm_state=128, chunk=128, remat=True,
         layer_kinds=("ssm", "ssm"), dtype="bfloat16")
     params = decoder.init_params(jax.random.PRNGKey(0), cfg)
     mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
@@ -256,8 +257,23 @@ def test_the_set_up_record_holds_the_state_space_sites(ssm_kernels_step):
     _, _, rec = ssm_kernels_step
     chose = {k: v for k, v in rec["choices"].items() if k[0] != "exchange"}
     assert chose == {("ssm_conv", "kernels"): 2, ("ssm_norm", "kernels"): 2,
-                     ("ssd", "kernels_packed"): 2}
+                     ("ssd", "kernels_packed"): 2,
+                     ("embed_bwd", "kernels"): 1}
     assert not rec["fallbacks"]
+
+
+def test_the_embeddings_backward_is_a_kernel_under_its_scope(
+        ssm_kernels_step):
+    """``bps_embed_dw`` once a step, in the backward phase under
+    ``bps.embed``, outside every layer's checkpoint: a trace's reader
+    finds the op by that scope."""
+    text, sites, _ = ssm_kernels_step
+    path, = sites["embed_dw"]
+    assert re.search(r"bps\.model/transpose\(bps\.model\)/"
+                     r"jvp\(bps\.embed\)/jit\(embed_dw\)$", path), path
+    assert "checkpoint" not in path and "rematted" not in path
+    assert 'loc("bps_embed_dw/pallas_call' in text
+    assert 'kernel_name = "bps_embed_dw"' in text
 
 
 @pytest.mark.parametrize("seq,kernels", [

@@ -200,6 +200,13 @@ def _call_resolve(hidden):
     resolve("auto", 512, hidden, 256, 4, 128)
 
 
+def _call_embed_grad(hidden):
+    from byteps_tpu.models.transformer import embed_grad
+    jax.eval_shape(lambda ids, ct: embed_grad(ids, ct, 1000),
+                   jnp.zeros((256,), jnp.int32),
+                   jnp.zeros((256, hidden), jnp.bfloat16))
+
+
 def _call_exchange(reducer):
     from byteps_tpu.parallel.collectives import tree_allreduce
     mesh = make_mesh({"data": 2}, devices=jax.devices()[:2])
@@ -226,6 +233,8 @@ CHOICES = [
     ("grouped_matmul", lambda: _call_grouped_matmul(100), "ragged", True),
     ("routed_rows", lambda: _call_resolve(256), "gmm", False),
     ("routed_rows", lambda: _call_resolve(100), "ragged", True),
+    ("embed_bwd", lambda: _call_embed_grad(128), "kernels", False),
+    ("embed_bwd", lambda: _call_embed_grad(96), "xla", True),
     ("exchange", lambda: _call_exchange(None), "leaves", False),
     ("exchange", lambda: _call_exchange(_flat_psum), "buckets", False),
 ]
