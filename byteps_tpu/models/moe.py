@@ -43,6 +43,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ..ops.grouped_matmul import grouped_matmul
+from ..ops.routed_act import gated_silu, relu2, routed_act
 from ..ops.routed_rows import (combine_rows, resolve as resolve_rows,
                                take_rows, take_xla, tile_bounds)
 from .transformer import (TransformerConfig, _attention, _layernorm,
@@ -284,17 +285,6 @@ class RoutedConfig:
         return min(self.top_k, len(self.held))
 
 
-def gated_silu(h):
-    """``silu(gate) * up`` of a fused [.., 2 m] gate|up projection."""
-    m = h.shape[-1] // 2
-    return jax.nn.silu(h[..., :m]) * h[..., m:]
-
-
-def relu2(h):
-    """``relu(up)^2`` of a plain [.., m] up projection (nemotron_h)."""
-    return jnp.square(jax.nn.relu(h))
-
-
 # an expert is ``down(act(first x))``: the function between its two
 # products, and the name of the first one's weights ([h, 2 m] fused gate
 # and up under ``gated_silu``, [h, m] under ``relu2``)
@@ -458,9 +448,10 @@ def routed_ffn(f, blk, cfg: RoutedConfig, sequences: int = 1):
     No row is dropped, whatever the imbalance: the buffer of rows is sized
     for the worst routing. The grouped products and the movement of rows
     (to the buffer by live row tile, back by the rows a tile of tokens has
-    here: ``ops/routed_rows.py``) take time by the rows routed this step;
-    the function between the products and the plan still walk the whole
-    buffer and every chosen pair (PERF.md section 5)."""
+    here: ``ops/routed_rows.py``) take time by the rows routed this step,
+    and so does the function between the products, forward and backward
+    (``ops/routed_act.py``); the plan still walks the whole buffer and
+    every chosen pair (PERF.md section 5)."""
     dt = f.dtype
     tile = cfg.row_tile
     act, first = ACTS[cfg.act]
@@ -476,8 +467,11 @@ def routed_ffn(f, blk, cfg: RoutedConfig, sequences: int = 1):
                 return grouped_matmul(
                     lhs, w.astype(dt), plan["tile_group"], plan["num_tiles"],
                     plan["group_rows"], tile, cfg.impl)
-            y = product(act(product(rows, blk["experts"][first])),
-                        blk["experts"]["down"])
+            # never the kernels around lax.ragged_dot, as the rows' movement
+            y = product(routed_act(
+                product(rows, blk["experts"][first]), plan["num_tiles"],
+                tile, cfg.act, cfg.impl if move != "ragged" else move),
+                blk["experts"]["down"])
         with jax.named_scope("bps.moe.route"):
             out = _combine(y, weights, plan, tile, move)
         if "shared" in blk:

@@ -8,10 +8,10 @@ belongs to ONE group: ``tile_group[t]`` names it. Only the first
 worst routing (no row is ever dropped) and is never touched: a grid step
 past ``num_tiles`` skips its body, and its index maps stay on the last
 tile that ran, so it moves nothing either. Device time of these kernels
-follows the rows that are there, not the buffer, and so does the movement
-of rows to and from the buffer (``ops/routed_rows.py``); of what
-surrounds them in ``routed_ffn`` the experts' function between the products
-still walks the whole buffer.
+follows the rows that are there, not the buffer, and so do the movement
+of rows to and from the buffer (``ops/routed_rows.py``) and the experts'
+function between the products (``ops/routed_act.py``); of what surrounds
+them in ``routed_ffn`` only the plan still walks the whole buffer.
 
   - ``bps_gmm``     out[r] = lhs[r] @ w[group(r)]          [rows, n]
   - ``bps_gmm_dx``  out[r] = lhs[r] @ w[group(r)]^T        [rows, k]

@@ -241,6 +241,31 @@ def test_grouped_products_off_the_lane_tile_compile_for_v5e(compile_for_chip,
         assert kernel in text
 
 
+@pytest.mark.parametrize("act,tiles,m", [("gated_silu", 272, 1024),
+                                         ("relu2", 200, 1856)])
+def test_the_experts_function_compiles_for_v5e(compile_for_chip, act, tiles,
+                                               m):
+    """``bps_moe_act_fwd`` and ``bps_moe_act_bwd`` at the two routed
+    cells' shapes in bf16: a worst-case buffer of 272 tiles of 512 rows
+    with gate and up in a row of 2,048, and 200 tiles at a width of 1,856
+    (14.5 lane tiles: the last chunk of lanes is half a tile)."""
+    from byteps_tpu.ops import routed_act as ra
+
+    tile, width = 512, m * ra.FORMS[act][1]
+    assert ra.supported((tiles * tile, width), tile, act)
+
+    def both(h, da, num):
+        a, pull = jax.vjp(lambda h: ra.routed_act(h, num, tile, act, "gmm"),
+                          h)
+        return a, pull(da)[0]
+
+    text = compile_for_chip(
+        both, ((tiles * tile, width), jnp.bfloat16),
+        ((tiles * tile, m), jnp.bfloat16), ((1,), jnp.int32))
+    for kernel in ("bps_moe_act_fwd", "bps_moe_act_bwd"):
+        assert kernel in text
+
+
 def test_state_space_scan_compiles_for_v5e(compile_for_chip):
     """``bps_ssd_fwd`` (with and without the states it saves) and
     ``bps_ssd_bwd`` at Nemotron 3 Nano's shape: 2 x 8,192 positions, 64

@@ -1,5 +1,6 @@
 """The routed feed-forward layer of one chip's share (``moe.routed_ffn``),
-its grouped products (``ops/grouped_matmul.py``) and the kernels that move
+its grouped products (``ops/grouped_matmul.py``), the experts' function
+between them (``ops/routed_act.py``) and the kernels that move
 its rows (``ops/routed_rows.py``): against a dense
 masked product over the held experts; no row dropped under an imbalance
 forced by a biased router; and THE TEST THAT TIES THE SHARE TO THE MODEL:
@@ -12,7 +13,9 @@ import numpy as np
 import pytest
 
 from byteps_tpu.models import moe
+from byteps_tpu.common import setup_record
 from byteps_tpu.ops import grouped_matmul as gm
+from byteps_tpu.ops import routed_act as ra
 from byteps_tpu.ops import routed_rows as rr
 
 from benchmark.reference import afmoe_share as ref
@@ -20,27 +23,28 @@ from benchmark.reference import afmoe_share as ref
 T, H, M, E, K = 96, 128, 128, 16, 4
 
 
-def _layer(seed, held, shared=True):
+def _layer(seed, held, shared=True, act="gated_silu"):
     rng = np.random.RandomState(seed)
     normal = lambda *s: jnp.asarray(rng.randn(*s) * 0.05, jnp.float32)  # noqa: E731
+    first, wide = moe.ACTS[act][1], M * ra.FORMS[act][1]
     blk = {"router": normal(H, E),
-           "experts": {"gate_up": normal(len(held), H, 2 * M),
+           "experts": {first: normal(len(held), H, wide),
                        "down": normal(len(held), M, H)}}
     if shared:
-        blk["shared"] = {"gate_up": normal(H, 2 * M), "down": normal(M, H)}
+        blk["shared"] = {first: normal(H, wide), "down": normal(M, H)}
     return blk, jnp.asarray(rng.randn(T, H), jnp.float32)
 
 
 def _dense(f, blk, cfg):
     """Every held expert over every row, masked by the router's choice."""
+    act, first = moe.ACTS[cfg.act]
     w, chosen = moe.route(f, blk["router"], cfg)
-    out = (moe.gated_silu(f @ blk["shared"]["gate_up"])
+    out = (act(f @ blk["shared"][first])
            @ blk["shared"]["down"]) if "shared" in blk else 0.0
     for g, e in enumerate(cfg.held):
         mine = jnp.where(chosen == e, w, 0.0).sum(-1)
         out = out + mine[:, None] * (
-            moe.gated_silu(f @ blk["experts"]["gate_up"][g])
-            @ blk["experts"]["down"][g])
+            act(f @ blk["experts"][first][g]) @ blk["experts"]["down"][g])
     return out
 
 
@@ -198,6 +202,82 @@ def test_grouped_products_carry_their_names():
         "bps_gmm", "bps_gmm_dx", "bps_gmm_dw"}
 
 
+# ---- the experts' function (ops/routed_act.py): bps_moe_act_fwd, _bwd
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("act,m", [("gated_silu", 1024), ("relu2", 1856)])
+def test_the_experts_function_skips_what_lies_behind_the_rows(act, m, dtype):
+    """Both kernels in the interpreter at the two cells' widths (2048 ->
+    1024, and 1856 = 14.5 lane tiles) against ``jax.vjp`` of the XLA
+    function in float32, on the live rows; the tiles past ``num_tiles``
+    hold NaN before the call: none reaches a live row, and nothing is
+    written behind the rows (the interpreter hands out NaN there)."""
+    tile, tiles, live = 128, 5, 3
+    used = live * tile
+    fn, per = ra.FORMS[act]
+    assert ra.supported((tiles * tile, per * m), tile, act)
+    rng = np.random.RandomState(11)
+    h = np.full((tiles * tile, per * m), np.nan, np.float32)
+    da = np.full((tiles * tile, m), np.nan, np.float32)
+    h[:used], da[:used] = rng.randn(used, per * m), rng.randn(used, m)
+    h, da = jnp.asarray(h, dtype), jnp.asarray(da, dtype)
+    num = jnp.asarray([live], jnp.int32)
+    got, pull = jax.vjp(
+        lambda h: ra.routed_act(h, num, tile, act, "gmm_interpret"), h)
+    d_got, = pull(da)
+    want, pull = jax.vjp(fn, h[:used].astype(jnp.float32))
+    d_want, = pull(da[:used].astype(jnp.float32))
+    assert got.dtype == dtype and d_got.dtype == dtype
+    assert got.shape == da.shape and d_got.shape == h.shape
+    # one rounding to the operands' dtype on the way out
+    tol = 1e-6 if dtype == jnp.float32 else 2.0 ** -8
+    for ours, theirs in ((got, want), (d_got, d_want)):
+        ours = np.asarray(ours, np.float32)
+        np.testing.assert_allclose(ours[:used], np.asarray(theirs), rtol=tol,
+                                   atol=tol)
+        assert np.isnan(ours[used:]).all()
+
+
+def test_the_experts_function_carries_its_names():
+    """``bps_moe_act_fwd`` and ``bps_moe_act_bwd``, not ``bps_gmm*``: the
+    benchmark reads every ``bps_gmm*`` event as a grouped product."""
+    import re
+    num = jnp.ones((1,), jnp.int32)
+    for act, width in (("gated_silu", 256), ("relu2", 192)):
+        jaxpr = str(jax.make_jaxpr(jax.grad(lambda h: ra.routed_act(
+            h, num, 128, act, "gmm").sum()))(jnp.zeros((256, width))))
+        names = set(re.findall(r"name=(bps_\w+)", jaxpr))
+        assert names == {"bps_moe_act_fwd", "bps_moe_act_bwd"}
+        assert not any(n.startswith("bps_gmm") for n in names)
+
+
+@pytest.mark.parametrize("m,took,fallbacks", [(128, "kernels", 0),
+                                              (192, "xla", 1)])
+def test_the_layers_choice_of_function_is_recorded(monkeypatch, m, took,
+                                                   fallbacks):
+    """On a TPU the layer's trace notes ``routed_act`` once: the kernels
+    where gate and up split on a lane tile's border, and at a width of
+    1.5 lane tiles XLA's function, which reads as ONE fall-back (the
+    grouped products and the rows' movement take that width)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(setup_record, "_warned", set())
+    held = (0, 1, 2, 3)
+    cfg = moe.RoutedConfig(E, held, K, 2.8, row_tile=128)
+    blk, f = _layer(12, held, shared=False)
+    blk["experts"] = {"gate_up": jnp.zeros((len(held), H, 2 * m)),
+                      "down": jnp.zeros((len(held), m, H))}
+    rec = setup_record.open_record()
+    try:
+        jax.eval_shape(lambda f, blk: moe.routed_ffn(f, blk, cfg), f, blk)
+    finally:
+        setup_record.close(rec)
+    assert rec["choices"]["routed_act", took] == 1
+    assert rec["choices"]["grouped_matmul", "gmm"] == 2
+    assert [key[:2] for key in rec["fallbacks"]] == [
+        ("routed_act", "xla")] * fallbacks
+
+
 @pytest.mark.parametrize("impl", ["ragged", "gmm_interpret"])
 def test_a_balanced_choice_gives_every_expert_its_share_of_the_rows(impl):
     """A router whose outputs sit far apart (experts 1 and 2 chosen by
@@ -238,26 +318,30 @@ def test_a_balanced_choice_gives_every_expert_its_share_of_the_rows(impl):
 
 # ---- the movement of rows (ops/routed_rows.py): bps_moe_take, bps_moe_combine
 
-@pytest.mark.parametrize("impl", ["ragged", "gmm_interpret"])
-@pytest.mark.parametrize("routing", ["worst", "none"])
-def test_layer_and_gradients_under_worst_and_under_no_routing(routing, impl):
-    """WORST: the router has as many outputs as a token chooses and all
-    are held, so every token sends all ``k`` rows here. NONE: no token
-    chooses a held expert: the layer is its shared expert, the experts'
-    gradients are zero, nothing is NaN."""
+def _worst_or_no_routing(routing, seed, impl, act="gated_silu"):
+    """(cfg, blk, f). WORST: the router has as many outputs as a token
+    chooses and all are held, so every token sends all ``k`` rows here.
+    NONE: no token chooses a held expert."""
     if routing == "worst":
         e, held = K, tuple(range(K))
     else:
         e, held = E, (3, 12)
-    cfg = moe.RoutedConfig(e, held, K, 2.8, row_tile=128, impl=impl)
-    blk, f = _layer(6, held)
+    cfg = moe.RoutedConfig(e, held, K, 2.8, row_tile=128, impl=impl, act=act)
+    blk, f = _layer(seed, held, act=act)
     blk["router"] = blk["router"][:, :e]
     if routing == "none":       # the held outputs are every token's lowest
         f = jnp.abs(f)
         blk["router"] = blk["router"].at[:, jnp.asarray(held)].add(-2.0)
     plan = moe.plan_rows(moe.route(f, blk["router"], cfg)[1], cfg)
     assert int(plan["counts"].sum()) == (T * K if routing == "worst" else 0)
+    # there are tiles behind the rows in both states
+    assert int(plan["num_tiles"][0]) * 128 < plan["row_pair"].shape[0]
+    return cfg, blk, f
 
+
+def _finite_and_the_dense_products(cfg, blk, f):
+    """Loss and every gradient of the layer: finite, and those of the
+    dense masked product. Returns the layer's gradients."""
     def loss(fn):
         return lambda f, blk: jnp.sum(jnp.sin(fn(f, blk, cfg)))
 
@@ -269,6 +353,16 @@ def test_layer_and_gradients_under_worst_and_under_no_routing(routing, impl):
         assert np.isfinite(np.asarray(x)).all()
         np.testing.assert_allclose(np.asarray(x), np.asarray(y), rtol=1e-4,
                                    atol=1e-6)
+    return ga
+
+
+@pytest.mark.parametrize("impl", ["ragged", "gmm_interpret"])
+@pytest.mark.parametrize("routing", ["worst", "none"])
+def test_layer_and_gradients_under_worst_and_under_no_routing(routing, impl):
+    """Under no routing the layer is its shared expert, the experts'
+    gradients are zero, nothing is NaN."""
+    cfg, blk, f = _worst_or_no_routing(routing, 6, impl)
+    ga = _finite_and_the_dense_products(cfg, blk, f)
     if routing == "none":
         shared = moe.gated_silu(f @ blk["shared"]["gate_up"]) @ blk[
             "shared"]["down"]
@@ -277,6 +371,29 @@ def test_layer_and_gradients_under_worst_and_under_no_routing(routing, impl):
             rtol=1e-6, atol=1e-7)
         for leaf in jax.tree_util.tree_leaves(ga[1]["experts"]):
             assert not np.asarray(leaf).any()
+
+
+@pytest.mark.parametrize("act", sorted(moe.ACTS))
+@pytest.mark.parametrize("routing", ["worst", "none"])
+def test_nan_behind_the_rows_reaches_neither_loss_nor_gradient(
+        monkeypatch, routing, act):
+    """The same two states with every kernel of the layer in the
+    interpreter and the buffer POISONED behind the live tiles (PR 30:
+    worst routing is a real state and stale rows may hold NaN): the
+    first product's dead rows are NaN when the experts' function gets
+    them, its own are NaN when the down product does, and the loss and
+    every gradient are the dense product's, under both functions."""
+    def poisoned(fn):
+        def call(*args):
+            out = fn(*args)
+            live = args[3][0] * args[4]     # num_tiles * tile
+            return jnp.where(jnp.arange(out.shape[0])[:, None] < live, out,
+                             jnp.nan)
+        return call
+
+    monkeypatch.setattr(gm, "_gmm", poisoned(gm._gmm))
+    _finite_and_the_dense_products(
+        *_worst_or_no_routing(routing, 13, "gmm_interpret", act))
 
 
 def _plan_with_a_run_that_ends_on_a_tile(tile):
@@ -410,3 +527,37 @@ def test_with_the_kernels_no_gather_walks_the_buffer_or_every_pair():
         "bps_gmm", "bps_gmm_dx", "bps_gmm_dw"}
     # the test sees them: two forward and three backward (none recomputed)
     assert len(wide_gathers("ragged")[0]) == 5
+
+
+def test_with_the_kernels_nothing_elementwise_walks_the_buffer():
+    """The layer and its gradients traced with the kernels, under both
+    functions: no equation outside a kernel makes a floating
+    ``[buffer, .]`` array (with ``ragged`` the function, its derivative
+    and the cotangents' pad-and-add do: the test sees them)."""
+    tokens, hidden, held = 256, 1024, (0, 1, 2, 3)
+    inside = ("pallas_call", "pjit", "jit", "custom_vjp_call",
+              "custom_vjp_call_jaxpr", "custom_jvp_call")
+
+    def walkers(impl, act):
+        cfg = moe.RoutedConfig(E, held, K, 2.8, row_tile=128, impl=impl,
+                               act=act)
+        first, wide = moe.ACTS[act][1], M * ra.FORMS[act][1]
+        blk = {"router": jnp.zeros((hidden, E)),
+               "experts": {first: jnp.zeros((len(held), hidden, wide)),
+                           "down": jnp.zeros((len(held), M, hidden))}}
+        f = jnp.zeros((tokens, hidden))
+        buffer = moe.plan_rows(jnp.zeros((tokens, K), jnp.int32),
+                               cfg)["row_pair"].shape[0]
+        traced = jax.make_jaxpr(jax.grad(
+            lambda f, blk: moe.routed_ffn(f, blk, cfg).sum(), (0, 1)))(f, blk)
+        return [eqn for eqn in _equations(traced.jaxpr)
+                if eqn.primitive.name not in inside
+                for out in eqn.outvars
+                if getattr(out.aval, "ndim", 0) == 2
+                and out.aval.shape[0] == buffer
+                and jnp.issubdtype(out.aval.dtype, jnp.floating)]
+
+    for act in sorted(moe.ACTS):
+        found = walkers("gmm", act)
+        assert not found, found
+        assert len(walkers("ragged", act)) >= 4

@@ -200,6 +200,13 @@ def _call_resolve(hidden):
     resolve("auto", 512, hidden, 256, 4, 128)
 
 
+def _call_routed_act(width):
+    from byteps_tpu.ops.routed_act import routed_act
+    jax.eval_shape(lambda h: routed_act(h, jnp.ones((1,), jnp.int32), 128,
+                                        "gated_silu"),
+                   jnp.zeros((256, 2 * width), jnp.bfloat16))
+
+
 def _call_embed_grad(hidden):
     from byteps_tpu.models.transformer import embed_grad
     jax.eval_shape(lambda ids, ct: embed_grad(ids, ct, 1000),
@@ -233,6 +240,8 @@ CHOICES = [
     ("grouped_matmul", lambda: _call_grouped_matmul(100), "ragged", True),
     ("routed_rows", lambda: _call_resolve(256), "gmm", False),
     ("routed_rows", lambda: _call_resolve(100), "ragged", True),
+    ("routed_act", lambda: _call_routed_act(128), "kernels", False),
+    ("routed_act", lambda: _call_routed_act(192), "xla", True),
     ("embed_bwd", lambda: _call_embed_grad(128), "kernels", False),
     ("embed_bwd", lambda: _call_embed_grad(96), "xla", True),
     ("exchange", lambda: _call_exchange(None), "leaves", False),
