@@ -108,22 +108,30 @@ def test_flash_fwd_bwd_compiles_for_v5e(compile_for_chip, shape, causal,
                                                   not lane_dense), line
 
 
-@pytest.mark.parametrize("seq,window", [(8192, 2048), (8192, None),
-                                        (512, 128)],
-                         ids=["band_8k", "triangle_8k", "one_block_band"])
-def test_flash_grouped_window_compiles_for_v5e(compile_for_chip, seq, window):
-    """One chip's share of a grouped-kv decoder: 8 query heads of 128 on
-    one kv head, a causal band or the whole triangle at 8k (the online
-    forward, the split backward), and the one-block pair at 512."""
+@pytest.mark.parametrize("seq,heads,kv_heads,window", [
+    (8192, 8, 1, 2048), (8192, 8, 1, None), (8192, 32, 2, None),
+    (512, 8, 1, 128)],
+    ids=["band_8k", "triangle_8k", "triangle_8k_fold16", "one_block_band"])
+def test_flash_grouped_window_compiles_for_v5e(compile_for_chip, seq, heads,
+                                               kv_heads, window):
+    """The grouped-kv decoders' calls at width 128, at the blocks the rule
+    gives them (1024 x 1024 at 8k: ``_default_block``), forward and
+    backward: Trinity's band layers and its full layer (8 query heads on
+    one kv head), Nemotron's triangle (32 on 2: a fold of 16) through the
+    online forward and the split backward with the mask on the edge
+    blocks alone and the held index maps, and the one-block pair at 512."""
     from byteps_tpu.ops.flash_attention import flash_attention
 
     def loss(q, k, v):
         return (flash_attention(q, k, v, True, window=window)
                 .astype(jnp.float32) ** 2).sum()
 
-    compile_for_chip(jax.value_and_grad(loss, argnums=(0, 1, 2)),
-                     ((2, seq, 8, 128), jnp.bfloat16),
-                     *[((2, seq, 1, 128), jnp.bfloat16)] * 2)
+    text = compile_for_chip(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                            ((2, seq, heads, 128), jnp.bfloat16),
+                            *[((2, seq, kv_heads, 128), jnp.bfloat16)] * 2)
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "bps_flash" in line]
+    assert len(calls) == (3 if seq > 1024 else 2)
 
 
 @pytest.mark.parametrize("seq,kernels", [(8192, 3), (1024, 2)],

@@ -481,21 +481,54 @@ def test_single_block_forward_matches_online(s, causal, blocks):
     (1024, 1024, True, False, True, (1024, 1024)),   # whole q too: triangle
     (1024, 1024, False, False, True, (512, 1024)),
     (2048, 1024, False, False, True, (512, 1024)),   # cross: kv whole
-    (2048, 2048, True, False, True, (512, 512)),     # past _WHOLE_KV
+    (2048, 2048, True, False, True, (1024, 1024)),   # past _WHOLE_KV
     (1024, 1024, True, True, True, (512, 512)),      # bias / rel_table
     (1024, 1024, True, False, False, (512, 512)),    # the backward
+    (8192, 8192, True, False, True, (1024, 1024)),   # the 8k cells' calls
+    (8192, 8192, True, False, False, (1024, 1024)),
+    (8192, 8192, True, {"window": 2048}, True, (1024, 1024)),
+    (8192, 8192, True, {"window": 2048}, False, (1024, 1024)),
+    (8192, 8192, True, {"kv_heads": 1}, True, (1024, 1024)),
+    (8192, 8192, True, {"kv_heads": 1}, False, (1024, 1024)),
+    (2048, 2048, True, False, False, (1024, 1024)),
+    (4096, 4096, False, False, True, (1024, 1024)),  # no mask: the same
+    (2048, 2048, True, True, True, (512, 512)),      # biased: not measured
+    (1536, 1536, True, False, True, (512, 512)),     # 1024 does not divide
 ], ids=["s512", "s128", "s1024_causal", "s1024", "cross", "s2048",
-        "biased", "backward"])
+        "biased", "backward", "s8192", "s8192_backward", "s8192_band",
+        "s8192_band_backward", "s8192_grouped", "s8192_grouped_backward",
+        "s2048_backward", "s4096_full", "s2048_biased", "s1536"])
 def test_default_blocks(sq, sk, causal, extra, forward, want):
-    """No block named: 512, and in a plain call's forward the whole kv
-    sequence up to 1024 keys (with the whole q when causal); a named
+    """No block named: in a plain call's forward the whole kv sequence
+    up to 1024 keys (with the whole q when causal); past them 1024 x
+    1024, forward and backward, a band and grouped kv heads like the
+    triangle (``_default_block``: what the chip said, PR 45); 512 in the
+    backward of at most 1024 keys and with bias / rel_table; a named
     block is taken as given."""
-    from byteps_tpu.ops.flash_attention import _resolve
+    from byteps_tpu.ops.flash_attention import _default_block, _resolve
+    shown = extra if isinstance(extra, dict) else {}
+    plain = extra is False or bool(shown)
     q = jnp.zeros((1, sq, 2, 64), jnp.bfloat16)
-    k = jnp.zeros((1, sk, 2, 64), jnp.bfloat16)
-    whole = forward and not extra
-    assert _resolve(q, k, None, None, None, whole, causal)[1:] == want
-    assert _resolve(q, k, None, 128, 128, whole, causal)[1:] == (128, 128)
+    k = jnp.zeros((1, sk, shown.get("kv_heads", 2), 64), jnp.bfloat16)
+    block = _default_block(sk, 64, 64, plain)
+    assert block == _default_block(sk, 128, 128, plain)   # widths alike
+    whole = forward and plain
+    assert _resolve(q, k, None, None, None, whole, causal, block)[1:] == want
+    assert _resolve(q, k, None, 128, 128, whole, causal, block)[1:] == (
+        128, 128)
+    if shown:       # through the call: the blocks of its three kernels
+        def loss(q, k):
+            return flash_attention(q, k, k, causal, window=shown.get(
+                "window")).astype(jnp.float32).sum()
+        calls = equations(jax.make_jaxpr(jax.grad(loss, (0, 1)))(q, k),
+                          "pallas_call")
+        group = 2 // k.shape[2]
+        grids = {str(e.params["name"]): e.params["grid_mapping"].grid[2:]
+                 for e in calls}
+        steps = 3 if "window" in shown else 8    # 2,048 keys: 3 of 1024
+        assert grids == {"bps_flash_fwd": (8 * group, steps),
+                         "bps_flash_bwd_dq": (8 * group, steps),
+                         "bps_flash_bwd_dkv": (8, steps * group)}
 
 
 def test_default_forward_is_single_block_at_1024():

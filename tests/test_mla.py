@@ -262,10 +262,14 @@ def test_supported_and_the_dispatcher_tell_the_truth_about_two_widths():
 
 
 def test_a_call_with_two_widths_takes_blocks_of_1024_by_default():
-    """Measured on the chip at 192 over 128 (``_default_block``); the
-    equal-width calls keep 512, and a named block is kept as named."""
-    assert fa._default_block(128, 128) == fa._default_block(64, 64) == 512
-    assert fa._default_block(192, 128) == 1024
+    """Measured on the chip at 192 over 128 (``_default_block``), at any
+    length; an equal-width call takes them past ``_WHOLE_KV`` keys alone
+    (PR 45), and a named block is kept as named."""
+    assert fa._default_block(1024, 128, 128) == 512
+    assert fa._default_block(1024, 64, 64) == 512
+    assert fa._default_block(1024, 192, 128) == 1024
+    assert fa._default_block(8192, 192, 128) == fa._default_block(
+        8192, 128, 128) == 1024
     q = jax.ShapeDtypeStruct((2, 8192, 32, 192), jnp.bfloat16)
     assert fa._resolve(q, q, None, None, None, block=1024) == (
         192 ** -0.5, 1024, 1024)

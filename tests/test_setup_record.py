@@ -168,6 +168,13 @@ def _call_attention(shape):
     jax.eval_shape(lambda q: attention(q, q, q), q)
 
 
+def _call_flash(seq, **kwargs):
+    """A head-major call of the kernels themselves (width 128)."""
+    from byteps_tpu.ops.flash_attention import flash_attention
+    q = jnp.zeros((1, seq, 2, 128), jnp.bfloat16)
+    jax.eval_shape(lambda q: flash_attention(q, q, q, True, **kwargs), q)
+
+
 def _call_ssd(packed):
     from byteps_tpu.ops import ssd as S
     bsz, s, heads, p, groups, n = (1, 256, 4, 64, 2, 128) if packed != "odd" \
@@ -233,6 +240,10 @@ def _flat_psum(x, axes):
 CHOICES = [
     ("attention", lambda: _call_attention((1, 128, 2, 64)), "flash", False),
     ("attention", lambda: _call_attention((1, 65, 2, 8)), "xla", True),
+    ("flash_blocks", lambda: _call_flash(2048), "1024x1024", False),
+    ("flash_blocks", lambda: _call_flash(512), "512x512", False),
+    ("flash_blocks", lambda: _call_flash(2048, block_q=256), "256x1024",
+     False),
     ("ssd", lambda: _call_ssd("plain"), "kernels", False),
     ("ssd", lambda: _call_ssd("packed"), "kernels_packed", False),
     ("ssd", lambda: _call_ssd("odd"), "xla", True),
