@@ -45,7 +45,7 @@ from jax.sharding import PartitionSpec as P
 from ..ops.grouped_matmul import grouped_matmul
 from ..ops.routed_act import gated_silu, relu2, routed_act
 from ..ops.routed_rows import (combine_rows, resolve as resolve_rows,
-                               take_rows, take_xla, tile_bounds)
+                               take_rows, tile_bounds)
 from .transformer import (TransformerConfig, _attention, _layernorm,
                           embed_lookup)
 
@@ -292,13 +292,15 @@ ACTS = {"gated_silu": (gated_silu, "gate_up"), "relu2": (relu2, "up")}
 
 
 # the name a ``jax.checkpoint`` policy keeps the routed layer's plan
-# under: every int32 array of ``plan_rows``, the choice of experts it is
-# made from and the chosen scores (``route``). They are a few MB a layer
-# and making them (top-k, a sort, searchsorted, gathers of int32 over the
-# whole buffer and of the scores by pair, a millisecond each at 131,072
-# pairs) is dear: ``decoder.apply``'s checkpoint saves them, so the
-# backward's recompute holds none of it. The int32 arrays carry no
-# gradient; the scores' flows through the name as through an identity.
+# under: every int32 array of ``plan_rows`` and the rows' weights beside
+# them, the choice of experts it is made from and the chosen scores
+# (``route``). They are a few MB a layer, and making them (top-k, a sort of
+# the 131,072 chosen pairs with its payloads, compares against the held
+# experts and every output, a running count, a slice of the sorted pairs a
+# row tile: under a millisecond a layer) is not worth a second time:
+# ``decoder.apply``'s checkpoint saves them, so the backward's recompute
+# holds none of it. The int32 arrays carry no gradient; the scores' flows
+# through the name as through an identity.
 PLAN_NAME = "moe_plan"
 
 
@@ -327,14 +329,23 @@ def route(f, router_w, cfg: RoutedConfig, sequences: int = 1):
         centred *= jax.lax.rsqrt(
             jnp.mean(centred * centred, 1, keepdims=True) + 1e-12)
         ranked = centred.reshape(logits.shape)
-    # the scores are a gather by the NAMED choice: neither is made again
+    # the scores are a choice by the NAMED experts: neither is made again.
+    # A compare against every output and a sum of which one term is not
+    # zero: ``take_along_axis``'s values to the bit, elementwise where that
+    # is a gather of single floats and its transpose a scatter-add. Tokens
+    # along the lanes, so that the sum over the outputs adds registers
     experts = checkpoint_name(jax.lax.top_k(ranked, cfg.top_k)[1], PLAN_NAME)
-    top = checkpoint_name(jnp.take_along_axis(scores, experts, axis=-1),
-                          PLAN_NAME)
+    chosen = experts.T[:, None, :] == jnp.arange(
+        cfg.num_experts, dtype=experts.dtype)[None, :, None]   # [k, E, T]
+    top = checkpoint_name(
+        jnp.where(chosen, scores.T[None], 0.0).sum(1).T, PLAN_NAME)
+    # XLA would fold that sum and the next into one over [k, outputs] and
+    # add the k scores in another order: an ulp of every weight
+    top = jax.lax.optimization_barrier(top)
     return cfg.route_scale * top / top.sum(-1, keepdims=True), experts
 
 
-def plan_rows(experts, cfg: RoutedConfig):
+def plan_rows(experts, cfg: RoutedConfig, weights=None):
     """Where each chosen (token, expert) pair's row goes. The pairs whose
     expert is held are sorted by expert; expert g's run starts on a tile
     border and is padded to whole tiles (at least one, so that every
@@ -348,38 +359,68 @@ def plan_rows(experts, cfg: RoutedConfig):
     ``grouped_matmul`` reads; ``counts`` [held]: rows routed to each;
     ``lo``, ``hi``, ``lanes``, ``live``: where a tile of tokens has its
     rows in each expert's run (``routed_rows.tile_bounds``: what
-    ``bps_moe_combine`` reads). Every one is named ``PLAN_NAME``."""
+    ``bps_moe_combine`` reads). Every one is named ``PLAN_NAME``.
+
+    Given the pairs' ``weights`` [T, k] as well, ``row_weight`` [buffer]
+    fp32 beside them: the weight of the row's pair, zero in a pad row (what
+    the combine's backward scales a row's cotangent by); a constant, no
+    gradient flows to ``weights`` through it.
+
+    What it costs is a sort of the pairs and passes over them, whatever
+    the routing: a pair's row is its expert's run and a running count of
+    that expert's pairs before it; an expert's run in the buffer is a
+    CONTIGUOUS stretch of the pairs sorted by expert, so a row tile is one
+    slice of them; every table read by expert has ``held`` entries and is
+    read by compares. Nothing takes one element an index over the buffer
+    or over the pairs."""
     t, k = experts.shape
     held, tile = len(cfg.held), cfg.row_tile
     pairs = t * k
     tiles = -(-t * cfg.rows // tile) + held
-    slot = jnp.full((cfg.num_experts,), held, jnp.int32).at[
-        jnp.asarray(cfg.held)].set(jnp.arange(held, dtype=jnp.int32))
-    local = slot[experts.reshape(-1)]                   # [pairs], held: none
-    order = jnp.argsort(local, stable=True).astype(jnp.int32)
-    rank = jnp.zeros((pairs,), jnp.int32).at[order].set(
-        jnp.arange(pairs, dtype=jnp.int32))
-    counts = (local[:, None] == jnp.arange(held)).sum(0, dtype=jnp.int32)
+    mine = experts.reshape(-1) == jnp.asarray(
+        cfg.held, experts.dtype)[:, None]               # [held, pairs]
+    to_held = mine.any(0)
+    local = jnp.where(to_held, jnp.argmax(mine, 0).astype(jnp.int32), held)
+    counts = mine.sum(1, dtype=jnp.int32)
     start = jnp.cumsum(counts) - counts                 # in the sorted pairs
     padded = jnp.maximum(-(-counts // tile), 1) * tile
     run = jnp.cumsum(padded) - padded                   # in the buffer
-    g = jnp.minimum(local, held - 1)
-    dest = jnp.where(local < held, run[g] + rank - start[g], tiles * tile)
+    before = jnp.cumsum(mine, 1, dtype=jnp.int32) - mine
+    dest = jnp.where(to_held, jnp.where(mine, run[:, None] + before, 0).sum(0),
+                     tiles * tile)
+    # the pairs by expert, each expert's by pair (a stable sort); where
+    # given, the weights' bits ride along, so that a row tile is ONE slice
+    payload = [jnp.arange(pairs, dtype=jnp.int32)]
+    if weights is not None:
+        payload.append(jax.lax.bitcast_convert_type(jax.lax.stop_gradient(
+            weights).astype(jnp.float32).reshape(-1), jnp.int32))
+    by_expert = jnp.stack(jax.lax.sort(
+        [local, *payload], num_keys=1, is_stable=True)[1:])
+    first = jnp.arange(tiles, dtype=jnp.int32) * tile   # a tile's first row
     tile_group = jnp.clip(jnp.searchsorted(
-        run, jnp.arange(tiles, dtype=jnp.int32) * tile, side="right") - 1,
+        run, first, side="right", method="compare_all") - 1,
         0, held - 1).astype(jnp.int32)
-    row = jnp.arange(tiles * tile, dtype=jnp.int32)
-    rg = tile_group[row // tile]
-    at = row - run[rg]
-    row_pair = jnp.where(at < counts[rg],
-                         order[jnp.clip(start[rg] + at, 0, pairs - 1)], pairs)
-    return checkpoint_name(
-        {"dest": dest.reshape(t, k), "row_pair": row_pair,
-         "row_token": jnp.where(row_pair < pairs, row_pair // k, t),
-         "tile_group": tile_group,
-         "num_tiles": (padded.sum() // tile).astype(jnp.int32)[None],
-         "group_rows": padded, "counts": counts,
-         **tile_bounds(local.reshape(t, k), padded)}, PLAN_NAME)
+    # tile i of expert g holds the sorted pairs from start[g] + i * tile -
+    # run[g] on, as far as the expert has rows ([tiles]-sized tables). The
+    # sorted pairs are padded by a tile: a slice that began inside their
+    # last tile would otherwise be moved back to fit
+    inside = first - run[tile_group]
+    there = (inside[:, None] + jnp.arange(tile, dtype=jnp.int32)
+             < counts[tile_group][:, None])             # [tiles, tile]
+    by_expert = jnp.pad(by_expert, ((0, 0), (0, tile)))
+    sliced = jax.vmap(lambda at: jax.lax.dynamic_slice(
+        by_expert, (0, at), (len(payload), tile)))(start[tile_group] + inside)
+    row_pair = jnp.where(there, sliced[:, 0], pairs).reshape(-1)
+    plan = {"dest": dest.reshape(t, k), "row_pair": row_pair,
+            "row_token": jnp.where(row_pair < pairs, row_pair // k, t),
+            "tile_group": tile_group,
+            "num_tiles": (padded.sum() // tile).astype(jnp.int32)[None],
+            "group_rows": padded, "counts": counts,
+            **tile_bounds(local.reshape(t, k), padded)}
+    if weights is not None:
+        plan["row_weight"] = jnp.where(there, jax.lax.bitcast_convert_type(
+            sliced[:, 1], jnp.float32), 0.0).reshape(-1)
+    return checkpoint_name(plan, PLAN_NAME)
 
 
 # Dispatch and combine are each other's transposes, and both are written
@@ -411,7 +452,9 @@ _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _combine(y, weights, plan, tile, move):
     """[T, h]: sum over a token's pairs of weight * the pair's row of
-    ``y``; a pair with no row here (its expert is not held) adds zero."""
+    ``y``; a pair with no row here (its expert is not held) adds zero.
+    ``plan``: ``plan_rows`` of the choice WITH these weights (the backward
+    reads the rows' weights there)."""
     return combine_rows(y, plan["dest"], weights, plan, impl=move)
 
 
@@ -421,9 +464,8 @@ def _combine_fwd(y, weights, plan, tile, move):
 
 def _combine_bwd(tile, move, res, d_out):
     y, weights, plan = res
-    row_weight = take_xla(weights.reshape(-1), plan["row_pair"])
     d_y = take_rows(d_out, plan["row_token"], plan["num_tiles"], tile,
-                    scale=row_weight, impl=move)
+                    scale=plan["row_weight"], impl=move)
     d_w = combine_rows(y, plan["dest"], None, plan, d_out=d_out,
                        impl=move)
     return d_y, d_w.astype(weights.dtype), None
@@ -450,8 +492,9 @@ def routed_ffn(f, blk, cfg: RoutedConfig, sequences: int = 1):
     (to the buffer by live row tile, back by the rows a tile of tokens has
     here: ``ops/routed_rows.py``) take time by the rows routed this step,
     and so does the function between the products, forward and backward
-    (``ops/routed_act.py``); the plan still walks the whole buffer and
-    every chosen pair (PERF.md section 5)."""
+    (``ops/routed_act.py``); the plan costs a sort of the chosen pairs,
+    a few passes over them and a slice of the sorted pairs a row tile,
+    whatever the routing (``plan_rows``; PERF.md section 5)."""
     dt = f.dtype
     tile = cfg.row_tile
     act, first = ACTS[cfg.act]
@@ -459,8 +502,9 @@ def routed_ffn(f, blk, cfg: RoutedConfig, sequences: int = 1):
                         len(cfg.held), tile)
     with jax.named_scope("bps.moe"):
         with jax.named_scope("bps.moe.route"):
-            weights, experts = route(f, blk["router"], cfg, sequences)
-            plan = plan_rows(experts, cfg)
+            with jax.named_scope("bps.moe.route.plan"):
+                weights, experts = route(f, blk["router"], cfg, sequences)
+                plan = plan_rows(experts, cfg, weights)
             rows = _dispatch(f, plan, tile, move)
         with jax.named_scope("bps.moe.experts"):
             def product(lhs, w):

@@ -113,6 +113,128 @@ def test_the_plan_puts_every_expert_on_whole_tiles():
     assert plan["row_pair"].shape[0] >= T * K
 
 
+def _plan_by_hand(experts, weights, held, tile, token_tile):
+    """``plan_rows``'s arrays by a loop over the held experts, in NumPy:
+    expert g's pairs in the pairs' order, its run on the next tile border,
+    padded to whole tiles and to at least one; then, a tile of tokens and
+    an expert, where that tile's pairs lie in the expert's run."""
+    t, k = experts.shape
+    pairs, flat = t * k, experts.reshape(-1)
+    tiles = -(-t * min(k, len(held)) // tile) + len(held)
+    dest = np.full(pairs, tiles * tile, np.int32)
+    row_pair = np.full(tiles * tile, pairs, np.int32)
+    row_weight = np.zeros(tiles * tile, np.float32)
+    tile_group = np.full(tiles, len(held) - 1, np.int32)
+    lo = np.zeros((t // token_tile, len(held)), np.int32)
+    hi = np.zeros_like(lo)
+    counts, group_rows, row = [], [], 0
+    for g, e in enumerate(held):
+        mine = np.flatnonzero(flat == e)
+        padded = max(-(-mine.size // tile), 1) * tile
+        dest[mine] = row + np.arange(mine.size)
+        row_pair[row:row + mine.size] = mine
+        row_weight[row:row + mine.size] = weights.reshape(-1)[mine]
+        tile_group[row // tile:(row + padded) // tile] = g
+        for i in range(t // token_tile):
+            before = (mine < i * token_tile * k).sum()
+            lo[i, g] = row + before
+            hi[i, g] = row + (mine < (i + 1) * token_tile * k).sum()
+        counts.append(mine.size)
+        group_rows.append(padded)
+        row += padded
+    return {"dest": dest.reshape(t, k), "row_pair": row_pair,
+            "row_token": np.where(row_pair < pairs, row_pair // k, t),
+            "tile_group": tile_group, "num_tiles": [row // tile],
+            "group_rows": group_rows, "counts": counts,
+            "lo": lo.reshape(-1), "hi": hi.reshape(-1),
+            "lanes": np.repeat(np.stack([lo, hi], 1), rr._WINDOW, axis=2),
+            "live": [row], "row_weight": row_weight}
+
+
+def _routing(kind, rng, tokens, k, held, tile):
+    """[tokens, k] distinct outputs of 128 a token, by the state's name."""
+    others = np.setdiff1d(np.arange(128), held)
+    if kind == "uniform":
+        return np.stack([rng.permutation(128)[:k] for _ in range(tokens)])
+    if kind == "worst":                 # every pair's expert is held
+        return np.stack([rng.permutation(held)[:k] for _ in range(tokens)])
+    chosen = np.stack([rng.permutation(others)[:k] for _ in range(tokens)])
+    if kind == "one_expert":            # it takes every token, the rest none
+        chosen[:, rng.randint(k)] = held[3]
+    if kind == "tile_border":           # runs of exactly one and two tiles
+        chosen[:tile, 0], chosen[tile:3 * tile, 1] = held[1], held[-1]
+    return chosen                       # "none": no pair's expert is held
+
+
+@pytest.mark.parametrize("routing", ["uniform", "worst", "none", "one_expert",
+                                     "tile_border"])
+@pytest.mark.parametrize("held", [tuple(range(8)), tuple(range(5, 128, 8))],
+                         ids=["first_8", "every_8th_16"])
+@pytest.mark.parametrize("k", [6, 8])
+def test_the_plan_is_the_plan_made_by_hand_over_the_whole_buffer(
+        monkeypatch, k, held, routing):
+    """Every array of ``plan_rows``, pad rows and dead tiles too, and the
+    rows' weights beside them, element for element against a loop over
+    the experts: 6 and 8 choices of 128 outputs, 8 held in a range and 16
+    spread, under uniform routing, the worst (every pair held), none, one
+    expert taking every token, and runs that end on a tile's border."""
+    monkeypatch.setattr(rr, "_TOKEN_TILE", 32)      # three tiles of tokens
+    tile = 16
+    rng = np.random.RandomState(k * len(held))
+    chosen = _routing(routing, rng, T, k, np.asarray(held), tile)
+    weights = rng.rand(T, k).astype(np.float32)
+    cfg = moe.RoutedConfig(128, held, k, row_tile=tile)
+    got = moe.plan_rows(jnp.asarray(chosen, jnp.int32), cfg,
+                        jnp.asarray(weights))
+    want = _plan_by_hand(chosen, weights, held, tile, 32)
+    assert set(got) == set(want)
+    for name, array in want.items():
+        np.testing.assert_array_equal(np.asarray(got[name]), array, name)
+        assert got[name].dtype == (jnp.float32 if name == "row_weight"
+                                   else jnp.int32), name
+    held_pairs = np.isin(chosen, held).sum()
+    assert int(np.sum(want["counts"])) == held_pairs == {
+        "worst": T * k, "none": 0, "one_expert": T}.get(routing, held_pairs)
+    if routing == "tile_border":
+        assert sorted(want["counts"])[-2:] == [tile, 2 * tile]
+    # without the weights: the same int32 arrays and nothing else
+    bare = moe.plan_rows(jnp.asarray(chosen, jnp.int32), cfg)
+    assert set(bare) == set(want) - {"row_weight"}
+    for name, array in bare.items():
+        np.testing.assert_array_equal(np.asarray(array), want[name], name)
+
+
+@pytest.mark.parametrize("balanced", [False, True],
+                         ids=["by_score", "balanced"])
+def test_the_chosen_scores_are_the_gathers_to_the_bit(balanced):
+    """``route`` reads the chosen experts' scores by a compare against
+    every output and a sum with one term that is not zero: the weights and
+    their gradient to the router's weights and to the tokens are those of
+    ``take_along_axis`` (whose transpose is a scatter-add), bit for bit,
+    op by op (no jit: XLA's CPU fusions round a sigmoid they fuse
+    otherwise)."""
+    held = (0, 1, 2, 3)
+    cfg = moe.RoutedConfig(E, held, K, 2.8, balanced=balanced)
+    blk, f = _layer(14, held)
+    ct = jnp.asarray(np.random.RandomState(14).randn(T, K), jnp.float32)
+    experts = moe.route(f, blk["router"], cfg, 2)[1]
+
+    def by_gather(f, w):
+        scores = jax.nn.sigmoid(jnp.dot(f, w, precision="highest"))
+        top = jnp.take_along_axis(scores, experts, axis=-1)
+        return cfg.route_scale * top / top.sum(-1, keepdims=True)
+
+    def ours(f, w):
+        return moe.route(f, w, cfg, 2)[0]
+
+    (a, pull_a), (b, pull_b) = (jax.vjp(fn, f, blk["router"])
+                                for fn in (ours, by_gather))
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for x, y in zip(pull_a(ct), pull_b(ct)):
+        assert np.asarray(x).any()
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
 @pytest.mark.parametrize("balanced", [False, True],
                          ids=["by_score", "balanced"])
 def test_the_shares_of_the_experts_add_up_to_the_uncut_layer(balanced):
@@ -457,7 +579,7 @@ def test_combine_gradients_match_autodiff_of_the_plain_gathers(impl):
     cfg = moe.RoutedConfig(E, held, K, 2.8, row_tile=128)
     blk, f = _layer(9, held)
     weights, chosen = moe.route(f, blk["router"], cfg)
-    plan = moe.plan_rows(chosen, cfg)
+    plan = moe.plan_rows(chosen, cfg, weights)
     live = int(plan["num_tiles"][0]) * 128
     rng = np.random.RandomState(9)
     y = jnp.zeros((plan["row_pair"].shape[0], H)).at[:live].set(
@@ -561,3 +683,75 @@ def test_with_the_kernels_nothing_elementwise_walks_the_buffer():
         found = walkers("gmm", act)
         assert not found, found
         assert len(walkers("ragged", act)) >= 4
+
+
+def _single_element_moves(lowered):
+    """(op, operand shape, index count, location) of every gather and
+    scatter of a lowered program that takes ONE element an index: a gather
+    whose slice sizes are all one, a scatter whose updates have no window."""
+    import math
+    import re
+    from jaxlib.mlir import ir
+    found = []
+
+    def visit(op):
+        if op.name in ("stablehlo.gather", "stablehlo.scatter"):
+            numbers = str(op.attributes[
+                "dimension_numbers" if op.name.endswith("gather")
+                else "scatter_dimension_numbers"])
+            if ("offset_dims" not in numbers
+                    and "update_window_dims" not in numbers):
+                shape = list(ir.RankedTensorType(op.operands[1].type).shape)
+                shape.pop(int(re.search(r"index_vector_dim = (\d+)",
+                                        numbers).group(1)))
+                found.append((op.name, str(op.operands[0].type),
+                              math.prod(shape), str(op.location)))
+        return ir.WalkResult.ADVANCE
+
+    lowered.compiler_ir().operation.walk(visit)
+    return found
+
+
+@pytest.mark.parametrize("impl", ["ragged", "gmm_interpret"])
+def test_nothing_takes_one_element_an_index_over_the_buffer_or_the_pairs(
+        impl):
+    """The lowered gradient of the layer: no gather or scatter whose slice
+    is one element runs over ``buffer`` or ``T * top_k`` indices (the
+    plan's int32 arrays, the chosen scores and the rows' weights are made
+    by sorts, compares and a slice a row tile). The rows' movement under
+    "ragged" (``take_xla``) is no such op: its slice is a whole row. And
+    the plan is still a scope of its own inside the route's."""
+    tokens, hidden, held = 256, 128, (0, 1, 2, 3)
+    cfg = moe.RoutedConfig(E, held, K, 2.8, row_tile=128, impl=impl)
+    blk = {"router": jnp.zeros((hidden, E)),
+           "experts": {"gate_up": jnp.zeros((len(held), hidden, 2 * M)),
+                       "down": jnp.zeros((len(held), M, hidden))}}
+    f = jnp.zeros((tokens, hidden))
+    buffer = moe.plan_rows(jnp.zeros((tokens, K), jnp.int32),
+                           cfg)["row_pair"].shape[0]
+    lowered = jax.jit(jax.grad(
+        lambda f, blk: moe.routed_ffn(f, blk, cfg).sum(), (0, 1))).lower(
+            f, blk)
+    moves = _single_element_moves(lowered)
+    assert not [m for m in moves if m[2] in (buffer, tokens * K)], moves
+    # what is left reads a table of ``held`` entries by row tile
+    assert all(m[2] == buffer // 128 for m in moves), moves
+    assert "bps.moe.route/bps.moe.route.plan/" in lowered.as_text(
+        debug_info=True)
+
+
+def test_the_single_element_check_sees_the_gathers_it_is_there_for():
+    """The same walk over a program that has them: a gather of single
+    int32s by ``buffer`` indices and the scatter that inverts a
+    permutation of the pairs (what ``plan_rows`` did before)."""
+    def before(order, table):
+        rank = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=order.dtype))
+        return table[rank], jnp.take_along_axis(
+            table.reshape(8, -1), rank.reshape(8, -1) % 4, axis=-1)
+
+    moves = _single_element_moves(jax.jit(before).lower(
+        jnp.arange(32, dtype=jnp.int32), jnp.arange(32, dtype=jnp.int32)))
+    assert sorted((m[0], m[2]) for m in moves) == [
+        ("stablehlo.gather", 32), ("stablehlo.gather", 32),
+        ("stablehlo.scatter", 32)]
