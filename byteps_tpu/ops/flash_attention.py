@@ -16,6 +16,15 @@ kernel pair:
   - causal masking by block skipping + an iota mask on diagonal blocks
   - the rows' statistics (lse, delta) cross HBM with the sequence on
     the lane axis, never as [.., s, 1] columns (padded 128x there)
+  - one additive score term, T5's relative-position table, made inside
+    the kernels from block offsets (``rel_table``)
+
+The five kernel bodies (the online and the single-block forward; the dq,
+the dk/dv and the fused backward) get a block's scores from ONE function
+and its mask from ONE (``_scores``, ``_mask``), and the backward bodies
+their probabilities and dL/dS from two more (``_probs``, ``_dscores``):
+a mechanism that changes how scores or the mask are made is an edit
+there, not five.
 
 Layout contract matches the rest of the stack: [batch, seq, heads,
 head_dim] in, same out. Kernels run per (batch, head tile) over a grid
@@ -99,6 +108,30 @@ def _visible(rows, cols, window):
     if window is None:
         return rows >= cols
     return jnp.logical_and(rows >= cols, rows - cols < window)
+
+
+def _positions(shape, axis, first=None):
+    """Positions in the sequence along ``axis`` of a [rows, keys] block
+    that starts at ``first``: (block index, block size), or for the
+    single block's row chunks (block index, block size, rows into the
+    block); None where the block starts at 0 and the body adds nothing
+    (adding a zero is an instruction)."""
+    start = None
+    if first is not None:
+        block, size, *into = first
+        start = block * size
+        if into:
+            start = start + into[0]
+    at = jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+    return at if start is None else start + at
+
+
+def _mask(shape, window, row0=None, col0=None):
+    """What a causal call lets a [rows, keys] block see (``_visible``):
+    the ONE place the kernels make their mask. ``row0`` and ``col0`` say
+    where the block's first row and first key stand (``_positions``)."""
+    return _visible(_positions(shape, 0, row0), _positions(shape, 1, col0),
+                    window)
 
 
 def _fold(x, group):
@@ -254,8 +287,8 @@ def _load_stat(ref, t):
 
 def _bucket_block(qb, kb, bq, bk, bidirectional, nb, maxd):
     from .relpos import relative_position_bucket
-    rows = qb * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    cols = kb * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    rows = _positions((bq, bk), 0, (qb, bq))
+    cols = _positions((bq, bk), 1, (kb, bk))
     return relative_position_bucket(cols - rows, bidirectional, nb, maxd)
 
 
@@ -314,6 +347,58 @@ def _table_grad(ds32, bucket, nb):
     return jnp.pad(g, (0, _DT_PAD[1] - nb))
 
 
+# ---- a block's scores, and what every backward body makes of them ----
+# Written once for the five kernel bodies below, with ``_mask`` above:
+# what stays in a body is what makes it that body (the online softmax's
+# state, the single block's plain softmax over its row chunks, which of
+# dq / dk / dv it accumulates and where it puts them). The backward's
+# share is two functions and not one because the dk/dv and the fused
+# bodies put dv's product between p and dp.
+
+def _scores(q, k, scale, rel_ref=None, bucket=None, head=None):
+    """A block's scores, [rows, keys] float32: q k' * scale and, with a
+    ``rel_table``, T5's relative-position term on top, S = q k' * scale
+    + B, folded in BEFORE the softmax. ``rel_ref`` is the table's ref,
+    ``bucket`` the block's bucket map (``_bucket_block``: shared by the
+    heads) and ``head`` = (ih, ht, t) the head's place (``_rel_row``)."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale
+    if rel_ref is not None:
+        row = _rel_row(rel_ref, *head)
+        s = s + _table_bias(row.astype(jnp.float32), bucket,
+                            rel_ref.shape[1])
+    return s
+
+
+def _probs(s, lse, mask=None):
+    """A backward body's probabilities, recomputed from the scores and the
+    saved log-sum-exp ([rows, 1]); ``mask`` is ``_mask``'s arguments in a
+    causal call, and what it hides is zero."""
+    p = jnp.exp(s - lse)
+    if mask is not None:
+        p = jnp.where(_mask(*mask), p, 0.0)
+    return p
+
+
+def _dscores(p, do, v, delta=None, delta_at=None):
+    """dL/dS of a block, float32: p * (dp - delta) with dp = do v'. The
+    rows' correction ``delta`` ([rows, 1]) as the split bodies load it
+    beside lse; or loaded here, after dp, from a statistic's block
+    ``delta_at`` = (ref, head) (the fused body under a ring caller's
+    GLOBAL delta); or with neither made in place, sum_j p_ij dp_ij, which
+    is right only where the block holds the row's every key (the fused
+    body: see ``_dqkv_fused_kernel``)."""
+    dp = jax.lax.dot_general(
+        do, v, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)                 # [rows, keys]
+    if delta_at is not None:
+        delta = _load_stat(*delta_at)
+    elif delta is None:
+        delta = jnp.sum(p * dp, -1, keepdims=True)
+    return p * (dp - delta)
+
+
 # scoped-VMEM budget for the tile chooser (heuristic: real usage exceeds
 # the estimate by the io double-buffers; 10M of estimate keeps Mosaic's
 # 16M limit safe). 11M admits ht=8 for the d64 fwd — measured NEUTRAL
@@ -366,13 +451,11 @@ def _dense_tile(h: int, nq: int, nk: int, bq: int, bk: int, d: int,
 # --------------------------------------------------------------- forward
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, bq, bk, nk,
-                ht, has_bias=False, rel=None, window=None, group=1, nqh=0):
+                ht, rel=None, window=None, group=1, nqh=0):
     """``nk`` is the kv dimension's length in grid steps; ``nqh`` the q
     blocks a head has (read only where the q axis is folded)."""
-    bias_ref = rel_ref = None
-    if has_bias:
-        bias_ref, o_ref, lse_ref, acc, m_scr, l_scr = rest
-    elif rel is not None:
+    rel_ref = None
+    if rel is not None:
         rel_ref, o_ref, lse_ref, acc, m_scr, l_scr = rest
     else:
         o_ref, lse_ref, acc, m_scr, l_scr = rest
@@ -396,33 +479,17 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, bq, bk, nk,
 
     @pl.when(run)
     def _block():
+        bucket = (None if rel is None else       # shared by the heads
+                  _bucket_block(qb, kb, bq, bk, *rel))
         # ``ht`` heads per program (unrolled): amortizes grid/dispatch
         # overhead — at seq 512 the per-(b,h) program is only ~0.2 GFLOP
-        if rel is not None:
-            bidirectional, nb, maxd = rel
-            bucket = _bucket_block(qb, kb, bq, bk, bidirectional, nb,
-                                   maxd)          # shared by the heads
         for t in range(ht):
             q = q_ref[0, t]                  # [bq, d]
             k = k_ref[0, t]                  # [bk, d]
             v = v_ref[0, t]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale   # [bq, bk]
-            if has_bias:
-                # additive score bias (T5 relative position): S =
-                # qkᵀ·scale + B — folded in BEFORE the online softmax
-                s = s + bias_ref[t].astype(jnp.float32)
-            if rel is not None:
-                row = _rel_row(rel_ref, ih, ht, t)
-                s = s + _table_bias(row.astype(jnp.float32), bucket,
-                                    rel[1])
+            s = _scores(q, k, scale, rel_ref, bucket, (ih, ht, t))  # [bq, bk]
             if causal:
-                rows = qb * bq + jax.lax.broadcasted_iota(
-                    jnp.int32, (bq, bk), 0)
-                cols = kb * bk + jax.lax.broadcasted_iota(
-                    jnp.int32, (bq, bk), 1)
-                visible = _visible(rows, cols, window)
+                visible = _mask((bq, bk), window, (qb, bq), (kb, bk))
                 s = jnp.where(visible, s, _NEG_INF)
             r = slice(t * bq, (t + 1) * bq)
             m_prev = m_scr[r, :1]                             # [bq, 1]
@@ -455,7 +522,7 @@ def _fwd_single_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
                        causal, bq, bk, nq, ht, window=None, group=1,
                        lanes=None):
     """Forward when ONE kv block holds every key of the row (nk == 1;
-    caller guarantees no bias/rel_table): a plain softmax a q row, no
+    caller guarantees no rel_table): a plain softmax a q row, no
     state carried from grid step to grid step. The online form's round
     trip through its scratch (init, rescale, the [bq, 128] stores of m
     and l, finish) is more than the block's own work at these lengths
@@ -475,17 +542,12 @@ def _fwd_single_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
     for r0 in range(0, bq, rc):
         keys = r0 + rc if triangle else bk
         if causal:                           # shared by the heads
-            rows = qb * bq + r0 + jax.lax.broadcasted_iota(
-                jnp.int32, (rc, keys), 0)
-            cols = jax.lax.broadcasted_iota(jnp.int32, (rc, keys), 1)
-            visible = _visible(rows, cols, window)
+            visible = _mask((rc, keys), window, (qb, bq, r0))
         for t in range(ht):          # heads per program (see _fwd_kernel)
             q = _take(q_ref, t, lanes, slice(r0, r0 + rc), alone=True)
             k = _take(k_ref, t, lanes, slice(keys))         # [keys, d]
             v = _take(v_ref, t, lanes, slice(keys))
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale   # [rc, keys]
+            s = _scores(q, k, scale)                        # [rc, keys]
             if causal:
                 s = jnp.where(visible, s, _NEG_INF)
             m = jnp.maximum(jnp.max(s, -1, keepdims=True), _NEG_INF)
@@ -499,7 +561,7 @@ def _fwd_single_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale,
 
 
 def _flash_fwd(q, k, v, causal, scale, bq, bk, interpret, out_dtype=None,
-               bias=None, rel_table=None, rel=None, window=None, heads=None):
+               rel_table=None, rel=None, window=None, heads=None):
     """q: [b, h, sq, d]; k,v: [b, hkv, sk, d] → (out [b,h,sq,d],
     lse [b,h,sq] fp32). sq and sk may DIFFER (cross-attention: the
     decoder's queries over the encoder's keys) — the kernels only ever
@@ -526,8 +588,7 @@ def _flash_fwd(q, k, v, causal, scale, bq, bk, interpret, out_dtype=None,
     if rel is not None:
         ht = _clamp_ht(ht, h)   # matches the bwd dtable tile bound
     grid = (b, h // ht, group * nq, steps)
-    has_bias = bias is not None
-    if nk == 1 and not has_bias and rel is None:
+    if nk == 1 and rel is None:
         kernel = functools.partial(_fwd_single_kernel, scale=scale,
                                    causal=causal, bq=bq, bk=bk, nq=nq, ht=ht,
                                    lanes=d if dense else None,
@@ -535,36 +596,20 @@ def _flash_fwd(q, k, v, causal, scale, bq, bk, interpret, out_dtype=None,
         scratch = []
     else:
         kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                                   bq=bq, bk=bk, nk=steps, ht=ht,
-                                   has_bias=has_bias, rel=rel,
+                                   bq=bq, bk=bk, nk=steps, ht=ht, rel=rel,
                                    **_band_args(window, group, nqh=nq))
         scratch = [pltpu.VMEM((ht * bq, vd), jnp.float32),
                    pltpu.VMEM((ht * bq, 128), jnp.float32),
                    pltpu.VMEM((ht * bq, 128), jnp.float32)]
-    k_spec, v_spec = (_kv_spec(ht, bq, bk, width, nq, group, window, dense,
-                               causal and nk > 1)
-                      for width in (d, vd))
-    q_spec, o_spec = (_rows_spec(ht, bq, width, dense,
-                                 lambda ib, ih, iq, ik: (ib, ih, iq))
-                      for width in (d, vd))
-    in_specs = [q_spec, k_spec, v_spec]
-    inputs = [q, k, v]
-    if has_bias:
-        in_specs.append(pl.BlockSpec(
-            (ht, bq, bk), lambda ib, ih, iq, ik: (ih, iq, ik)))
-        inputs.append(bias)
-    elif rel is not None:
-        in_specs.append(pl.BlockSpec(
-            rel_table.shape, lambda ib, ih, iq, ik: (0, 0)))
-        inputs.append(rel_table)
+    q_spec, k_spec, v_spec, o_spec, stat = _specs(
+        ht, bq, bk, d, vd, dense, lambda iq, ik: iq,
+        _kv_block(bq, bk, nq, group, window, causal and nk > 1))
+    table_spec, table = _table_operand(rel_table)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            o_spec,
-            pl.BlockSpec((1, ht, 1, bq), lambda ib, ih, iq, ik: (ib, ih, 0, iq)),
-        ],
+        in_specs=[q_spec, k_spec, v_spec] + table_spec,
+        out_specs=[o_spec, stat],
         out_shape=[
             jax.ShapeDtypeStruct(q.shape[:-1] + (v.shape[-1],),
                                  out_dtype or q.dtype),
@@ -574,24 +619,49 @@ def _flash_fwd(q, k, v, causal, scale, bq, bk, interpret, out_dtype=None,
         compiler_params=_DIM_SEMANTICS,
         interpret=interpret,
         name="bps_flash_fwd",
-    )(*inputs)
+    )(q, k, v, *table)
     return _unfold(out, group), _unfold(lse[:, :, 0], group)
 
 
-def _kv_spec(ht, bq, bk, d, nq, group, window, dense=False, causal=False):
-    """k's and v's BlockSpec on a (b, heads, q blocks, kv steps) grid: the
-    step's own block, under a band the block the step stands for, and in
-    a causal call held at the q block's diagonal block once the steps
-    have passed it: a repeated block index is not fetched again, so a
-    skipped step moves nothing."""
-    def at(ib, ih, iq, ik):
+def _specs(ht, bq, bk, d, vd, dense, q_at, k_at):
+    """The BlockSpecs of a call on a (b, head tiles, i, j) grid whose step
+    (i, j) stands at q block ``q_at(i, j)`` and kv block ``k_at(i, j)``:
+    those of (q and dq, k and dk, v and dv, out and do, a row statistic).
+    The forward carries the kv axis and so does the dq call; the dk/dv
+    call carries q."""
+    def rows(n, width, at):
+        return _rows_spec(ht, n, width, dense,
+                          lambda ib, ih, i, j: (ib, ih, at(i, j)))
+    stat = pl.BlockSpec((1, ht, 1, bq),
+                        lambda ib, ih, i, j: (ib, ih, 0, q_at(i, j)))
+    return (rows(bq, d, q_at), rows(bk, d, k_at), rows(bk, vd, k_at),
+            rows(bq, vd, q_at), stat)
+
+
+def _table_operand(rel_table):
+    """([its BlockSpec], [the table]) for a call with a ``rel_table``, two
+    empty lists for one without: the table rides as ONE full-array block
+    (``_rel_row``)."""
+    if rel_table is None:
+        return [], []
+    return [pl.BlockSpec(rel_table.shape,
+                         lambda ib, ih, i, j: (0, 0))], [rel_table]
+
+
+def _kv_block(bq, bk, nq, group, window, causal):
+    """The kv block of a step on a (.., q blocks, kv steps) grid
+    (``_specs``' ``k_at``): the step's own, under a band the block the
+    step stands for, and in a causal call held at the q block's diagonal
+    block once the steps have passed it: a repeated block index is not
+    fetched again, so a skipped step moves nothing."""
+    def at(iq, ik):
         if causal:
             qb = iq % nq if group > 1 else iq
             if window is not None:
                 ik = _band_lo_k(qb, bq, bk, window) + ik
             ik = jnp.minimum(ik, (qb * bq + bq - 1) // bk)
-        return ib, ih, ik
-    return _rows_spec(ht, bk, d, dense, at)
+        return ik
+    return at
 
 
 def _band_args(window, group, **more):
@@ -605,14 +675,12 @@ def _band_args(window, group, **more):
 # -------------------------------------------------------------- backward
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-               scale, causal, bq, bk, nk, ht, has_bias=False, rel=None,
-               nq=0, window=None, group=1, nqh=0, lanes=None):
+               scale, causal, bq, bk, nk, ht, rel=None, nq=0, window=None,
+               group=1, nqh=0, lanes=None):
     """``nk``: the kv dimension's grid steps; ``nqh``: q blocks a head
     (folded q axis), as in ``_fwd_kernel``."""
-    bias_ref = dbias_ref = rel_ref = dt_ref = dt_scr = None
-    if has_bias:
-        bias_ref, dq_ref, dbias_ref, dq_acc = rest
-    elif rel is not None:
+    rel_ref = dt_ref = dt_scr = None
+    if rel is not None:
         rel_ref, dq_ref, dt_ref, dq_acc, dt_scr = rest
     else:
         dq_ref, dq_acc = rest
@@ -636,18 +704,11 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
             dt_scr[...] = jnp.zeros_like(dt_scr)
 
     run = True if not causal else (kb * bk <= qb * bq + bq - 1)
-
-    if has_bias:
-        # every (iq, ik) grid point owns its own dbias block, INCLUDING
-        # causally-skipped ones — an unwritten output block is garbage
-        @pl.when(jnp.logical_not(run))
-        def _zero_dbias():
-            dbias_ref[...] = jnp.zeros_like(dbias_ref)
+    mask = ((bq, bk), window, (qb, bq), (kb, bk)) if causal else None
 
     @pl.when(run)
     def _block():
-        if rel is not None:
-            bucket = _bucket_block(qb, kb, bq, bk, rel[0], rel[1], rel[2])
+        bucket = None if rel is None else _bucket_block(qb, kb, bq, bk, *rel)
         for t in range(ht):                  # heads per program (see fwd)
             q = _take(q_ref, t, lanes, alone=True)
             k = _take(k_ref, t, lanes)
@@ -655,29 +716,10 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
             do = _take(do_ref, t, lanes, alone=True)
             lse = _load_stat(lse_ref, t)                    # [bq, 1]
             delta = _load_stat(delta_ref, t)                # [bq, 1]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            if has_bias:
-                s = s + bias_ref[t].astype(jnp.float32)
+            p = _probs(_scores(q, k, scale, rel_ref, bucket, (ih, ht, t)),
+                       lse, mask)                           # [bq, bk]
+            ds32 = _dscores(p, do, v, delta)  # dL/dS, S = qkᵀ·scale + B
             if rel is not None:
-                row = _rel_row(rel_ref, ih, ht, t)
-                s = s + _table_bias(row.astype(jnp.float32), bucket,
-                                    rel[1])
-            p = jnp.exp(s - lse)                            # [bq, bk]
-            if causal:
-                rows = qb * bq + jax.lax.broadcasted_iota(
-                    jnp.int32, (bq, bk), 0)
-                cols = kb * bk + jax.lax.broadcasted_iota(
-                    jnp.int32, (bq, bk), 1)
-                p = jnp.where(_visible(rows, cols, window), p, 0.0)
-            dp = jax.lax.dot_general(
-                do, v, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)         # [bq, bk]
-            ds32 = p * (dp - delta)           # dL/dS, S = qkᵀ·scale + B
-            if has_bias:
-                dbias_ref[0, t] = ds32        # dB = dS (summed over batch
-            if rel is not None:               # by the caller)
                 dt_scr[t] += _table_grad(ds32, bucket, rel[1])
             ds = ds32.astype(k.dtype)
             r = slice(t * bq, (t + 1) * bq)
@@ -697,15 +739,13 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-                scale, causal, bq, bk, nq, ht, has_bias=False, rel=None,
-                window=None, group=1, nqh=0, steps=0, lanes=None):
+                scale, causal, bq, bk, nq, ht, rel=None, window=None,
+                group=1, nqh=0, steps=0, lanes=None):
     """``nq``: the q dimension's grid steps, ``group * steps`` of them
     where the q axis is folded or banded: ``steps`` a head, over its
     ``nqh`` q blocks or the part of them in the kv block's band."""
-    bias_ref = rel_ref = None
-    if has_bias:
-        bias_ref, dk_ref, dv_ref, dk_acc, dv_acc = rest
-    elif rel is not None:
+    rel_ref = None
+    if rel is not None:
         rel_ref, dk_ref, dv_ref, dk_acc, dv_acc = rest
     else:
         dk_ref, dv_ref, dk_acc, dv_acc = rest
@@ -726,11 +766,11 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     if window is not None:    # inside the head, and the band reaches kb
         run = jnp.logical_and(run, jnp.logical_and(
             qb < nqh, qb * bq - (window - 1) <= kb * bk + bk - 1))
+    mask = ((bq, bk), window, (qb, bq), (kb, bk)) if causal else None
 
     @pl.when(run)
     def _block():
-        if rel is not None:
-            bucket = _bucket_block(qb, kb, bq, bk, rel[0], rel[1], rel[2])
+        bucket = None if rel is None else _bucket_block(qb, kb, bq, bk, *rel)
         for t in range(ht):                  # heads per program (see fwd)
             q = _take(q_ref, t, lanes, alone=True)          # [bq, d]
             k = _take(k_ref, t, lanes)                      # [bk, d]
@@ -738,31 +778,14 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
             do = _take(do_ref, t, lanes, alone=True)        # [bq, d]
             lse = _load_stat(lse_ref, t)                    # [bq, 1]
             delta = _load_stat(delta_ref, t)
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale  # [bq, bk]
-            if has_bias:
-                s = s + bias_ref[t].astype(jnp.float32)
-            if rel is not None:
-                row = _rel_row(rel_ref, ih, ht, t)
-                s = s + _table_bias(row.astype(jnp.float32), bucket,
-                                    rel[1])
-            p = jnp.exp(s - lse)
-            if causal:
-                rows = qb * bq + jax.lax.broadcasted_iota(
-                    jnp.int32, (bq, bk), 0)
-                cols = kb * bk + jax.lax.broadcasted_iota(
-                    jnp.int32, (bq, bk), 1)
-                p = jnp.where(_visible(rows, cols, window), p, 0.0)
+            p = _probs(_scores(q, k, scale, rel_ref, bucket, (ih, ht, t)),
+                       lse, mask)                           # [bq, bk]
             pt = p.astype(do.dtype)
             r = slice(t * bk, (t + 1) * bk)
             dv_acc[r] += jax.lax.dot_general(
                 pt, do, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)         # [bk, d]
-            dp = jax.lax.dot_general(
-                do, v, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)         # [bq, bk]
-            ds = (p * (dp - delta)).astype(q.dtype)
+            ds = _dscores(p, do, v, delta).astype(q.dtype)
             dk_acc[r] += jax.lax.dot_general(
                 ds, q, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale  # [bk, d]
@@ -806,24 +829,19 @@ def _dqkv_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, *rest,
             dk_acc[...] = jnp.zeros_like(dk_acc)
             dv_acc[...] = jnp.zeros_like(dv_acc)
 
+    delta_ref = None
     if has_delta:
         delta_ref, dq_ref, dk_ref, dv_ref = rest
     else:
         dq_ref, dk_ref, dv_ref = rest
+    mask = ((bq, bk), window) if causal else None   # the one block pair
     for t in range(ht):
         q = _take(q_ref, t, lanes, alone=True)              # [bq, d]
         k = _take(k_ref, t, lanes)                          # [bk, d]
         v = _take(v_ref, t, lanes)
         do = _take(do_ref, t, lanes, alone=True)
         lse = _load_stat(lse_ref, t)                        # [bq, 1]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale     # [bq, bk]
-        p = jnp.exp(s - lse)
-        if causal:
-            rows = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            cols = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            p = jnp.where(_visible(rows, cols, window), p, 0.0)
+        p = _probs(_scores(q, k, scale), lse, mask)         # [bq, bk]
         pt = p.astype(do.dtype)
         dv = jax.lax.dot_general(
             pt, do, (((0,), (0,)), ((), ())),
@@ -832,15 +850,8 @@ def _dqkv_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, *rest,
             _put(dv_ref, t, dv, lanes)
         else:
             dv_acc[...] += dv
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)             # [bq, bk]
-        if has_delta:
-            delta = _load_stat(delta_ref, t)                # [bq, 1]
-        else:
-            delta = jnp.sum(p * dp, -1, keepdims=True)      # [bq, 1]
-        ds32 = p * (dp - delta)
-        ds = ds32.astype(q.dtype)
+        ds = _dscores(p, do, v, delta_at=(delta_ref, t) if has_delta
+                      else None).astype(q.dtype)
         _put(dq_ref, t, jax.lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale, lanes)
@@ -862,7 +873,7 @@ def _dqkv_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, *rest,
 def _flash_bwd_fused(q, k, v, lse, do, delta, causal, scale, bq, bk,
                      interpret, ht, window=None, group=1, heads=None):
     """One pallas_call emitting (dq, dk, dv); caller guarantees
-    nq == nk == 1 a head and no bias/rel_table. ``lse`` and ``delta``
+    nq == nk == 1 a head and no rel_table. ``lse`` and ``delta``
     are [b,h,sq]; ``delta=None`` computes it in-kernel (see
     _dqkv_fused_kernel) — the no-``out``-input form. With ``group`` > 1
     q, do and the statistics come folded and the grid gains the group as
@@ -921,9 +932,8 @@ def _flash_bwd_fused(q, k, v, lse, do, delta, causal, scale, bq, bk,
 
 
 def _flash_bwd(q, k, v, out, lse, do, causal, scale, bq, bk, interpret,
-               delta=None, bias=None, rel_table=None, rel=None, window=None,
-               heads=None):
-    """(dq, dk, dv, dbias, drel). ``lse`` and a caller's ``delta`` are
+               delta=None, rel_table=None, rel=None, window=None, heads=None):
+    """(dq, dk, dv, drel). ``lse`` and a caller's ``delta`` are
     [b,h,sq] fp32, like every row statistic outside the kernels. k and v
     may have fewer heads than q (grouped), ``window`` as in _flash_fwd;
     so ``heads``: q, k, v, out, do, dq, dk and dv lane-dense."""
@@ -938,9 +948,8 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, bq, bk, interpret,
     lanes = d if dense else None
     tile = _dense_tile if dense else _head_tile
 
-    has_bias = bias is not None
     has_rel = rel is not None
-    if not has_bias and not has_rel and nq == 1 and nk == 1:
+    if not has_rel and nq == 1 and nk == 1:
         # mats=4: p, dp, ds32 and the cast ds are live per unrolled
         # head. delta passes through as given: None lets the kernel
         # compute it in-kernel (dropping `out` from the backward's
@@ -951,7 +960,7 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, bq, bk, interpret,
         dq, dk, dv = _flash_bwd_fused(q, k, v, lse, do, delta, causal,
                                       scale, bq, bk, interpret, ht_f,
                                       heads=heads, **band)
-        return _unfold(dq, group), dk, dv, None, None
+        return _unfold(dq, group), dk, dv, None
 
     if delta is None:      # ring callers hoist this loop-invariant reduction
         delta = do.astype(jnp.float32) * out.astype(jnp.float32)
@@ -962,45 +971,26 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, bq, bk, interpret,
             delta = jnp.sum(delta, axis=-1)                 # [b,h,s]
     lse, delta = lse[:, :, None], delta[:, :, None]         # [b,h,1,s]
     ht = tile(h, group * nq, nk, bq, bk, d, interpret,
-              mats=5 if has_rel else (4 if has_bias else 3))
+              mats=5 if has_rel else 3)
     if has_rel:
         # the dtable scratch and output tiles are hard-sized to
         # _DT_PAD rows — a tile above that would write out of bounds
         # and break the drel reshape
         ht = _clamp_ht(ht, h)
-    qspec, dospec = (_rows_spec(ht, bq, width, dense,
-                                lambda ib, ih, iq, ik: (ib, ih, iq))
-                     for width in (d, vd))
-    kspec, vspec = (_kv_spec(ht, bq, bk, width, nq, group, window, dense,
-                             causal)
-                    for width in (d, vd))
-    stat = pl.BlockSpec((1, ht, 1, bq), lambda ib, ih, iq, ik: (ib, ih, 0, iq))
-    steps_k = nk if window is None else _band_steps_k(nq, bq, bk, window)
-
-    in_specs = [qspec, kspec, vspec, dospec, stat, stat]
-    inputs = [q, k, v, do, lse, delta]
-    out_specs = qspec
-    out_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
+    table_spec, table = _table_operand(rel_table)
     acc_lanes = 128 if dense else d     # lane-dense: a head's whole tile
     acc_lanes_v = 128 if dense else vd
+
+    # dq: q block outer, the kv steps the carried dim, as in the forward
+    qspec, kspec, vspec, dospec, stat = _specs(
+        ht, bq, bk, d, vd, dense, lambda iq, ik: iq,
+        _kv_block(bq, bk, nq, group, window, causal))
+    steps_k = nk if window is None else _band_steps_k(nq, bq, bk, window)
+    out_specs = qspec
+    out_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
     scratches = [pltpu.VMEM((ht * bq, acc_lanes), jnp.float32)]
     params = _DIM_SEMANTICS
-    if has_bias:
-        bspec = pl.BlockSpec((ht, bq, bk), lambda ib, ih, iq, ik: (ih, iq, ik))
-        in_specs.append(bspec)
-        inputs.append(bias)
-        # per-batch dbias blocks (dB = dS); summed over batch below.
-        # O(b·h·sq·sk) fp32 — the biased path is for MODERATE lengths;
-        # the rel_table path below is the O(s)-memory long-length form.
-        out_specs = [qspec, pl.BlockSpec(
-            (1, ht, bq, bk), lambda ib, ih, iq, ik: (ib, ih, iq, ik))]
-        out_shape = [out_shape,
-                     jax.ShapeDtypeStruct((b, h, sq, sk), jnp.float32)]
-    elif has_rel:
-        nb = rel_table.shape[1]
-        in_specs.append(pl.BlockSpec(
-            rel_table.shape, lambda ib, ih, iq, ik: (0, 0)))
-        inputs.append(rel_table)
+    if has_rel:
         # dtable accumulates in VMEM scratch across BOTH block dims —
         # iq must therefore be CARRIED (arbitrary), not parallel.
         # Output tiles are padded to the minimum legal TPU block
@@ -1012,30 +1002,24 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, bq, bk, interpret,
         scratches.append(pltpu.VMEM(_DT_PAD, jnp.float32))
         params = pltpu.CompilerParams(dimension_semantics=(
             "parallel", "parallel", "arbitrary", "arbitrary"))
-    res = pl.pallas_call(
+    dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, nk=steps_k, ht=ht, has_bias=has_bias,
-                          rel=rel, nq=nq, lanes=lanes,
-                          **(band and dict(band, nqh=nq))),
+                          bq=bq, bk=bk, nk=steps_k, ht=ht, rel=rel, nq=nq,
+                          lanes=lanes, **(band and dict(band, nqh=nq))),
         grid=(b, h // ht, group * nq, steps_k),
-        in_specs=in_specs,
+        in_specs=[qspec, kspec, vspec, dospec, stat, stat] + table_spec,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratches,
         compiler_params=params,
         interpret=interpret,
         name="bps_flash_bwd_dq",
-    )(*inputs)
-    dbias = drel = None
-    if has_bias:
-        dq, dbias_b = res
-        dbias = jnp.sum(dbias_b, axis=0)                   # [h, sq, sk]
-    elif has_rel:
-        dq, dt_b = res                 # [b, h//ht, 8, 128] padded tiles
+    )(q, k, v, do, lse, delta, *table)
+    drel = None
+    if has_rel:
+        dq, dt_b = dq                  # [b, h//ht, 8, 128] padded tiles
         nb = rel_table.shape[1]
         drel = jnp.sum(dt_b[:, :, :ht, :nb], axis=0).reshape(h, nb)
-    else:
-        dq = res
 
     # dk/dv: kv block is the outer (carried) grid dim, q block inner. A
     # causal call walks a kv block's q blocks from its diagonal block on
@@ -1054,33 +1038,16 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, bq, bk, interpret,
             qb = first + qb
             last = jnp.minimum(last, (ik * bk + bk + window - 2) // bq)
         return iq // steps_q * nq + jnp.clip(qb, first, last)
-    qspec2, dospec2 = (_rows_spec(
-        ht, bq, width, dense,
-        lambda ib, ih, ik, iq: (ib, ih, q_block(ik, iq)))
-        for width in (d, vd))
-    kspec2, vspec2 = (_rows_spec(ht, bk, width, dense,
-                                 lambda ib, ih, ik, iq: (ib, ih, ik))
-                      for width in (d, vd))
-    stat2 = pl.BlockSpec(
-        (1, ht, 1, bq), lambda ib, ih, ik, iq: (ib, ih, 0, q_block(ik, iq)))
-    in_specs2 = [qspec2, kspec2, vspec2, dospec2, stat2, stat2]
-    inputs2 = [q, k, v, do, lse, delta]
-    if has_bias:
-        in_specs2.append(pl.BlockSpec(
-            (ht, bq, bk), lambda ib, ih, ik, iq: (ih, iq, ik)))
-        inputs2.append(bias)
-    elif has_rel:
-        in_specs2.append(pl.BlockSpec(
-            rel_table.shape, lambda ib, ih, ik, iq: (0, 0)))
-        inputs2.append(rel_table)
+    qspec, kspec, vspec, dospec, stat = _specs(
+        ht, bq, bk, d, vd, dense, q_block, lambda ik, iq: ik)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          bq=bq, bk=bk, nq=group * steps_q, ht=ht,
-                          has_bias=has_bias, rel=rel, lanes=lanes,
+                          bq=bq, bk=bk, nq=group * steps_q, ht=ht, rel=rel,
+                          lanes=lanes,
                           **(band and dict(band, nqh=nq, steps=steps_q))),
         grid=(b, h // ht, nk, group * steps_q),
-        in_specs=in_specs2,
-        out_specs=[kspec2, vspec2],
+        in_specs=[qspec, kspec, vspec, dospec, stat, stat] + table_spec,
+        out_specs=[kspec, vspec],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((ht * bk, acc_lanes), jnp.float32),
@@ -1088,17 +1055,17 @@ def _flash_bwd(q, k, v, out, lse, do, causal, scale, bq, bk, interpret,
         compiler_params=_DIM_SEMANTICS,
         interpret=interpret,
         name="bps_flash_bwd_dkv",
-    )(*inputs2)
-    return _unfold(dq, group), dk, dv, dbias, drel
+    )(q, k, v, do, lse, delta, *table)
+    return _unfold(dq, group), dk, dv, drel
 
 
 # ------------------------------------------------------------ public API
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(3, 4, 5, 6, 7, 10, 11, 12))
+                   nondiff_argnums=(3, 4, 5, 6, 7, 9, 10, 11))
 def flash_attention(q, k, v, causal=False, scale=None,
                     block_q=None, block_k=None, interpret=False,
-                    bias=None, rel_table=None, rel_bidirectional=True,
+                    rel_table=None, rel_bidirectional=True,
                     rel_max_distance=128, window=None):
     """Pallas flash attention. q: [b, sq, heads, d]; k,v: [b, sk, heads,
     d] → [b, sq, heads, d]. sq and sk may differ (cross-attention).
@@ -1116,8 +1083,7 @@ def flash_attention(q, k, v, causal=False, scale=None,
     ``i // (heads // kv_heads)``, k and v are never repeated in HBM and
     dk / dv come back summed over each group. ``window`` = w (causal
     only) lets query i see key j where ``0 <= i - j < w``; kv blocks
-    outside the band are not visited. Neither goes with ``bias`` or
-    ``rel_table``.
+    outside the band are not visited. Neither goes with ``rel_table``.
 
     Which layout crosses HBM (``_fwd_rule`` chooses, once a call; no
     argument does): q, k, v, out and in the backward do, dq, dk, dv go
@@ -1125,41 +1091,35 @@ def flash_attention(q, k, v, causal=False, scale=None,
     returned, where head_dim divides a 128-lane tile (64, 32), a head
     tile of whole lane tiles divides the heads (an even number of heads
     at width 64) within the kernels' VMEM count, k and v have as many
-    heads as q, there is no ``bias``, ``rel_table``
-    or ``window``, and one forward block holds a row's keys (up to 1024
-    by default; explicit blocks that split the keys do not). Unequal q
-    and kv lengths are covered (T5's cross-attention at width 64). Every
-    other call (width 128, T5's biased self-attention, grouped kv heads,
-    a window, the online forward at long rows, three heads of 64) is
-    head-major, [b, heads, s, d], behind a swapaxes each way, and so are
-    the ring's own calls of ``_flash_fwd`` / ``_flash_bwd``.
+    heads as q, there is no ``rel_table`` or ``window``, and one forward
+    block holds a row's keys (up to 1024 by default; explicit blocks
+    that split the keys do not). Unequal q and kv lengths are covered
+    (T5's cross-attention at width 64). Every other call (width 128,
+    T5's self-attention under its table, grouped kv heads, a window, the
+    online forward at long rows, three heads of 64) is head-major,
+    [b, heads, s, d], behind a swapaxes each way, and so are the ring's
+    own calls of ``_flash_fwd`` / ``_flash_bwd``.
 
     Each seq must be divisible by the (auto-shrunk) block sizes; a
     block size of None is the default (see ``_resolve``): in a plain
     call's forward the whole kv sequence up to 1024 keys, else what
     ``_default_block`` reads off the call: 1024 x 1024 past 1024 keys or
     where v has a width of its own, 512 x 512 in the backward of a call
-    of at most 1024 keys and with ``bias`` / ``rel_table``. Differentiable
+    of at most 1024 keys and with a ``rel_table``. Differentiable
     via the flash backward kernels. The larger block won at every shape
     measured (512 read ~29% faster than 256 on BERT-large seq-512, 1024
     35-50 % faster than 512 in the online forward at 8k: fewer grid
     steps, fewer rescalings of the accumulator); 1024 x 1024 fits VMEM
     through d=256, 1024 x 2048 does not.
 
-    Two additive-score-bias forms (mutually exclusive):
-
-    - ``rel_table`` [heads, num_buckets]: T5 relative-position bias
-      computed IN-KERNEL from block offsets — no [h, sq, sk] bias ever
-      materializes (O(s) memory at any length), dtable accumulated in
-      VMEM scratch. This is the long-sequence form.
-    - ``bias`` [heads, sq, sk]: an arbitrary materialized bias; its
-      BACKWARD materializes per-batch dbias blocks
-      (O(batch·heads·sq·sk) fp32) before the batch sum — moderate
-      lengths only.
+    One additive score term: ``rel_table`` [heads, num_buckets], T5's
+    relative-position bias computed IN-KERNEL from block offsets — no
+    [h, sq, sk] bias ever materializes (O(s) memory at any length), dtable
+    accumulated in VMEM scratch. (A materialized [heads, sq, sk] bias is
+    ``local_attention``'s, the reference's, alone.)
     """
     out, _ = _fwd_rule(q, k, v, causal, scale, block_q, block_k, interpret,
-                       bias, rel_table, rel_bidirectional, rel_max_distance,
-                       window)
+                       rel_table, rel_bidirectional, rel_max_distance, window)
     return out
 
 
@@ -1172,7 +1132,7 @@ def _resolve(q, k, scale, block_q, block_k, whole_kv=False, causal=False,
              block=512):
     """(scale, bq, bk). A block size of None is the default: ``block``
     (``_default_block``), and with ``whole_kv`` (the forward of a call
-    without bias/rel_table) a kv sequence of up to _WHOLE_KV keys is one
+    without a rel_table) a kv sequence of up to _WHOLE_KV keys is one
     block — with the whole q beside it when causal, so that the visible
     triangle is static."""
     _, sq, _, d = q.shape
@@ -1194,8 +1154,8 @@ def _default_block(sk: int, d: int, vd: int, plain: bool = True) -> int:
     holds whole (``_WHOLE_KV``: the online forward, the split backward)
     or v has a width of its own; 512 for the calls of at most
     ``_WHOLE_KV`` keys (GPT-2's split backward, BERT's one block) and for
-    a call with ``bias`` or ``rel_table`` (not ``plain``: its [bq, bk]
-    operands were never measured at 1024).
+    a call with a ``rel_table`` (not ``plain``: its [bq, bk] temporaries
+    were never measured at 1024).
 
     Measured on the chip, the kernels alone, device ms a call, forward |
     dq | dk/dv at 512 x 512 -> 1024 x 1024 (my chip runs, PR 45, calls 1
@@ -1223,7 +1183,7 @@ def _rel_static(rel_table, bidirectional, max_distance):
             int(max_distance))
 
 
-def _check_band(q, k, causal, window, extra) -> None:
+def _check_band(q, k, causal, window, has_rel) -> None:
     """What a window or grouped kv heads need of a call."""
     heads, kv_heads = q.shape[2], k.shape[2]
     if kv_heads < 1 or heads % kv_heads:
@@ -1232,13 +1192,13 @@ def _check_band(q, k, causal, window, extra) -> None:
     if window is not None and not (causal and window >= 1):
         raise ValueError("a window is a causal band of at least one key "
                          f"(got window={window!r}, causal={causal})")
-    if extra and (window is not None or kv_heads != heads):
-        raise ValueError("bias / rel_table go with neither a window nor "
+    if has_rel and (window is not None or kv_heads != heads):
+        raise ValueError("a rel_table goes with neither a window nor "
                          "grouped kv heads")
 
 
 def _lane_dense(q, k, bq, bk, bwd_blocks, interpret) -> bool:
-    """Whether a call with no bias, rel_table or window sends its
+    """Whether a call with no rel_table or window sends its
     operands across HBM as [b, s, heads*d] (the note on narrow heads):
     a head width that divides a lane tile, as many kv heads as query heads,
     every key of a row in the forward's one block (``_fwd_single_kernel``)
@@ -1257,16 +1217,15 @@ def _lane_dense(q, k, bq, bk, bwd_blocks, interpret) -> bool:
 
 
 def _fwd_rule(q, k, v, causal, scale, block_q, block_k, interpret,
-              bias=None, rel_table=None, rel_bidirectional=True,
-              rel_max_distance=128, window=None):
+              rel_table=None, rel_bidirectional=True, rel_max_distance=128,
+              window=None):
     """The forward and its residuals. The layout in which q, k, v, out
     (and in the backward their cotangents) cross HBM is chosen here,
     once a call, from what the call shows (``_lane_dense``): lane-dense
     [b, s, heads*d], a free reshape of the arguments, or head-major
     [b, heads, s, d] behind a swapaxes each way. The residuals carry the
     layout to ``_vjp_bwd`` in their rank."""
-    _check_band(q, k, causal, window, bias is not None
-                or rel_table is not None)
+    _check_band(q, k, causal, window, rel_table is not None)
     if rel_table is not None and rel_table.shape[1] > _DT_PAD[1]:
         raise ValueError(
             f"rel_table has {rel_table.shape[1]} buckets; the in-kernel "
@@ -1276,19 +1235,17 @@ def _fwd_rule(q, k, v, causal, scale, block_q, block_k, interpret,
             "causal masking requires equal q/kv lengths (got "
             f"{q.shape[1]} vs {k.shape[1]}); cross-attention is "
             "bidirectional")
-    if bias is not None and rel_table is not None:
-        raise ValueError("bias and rel_table are mutually exclusive")
     if q.shape[3] != k.shape[3] or k.shape[:3] != v.shape[:3]:
         raise ValueError(
             f"q {q.shape} and k {k.shape} share their last width and k "
             f"and v {v.shape} all but it")
     rel = _rel_static(rel_table, rel_bidirectional, rel_max_distance)
-    plain = bias is None and rel is None
+    plain = rel is None
     block = _default_block(k.shape[1], q.shape[3], v.shape[3], plain)
     scale, bq, bk = _resolve(q, k, scale, block_q, block_k, whole_kv=plain,
                              causal=causal, block=block)
     heads = None
-    if (bias is None and rel is None and window is None
+    if (plain and window is None
             and v.shape[3] == q.shape[3] and _lane_dense(
             q, k, bq, bk, _resolve(q, k, scale, block_q, block_k)[1:],
             interpret)):
@@ -1300,50 +1257,50 @@ def _fwd_rule(q, k, v, causal, scale, block_q, block_k, interpret,
         kt = jnp.swapaxes(k, 1, 2)
         vt = jnp.swapaxes(v, 1, 2)
     out, lse = _flash_fwd(qt, kt, vt, causal, scale, bq, bk, interpret,
-                          bias=bias, rel_table=rel_table, rel=rel,
-                          window=window, heads=heads)
+                          rel_table=rel_table, rel=rel, window=window,
+                          heads=heads)
     # named so a remat policy can pin the flash residuals while everything
     # around them recomputes (SAVED_NAMES; remat_policy="save_attn")
     out, lse = map(checkpoint_name, (out, lse), SAVED_NAMES)  # lse [b,h,sq]
-    res = (qt, kt, vt, out, lse, bias, rel_table)
+    res = (qt, kt, vt, out, lse, rel_table)
     if heads is not None:
         return out.reshape(q.shape), res
     return jnp.swapaxes(out, 1, 2), res
 
 
 def _vjp_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
-             bias=None, rel_table=None, rel_bidirectional=True,
-             rel_max_distance=128, window=None):
+             rel_table=None, rel_bidirectional=True, rel_max_distance=128,
+             window=None):
     out, res = _fwd_rule(q, k, v, causal, scale, block_q, block_k, interpret,
-                         bias, rel_table, rel_bidirectional,
-                         rel_max_distance, window)
+                         rel_table, rel_bidirectional, rel_max_distance,
+                         window)
     return out, res
 
 
 def _vjp_bwd(causal, scale, block_q, block_k, interpret,
              rel_bidirectional, rel_max_distance, window, res, g):
-    qt, kt, vt, out, lse, bias, rel_table = res
+    qt, kt, vt, out, lse, rel_table = res
     if qt.ndim == 3:                 # lane-dense residuals (_fwd_rule)
         heads = lse.shape[1]
         kv = jax.ShapeDtypeStruct((*kt.shape[:2], heads, g.shape[3]),
                                   kt.dtype)
         scale, bq, bk = _resolve(g, kv, scale, block_q, block_k)
-        dq, dk, dv, _, _ = _flash_bwd(
+        dq, dk, dv, _ = _flash_bwd(
             qt, kt, vt, out, lse, g.reshape(qt.shape), causal, scale, bq, bk,
             interpret, heads=heads)
         return (dq.reshape(g.shape), dk.reshape(kv.shape),
-                dv.reshape(kv.shape), None, None)
+                dv.reshape(kv.shape), None)
     scale, bq, bk = _resolve(
         jnp.swapaxes(qt, 1, 2), jnp.swapaxes(kt, 1, 2), scale, block_q,
         block_k, block=_default_block(kt.shape[2], qt.shape[3], vt.shape[3],
-                                      bias is None and rel_table is None))
+                                      rel_table is None))
     rel = _rel_static(rel_table, rel_bidirectional, rel_max_distance)
     do = jnp.swapaxes(g, 1, 2)
-    dq, dk, dv, dbias, drel = _flash_bwd(
+    dq, dk, dv, drel = _flash_bwd(
         qt, kt, vt, out, lse, do, causal, scale, bq, bk,
-        interpret, bias=bias, rel_table=rel_table, rel=rel, window=window)
+        interpret, rel_table=rel_table, rel=rel, window=window)
     return (jnp.swapaxes(dq, 1, 2), jnp.swapaxes(dk, 1, 2),
-            jnp.swapaxes(dv, 1, 2), dbias, drel)
+            jnp.swapaxes(dv, 1, 2), drel)
 
 
 flash_attention.defvjp(_vjp_fwd, _vjp_bwd)
@@ -1397,7 +1354,7 @@ def local_attention(q, k, v, causal: bool = False,
     return out.reshape(b, s, h, v.shape[3]).astype(q.dtype)
 
 
-def attention(q, k, v, causal=False, scale=None, impl="auto", bias=None,
+def attention(q, k, v, causal=False, scale=None, impl="auto",
               rel_table=None, rel_bidirectional=True,
               rel_max_distance=128, window=None):
     """Dispatcher: Pallas flash kernels on TPU, blockwise JAX elsewhere.
@@ -1407,8 +1364,8 @@ def attention(q, k, v, causal=False, scale=None, impl="auto", bias=None,
 
     ``rel_table`` [heads, num_buckets]: T5 relative-position bias,
     computed in-kernel on the flash path (no materialized [h, sq, sk]
-    bias); materialized only on the naive fall-back. ``bias``
-    [heads, sq, sk]: arbitrary materialized bias. Mutually exclusive.
+    bias); materialized only on the naive fall-back, into
+    ``local_attention``'s ``bias``.
 
     ``window``, grouped kv heads (k, v with fewer heads than q) and a
     width of v unlike q's and k's as in ``flash_attention``, on every path.
@@ -1418,7 +1375,7 @@ def attention(q, k, v, causal=False, scale=None, impl="auto", bias=None,
             f"attn impl must be auto|flash|naive, got {impl!r}")
 
     def _naive():
-        b = bias
+        b = None
         if rel_table is not None:
             from .relpos import relative_bias
             b = relative_bias(rel_table.T, q.shape[1], k.shape[1],
@@ -1441,7 +1398,7 @@ def attention(q, k, v, causal=False, scale=None, impl="auto", bias=None,
                 asked=impl)
     if flash:
         return flash_attention(q, k, v, causal=causal, scale=scale,
-                               bias=bias, rel_table=rel_table,
+                               rel_table=rel_table,
                                rel_bidirectional=rel_bidirectional,
                                rel_max_distance=rel_max_distance,
                                window=window)

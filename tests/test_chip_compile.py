@@ -78,10 +78,9 @@ def mosaic(monkeypatch):
     # tile that fills the lanes)
     ((8, 1024, 16, 64), True, None, True),
     ((256, 128, 16, 64), False, None, True),    # BERT phase 1: ht = 8
-    ((8, 512, 8, 64), False, "bias", False),    # T5's two score-bias forms
-    ((8, 1024, 8, 64), False, "rel_table", False),
+    ((8, 1024, 8, 64), False, "rel_table", False),  # T5's score-bias form
 ], ids=["bert_large", "dh128", "gpt2_32k_causal", "gpt2_medium_causal",
-        "bert_s128", "bias", "rel_table"])
+        "bert_s128", "rel_table"])
 def test_flash_fwd_bwd_compiles_for_v5e(compile_for_chip, shape, causal,
                                         extra, lane_dense):
     """Mosaic takes every flash call of the main paths, and each in the
@@ -89,8 +88,7 @@ def test_flash_fwd_bwd_compiles_for_v5e(compile_for_chip, shape, causal,
     are 64 wide and the forward is one block, [b, h, s, d] elsewhere."""
     from byteps_tpu.ops.flash_attention import flash_attention
     b, s, h, d = shape
-    extra_shape = {None: [], "bias": [((h, s, s), jnp.float32)],
-                   "rel_table": [((h, 32), jnp.float32)]}[extra]
+    extra_shape = {None: [], "rel_table": [((h, 32), jnp.float32)]}[extra]
 
     def loss(q, k, v, *e):
         return (flash_attention(q, k, v, causal, **dict(zip([extra], e)))

@@ -6,6 +6,7 @@ is validated — forward values and all three input gradients, causal and
 bidirectional, fp32 and bf16."""
 
 import ast
+import inspect
 import re
 
 import jax
@@ -151,28 +152,40 @@ def test_causal_cross_attention_rejected():
         flash_attention(q, k, q, True, None, 128, 128, True)
 
 
+def _naive_product(q, k, v, bias, causal=False):
+    """softmax(q k' / sqrt(d) + bias) v spelled out, no helper of the
+    package in it: what ``local_attention(bias=)`` is held to."""
+    sc = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    sc = sc + bias[None]
+    if causal:
+        sc = jnp.where(jnp.tril(jnp.ones(sc.shape[-2:], bool)), sc, -jnp.inf)
+    p = jnp.exp(sc - sc.max(-1, keepdims=True))
+    return jnp.einsum("bhqk,bkhd->bqhd", p / p.sum(-1, keepdims=True), v)
+
+
 @pytest.mark.parametrize("causal", [False, True])
 def test_bias_forward_backward_exact(causal):
-    """Additive [h, sq, sk] score bias (T5 relative position): forward
-    plus dq/dk/dv AND the dbias reduction (accumulated per-batch in
-    the dq kernel, summed outside) against the reference."""
+    """Additive [h, sq, sk] score bias (T5 relative position) in the
+    reference, ``local_attention(bias=)``: what ``attention`` falls back
+    to for a ``rel_table`` off the kernels, and what every ``rel_table``
+    test compares the kernels with. Forward, dq/dk/dv and dbias against
+    the product spelled out. (The kernels took such a bias until PR 47;
+    no caller passed one.)"""
     rng = np.random.RandomState(3)
     b, s, h, d = 2, 256, 2, 64
     q, k, v = make_qkv(rng, b, s, h, d, np.float32)
     bias = jnp.asarray(rng.randn(h, s, s).astype(np.float32))
-    out = flash_attention(q, k, v, causal, None, 128, 128, True,
-                          bias=bias)
-    ref = local_attention(q, k, v, causal=causal, bias=bias)
+    out = local_attention(q, k, v, causal=causal, bias=bias)
+    ref = _naive_product(q, k, v, bias, causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 
     def f_loss(q, k, v, bb):
-        return (flash_attention(q, k, v, causal, None, 128, 128, True,
-                                bias=bb) ** 2).sum()
-
-    def n_loss(q, k, v, bb):
         return (local_attention(q, k, v, causal=causal, bias=bb)
                 ** 2).sum()
+
+    def n_loss(q, k, v, bb):
+        return (_naive_product(q, k, v, bb, causal) ** 2).sum()
 
     gf = jax.grad(f_loss, argnums=(0, 1, 2, 3))(q, k, v, bias)
     gn = jax.grad(n_loss, argnums=(0, 1, 2, 3))(q, k, v, bias)
@@ -184,18 +197,25 @@ def test_bias_forward_backward_exact(causal):
 
 
 def test_mismatched_bias_cross():
-    """bias + mismatched lengths together (biased cross-attention is
-    not a T5 case but the kernel contract covers it)."""
+    """bias + mismatched lengths together in the reference (biased
+    cross-attention is not a T5 case, but the fall-back's contract
+    covers it), and through ``attention``'s fall-back a ``rel_table``
+    over them."""
+    from byteps_tpu.ops.relpos import relative_bias
     rng = np.random.RandomState(4)
     q = jnp.asarray(rng.randn(1, 128, 2, 64).astype(np.float32))
     k = jnp.asarray(rng.randn(1, 384, 2, 64).astype(np.float32))
     v = jnp.asarray(rng.randn(1, 384, 2, 64).astype(np.float32))
     bias = jnp.asarray(rng.randn(2, 128, 384).astype(np.float32))
-    out = flash_attention(q, k, v, False, None, 128, 128, True,
-                          bias=bias)
-    ref = local_attention(q, k, v, bias=bias)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(local_attention(q, k, v, bias=bias)),
+        np.asarray(_naive_product(q, k, v, bias)), rtol=2e-5, atol=2e-5)
+    table = jnp.asarray(rng.randn(2, 32).astype(np.float32))
+    np.testing.assert_allclose(
+        np.asarray(attention(q, k, v, impl="naive", rel_table=table)),
+        np.asarray(_naive_product(
+            q, k, v, relative_bias(table.T, 128, 384, True, 32, 128))),
+        rtol=2e-5, atol=2e-5)
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -316,6 +336,57 @@ def test_rel_table_no_materialized_bias_in_jaxpr():
                 f"O(s^2) intermediate {shape} materialized by {eqn.primitive}")
 
 
+# ---- one function makes a block's scores and one its mask (PR 47)
+
+@pytest.mark.parametrize("body,shape,table,scores,masks", [
+    # a rel_table at one block pair: four heads a program in all three
+    ("_fwd_kernel", (2, 256, 4, 64), True, 4, 4),
+    ("_dq_kernel", (2, 256, 4, 64), True, 4, 4),
+    ("_dkv_kernel", (2, 256, 4, 64), True, 4, 4),
+    # GPT-2's forward: two heads a program, two row chunks of 512, and
+    # the mask shared by the heads of a chunk
+    ("_fwd_single_kernel", (2, 1024, 4, 64), False, 4, 2),
+    ("_dqkv_fused_kernel", (2, 512, 8, 128), False, 2, 2),
+], ids=["online_forward", "dq", "dkv", "single_block", "fused_backward"])
+def test_every_body_makes_its_scores_and_mask_by_the_one_function(
+        monkeypatch, body, shape, table, scores, masks):
+    """Each of the five kernel bodies gets a block's scores from
+    ``_scores`` and its mask from ``_mask``, one call a head (the single
+    block's mask one a row chunk: its heads share it): what the next
+    mechanism on the scores or the mask has to edit is one function, not
+    five bodies. Traced without interpret mode, so the head tiles are the
+    chip's."""
+    traced = getattr(fa, body)
+    # no body makes a position's iota of its own
+    assert "broadcasted_iota" not in inspect.getsource(traced)
+    inside = []                 # non-empty while ``body`` is being traced
+    counts = {"_scores": 0, "_mask": 0}
+
+    def entered(*args, **kwargs):
+        inside.append(body)
+        try:
+            return traced(*args, **kwargs)
+        finally:
+            inside.pop()
+    monkeypatch.setattr(fa, body, entered)
+    for name in counts:
+        def counted(*args, _fn=getattr(fa, name), _name=name, **kwargs):
+            counts[_name] += len(inside)
+            return _fn(*args, **kwargs)
+        monkeypatch.setattr(fa, name, counted)
+    q = jnp.zeros(shape, jnp.bfloat16)
+    operands = (q, q, q) + (
+        (jnp.zeros((shape[2], 32), jnp.float32),) if table else ())
+
+    def loss(q, k, v, *t):
+        return flash_attention(q, k, v, True, rel_table=t[0] if t else None,
+                               rel_bidirectional=False).astype(
+                                   jnp.float32).sum()
+
+    jax.make_jaxpr(jax.grad(loss, tuple(range(len(operands)))))(*operands)
+    assert counts == {"_scores": scores, "_mask": masks}
+
+
 # ---- the row statistics' contract: lane-dense across every pallas_call
 
 def _sub_jaxprs(eqn):
@@ -369,12 +440,11 @@ def assert_statistics_lane_dense(jaxpr, rows, min_calls):
     (128, 128, 16, False, (512, 512), None, 2),       # fused, ht 8
     (1024, 1024, 16, False, (512, 512), None, 3),     # split backward, ht 1
     (1024, 1024, 16, True, (512, 512), None, 3),      # causal split
-    (256, 256, 4, False, (128, 128), "bias", 3),
     (256, 256, 4, True, (128, 128), "rel_table", 3),
     (128, 384, 4, False, (128, 128), None, 3),        # cross: follows sq
     (384, 128, 4, False, (128, 128), None, 3),
     (1024, 1024, 16, True, (None, None), None, 3),    # GPT-2's cell
-], ids=["fused", "fused_s128", "split", "causal", "bias", "rel_table",
+], ids=["fused", "fused_s128", "split", "causal", "rel_table",
         "cross_sq128", "cross_sq384", "default_blocks"])
 def test_no_padded_statistic_crosses_a_kernel(sq, sk, h, causal, blocks,
                                               extra, calls):
@@ -385,7 +455,7 @@ def test_no_padded_statistic_crosses_a_kernel(sq, sk, h, causal, blocks,
     b, d = 2, 64
     q = jnp.zeros((b, sq, h, d), jnp.bfloat16)
     k = jnp.zeros((b, sk, h, d), jnp.bfloat16)
-    operand = {None: (), "bias": (jnp.zeros((h, sq, sk), jnp.float32),),
+    operand = {None: (),
                "rel_table": (jnp.zeros((h, 32), jnp.float32),)}[extra]
 
     def loss(q, k, v, *e):
@@ -482,7 +552,7 @@ def test_single_block_forward_matches_online(s, causal, blocks):
     (1024, 1024, False, False, True, (512, 1024)),
     (2048, 1024, False, False, True, (512, 1024)),   # cross: kv whole
     (2048, 2048, True, False, True, (1024, 1024)),   # past _WHOLE_KV
-    (1024, 1024, True, True, True, (512, 512)),      # bias / rel_table
+    (1024, 1024, True, True, True, (512, 512)),      # a rel_table
     (1024, 1024, True, False, False, (512, 512)),    # the backward
     (8192, 8192, True, False, True, (1024, 1024)),   # the 8k cells' calls
     (8192, 8192, True, False, False, (1024, 1024)),
@@ -492,7 +562,7 @@ def test_single_block_forward_matches_online(s, causal, blocks):
     (8192, 8192, True, {"kv_heads": 1}, False, (1024, 1024)),
     (2048, 2048, True, False, False, (1024, 1024)),
     (4096, 4096, False, False, True, (1024, 1024)),  # no mask: the same
-    (2048, 2048, True, True, True, (512, 512)),      # biased: not measured
+    (2048, 2048, True, True, True, (512, 512)),      # a rel_table: not measured
     (1536, 1536, True, False, True, (512, 512)),     # 1024 does not divide
 ], ids=["s512", "s128", "s1024_causal", "s1024", "cross", "s2048",
         "biased", "backward", "s8192", "s8192_backward", "s8192_band",
@@ -503,8 +573,9 @@ def test_default_blocks(sq, sk, causal, extra, forward, want):
     up to 1024 keys (with the whole q when causal); past them 1024 x
     1024, forward and backward, a band and grouped kv heads like the
     triangle (``_default_block``: what the chip said, PR 45); 512 in the
-    backward of at most 1024 keys and with bias / rel_table; a named
-    block is taken as given."""
+    backward of at most 1024 keys and with a rel_table (``plain`` False:
+    since PR 47 the one biased form the kernels take); a named block is
+    taken as given."""
     from byteps_tpu.ops.flash_attention import _default_block, _resolve
     shown = extra if isinstance(extra, dict) else {}
     plain = extra is False or bool(shown)
@@ -618,7 +689,7 @@ def flash_calls(fn, *args):
 
 def wide_operands(eqn):
     """Shapes of a kernel's q, k, v, out, do, dq, dk, dv: its operands and
-    results that are no float32 statistic, bias or table."""
+    results that are no float32 statistic or table."""
     return [v.aval.shape for v in list(eqn.invars) + list(eqn.outvars)
             if v.aval.ndim >= 3 and v.aval.shape[-2] != 1
             and v.aval.dtype != jnp.float32]
@@ -751,24 +822,24 @@ def test_narrow_heads_cross_no_transpose():
     (8, 1, 128, dict(causal=True)),
     (8, 1, 128, dict(causal=True, window=100)),
     (8, 8, 128, dict()),
-    (4, 4, 64, dict(extra=("bias",))),
     (4, 4, 64, dict(extra=("rel_table",))),
+    (4, 4, 64, dict(causal=True, extra=("rel_table",))),
     (4, 2, 64, dict()),
     (3, 3, 64, dict()),
 ], ids=["width128_grouped", "width128_grouped_window", "width128",
-        "bias", "rel_table", "grouped_width64", "three_heads"])
+        "rel_table", "rel_table_causal", "grouped_width64", "three_heads"])
 def test_every_other_call_keeps_the_head_major_path(heads, kv_heads, d,
                                                     kwargs):
-    """Width 128, a bias, a table, grouped kv heads, a window, a head
-    count no lane tile divides: the kernels' operands are [b, h, s, d]
+    """Width 128, a table (T5's encoder and its causal decoder), grouped
+    kv heads, a window, a head count no lane tile divides: the kernels'
+    operands are [b, h, s, d]
     (q and its kin folded over the group) behind a swapaxes each: three
     in and one out, in the backward two for the shapes, do in and three
     out, as before the lane-dense layout existed."""
     b, s = 2, 256
     q = jnp.zeros((b, s, heads, d), jnp.bfloat16)
     k = jnp.zeros((b, s, kv_heads, d), jnp.bfloat16)
-    extra = {"bias": jnp.zeros((heads, s, s), jnp.float32),
-             "rel_table": jnp.zeros((heads, 32), jnp.float32)}
+    extra = {"rel_table": jnp.zeros((heads, 32), jnp.float32)}
     operands = tuple(extra[e] for e in kwargs.get("extra", ()))
     calls, transposes = flash_calls(
         jax.value_and_grad(_squared_loss(**kwargs),

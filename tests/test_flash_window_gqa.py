@@ -119,8 +119,9 @@ def test_what_a_call_must_not_combine():
     with pytest.raises(ValueError, match="do not divide"):
         flash_attention(q, k[:, :, :1].repeat(3, 2), v, True, None, None,
                         None, True)
-    bias = jnp.zeros((4, 128, 128))
+    table = jnp.zeros((4, 32))
     with pytest.raises(ValueError, match="neither a window nor"):
-        flash_attention(q, k, v, False, None, None, None, True, bias=bias)
+        flash_attention(q, k, v, False, None, None, None, True,
+                        rel_table=table)
     with pytest.raises(ValueError, match="causal band"):
         local_attention(q, k, v, window=16)
