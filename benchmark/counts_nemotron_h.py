@@ -1,8 +1,9 @@
 """Required operations of the ``nemotron_h`` share (configuration
 ``nemotron3_nano_lm``), by the benchmark's own count: a training step's
 operations a token (``flops_per_token``: the configuration's
-``flops_rule``), each flash kernel's operations and bytes a call
-(``kernel_counts``) and the state-space scan's a step (``scan_count``).
+``flops_rule``), each flash kernel's and grouped product's operations
+and bytes a call (``kernel_counts``) and the state-space scan's a step
+(``scan_count``).
 ``flops.py`` has the rules of what counts: the forward's matrix products
 times 3, no recomputation, no gathers, no elementwise work.
 
@@ -23,6 +24,7 @@ a trace cannot count a step's own).
 from __future__ import annotations
 
 from benchmark import kernel_counts as flash
+from benchmark.counts_afmoe import GMM_KERNELS, gmm_call
 
 
 def routed_rows_per_token(sizes: dict) -> float:
@@ -69,15 +71,26 @@ def flops_per_token(sizes: dict, seq: int, targets_per_row: int) -> float:
 
 
 def kernel_counts(sizes: dict, mix: dict) -> dict:
-    """Each flash kernel's kinds of call in a step: one kind, the causal
+    """Each kernel's kinds of call in a step. Flash: one kind, the causal
     triangle over grouped kv heads (32 query heads over 2), one call an
-    ``attn`` layer (the forward kernel twice: forward and recompute,
-    which doubles both and keeps the ratio)."""
+    ``attn`` layer. Grouped products, a ``moe`` layer: the experts are
+    not gated, so up is [h, m] and down [m, h], at the mean routed rows
+    and each held expert's weights moved once, as ``counts_afmoe`` counts
+    a call (``bps_gmm`` runs both forward and again as the recompute,
+    ``bps_gmm_dx`` and ``bps_gmm_dw`` once each backward: every kernel's
+    calls a step are a whole multiple of the two kinds)."""
+    batch, seq = mix["batch_per_chip"], mix["seq"]
     layers = sum(kind == "attn" for kind in sizes["layer_kinds"])
-    return {kernel: [dict(flash.flash_call(
-        kernel, mix["batch_per_chip"], sizes["heads"], mix["seq"],
-        sizes["head_dim"], True, kv_heads=sizes["kv_heads"]), calls=layers)]
+    counts = {kernel: [dict(flash.flash_call(
+        kernel, batch, sizes["heads"], seq, sizes["head_dim"], True,
+        kv_heads=sizes["kv_heads"]), calls=layers)]
         for kernel in flash.KERNELS}
+    rows = batch * seq * routed_rows_per_token(sizes)
+    h, m, held = sizes["hidden"], sizes["moe_dim"], sizes["experts_held"]
+    for kernel in GMM_KERNELS:
+        counts[kernel] = [dict(gmm_call(kernel, rows, h, m, held), calls=1),
+                          dict(gmm_call(kernel, rows, m, h, held), calls=1)]
+    return counts
 
 
 def scan_count(sizes: dict, mix: dict, itemsize: int = 2) -> dict:

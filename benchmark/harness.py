@@ -19,6 +19,11 @@ The order of a run:
    and feed the window uses, are compared with the reference's
    (``correct.py``); it warms up;
 4. the window drives that same trainer for ``--seconds``.
+
+``setup_s`` is the seconds of set-up that lines of this repository own:
+every lap of the ``setup`` line but ``OUTSIDE_LAPS`` (the TPU runtime's
+start, the reference's steps, the dump and search of the step's text).
+Those are printed a run and judged by nothing.
 """
 
 from __future__ import annotations
@@ -33,6 +38,7 @@ import json
 import math
 import os
 import queue
+import re
 import threading
 import time
 from collections import defaultdict
@@ -52,6 +58,10 @@ TRACE_SECONDS = 6.0      # length of the traced window of a --trace 1 run
 ARM_STEPS = 8            # steps timed together in each arm of a traced run
 COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration",
                   "/jax/compilation_cache/cache_retrieval_time_sec")
+# laps of set-up that are no part of ``setup_s``: no line of the repository
+# decides the runtime's start, which wanders by seconds on one machine; the
+# other two are the benchmark's own checks (PERF.md section 2)
+OUTSIDE_LAPS = ("runtime_s", "reference_s", "step_text_s")
 
 
 def use_compile_cache(root: str) -> None:
@@ -247,7 +257,9 @@ class Spans:
 
 class Laps:
     """Seconds of set-up by phase, for the ``setup`` line: where a slow
-    set-up was slow."""
+    set-up was slow. Each lap runs from the end of the one before it, the
+    first from ``t_start``, so together they cover the process's start
+    to the window's."""
 
     def __init__(self, t_start: float) -> None:
         self.seconds = {}
@@ -258,6 +270,10 @@ class Laps:
         self.seconds[name] = self.seconds.get(name, 0.0) + now - self._last
         self._last = now
         return self.seconds[name]
+
+    def total(self, outside=()) -> float:
+        """The laps' sum, less the ones named ``outside``."""
+        return sum(v for k, v in self.seconds.items() if k not in outside)
 
 
 class CompileCounter:
@@ -402,6 +418,29 @@ class SetUp:
         gc.collect()
 
 
+def step_text_checks(text: str, wanted) -> list:
+    """One row a name of ``wanted`` (a configuration's
+    ``step_must_contain``): whether the compiled step's text holds it as a
+    whole name, so that ``bps_gmm_dx`` does not stand for ``bps_gmm``.
+    Before them a line of every ``bps_*`` kernel the text holds, with how
+    many custom calls are its (an ``op_name`` that ends in
+    ``<kernel>/pallas_call``): what a list is written from."""
+    calls = defaultdict(int)
+    for line in text.splitlines():
+        call = "custom-call" in line and re.search(
+            r'op_name="[^"]*\b(bps_\w+)/pallas_call[^"/]*"', line)
+        if call:
+            calls[call.group(1)] += 1
+    emit("step_kernels", **dict(sorted(calls.items())))
+    rows = []
+    for needle in wanted:
+        found = re.search(r"(?<!\w)" + re.escape(needle) + r"(?!\w)",
+                          text) is not None
+        rows.append({"check": "step_contains:" + needle, "value": int(found),
+                     "limit": 1, "where": "compiled step", "ok": found})
+    return rows
+
+
 def open_mesh(cell: Cell, require_chip: bool):
     """The mesh over exactly the chips the cell asks for; no TPU, or
     another number of chips, ends the run."""
@@ -473,16 +512,14 @@ def set_up(cell: Cell, seed: int, mesh, spans: Spans, require_chip: bool,
             compiled = trainer._step_fn.lower(
                 trainer.params, trainer.opt_state, batch).compile()
             memory_peak = step_memory_bytes(compiled)
+            laps.lap("lower_s")
+            # the benchmark's own look at the step's text: no part of setup_s
             wanted = (cell.config["program"].get("step_must_contain", [])
                       if require_chip else [])
-            text = compiled.as_text() if wanted else ""
-            for needle in wanted:
-                checks.append({"check": "step_contains:" + needle,
-                               "value": int(needle in text), "limit": 1,
-                               "where": "compiled step",
-                               "ok": needle in text})
-            del compiled, text
-            laps.lap("lower_s")
+            if wanted:
+                checks += step_text_checks(compiled.as_text(), wanted)
+            del compiled
+            laps.lap("step_text_s")
         with spans.span("step"):
             loss = trainer.step(batch)
         program["loss"].append(float(loss))
@@ -507,25 +544,32 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     ``require_chip=False`` is for the tests: it skips the look for a TPU
     and what only a TPU has (the kernel in the step, the peaks)."""
     import byteps_tpu as bps
+    import byteps_tpu.parallel.mesh     # noqa: F401  open_mesh's, ahead of it
+    laps = Laps(t_start)
     cell = load_cell(root, workload)
-    mesh = open_mesh(cell, require_chip)
+    imports_py_s = laps.lap("imports_py_s")     # jax, benchmark, byteps_tpu
+    mesh = open_mesh(cell, require_chip)        # jax.devices() answers
     devices = list(mesh.devices.flat)
     compiles = CompileCounter()
     spans = Spans()
     bps.init(mesh=mesh)
-    laps = Laps(t_start)
+    runtime_s = laps.lap("runtime_s")
     emit("cell", workload=workload, seed=seed, chips=cell.chips,
          rows=cell.rows, seq=cell.mix["seq"],
          targets_per_row=generator.targets_per_row(cell.mix),
-         imports_s=laps.lap("imports_s"))
+         imports_s=imports_py_s + runtime_s, imports_py_s=imports_py_s,
+         runtime_s=runtime_s)
     up = set_up(cell, seed, mesh, spans, require_chip, laps)
     trainer, feed, checks = up.trainer, up.feed, up.checks
-    reference_s, memory_peak = up.reference_s, up.memory_peak
+    memory_peak = up.memory_peak
     for _ in range(WARM_STEPS):
         loss = trainer.step(next(feed))
     jax.block_until_ready(loss)
     laps.lap("warm_s")
-    emit("setup", **laps.seconds)
+    setup_s = laps.total(OUTSIDE_LAPS)
+    # process_to_window_s: what setup_s was until PR 50, for the same run
+    emit("setup", **laps.seconds, setup_s=setup_s,
+         process_to_window_s=laps.total(("reference_s",)))
     spans.seconds.clear()
 
     # 4. the window
@@ -533,7 +577,6 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     device = device_record(devices, memory_peak)
     breakdown = None
     if not trace:
-        setup_s = time.time() - t_start - reference_s
         window = drive(trainer, feed, seconds, spans, compiles)
         done = window.attempted - window.failed
         emit("window", steps=done, seconds=window.seconds,

@@ -1,7 +1,8 @@
 """Device time a step of the forward flash kernel, on the first chip:
 the ``bps_flash_fwd`` events of the trace (the program's ``name=`` on the
-``pallas_call``), which run in the forward pass and again as its
-recompute."""
+``pallas_call``). Since PR 36 every block's checkpoint keeps the kernel's
+output and row statistics, so it runs once a layer, in the forward pass;
+under a checkpoint that keeps neither it runs again as the recompute."""
 from benchmark.trace import program
 
 UNIT, LAYER, MOVES, SOURCE = "ms", "kernels", "tokens_per_s_chip", "device_trace"

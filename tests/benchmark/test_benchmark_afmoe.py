@@ -12,7 +12,8 @@ import types
 
 import pytest
 
-from tinybench import OPTIMIZER, ROOT, TIGHT, write_tiny_benchmark
+from tinybench import (OPTIMIZER, ROOT, ROUTED_CELLS, ROUTED_METRICS, TIGHT,
+                       write_tiny_benchmark)
 
 from benchmark import counts_afmoe, harness, kernel_counts
 from benchmark.trace import named, program
@@ -152,6 +153,8 @@ def _trace(steps=2):
         op("%bps_gmm_dw = bf16[] custom-call()",
            root + "bps.moe.experts/pallas_call", 2e6)
         op("%fusion.9 = gather", root + "bps.moe.route/gather", 3e6)
+        op("%sort.2 = sort", root + "bps.moe.route/bps.moe.route.plan/sort",
+           2.5e5)
         op("%fusion.3 = dot", root + "bps.moe.shared/dot_general", 5e5)
         op("%fusion.4 = dot", "jit(step)/bps.model/jvp(bps.attn)/dot", 7e6)
     return program.Program("/device:TPU:0", (0.0, t), steps, ops, [], [],
@@ -160,8 +163,9 @@ def _trace(steps=2):
 
 def test_scopes_and_kernels_are_read_by_name():
     trace = _trace()
-    assert named.scope_ms(trace, "bps.moe") == 4 + 2 + 3 + 0.5
-    assert named.scope_ms(trace, "bps.moe.route") == 3
+    assert named.scope_ms(trace, "bps.moe") == 4 + 2 + 3 + 0.25 + 0.5
+    assert named.scope_ms(trace, "bps.moe.route") == 3 + 0.25
+    assert named.scope_ms(trace, "bps.moe.route.plan") == 0.25
     assert named.scope_ms(trace, "bps.moe.nothing") is None
     assert named.ns_by_kernel(trace, "bps_gmm") == {
         "bps_gmm": (8e6, 8), "bps_gmm_dw": (4e6, 2)}
@@ -170,8 +174,8 @@ def test_scopes_and_kernels_are_read_by_name():
 
 
 @pytest.mark.parametrize("metric,want", [
-    ("model.moe_ms", 9.5), ("model.moe_route_ms", 3.0),
-    ("kernels.gmm_ms", 6.0)])
+    ("model.moe_ms", 9.75), ("model.moe_route_ms", 3.25),
+    ("model.moe_plan_ms", 0.25), ("kernels.gmm_ms", 6.0)])
 def test_the_readers_on_the_handmade_trace(cell, monkeypatch, metric, want):
     reader = harness.load_metric(metric, cell.dirs)
     run = types.SimpleNamespace(cell=cell, peaks=PEAKS, chips=[object()])
@@ -206,6 +210,37 @@ def test_the_roofline_reader_needs_the_counts_to_fit_the_calls(
         for kind in counts[kernel])
     assert reader.read(run) == pytest.approx(100.0 * least / 8e-3)
     assert 0 < reader.read(run) < 100
+
+
+@pytest.mark.parametrize("name", ROUTED_CELLS)
+@pytest.mark.parametrize("metric", ROUTED_METRICS)
+def test_the_routed_metrics_read_every_routed_cell(metric, name):
+    """Each lists the three cells whose steps run ``models/moe.py``, by
+    name and wherever its entry stands, has its reader, and the cell's
+    configuration names a count with the grouped products in it."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        (entry,) = [m for m in json.load(f)["per_layer"]
+                    if m["name"] == metric]
+    assert entry["workloads"] == list(ROUTED_CELLS)
+    assert (entry["moves"], entry["source"]) == ("tokens_per_s_chip",
+                                                 "device_trace")
+    routed = harness.load_cell(ROOT, name)
+    assert metric in routed.per_layer
+    assert callable(harness.load_metric(metric, routed.dirs).read)
+    found = harness.named_count(routed, "kernel_counts")(
+        routed.config["sizes"], routed.mix)
+    assert set(counts_afmoe.GMM_KERNELS) <= set(found)
+    assert all(kind["calls"] == 1 and kind["flops"] > 0 and kind["bytes"] > 0
+               for kernel in counts_afmoe.GMM_KERNELS
+               for kind in found[kernel])
+
+
+def test_no_dense_cell_lists_a_routed_metric():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        names = [w["name"] for w in json.load(f)["workloads"]]
+    for name in set(names) - set(ROUTED_CELLS):
+        assert not set(ROUTED_METRICS) & set(
+            harness.load_cell(ROOT, name).per_layer)
 
 
 # ------------------------------------------- the harness, on the CPU
@@ -246,11 +281,10 @@ def _write_tiny_afmoe(root):
         "name": "tiny_afmoe_cell", "config": "tiny_afmoe",
         "traffic": "lm_tiny", "chips": 1, "why": "test"})
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        new = [m for m in json.load(f)["per_layer"]
-               if m.get("workloads") == [CELL]]
-    assert len(new) == 4
-    manifest["per_layer"] += [dict(m, workloads=["tiny_afmoe_cell"])
-                              for m in new]
+        by_name = {m["name"]: m for m in json.load(f)["per_layer"]}
+    manifest["per_layer"] += [
+        dict(by_name[name], workloads=["tiny_afmoe_cell"])
+        for name in ROUTED_METRICS]
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(manifest, f)
     return str(root)
@@ -267,8 +301,7 @@ def test_the_harness_runs_a_cell_of_the_family_unchanged(tmp_path, trace,
     assert {"loss_rel", "grad_norm_rel", "change_norm_rel",
             "compiles_in_window"} <= set(result["checks"])
     if trace:       # no device trace on the CPU: the new readers say nothing
-        assert not {"model.moe_ms", "model.moe_route_ms", "kernels.gmm_ms",
-                    "kernels.gmm_roofline_pct"} & set(result["metrics"])
+        assert not set(ROUTED_METRICS) & set(result["metrics"])
     else:
         assert set(result["metrics"]) == {"tokens_per_s_chip", "step_ms_p95",
                                           "setup_s"}

@@ -17,7 +17,8 @@ import types
 import jax
 import pytest
 
-from tinybench import OPTIMIZER, ROOT, TIGHT, write_tiny_benchmark
+from tinybench import (OPTIMIZER, ROOT, ROUTED_METRICS, TIGHT,
+                       write_tiny_benchmark)
 
 import manifest_rules as rules
 from benchmark import counts_afmoe, counts_deepseek_v3 as counts, harness
@@ -295,8 +296,9 @@ def test_the_flash_share_of_the_roofline_at_two_widths(cell):
 
 
 def test_the_new_entries_are_the_new_cells_alone(manifest):
-    mine = [m for m in manifest["per_layer"] if m.get("workloads") == [CELL]]
-    assert [m["name"] for m in mine] == list(NEW_METRICS)
+    by_name = {m["name"]: m for m in manifest["per_layer"]}
+    mine = [by_name[name] for name in NEW_METRICS]
+    assert all(m["workloads"] == [CELL] for m in mine)
     assert all(m["moves"] == "tokens_per_s_chip" and m["layer"] == "model"
                and m["unit"] == "ms" and m["source"] == "device_trace"
                for m in mine)
@@ -306,14 +308,14 @@ def test_the_new_entries_are_the_new_cells_alone(manifest):
     (config,) = [c for c in manifest["configs"] if c["name"] == CONFIG]
     assert config["reduced"] == list(REDUCED)
     assert sum(w["chips"] == 4 for w in manifest["workloads"]) == 1
-    # no other cell's list names it, and the accepted flash metrics,
-    # which have no list, reach it
+    # the accepted flash metrics, which have no list, reach it; so do,
+    # since PR 50, the routed layers' (its routed half is afmoe's code);
+    # the state-space layers' do not
     cell = harness.load_cell(ROOT, CELL)
     assert {"kernels.flash_fwd_ms", "kernels.flash_bwd_ms",
             "kernels.flash_roofline_pct", "kernels.fallback_sites",
-            *NEW_METRICS} <= set(cell.per_layer)
-    assert not {"model.moe_ms", "kernels.gmm_ms", "model.ssm_ms"} & set(
-        cell.per_layer)
+            *NEW_METRICS, *ROUTED_METRICS} <= set(cell.per_layer)
+    assert not {"model.ssm_ms", "model.ssm_scan_ms"} & set(cell.per_layer)
 
 
 # ------------------------------------------- the harness, on the CPU

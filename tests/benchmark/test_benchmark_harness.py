@@ -118,6 +118,60 @@ def test_broken_timed_path_comes_out_not_correct(tmp_path, monkeypatch,
     assert fails <= failed
 
 
+def _name_the_step_must_hold(root, names):
+    path = os.path.join(root, "tinybench", "configs", "tiny_mlm.json")
+    with open(path) as f:
+        doc = json.load(f)
+    doc["program"]["step_must_contain"] = names
+    with open(path, "w") as f:
+        json.dump(doc, f)
+
+
+@pytest.mark.parametrize("names,correct", [
+    (["ENTRY"], True),
+    (["ENTRY", "bps_flash_fwd"], False),
+])
+def test_a_listed_name_missing_from_the_steps_text_is_not_correct(
+        tmp_path, monkeypatch, capsys, names, correct):
+    """The rest of a run as on the chip but for the look for one: every
+    name of ``step_must_contain`` is searched in the compiled step's text,
+    which here holds an ``ENTRY`` and no kernel."""
+    root = write_tiny_benchmark(tmp_path)
+    _name_the_step_must_hold(root, names)
+    real = harness.open_mesh
+    monkeypatch.setattr(harness, "open_mesh",
+                        lambda cell, require_chip: real(cell, False))
+    result = harness.run_cell(root, "tiny_mlm_cell", SEED, 0.3, False,
+                              time.time(), require_chip=True)
+    assert result["correct"] is correct, capsys.readouterr().out
+    assert result["failed"] == 0
+    assert {k: v["value"] for k, v in result["checks"].items()
+            if k.startswith("step_contains:")} == {
+                "step_contains:" + n: int(n == "ENTRY") for n in names}
+
+
+def test_a_name_is_searched_whole(capsys):
+    """``bps_gmm_dx`` does not stand for ``bps_gmm``, an instruction's
+    number and an ``op_name``'s slashes end a name; the line before the
+    rows counts the custom calls by the kernel's name."""
+    text = ('%bps_gmm_dx.3 = bf16[8] custom-call(), custom_call_target='
+            '"tpu_custom_call", metadata={op_name="jit(step)/bps.moe/'
+            'bps_gmm_dx/pallas_call"}\n%bps_flash_fwd = f32[] custom-call(),'
+            ' metadata={op_name="jit(step)/bps_flash_fwd/pallas_call"}\n'
+            '%fusion.1 = f32[] fusion(%bps_flash_fwd), metadata={op_name='
+            '"jit(step)/bps_flash_fwd/pallas_call"}')   # a consumer's line
+    rows = harness.step_text_checks(
+        text, ["tpu_custom_call", "bps_gmm", "bps_gmm_dx", "bps_flash_fwd"])
+    assert [(r["check"], r["value"], r["ok"]) for r in rows] == [
+        ("step_contains:tpu_custom_call", 1, True),
+        ("step_contains:bps_gmm", 0, False),
+        ("step_contains:bps_gmm_dx", 1, True),
+        ("step_contains:bps_flash_fwd", 1, True)]
+    assert all(r["limit"] == 1 for r in rows)
+    assert json.loads(capsys.readouterr().out) == {
+        "phase": "step_kernels", "bps_gmm_dx": 1, "bps_flash_fwd": 1}
+
+
 def test_without_a_tpu_the_command_fails_and_prints_no_result():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     out = subprocess.run(

@@ -18,10 +18,12 @@ import numpy as np
 import optax
 import pytest
 
-from tinybench import OPTIMIZER, ROOT, TIGHT, write_tiny_benchmark
+from tinybench import (OPTIMIZER, ROOT, ROUTED_METRICS, TIGHT,
+                       write_tiny_benchmark)
 
 import manifest_rules as rules
-from benchmark import correct, counts_nemotron_h as counts, harness
+from benchmark import correct, counts_afmoe, counts_nemotron_h as counts
+from benchmark import harness
 from benchmark import kernel_counts
 from benchmark.reference import nemotron_h_share as ref
 from benchmark.trace import program
@@ -171,7 +173,43 @@ def test_the_scan_and_the_flash_calls_counted_by_hand():
     flash = counts.kernel_counts(TINY, mix)
     assert flash["bps_flash_bwd_dq"] == [dict(kernel_counts.flash_call(
         "bps_flash_bwd_dq", 2, 2, 4, 2, True, kv_heads=1), calls=1)]
-    assert set(flash) == set(kernel_counts.KERNELS)
+    assert set(flash) == set(kernel_counts.KERNELS) | set(
+        counts_afmoe.GMM_KERNELS)
+    # a token sends 2 * 4 / 8 = 1 row to the 4 experts held: 8 rows of
+    # [.., 4] against 4 weights [4, 3], not gated, and back
+    for kernel in counts_afmoe.GMM_KERNELS:
+        assert flash[kernel] == [
+            {"flops": 2.0 * 8 * 4 * 3, "calls": 1,
+             "bytes": float((8 * (4 + 3) + 4 * 4 * 3) * 2)}] * 2
+
+
+def test_the_grouped_products_at_the_cells_own_widths(cell):
+    """Ungated relu2 experts: up 2688 -> 1856 and down 1856 -> 2688 over
+    the 8 experts held at 768 mean rows each (16,384 tokens x 6 of 128
+    outputs x 8 held = 6,144 rows); a step runs ``bps_gmm`` 12 times
+    (three layers, forward and recompute), ``_dx`` and ``_dw`` 6 times:
+    24 calls of 0.311 ms by operations, 7.47 ms."""
+    sizes = cell.config["sizes"]
+    assert (sizes["hidden"], sizes["moe_dim"], sizes["experts_held"],
+            sizes["layer_kinds"].count("moe")) == (2688, 1856, 8, 3)
+    assert 16384 * counts.routed_rows_per_token(sizes) == 8 * 768
+    found = harness.named_count(cell, "kernel_counts")(sizes, cell.mix)
+    for kernel in counts_afmoe.GMM_KERNELS:
+        up, down = found[kernel]
+        assert up == down == {
+            "flops": 2.0 * 6144 * 2688 * 1856, "calls": 1,
+            "bytes": float((6144 * (2688 + 1856) + 8 * 2688 * 1856) * 2)}
+        assert kernel_counts.least_seconds(up, PEAKS) == (
+            pytest.approx(0.3112e-3, rel=1e-3), "flops")
+    took = {"bps_gmm": (8.0e6 * 2, 12 * 2), "bps_gmm_dx": (3.8e6 * 2, 6 * 2),
+            "bps_gmm_dw": (6.1e6 * 2, 6 * 2)}       # (ns, calls), 2 steps
+    got = program.roofline(took, found, PEAKS, 2)
+    assert got["all"]["pct"] == pytest.approx(
+        100 * 24 * 61303947264.0 / 197e12 / 17.9e-3)
+    assert 0 < got["all"]["pct"] < 100
+    # 5 calls a step are no whole multiple of (up, down): no share
+    odd = dict(took, bps_gmm_dw=(6.1e6 * 2, 5 * 2))
+    assert "all" not in program.roofline(odd, found, PEAKS, 2)
 
 
 def test_the_cells_count(cell):
@@ -250,17 +288,23 @@ def test_the_readers_on_the_handmade_trace(cell, monkeypatch):
 
 
 def test_the_new_metrics_are_the_new_cells_alone():
+    """By name, wherever each stands: later PRs append entries."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
-    mine = [m for m in manifest["per_layer"] if m.get("workloads") == [CELL]]
-    assert [m["name"] for m in mine] == list(NEW_METRICS)
-    assert [m["name"] for m in manifest["per_layer"][-3:]] == list(
-        NEW_METRICS)
-    assert all(m["moves"] == "tokens_per_s_chip" and m["layer"] == "model"
+    by_name = {m["name"]: m for m in manifest["per_layer"]}
+    mine = [by_name[name] for name in NEW_METRICS]
+    assert all(m["workloads"] == [CELL]
+               and m["moves"] == "tokens_per_s_chip" and m["layer"] == "model"
                for m in mine)
-    assert manifest["workloads"][-1]["name"] == CELL
-    assert manifest["configs"][-1]["name"] == "nemotron3_nano_lm"
+    (entry,) = [w for w in manifest["workloads"] if w["name"] == CELL]
+    assert (entry["config"], entry["traffic"], entry["chips"]) == (
+        "nemotron3_nano_lm", "lm_b2_s8192", 1)
+    (config,) = [c for c in manifest["configs"]
+                 if c["name"] == "nemotron3_nano_lm"]
+    assert config["file"] == "benchmark/configs/nemotron3_nano_lm.json"
     assert sum(w["chips"] == 4 for w in manifest["workloads"]) == 1
+    # the routed layers' metrics, which list their cells, reach this one
+    assert set(ROUTED_METRICS) <= set(harness.load_cell(ROOT, CELL).per_layer)
 
 
 # ------------------------------------------- the harness, on the CPU

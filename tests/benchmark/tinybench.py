@@ -14,6 +14,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+# the routed layers' per-layer metrics and the cells that list them (PR 50)
+ROUTED_METRICS = ("model.moe_ms", "model.moe_route_ms", "model.moe_plan_ms",
+                  "kernels.gmm_ms", "kernels.gmm_roofline_pct")
+ROUTED_CELLS = ("trinity_mini_s8192_1chip", "nemotron3_nano_s8192_1chip",
+                "kanana2_30b_s8192_1chip")
+
 TINY_SIZES = {"vocab_size": 512, "hidden": 64, "layers": 2, "heads": 4,
               "mlp_dim": 256, "max_seq": 64, "ln_eps": 1e-5}
 OPTIMIZER = {"name": "adamw", "learning_rate": 1e-4, "b1": 0.9,
