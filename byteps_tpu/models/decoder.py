@@ -94,7 +94,10 @@ class DecoderConfig:
     dtype: str = "bfloat16"       # compute dtype (params stay fp32)
     remat: bool = True            # checkpoint each layer
     lm_head_chunk: int = 0        # >0: the head a chunk of positions at
-    # a time (transformer._chunked_nll_sum); 0: all positions at once
+    # a time (transformer._chunked_nll_sum: O(chunk x vocab) live, the
+    # chunk's gradient formed beside its logits, d h [b, s, hidden] and
+    # d head [vocab, hidden] kept from the forward to the backward);
+    # 0: all positions as one chunk
 
     def __post_init__(self):
         bad = [k for k in self.layer_kinds if k not in KINDS]

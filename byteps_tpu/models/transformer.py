@@ -86,7 +86,7 @@ class TransformerConfig:
     # honors remat_policy (e.g. "mlp_only") at full memory cost.
     scan_unroll: int = 1          # lax.scan unroll factor over layers
     lm_head_chunk: int = 0        # >0: chunked cross-entropy — the LM
-    # head + softmax run per sequence chunk under jax.checkpoint, so the
+    # head + softmax run per sequence chunk (_chunked_nll_sum), so the
     # [s, vocab] logits never materialize (13 GB at GPT-2 seq 64k; the
     # enabler for very long contexts on one chip). 0 = full head.
 
@@ -478,34 +478,97 @@ def logits(params, cfg: TransformerConfig, hidden: jnp.ndarray) -> jnp.ndarray:
 _warned_chunk: set = set()
 
 
-@jax.named_scope("bps.head")
+def _by_chunk(n: int, *arrays):
+    """Each ``[b, n * chunk, ...]`` as the ``n`` chunks a scan takes in
+    turn."""
+    return tuple(
+        jnp.moveaxis(a.reshape(a.shape[0], n, -1, *a.shape[2:]), 1, 0)
+        for a in arrays)
+
+
+def _chunk_logits(hb, w):
+    """A chunk's logits in fp32 from compute-dtype operands, and their
+    log-sum-exp ``[b, chunk, 1]``."""
+    lg = jnp.einsum("bch,vh->bcv", hb.astype(w.dtype), w,
+                    preferred_element_type=jnp.float32)
+    return lg, jax.nn.logsumexp(lg, axis=-1, keepdims=True)
+
+
+def _chunk_nll(lg, lse, tb, mb):
+    """The masked NLL sum of a chunk from its logits."""
+    pick = jnp.take_along_axis(lg, jnp.where(mb, tb, 0)[..., None], axis=-1)
+    return ((lse - pick)[..., 0] * mb).sum()
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(4, 5))
 def _chunked_nll_sum(h, emb, targets, mask, chunk: int, dt) -> jnp.ndarray:
     """Masked NLL sum with the LM head applied per sequence chunk.
 
-    Each chunk's logits/log-softmax live only inside a jax.checkpoint
-    region of a lax.scan: the forward keeps no [s, vocab] tensor and the
-    backward recomputes one [chunk, vocab] block at a time — O(chunk·V)
-    memory instead of O(s·V)."""
+    A chunk's logits, softmax and pick live only inside one turn of a
+    ``lax.scan``: O(chunk x vocab) live, never an [s, vocab] tensor, one
+    product a chunk. Under differentiation (``_chunked_nll_fwd``) the same
+    turn forms the chunk's gradient while it holds the logits, and the
+    forward leaves two residuals, d h ``[b, s, hidden]`` and d head
+    ``[vocab, hidden]``, which the backward scales: three products a
+    chunk, nothing computed twice."""
+    n = h.shape[1] // chunk
+    with jax.named_scope("bps.head"):
+        w = emb.astype(dt)
+
+        def body(total, xs):
+            hb, tb, mb = xs
+            return total + _chunk_nll(*_chunk_logits(hb, w), tb, mb), None
+
+        return jax.lax.scan(body, jnp.float32(0.0),
+                            _by_chunk(n, h, targets, mask))[0]
+
+
+def _chunked_nll_fwd(h, emb, targets, mask, chunk: int, dt):
+    """The sum, and its gradients to ``h`` and ``emb`` for a unit
+    cotangent: the loss is a sum of per-position terms, so d logits is
+    ``(softmax - one_hot(target)) * mask`` whatever the cotangent, formed
+    here from the chunk's live logits. The two gradient products take it
+    at the compute dtype (what the chip's default precision makes of the
+    fp32 cotangent of a ``preferred_element_type=float32`` product of
+    compute-dtype operands) and give fp32; d head adds up over the chunks
+    in fp32. Softmax, log-sum-exp, pick and sum are fp32."""
     b, s, hid = h.shape
     n = s // chunk
-    hc = jnp.moveaxis(h.reshape(b, n, chunk, hid), 1, 0)
-    tc = jnp.moveaxis(targets.reshape(b, n, chunk), 1, 0)
-    mc = jnp.moveaxis(mask.reshape(b, n, chunk), 1, 0)
+    with jax.named_scope("bps.head"):
+        w = emb.astype(dt)
 
-    @jax.checkpoint
-    def one(hb, tb, mb):
-        lg = jnp.einsum("bch,vh->bcv", hb.astype(dt), emb.astype(dt),
-                        preferred_element_type=jnp.float32)
-        logp = jax.nn.log_softmax(lg, axis=-1)
-        nll = -jnp.take_along_axis(
-            logp, jnp.where(mb, tb, 0)[..., None], axis=-1)[..., 0]
-        return (nll * mb).sum()
+        def body(carry, xs):
+            total, dw = carry
+            hb, tb, mb = xs
+            lg, lse = _chunk_logits(hb, w)
+            total = total + _chunk_nll(lg, lse, tb, mb)
+            with jax.named_scope("bps.head.grad"):
+                hit = (jax.lax.broadcasted_iota(jnp.int32, lg.shape, 2)
+                       == tb[..., None])
+                p = jnp.exp(lg - lse)
+                g = jnp.where(mb[..., None], jnp.where(hit, p - 1.0, p),
+                              0.0).astype(dt)
+                dh = jnp.einsum("bcv,vh->bch", g, w,
+                                preferred_element_type=jnp.float32)
+                dw = dw + jnp.einsum("bcv,bch->vh", g, hb.astype(dt),
+                                     preferred_element_type=jnp.float32)
+            return (total, dw), dh.astype(h.dtype)
 
-    def body(acc, xs):
-        return acc + one(*xs), None
+        (total, dw), dh = jax.lax.scan(
+            body, (jnp.float32(0.0), jnp.zeros(emb.shape, jnp.float32)),
+            _by_chunk(n, h, targets, mask))
+        dh = jnp.moveaxis(dh, 0, 1).reshape(b, s, hid)
+    return total, (dh, dw.astype(emb.dtype))
 
-    total, _ = jax.lax.scan(body, jnp.float32(0.0), (hc, tc, mc))
-    return total
+
+def _chunked_nll_bwd(chunk, dt, residuals, ct):
+    dh, dw = residuals
+    with jax.named_scope("bps.head"):
+        return ((ct * dh).astype(dh.dtype), (ct * dw).astype(dw.dtype),
+                None, None)
+
+
+_chunked_nll_sum.defvjp(_chunked_nll_fwd, _chunked_nll_bwd)
 
 
 def lm_loss(params, cfg: TransformerConfig, batch) -> jnp.ndarray:
