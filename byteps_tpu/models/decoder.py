@@ -37,14 +37,34 @@ embedding and of the head held here, ``held`` the experts held here of
 The parameter tree (``init_params``) is a list of per-layer dicts (the
 layers differ in shape, so they are not stacked); the layers run as a
 Python loop, each under ``jax.checkpoint``. A layer's checkpoint keeps
-its input, the flash kernel's output and row statistics (``flash_out``,
-``flash_lse``: [b, s, heads * head_dim] bf16 + [b, heads, s] fp32, 34 MB
-at 2 x 8192 x 8 heads of 128, 135 MB at 32 heads) and the routed layer's
-plan (``moe.PLAN_NAME``: the choice, its scores and the int32 arrays of the
-chosen pairs and the buffer's rows, 3 MB at 16,384 tokens x 8); everything
-else is recomputed in the backward, so the kernel's forward and the plan's
-top-k, sort and gathers run once a layer. The embedding lookup, the chunked head and the target
-convention are ``transformer.py``'s.
+what costs more to remake than to hold (the layers are not scanned, so a
+kept value is copied nowhere):
+
+* its input;
+* the flash kernel's output and row statistics (``flash_out``,
+  ``flash_lse``: [b, s, heads * head_dim] bf16 + [b, heads, s] fp32, 34 MB
+  at 2 x 8192 x 8 heads of 128, 135 MB at 32 heads);
+* the routed layer's plan (``moe.PLAN_NAME``: the choice, its scores and
+  the int32 arrays of the chosen pairs and the buffer's rows, 3 MB at
+  16,384 tokens x 8);
+* the held experts' weights in the compute dtype (``moe.WEIGHTS_NAME``:
+  2 bytes a held expert parameter, 201 MB a layer at 16 experts of 1024
+  over a hidden of 2048, 151 MB at 16 of 768, 160 MB at 8 ungated of 1856
+  over 2688): the float32 parameters are cast once a layer and step;
+* the sum a norm AFTER a half reads (``POST_NORM_NAME``: [b, s, hidden]
+  bf16, 67 MB at 2 x 8192 x 2048, two a layer): only afmoe's halves end
+  in a norm, whose backward needs its input. Without the name the
+  recompute runs everything that makes that input: the attention's output
+  projection, the routed layer's second grouped product, combine and
+  shared expert, the dense feed-forward's second product. A family
+  without such a norm names nothing and keeps nothing.
+
+Everything else is recomputed in the backward, so the kernel's forward,
+the plan's top-k and sort, the weights' cast and, in afmoe, the combine
+run once a layer (the grouped products are still recomputed: their
+outputs are the backward's operands, 570 MB a layer). The embedding
+lookup, the chunked head and the target convention are
+``transformer.py``'s.
 """
 
 from __future__ import annotations
@@ -56,11 +76,13 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.flash_attention import SAVED_NAMES, attention
 from .mamba2 import SSMConfig, init_mixer, mixer as ssm_mixer
 from .mla import MLAConfig, attention_half as mla_half, init_attention
-from .moe import PLAN_NAME, RoutedConfig, gated_silu, routed_ffn
+from .moe import (PLAN_NAME, WEIGHTS_NAME, RoutedConfig, gated_silu,
+                  routed_ffn)
 from .transformer import _chunked_nll_sum, embed_lookup
 
 # a layer of two halves (attention, feed-forward) ...
@@ -70,6 +92,11 @@ MIXERS = ("ssm", "attn", "moe")
 # ... or of two halves with a norm before each, the attention latent
 LATENT = ("mla_dense", "mla_moe")
 KINDS = HALVES + MIXERS + LATENT
+
+# the name a layer's checkpoint keeps the input of a norm AFTER a half
+# under (afmoe's ``norm_post``, both halves): the half's whole output
+# before its norm, which the norm's backward reads
+POST_NORM_NAME = "post_norm_in"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -335,7 +362,8 @@ def _attention_half(x, blk, cfg: DecoderConfig, sliding: bool):
                     window=cfg.window if sliding else None)
     out = jnp.einsum("bsnd,ndh->bsh", out * jax.nn.sigmoid(gate),
                      blk["o"].astype(dt))
-    return rmsnorm(out, blk["norm_post"], cfg.norm_eps)
+    return rmsnorm(checkpoint_name(out, POST_NORM_NAME), blk["norm_post"],
+                   cfg.norm_eps)
 
 
 def _ffn(f, blk, cfg: DecoderConfig, routed: bool):
@@ -351,7 +379,8 @@ def _ffn(f, blk, cfg: DecoderConfig, routed: bool):
 
 def _ffn_half(x, blk, cfg: DecoderConfig, routed: bool):
     m = _ffn(rmsnorm(x, blk["norm_pre"], cfg.norm_eps), blk, cfg, routed)
-    return rmsnorm(m, blk["norm_post"], cfg.norm_eps)
+    return rmsnorm(checkpoint_name(m, POST_NORM_NAME), blk["norm_post"],
+                   cfg.norm_eps)
 
 
 def _latent_layer(x, blk, cfg: DecoderConfig, kind: str):
@@ -406,9 +435,10 @@ def apply(params, cfg: DecoderConfig, tokens) -> jnp.ndarray:
         x = embed_lookup(params["embed"], tokens, dt, math.sqrt(cfg.hidden)
                          if cfg.scale_embedding else None)
     # a layer's checkpoint keeps its input, the flash kernel's output and
-    # row statistics and the routed layer's plan; the rest is recomputed
+    # row statistics, the routed layer's plan and bf16 weights and what a
+    # norm after a half reads; the rest is recomputed
     policy = jax.checkpoint_policies.save_only_these_names(
-        *SAVED_NAMES, PLAN_NAME)
+        *SAVED_NAMES, PLAN_NAME, WEIGHTS_NAME, POST_NORM_NAME)
     for kind, blk in zip(cfg.layer_kinds, params["layers"]):
         layer = functools.partial(_layer, cfg=cfg, kind=kind)
         if cfg.remat:

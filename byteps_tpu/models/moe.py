@@ -303,6 +303,19 @@ ACTS = {"gated_silu": (gated_silu, "gate_up"), "relu2": (relu2, "up")}
 # through the name as through an identity.
 PLAN_NAME = "moe_plan"
 
+# ... and the experts' weights in the compute dtype under: the float32
+# parameters cast to bf16 for the grouped products, [held, h, 2 m] and
+# [held, m, h] (201 MB a layer at 16 experts of 1024 over a hidden of 2048).
+# The Pallas products cannot take the convert as a fused operand the way
+# XLA's own dots do, so the cast is an op of its own over every held
+# parameter, and a relayout before it where a width is off the lane tile:
+# ``decoder.apply``'s checkpoint keeps the forward's copy from a layer's
+# forward to its backward (the compute copy every mixed-precision trainer
+# holds), and the recompute and ``_dx`` read it. The gradient flows through
+# the name as through an identity: ``_gmm_dw``'s bf16 cotangent becomes
+# float32 in the cast's transpose, as without the name.
+WEIGHTS_NAME = "moe_weights"
+
 
 def route(f, router_w, cfg: RoutedConfig, sequences: int = 1):
     """(weights [T, k] fp32, experts [T, k] int32): sigmoid scores over
@@ -509,7 +522,8 @@ def routed_ffn(f, blk, cfg: RoutedConfig, sequences: int = 1):
         with jax.named_scope("bps.moe.experts"):
             def product(lhs, w):
                 return grouped_matmul(
-                    lhs, w.astype(dt), plan["tile_group"], plan["num_tiles"],
+                    lhs, checkpoint_name(w.astype(dt), WEIGHTS_NAME),
+                    plan["tile_group"], plan["num_tiles"],
                     plan["group_rows"], tile, cfg.impl)
             # never the kernels around lax.ragged_dot, as the rows' movement
             y = product(routed_act(

@@ -412,3 +412,11 @@ def test_the_checkpoint_keeps_the_flash_output_on_v5e(
         # two a routed layer: the choice's top-k and the plan's sort
         assert len(sorts) == 4, sorts
         assert not any("rematted_computation" in s for s in sorts)
+        # ... and (ISSUE 52) combine three times a routed layer, never in
+        # the recompute: the checkpoint keeps what afmoe's norm after the
+        # feed-forward reads
+        combines = [line for line in text.splitlines()
+                    if "custom-call" in line and re.search(
+                        r'op_name="[^"]*bps_moe_combine/pallas_call"', line)]
+        assert len(combines) == 6, combines
+        assert not any("rematted_computation" in c for c in combines)
