@@ -27,13 +27,28 @@ buffer held: callers read only the rows they routed.
 
 Which widths run the kernels (``supported``): ``k`` and ``n`` in whole
 lane tiles of 128 OR ending in a half one (1856 = 14.5 tiles, an
-expert's width in nemotron_h). A width that a power-of-two block of
-whole lane tiles divides is cut into such blocks, as ever; any other is
-cut into blocks of whole lane tiles that leave the least over
-(``_cols``: 2688 -> 7 x 384, 1856 -> 4 x 384 + 320), and the last block
-hangs over the array's edge: what it reads there is garbage that only
-reaches results past the edge, which are not written. A contraction is
-never cut, so it takes the whole width, half tile and all.
+expert's width in nemotron_h). A contraction is never cut, so it takes
+the whole width, half tile and all.
+
+How the columns are cut (``_blocks``, PR 53): the column blocks are the
+grid's OUTER dimensions, so the whole buffer of rows crosses HBM once for
+every column block, and in ``bps_gmm_dw`` every block of the rows is
+transposed for the MXU once for every column block it meets. So a kernel
+takes the widest blocks whose step fits ``_VMEM_BUDGET`` (64 of the v5e's
+128 MiB) by ``_step_bytes``' count, and asks Mosaic for that step's bytes
+(``vmem_limit_bytes``; its default of 16 MiB holds no expert's weight of a
+cell twice, and blocks cut to fit it read the rows two to seven times):
+the whole width wherever it fits, which it does at every shape a cell
+runs (one block a width, nothing over an edge whatever the width:
+``operand_passes`` is 1 for every operand); else ``_cols``' cuts, widest
+first: a width that a power-of-two block of whole lane tiles divides is
+cut into such blocks, any other into blocks of whole lane tiles that
+leave the least over (2688 -> 3 x 896 or 7 x 384, 1856 -> 3 x 640 or
+4 x 384 + 320), and the last block then hangs over the array's edge: what
+it reads there is garbage that only reaches results past the edge, which
+are not written. The results do not depend on the cut, to the bit: no
+contraction is cut, and ``bps_gmm_dw`` sums a group's row tiles in the
+same order whatever its column blocks.
 
 ``grouped_matmul`` is the differentiable entry: the kernels on the TPU,
 ``lax.ragged_dot`` elsewhere (CPU tests), like ``ops.flash_attention
@@ -76,24 +91,116 @@ EMBED_TILE = 256
 
 # a step past the rows revisits the last tile's blocks: nothing may be
 # reordered around it, so the tile dimension is never "parallel"
-_GMM_SEMANTICS = pltpu.CompilerParams(
-    dimension_semantics=("arbitrary", "arbitrary"))
-_DW_SEMANTICS = pltpu.CompilerParams(
-    dimension_semantics=("parallel", "parallel", "arbitrary"))
+_GMM_DIMENSIONS = ("arbitrary", "arbitrary")
+_DW_DIMENSIONS = ("parallel", "parallel", "arbitrary")
+
+_LANES = 128
+# VMEM a step of a grouped product may count on, of the v5e's 128 MiB: half.
+# The widest step a cell runs (``bps_gmm_dw`` on a whole [2688, 1856] of
+# nemotron_h: the block twice in bf16 and once in float32) counts 51 MiB;
+# Mosaic's default of 16 holds none of the cells' weights whole, and the
+# blocks cut to fit it re-read the rows once a column block (PERF.md, PR 53)
+_VMEM_BUDGET = 64 << 20
+# what Mosaic keeps beside the blocks counted in ``_step_bytes``: chunks of
+# a float32 product on their way to the result. Its own count for the
+# cells' eighteen calls (``used_scoped_memory_configs`` of a compile for the
+# v5e) is 0.1-0.4 MiB over ``_step_bytes``
+_MOSAIC_ROOM = 2 << 20
+_DEFAULT_LIMIT = 16 << 20   # Mosaic's own, where a call asks for nothing
+KERNELS = ("bps_gmm", "bps_gmm_dx", "bps_gmm_dw")
 
 
 def _cols(width: int, want: int) -> int:
-    """Columns a block of a dimension ``width`` long. ``_pick``'s where
+    """Columns a block of a dimension ``width`` long where it is CUT, into
+    blocks of at most ``want`` (``_blocks`` asks only where the whole
+    width does not fit its budget; ``embed_dw`` always). ``_pick``'s where
     that is a power-of-two number of lane tiles, at least two (every
-    width of whole 256s: the blocks those widths always had). Else the
+    width of whole 256s: 2048 -> 512 under a ``want`` of 512). Else the
     multiple of 128 up to ``want`` (and the width) whose blocks hang
-    least over the edge, the larger of equals; a width under a lane tile
-    is one block."""
+    least over the edge, the larger of equals (1856 -> 384 under 512:
+    4 x 384 + 320, 64 columns over; 640 under 1024); a width under a
+    lane tile is one block."""
     b = _pick(width, want)
     if 256 <= b <= want or width < 128:
         return b
     return min(range(128, min(want, width) + 1, 128),
                key=lambda c: (-(-width // c) * c - width, -c))
+
+
+def _widths(width: int, floor: int) -> list[int]:
+    """The column blocks to try for a dimension, widest first: the whole
+    width (one block, nothing over the edge, whatever the width), then
+    ``_cols``' cuts at every power of two down to ``floor``."""
+    out, want = [width], 1 << (width - 1).bit_length()
+    while want > floor:
+        want //= 2
+        cut = _cols(width, want)
+        if cut < out[-1]:
+            out.append(cut)
+    return out
+
+
+def _step_bytes(kernel: str, k: int, n: int, tile: int, itemsize: int,
+                blocks: tuple[int, ...]) -> int:
+    """VMEM bytes a grid step of ``kernel`` holds at ``blocks``, by count:
+    the pipeline's two buffers of each operand's block and of the
+    result's, each padded to whole lane tiles, and what the body keeps: in
+    ``bps_gmm`` / ``_dx`` a third copy of the rows' block (relaid for the
+    MXU), in ``bps_gmm_dw`` the float32 accumulator and the rows' block
+    transposed."""
+    def lanes(c):
+        return -(-c // _LANES) * _LANES
+
+    if kernel == "bps_gmm_dw":
+        tk, tn = blocks
+        return ((3 * tile * lanes(tk) + 2 * tile * lanes(tn)
+                 + 2 * tk * lanes(tn)) * itemsize + tk * lanes(tn) * 4)
+    (tn,) = blocks
+    if kernel == "bps_gmm":
+        rows, weight = tile * lanes(k), k * lanes(tn)
+    else:
+        rows, weight = tile * lanes(n), tn * lanes(n)
+    return (3 * rows + 2 * weight + 2 * tile * lanes(tn)) * itemsize
+
+
+def _blocks(kernel: str, k: int, n: int, tile: int,
+            itemsize: int) -> tuple[tuple[int, ...], int]:
+    """The column blocks of ``kernel`` against a weight [k, n] and the
+    ``vmem_limit_bytes`` its call asks for: the widest blocks whose step
+    fits ``_VMEM_BUDGET`` by ``_step_bytes``, so that every operand
+    crosses HBM once where a whole width fits (``operand_passes``), and
+    for the limit what that step needs, never the budget: what a kernel
+    reserves XLA cannot use to keep its neighbours' operands in VMEM.
+    ``bps_gmm`` / ``_dx``: (tn,), the block of the result's columns.
+    ``bps_gmm_dw``: (tk, tn), the widest ``tn`` first (a transposed block
+    of the rows then serves all of ``n``), ``tk`` by what is left. Where
+    nothing fits, the blocks the default limit got: 512, and 512 x 1024."""
+    if kernel == "bps_gmm_dw":
+        tries = [(tk, tn) for tn in _widths(n, 1024)
+                 for tk in _widths(k, 512)]
+    else:
+        tries = [(tn,) for tn in _widths(n if kernel == "bps_gmm" else k,
+                                         512)]
+    need = [_step_bytes(kernel, k, n, tile, itemsize, b) + _MOSAIC_ROOM
+            for b in tries]
+    best = next((i for i, b in enumerate(need) if b <= _VMEM_BUDGET),
+                len(tries) - 1)
+    return tries[best], max(need[best], _DEFAULT_LIMIT)
+
+
+def operand_passes(kernel: str, k: int, n: int, tile: int,
+                   itemsize: int) -> dict[str, int]:
+    """How often each operand of ``kernel`` crosses HBM under the blocks
+    ``_blocks`` chooses, the result included. The column blocks are the
+    grid's outer dimensions, so the rows go by once for every column block
+    of the OTHER operand; a weight's block stays put over a group's
+    consecutive row tiles, and a block of the result is written once."""
+    blocks, _ = _blocks(kernel, k, n, tile, itemsize)
+    if kernel == "bps_gmm_dw":
+        tk, tn = blocks
+        return {"lhs": -(-n // tn), "dout": -(-k // tk), "out": 1}
+    width = n if kernel == "bps_gmm" else k
+    return {"lhs": -(-width // blocks[0]), "w": 1, "out": 1}
 
 
 def _gmm_kernel(group_ref, num_ref, lhs_ref, rhs_ref, out_ref, *, transpose):
@@ -119,7 +226,8 @@ def _gmm(lhs, w, tile_group, num_tiles, tile, transpose, interpret):
     rows, c = lhs.shape
     g, k, n = w.shape
     width = k if transpose else n
-    tn = _cols(width, 512)
+    name = "bps_gmm_dx" if transpose else "bps_gmm"
+    (tn,), vmem = _blocks(name, k, n, tile, lhs.dtype.itemsize)
 
     def last(t, num):           # a step past the rows stays on the last tile
         return jnp.minimum(t, num[0] - 1)
@@ -142,9 +250,10 @@ def _gmm(lhs, w, tile_group, num_tiles, tile, transpose, interpret):
             out_specs=pl.BlockSpec(
                 (tile, tn), lambda j, t, grp, num: (last(t, num), j))),
         out_shape=jax.ShapeDtypeStruct((rows, width), lhs.dtype),
-        compiler_params=_GMM_SEMANTICS,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=_GMM_DIMENSIONS, vmem_limit_bytes=vmem),
         interpret=interpret,
-        name="bps_gmm_dx" if transpose else "bps_gmm",
+        name=name,
     )(tile_group, num_tiles, lhs, w)
 
 
@@ -179,7 +288,7 @@ def _gmm_dw(lhs, dout, tile_group, num_tiles, groups, tile, interpret):
     zero rows), so every block of the result is written."""
     rows, k = lhs.shape
     n = dout.shape[1]
-    tk, tn = _cols(k, 512), _cols(n, 1024)
+    (tk, tn), vmem = _blocks("bps_gmm_dw", k, n, tile, lhs.dtype.itemsize)
 
     def last(t, num):
         return jnp.minimum(t, num[0] - 1)
@@ -199,7 +308,8 @@ def _gmm_dw(lhs, dout, tile_group, num_tiles, groups, tile, interpret):
                 lambda i, j, t, grp, num: (grp[last(t, num)], i, j)),
             scratch_shapes=[pltpu.VMEM((tk, tn), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((groups, k, n), lhs.dtype),
-        compiler_params=_DW_SEMANTICS,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=_DW_DIMENSIONS, vmem_limit_bytes=vmem),
         interpret=interpret,
         name="bps_gmm_dw",
     )(tile_group, num_tiles, lhs, dout)
@@ -287,7 +397,8 @@ def embed_dw(ids, dout, vocab, scale, out_dtype, interpret):
                 (block, tn), lambda j, t, grp, row, num: (grp[t], j)),
             scratch_shapes=[pltpu.VMEM((block, tn), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((vocab, n), out_dtype),
-        compiler_params=_GMM_SEMANTICS,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=_GMM_DIMENSIONS),
         interpret=interpret,
         name="bps_embed_dw",
     )(tile_group, tile_row, num_tiles, ids.reshape(tiles, 1, tile), dout)

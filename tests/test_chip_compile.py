@@ -248,29 +248,41 @@ def test_the_embeddings_backward_compiles_for_v5e(compile_for_chip, tokens,
     assert f"f32[{vocab},{hidden}]" in text
 
 
-@pytest.mark.parametrize("k,n", [(2688, 1856), (1856, 2688)],
-                         ids=["up", "down"])
+@pytest.mark.parametrize("k,n", [(2688, 1856), (1856, 2688), (2048, 2048),
+                                 (1024, 2048), (2048, 1536), (768, 2048)],
+                         ids=["up", "down", "trinity_up", "trinity_down",
+                              "kanana_up", "kanana_down"])
 def test_grouped_products_off_the_lane_tile_compile_for_v5e(compile_for_chip,
                                                             k, n):
-    """``bps_gmm``, ``bps_gmm_dx`` and ``bps_gmm_dw`` at Nemotron 3 Nano's
-    expert width of 1,856 (14.5 lane tiles) against a hidden size of 2,688
-    (21): blocks of 384 or 640 columns whose last hangs over the edge, and
-    a contraction over a width that ends in half a tile."""
+    """``bps_gmm``, ``bps_gmm_dx`` and ``bps_gmm_dw`` at the three routed
+    cells' expert weights, Nemotron 3 Nano's first: a width of 1,856 (14.5
+    lane tiles) against a hidden size of 2,688 (21), each ONE block a
+    width since PR 53 (a block equal to the array's dimension hangs over
+    nothing), and a contraction over a width that ends in half a tile.
+    The compiler must find room for a whole weight twice: each call asks
+    for what ``_blocks`` counted, and Mosaic's own count is under it."""
     from byteps_tpu.ops import grouped_matmul as gm
 
     held, tile, tiles = 8, 512, 200
     assert gm.supported((tiles * tile, k), (held, k, n), tile)
 
     def grads(lhs, w, group, num):
-        return jax.grad(lambda lhs, w: gm.grouped_matmul(
-            lhs, w, group, num, None, tile, "gmm").astype(
-                jnp.float32).sum(), (0, 1))(lhs, w)
+        out, pull = jax.vjp(lambda lhs, w: gm.grouped_matmul(
+            lhs, w, group, num, None, tile, "gmm"), lhs, w)
+        return out, pull(out)
 
     text = compile_for_chip(
         grads, ((tiles * tile, k), jnp.bfloat16), ((held, k, n), jnp.bfloat16),
         ((tiles,), jnp.int32), ((1,), jnp.int32))
-    for kernel in ("bps_gmm_dx", "bps_gmm_dw"):
-        assert kernel in text
+    size = r'\[\{"memory_space":"1","offset":"0","size":"(\d+)"\}\]'
+    for kernel in gm.KERNELS:
+        (line,) = [ln for ln in text.splitlines()
+                   if "tpu_custom_call" in ln
+                   and f'/{kernel}/pallas_call"' in ln]
+        asked, = re.findall('"scoped_memory_configs":' + size, line)
+        used, = re.findall('"used_scoped_memory_configs":' + size, line)
+        assert int(asked) == gm._blocks(kernel, k, n, tile, 2)[1]
+        assert int(used) <= int(asked) <= gm._VMEM_BUDGET
 
 
 @pytest.mark.parametrize("act,tiles,m", [("gated_silu", 272, 1024),

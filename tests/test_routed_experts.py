@@ -272,6 +272,27 @@ def test_the_shares_of_the_experts_add_up_to_the_uncut_layer(balanced):
                                rtol=1e-4, atol=2e-6)
 
 
+def _ragged_setup(k, n, tile=8, rows_in_groups=(8, 20, 0), spare=2, seed=7,
+                  whole_numbers=False):
+    """Rows sorted by group on whole tiles, ``spare`` tiles behind them."""
+    padded = np.maximum(-(-np.asarray(rows_in_groups) // tile), 1) * tile
+    used, tiles = int(padded.sum()), int(padded.sum()) // tile + spare
+    rng = np.random.RandomState(seed)
+    draw = ((lambda *s: rng.randint(-3, 4, s).astype(np.float32))
+            if whole_numbers else (lambda *s: rng.randn(*s) * 0.25))
+    lhs = np.full((tiles * tile, k), np.nan, np.float32)
+    lhs[:used] = draw(used, k)
+    dout = np.full((tiles * tile, n), np.nan, np.float32)
+    dout[:used] = draw(used, n)
+    w = jnp.asarray(draw(len(padded), k, n), jnp.float32)
+    group = np.repeat(np.arange(len(padded)), padded // tile)
+    group = np.concatenate([group, np.full(tiles - len(group), group[-1])])
+    return (jnp.asarray(lhs), jnp.asarray(dout), w,
+            jnp.asarray(group, jnp.int32),
+            jnp.asarray([used // tile], jnp.int32),
+            jnp.asarray(padded, jnp.int32), used)
+
+
 @pytest.mark.parametrize("rows_in_groups", [(8, 24, 0, 16), (0, 0, 0, 8)],
                          ids=str)
 def test_grouped_products_skip_what_lies_behind_the_rows(rows_in_groups):
@@ -279,17 +300,8 @@ def test_grouped_products_skip_what_lies_behind_the_rows(rows_in_groups):
     on a buffer longer than its rows: tiles past ``num_tiles`` are not
     read (NaNs there do no harm) and not written."""
     tile, k, n = 8, 128, 256
-    padded = np.maximum(-(-np.asarray(rows_in_groups) // tile), 1) * tile
-    used, tiles = int(padded.sum()), int(padded.sum()) // tile + 3
-    rng = np.random.RandomState(4)
-    lhs = np.full((tiles * tile, k), np.nan, np.float32)
-    lhs[:used] = rng.randn(used, k)
-    w = jnp.asarray(rng.randn(len(padded), k, n), jnp.float32)
-    group = np.repeat(np.arange(len(padded)), padded // tile)
-    group = np.concatenate([group, np.full(tiles - len(group), group[-1])])
-    args = (jnp.asarray(group, jnp.int32), jnp.asarray([used // tile],
-                                                       jnp.int32))
-    sizes = jnp.asarray(padded, jnp.int32)
+    lhs, ct, w, *args, sizes, used = _ragged_setup(
+        k, n, tile, rows_in_groups, spare=3, seed=4)
 
     def kernels(lhs, w):
         return gm.grouped_matmul(lhs, w, *args, sizes, tile, "gmm_interpret")
@@ -297,12 +309,11 @@ def test_grouped_products_skip_what_lies_behind_the_rows(rows_in_groups):
     def ragged(lhs, w):
         return gm.grouped_matmul(lhs, w, *args, sizes, tile, "ragged")
 
-    lhs = jnp.asarray(lhs)
     got = kernels(lhs, w)[:used]
     want = ragged(jnp.nan_to_num(lhs), w)[:used]
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
                                atol=1e-5)
-    ct = jnp.asarray(rng.randn(tiles * tile, n), jnp.float32)
+    ct = jnp.nan_to_num(ct)
     loss = lambda fn: lambda a, b: jnp.sum(fn(a, b)[:used] * ct[:used])  # noqa: E731
     (da, dw), (ea, ew) = (jax.grad(loss(fn), (0, 1))(jnp.nan_to_num(lhs), w)
                           for fn in (kernels, ragged))
@@ -322,6 +333,193 @@ def test_grouped_products_carry_their_names():
         jnp.zeros((256, 128)), jnp.zeros((1, 128, 128))))
     assert set(re.findall(r"name=(bps_gmm\w*)", jaxpr)) == {
         "bps_gmm", "bps_gmm_dx", "bps_gmm_dw"}
+
+
+# ---- the grouped products' blocks (PR 53): each operand across HBM once
+
+# the three routed cells' experts: the two weights [k, n] a layer (the first
+# product's, the second's), at a row tile of 512 in bf16
+CELL_WEIGHTS = {"trinity": ((2048, 2048), (1024, 2048)),
+                "nemotron": ((2688, 1856), (1856, 2688)),
+                "kanana": ((2048, 1536), (768, 2048))}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_WEIGHTS))
+@pytest.mark.parametrize("kernel", gm.KERNELS)
+def test_at_the_cells_shapes_every_operand_crosses_hbm_once(kernel, cell):
+    """The nine kernel shapes of the three routed cells: the rule takes
+    the whole width, so no operand is read twice; what a step holds by
+    count is under the limit the call asks for, and the limit is what the
+    step needs, under the budget."""
+    for k, n in CELL_WEIGHTS[cell]:
+        blocks, limit = gm._blocks(kernel, k, n, 512, 2)
+        assert blocks == ((k, n) if kernel == "bps_gmm_dw" else
+                          (n if kernel == "bps_gmm" else k,))
+        assert set(gm.operand_passes(kernel, k, n, 512, 2).values()) == {1}
+        held = gm._step_bytes(kernel, k, n, 512, 2, blocks)
+        assert held < limit <= gm._VMEM_BUDGET
+        assert limit <= max(held + (4 << 20), 16 << 20)
+
+
+@pytest.mark.parametrize("k,n", [(2688, 1856), (1856, 2688), (2048, 1536),
+                                 (768, 2048)])
+def test_whole_width_blocks_match_ragged_dot_and_its_gradients(k, n):
+    """The three kernels at ONE block a width (1856 and 1536 are no power
+    of two of lane tiles, 1856 not even whole ones: a block equal to the
+    array's dimension hangs over nothing) against ``lax.ragged_dot`` and
+    its gradients, a few rows and experts; the tiles past ``num_tiles``
+    hold NaNs in both operands and are neither read nor written."""
+    tile = 8
+    lhs, dout, w, group, num, sizes, used = _ragged_setup(k, n, tile)
+    assert gm._blocks("bps_gmm", k, n, tile, 4)[0] == (n,)
+    assert gm._blocks("bps_gmm_dx", k, n, tile, 4)[0] == (k,)
+    assert gm._blocks("bps_gmm_dw", k, n, tile, 4)[0] == (k, n)
+
+    def product(impl):
+        return lambda a, b: gm.grouped_matmul(a, b, group, num, sizes, tile,
+                                              impl)
+
+    got, pull = jax.vjp(product("gmm_interpret"), lhs, w)
+    want, pull_ragged = jax.vjp(product("ragged"), jnp.nan_to_num(lhs), w)
+    np.testing.assert_allclose(np.asarray(got[:used]),
+                               np.asarray(want[:used]), rtol=1e-4, atol=1e-3)
+    (da, dw), (ea, ew) = pull(dout), pull_ragged(jnp.nan_to_num(dout))
+    np.testing.assert_allclose(np.asarray(da[:used]), np.asarray(ea[:used]),
+                               rtol=1e-4, atol=1e-3)
+    np.testing.assert_allclose(np.asarray(dw), np.asarray(ew), rtol=1e-4,
+                               atol=1e-3)
+
+
+@pytest.mark.parametrize("kernel", gm.KERNELS)
+def test_the_narrowest_and_the_widest_block_give_the_same_bits(monkeypatch,
+                                                               kernel):
+    """A contraction is never cut and ``bps_gmm_dw`` sums the row tiles in
+    the same order whatever its column blocks: blocks of one lane tile
+    (the last hanging over the edge of 1856 = 14.5) and the whole width
+    give equal results. In whole numbers, so that the CPU's own order of
+    a float32 product's sums (it follows the block's width) is out of the
+    comparison; the MXU's is asserted on the chip (PERF.md, PR 53)."""
+    k, n, tile = 384, 1856, 8
+    lhs, dout, w, group, num, _, used = _ragged_setup(
+        k, n, tile, whole_numbers=True)
+
+    def run(blocks):
+        monkeypatch.setattr(gm, "_blocks", lambda *a: (blocks, 16 << 20))
+        if kernel == "bps_gmm_dw":
+            return gm._gmm_dw.__wrapped__(lhs, dout, group, num, 3, tile,
+                                          True)
+        rows = dout if kernel == "bps_gmm_dx" else lhs
+        return gm._gmm.__wrapped__(rows, w, group, num, tile,
+                                   kernel == "bps_gmm_dx", True)[:used]
+
+    narrow, wide = (((128, 128), (k, n)) if kernel == "bps_gmm_dw" else
+                    ((128,), (k if kernel == "bps_gmm_dx" else n,)))
+    a, b = np.asarray(run(narrow)), np.asarray(run(wide))
+    assert np.isfinite(a).all() and np.abs(a).max() > 0
+    np.testing.assert_array_equal(a, b)
+
+
+def test_a_width_that_cannot_fit_whole_is_cut_and_the_passes_say_so():
+    """An expert of 8192 x 8192 in bf16: a whole width is 128 MiB a
+    buffer. ``bps_gmm`` / ``_dx`` take the widest cut that fits the budget
+    (1024: eight passes over the rows where the default limit's 512 made
+    sixteen); ``bps_gmm_dw`` keeps ``n`` whole (the rows' transposed
+    block serves all of it) and cuts ``k``. Past every cut that fits, the
+    blocks the default limit got."""
+    for kernel in ("bps_gmm", "bps_gmm_dx"):
+        blocks, limit = gm._blocks(kernel, 8192, 8192, 512, 2)
+        assert blocks == (1024,) and limit <= gm._VMEM_BUDGET
+        assert gm.operand_passes(kernel, 8192, 8192, 512, 2) == {
+            "lhs": 8, "w": 1, "out": 1}
+    blocks, limit = gm._blocks("bps_gmm_dw", 8192, 8192, 512, 2)
+    assert blocks == (512, 8192) and limit <= gm._VMEM_BUDGET
+    assert gm.operand_passes("bps_gmm_dw", 8192, 8192, 512, 2) == {
+        "lhs": 1, "dout": 16, "out": 1}
+    assert gm._blocks("bps_gmm", 1 << 17, 4096, 512, 4)[0] == (512,)
+    # bps_gmm_dw holds no whole dimension, so a cut of it always fits a
+    # row tile of 512; under one of 8192 nothing does
+    assert gm._blocks("bps_gmm_dw", 1 << 17, 1 << 17, 512, 4)[0] == (
+        512, 4096)
+    assert gm._blocks("bps_gmm_dw", 4096, 4096, 8192, 4)[0] == (512, 1024)
+    # a width under the floor is one block whatever the budget
+    assert gm._blocks("bps_gmm", 128, 64, 8, 4)[0] == (64,)
+
+
+def test_under_a_small_budget_the_cut_blocks_still_match_ragged_dot(
+        monkeypatch):
+    """The same rule with less to spend: the blocks narrow (1856 -> 640
+    and 384, the last block over the edge) and the results do not move."""
+    k, n, tile = 1856, 768, 8
+    lhs, dout, w, group, num, sizes, used = _ragged_setup(k, n, tile)
+    monkeypatch.setattr(gm, "_VMEM_BUDGET", 6 << 20)
+    monkeypatch.setattr(gm, "_MOSAIC_ROOM", 0)
+    assert gm._blocks("bps_gmm_dx", k, n, tile, 4)[0] == (640,)
+    assert gm._blocks("bps_gmm_dw", k, n, tile, 4)[0] == (640, 768)
+    assert gm.operand_passes("bps_gmm_dx", k, n, tile, 4)["lhs"] == 3
+    want, pull = jax.vjp(lambda a, b: gm.grouped_matmul(
+        a, b, group, num, sizes, tile, "ragged"), jnp.nan_to_num(lhs), w)
+    ea, ew = pull(jnp.nan_to_num(dout))
+    got = gm._gmm.__wrapped__(lhs, w, group, num, tile, False, True)
+    da = gm._gmm.__wrapped__(dout, w, group, num, tile, True, True)
+    dw = gm._gmm_dw.__wrapped__(lhs, dout, group, num, 3, tile, True)
+    for a, b in ((got[:used], want[:used]), (da[:used], ea[:used]),
+                 (dw, ew)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
+                                   atol=1e-3)
+
+
+@pytest.mark.parametrize("tokens,vocab,hidden,cols", [
+    (16384, 25024, 2048, 1024), (16384, 16384, 2688, 896),
+    (16384, 16032, 2048, 1024), (32768, 30522, 1024, 1024),
+    (8192, 50257, 1024, 1024)],
+    ids=["trinity_mini", "nemotron3_nano", "kanana2_30b", "bert_large",
+         "gpt2_medium"])
+def test_the_embeddings_backward_keeps_its_blocks(tokens, vocab, hidden,
+                                                  cols):
+    """``bps_embed_dw`` is not the experts': its grid and blocks at the
+    configurations' shapes are PR 42's (256 rows of the vocabulary, 256
+    token rows, ``_cols(hidden, 1024)`` columns) and it asks for no VMEM
+    beyond Mosaic's default."""
+    jaxpr = jax.make_jaxpr(lambda ids, d: gm.embed_dw(
+        ids, d, vocab, None, jnp.float32, False))(
+            jax.ShapeDtypeStruct((tokens,), jnp.int32),
+            jax.ShapeDtypeStruct((tokens, hidden), jnp.bfloat16))
+    (call,) = [eqn for eqn in _equations(jaxpr.jaxpr)
+               if eqn.primitive.name == "pallas_call"]
+    assert call.params["name"] == "bps_embed_dw"
+    mapping = call.params["grid_mapping"]
+    assert mapping.grid == (hidden // cols, tokens // 256 + -(-vocab // 256))
+    assert [tuple(getattr(d, "block_size", d) for d in bm.block_shape)
+            for bm in mapping.block_mappings] == [
+                (1, 1, 256), (256, cols), (256, cols)]
+    params = call.params["compiler_params"]["mosaic_tpu"]
+    assert params.vmem_limit_bytes is None
+    assert params.dimension_semantics == ("arbitrary", "arbitrary")
+
+
+def test_the_lowered_grouped_products_ask_for_their_vmem():
+    """Lowered for the TPU, each ``bps_gmm*`` custom call carries the
+    scoped VMEM ``_blocks`` counted for it (Mosaic's default of 16 MiB
+    holds no whole weight of a cell)."""
+    import re
+    k, n, tile, tiles, held = 2688, 1856, 512, 4, 2
+    shapes = [jax.ShapeDtypeStruct(s, d) for s, d in (
+        ((tiles * tile, k), jnp.bfloat16), ((held, k, n), jnp.bfloat16),
+        ((tiles,), jnp.int32), ((1,), jnp.int32))]
+
+    def grads(lhs, w, group, num):
+        out, pull = jax.vjp(lambda a, b: gm.grouped_matmul(
+            a, b, group, num, None, tile, "gmm"), lhs, w)
+        return out, pull(out)
+
+    text = jax.jit(grads).trace(*shapes).lower(
+        lowering_platforms=("tpu",)).as_text()
+    asked = {name: int(size) for size, name in re.findall(
+        r'scoped_memory_configs[^\n]*?size\\22: (\d+)[^\n]*?'
+        r'kernel_name = "(bps_gmm\w*)"', text)}
+    assert asked == {kernel: gm._blocks(kernel, k, n, tile, 2)[1]
+                     for kernel in gm.KERNELS}
+    assert min(asked.values()) > 16 << 20
 
 
 # ---- the experts' function (ops/routed_act.py): bps_moe_act_fwd, _bwd
