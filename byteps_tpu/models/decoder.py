@@ -1,6 +1,7 @@
-"""A causal decoder whose layers are a LIST OF KINDS, and the three
+"""A causal decoder whose layers are a LIST OF KINDS, and the four
 families built on it: ``afmoe`` (arcee-ai Trinity), ``nemotron_h``
-(NVIDIA Nemotron 3) and ``deepseek_v3`` (latent attention: Kanana-2).
+(NVIDIA Nemotron 3), ``deepseek_v3`` (latent attention: Kanana-2) and
+``qwen3_next`` (gated delta-rule linear attention: Qwen3-Next).
 
 ``transformer.py`` is one kind of block under one ``lax.scan``. Here each
 layer is data, and a kind says everything about its layer:
@@ -25,6 +26,18 @@ layer is data, and a kind says everything about its layer:
   positions on every layer, no gate, no norm on q or k a head); the
   feed-forward the dense gated-SiLU MLP or the routed layer, the same
   code as afmoe's without its norm after.
+
+* ``gdn_moe``, ``gattn_moe`` (qwen3_next): two halves with a norm before
+  each and none after, every norm ZERO-CENTRED (``cfg.zero_centred``:
+  ``x * rsqrt(mean x^2 + eps) * (1 + w)``, the leaf ``w`` starting at
+  zero, so that weight decay pulls the scale toward one). ``gdn_moe``:
+  the Gated DeltaNet mixer of ``gated_delta_net.py`` (``cfg.gdn``: a
+  causal convolution without a bias, the gated delta rule in chunks, a
+  head norm with the gate after it); ``gattn_moe``: afmoe's gated
+  attention over grouped kv heads without a window and without its norm
+  after, rotary positions on the first ``cfg.rotary_dim`` lanes of a head
+  on EVERY such layer. The second half of both is the routed layer with
+  softmax scores and a sigmoid gate a token on the shared expert's output.
 
 The embedding is scaled by sqrt(hidden) where ``scale_embedding`` (afmoe)
 and the head is untied.
@@ -57,7 +70,18 @@ kept value is copied nowhere):
   recompute runs everything that makes that input: the attention's output
   projection, the routed layer's second grouped product, combine and
   shared expert, the dense feed-forward's second product. A family
-  without such a norm names nothing and keeps nothing.
+  without such a norm names nothing and keeps nothing;
+* the gated delta rule's in-chunk inverse (``gated_delta.INVERSE_NAME``:
+  ``T = (I + A)^-1``, [b, chunks, heads, c, c] in the compute dtype, 134 MB
+  a ``gdn_moe`` layer at 2 x 8192 x 32 heads in chunks of 128), which is
+  also all that the inverse's backward reads: the series and the merges
+  (36 passes of the MXU a matrix) run once a layer and step. Of the rule
+  NOTHING else: the layer's backward remakes the other operands of the
+  chunks (``U``, ``W``, the masked ``q k^T``, 0.7 GB a layer) and runs the
+  pass across the chunks again, which then writes the state before each
+  chunk, [b, chunks, heads, 128, 128] float32, 268 MB, held for as long as
+  that one layer's backward runs. Keeping those from the forward would
+  hold every such layer's at once (3 GB at three layers).
 
 Everything else is recomputed in the backward, so the kernel's forward,
 the plan's top-k and sort, the weights' cast and, in afmoe, the combine
@@ -79,6 +103,9 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ..ops.flash_attention import SAVED_NAMES, attention
+from ..ops.gated_delta import INVERSE_NAME
+from .gated_delta_net import (GDNConfig, init_mixer as init_gdn,
+                              mixer as gdn_mixer)
 from .mamba2 import SSMConfig, init_mixer, mixer as ssm_mixer
 from .mla import MLAConfig, attention_half as mla_half, init_attention
 from .moe import (PLAN_NAME, WEIGHTS_NAME, RoutedConfig, gated_silu,
@@ -91,7 +118,10 @@ HALVES = ("dense_sliding", "dense_full", "moe_sliding", "moe_full")
 MIXERS = ("ssm", "attn", "moe")
 # ... or of two halves with a norm before each, the attention latent
 LATENT = ("mla_dense", "mla_moe")
-KINDS = HALVES + MIXERS + LATENT
+# ... or of two halves with a norm before each, the first a Gated DeltaNet
+# or gated attention with partial rotary positions, the second routed
+GATED = ("gdn_moe", "gattn_moe")
+KINDS = HALVES + MIXERS + LATENT + GATED
 
 # the name a layer's checkpoint keeps the input of a norm AFTER a half
 # under (afmoe's ``norm_post``, both halves): the half's whole output
@@ -114,6 +144,9 @@ class DecoderConfig:
     routed: Optional[RoutedConfig] = None
     ssm: Optional[SSMConfig] = None     # an ``ssm`` layer's mixer
     mla: Optional[MLAConfig] = None     # an ``mla_*`` layer's attention
+    gdn: Optional[GDNConfig] = None     # a ``gdn_moe`` layer's mixer
+    rotary_dim: int = 0           # lanes of a head RoPE rotates; 0: all
+    zero_centred: bool = False    # norms scale by 1 + w (w starts at 0)
     scale_embedding: bool = True  # the embedding times sqrt(hidden)
     max_seq: int = 1 << 17        # positions RoPE is defined for
     rope_theta: float = 10000.0
@@ -131,8 +164,8 @@ class DecoderConfig:
         if bad:
             raise ValueError(
                 f"layer kinds {bad} are none of {KINDS}: a layer of two "
-                f"halves is one of {HALVES} or of {LATENT}, a layer of one "
-                f"mixer one of {MIXERS}")
+                f"halves is one of {HALVES}, of {LATENT} or of {GATED}, a "
+                f"layer of one mixer one of {MIXERS}")
         if self.heads % self.kv_heads:
             raise ValueError(f"{self.heads} heads over {self.kv_heads} kv")
         if any("moe" in k for k in self.layer_kinds) and (
@@ -142,6 +175,11 @@ class DecoderConfig:
             raise ValueError("a state-space layer needs `ssm`")
         if set(LATENT) & set(self.layer_kinds) and self.mla is None:
             raise ValueError("a latent attention layer needs `mla`")
+        if "gdn_moe" in self.layer_kinds and self.gdn is None:
+            raise ValueError("a Gated DeltaNet layer needs `gdn`")
+        if self.rotary_dim % 2 or self.rotary_dim > self.head_dim:
+            raise ValueError(f"{self.rotary_dim} rotary lanes of a head of "
+                             f"{self.head_dim}")
 
 
 def afmoe_config(vocab_size, hidden, heads, kv_heads, head_dim, mlp_dim,
@@ -222,6 +260,51 @@ def deepseek_v3_config(vocab_size, hidden, heads, kv_lora_rank,
         **kw)
 
 
+def qwen3_next_config(vocab_size, hidden, heads, kv_heads, head_dim,
+                      moe_dim, shared_dim, layer_kinds: Sequence[str], top_k,
+                      router_outputs, held: Sequence[int], gdn_key_heads,
+                      gdn_value_heads, gdn_head_dim, conv_kernel=4, chunk=128,
+                      rotary_dim=0, route_scale=1.0, max_seq=1 << 18,
+                      rope_theta=1e7, norm_eps=1e-6, balanced=False,
+                      routed_kw=None, **kw) -> DecoderConfig:
+    """The qwen3_next family (Qwen3-Next) from its sizes, as the
+    benchmark's configuration gives them: layers of ``gdn_moe`` /
+    ``gattn_moe`` (three Gated DeltaNet layers to every gated
+    full-attention layer in the published pattern), zero-centred norms,
+    rotary positions on ``rotary_dim`` lanes of a head, softmax scores
+    over all ``router_outputs`` with the chosen ones normalised,
+    gated-SiLU experts of ``moe_dim`` and one shared expert of
+    ``shared_dim`` under a sigmoid gate a token, an embedding that is not
+    scaled. ``chunk``: positions a chunk of the delta rule. ``balanced``,
+    ``routed_kw`` and further keywords as ``afmoe_config``'s."""
+    return DecoderConfig(
+        vocab_size=vocab_size, hidden=hidden, heads=heads, kv_heads=kv_heads,
+        head_dim=head_dim, mlp_dim=0, moe_dim=moe_dim,
+        layer_kinds=tuple(layer_kinds), window=0, max_seq=max_seq,
+        rope_theta=rope_theta, norm_eps=norm_eps, scale_embedding=False,
+        rotary_dim=rotary_dim, zero_centred=True,
+        gdn=GDNConfig(gdn_key_heads, gdn_value_heads, gdn_head_dim,
+                      conv_kernel, chunk),
+        routed=RoutedConfig(router_outputs, tuple(held), top_k, route_scale,
+                            balanced=balanced, score="softmax",
+                            shared_dim=shared_dim, **(routed_kw or {})),
+        **kw)
+
+
+def qwen3_next_tiny(**kw) -> DecoderConfig:
+    """Test-sized: one published period (three Gated DeltaNet layers and a
+    gated attention layer), 2 value heads a key head, 2 query heads a kv
+    head, a quarter of a head's lanes rotated, 4 of 8 experts held."""
+    sizes = dict(vocab_size=128, hidden=64, heads=4, kv_heads=2, head_dim=16,
+                 moe_dim=24, shared_dim=24, top_k=3, router_outputs=8,
+                 held=(0, 1, 2, 3), gdn_key_heads=2, gdn_value_heads=4,
+                 gdn_head_dim=8, chunk=16, rotary_dim=4, rope_theta=1e7,
+                 routed_kw={"row_tile": 8},
+                 layer_kinds=("gdn_moe", "gdn_moe", "gdn_moe", "gattn_moe"),
+                 dtype="float32", remat=False)
+    return qwen3_next_config(**{**sizes, **kw})
+
+
 def deepseek_v3_tiny(**kw) -> DecoderConfig:
     """Test-sized: both kinds of latent layer, q·k of 16 + 8 over values
     of 16, a latent of 32, 4 of 8 experts held, two shared experts."""
@@ -264,10 +347,15 @@ def afmoe_tiny(**kw) -> DecoderConfig:
 # ----------------------------------------------------------------- params
 
 def init_params(rng, cfg: DecoderConfig):
-    """The parameter tree: N(0, 0.02) matrices, unit norm scales, fp32;
-    a state-space mixer's leaves as ``mamba2.init_mixer`` seeds them."""
+    """The parameter tree: N(0, 0.02) matrices, unit norm scales (zero
+    where ``cfg.zero_centred``: the scale is then ``1 + w``), fp32; a
+    state-space mixer's leaves as ``mamba2.init_mixer`` seeds them, a
+    Gated DeltaNet's as ``gated_delta_net.init_mixer``."""
     h, d = cfg.hidden, cfg.head_dim
     keys = iter(jax.random.split(rng, 16 * len(cfg.layer_kinds) + 2))
+
+    def unit(n):        # a norm's leaf at a scale of one
+        return jnp.zeros((n,)) if cfg.zero_centred else jnp.ones((n,))
 
     def normal(*shape):
         return jax.random.normal(next(keys), shape, jnp.float32) * 0.02
@@ -296,13 +384,29 @@ def init_params(rng, cfg: DecoderConfig):
     def routed():
         blk = {"router": normal(h, cfg.routed.num_experts),
                "experts": mlp(cfg.moe_dim, len(cfg.routed.held))}
-        if cfg.shared_experts:
-            blk["shared"] = mlp(cfg.shared_experts * cfg.moe_dim)
+        shared = cfg.routed.shared_dim or cfg.shared_experts * cfg.moe_dim
+        if shared:
+            blk["shared"] = mlp(shared)
         return blk
+
+    def gated_layer(kind):
+        if kind == "gdn_moe":
+            attn = init_gdn(next(keys), h, cfg.gdn)
+        else:
+            attn = {"q": normal(h, cfg.heads, d),
+                    "k": normal(h, cfg.kv_heads, d),
+                    "v": normal(h, cfg.kv_heads, d),
+                    "gate": normal(h, cfg.heads, d), "q_norm": unit(d),
+                    "k_norm": unit(d), "o": normal(cfg.heads, d, h)}
+        return {"attn": {"norm": unit(h), **attn},
+                "ffn": {"norm": unit(h), **routed(),
+                        "shared_gate": normal(h, 1)}}
 
     def layer(kind):
         if kind in MIXERS:
             return mixer_layer(kind)
+        if kind in GATED:
+            return gated_layer(kind)
         if kind in LATENT:
             attn = init_attention(normal, h, cfg.heads, cfg.mla)
             ffn = routed() if kind == "mla_moe" else mlp(cfg.mlp_dim)
@@ -322,7 +426,7 @@ def init_params(rng, cfg: DecoderConfig):
 
     return {"embed": normal(cfg.vocab_size, h),
             "layers": [layer(kind) for kind in cfg.layer_kinds],
-            "final_norm": jnp.ones((h,)),
+            "final_norm": unit(h),
             "head": normal(cfg.vocab_size, h)}
 
 
@@ -334,9 +438,15 @@ def rmsnorm(x, scale, eps):
     return (out * scale).astype(x.dtype)
 
 
-def rope(x, theta: float):
+def rope(x, theta: float, width: int = 0):
     """Rotary positions on [b, s, heads, d], position = row of ``s``, the
-    halves of ``d`` paired (i, i + d/2) as the published code pairs them."""
+    halves of ``d`` paired (i, i + d/2) as the published code pairs them.
+    ``width`` > 0: on the first ``width`` lanes of a head alone (their
+    halves paired, the frequencies a head of ``width`` would have), the
+    lanes after them as they are."""
+    if 0 < width < x.shape[-1]:
+        return jnp.concatenate(
+            [rope(x[..., :width], theta), x[..., width:]], -1)
     half = x.shape[-1] // 2
     freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * freq
@@ -347,21 +457,36 @@ def rope(x, theta: float):
                            -1).astype(x.dtype)
 
 
-def _attention_half(x, blk, cfg: DecoderConfig, sliding: bool):
-    dt = x.dtype
-    a = rmsnorm(x, blk["norm_in"], cfg.norm_eps)
+def _norm(x, w, cfg: "DecoderConfig"):
+    """The family's RMSNorm of ``x`` by the leaf ``w``: the scale is
+    ``1 + w`` where ``cfg.zero_centred``."""
+    return rmsnorm(x, 1.0 + w if cfg.zero_centred else w, cfg.norm_eps)
+
+
+def _gated_attention(a, blk, cfg: DecoderConfig, positions: bool,
+                     window=None):
+    """Gated attention of a normed input over grouped kv heads: RMSNorm
+    on q and k a head, rotary ``positions`` or none, a causal band or the
+    whole triangle, a sigmoid gate on the output, the output projection."""
+    dt = a.dtype
     q = jnp.einsum("bsh,hnd->bsnd", a, blk["q"].astype(dt))
     k = jnp.einsum("bsh,hnd->bsnd", a, blk["k"].astype(dt))
     v = jnp.einsum("bsh,hnd->bsnd", a, blk["v"].astype(dt))
     gate = jnp.einsum("bsh,hnd->bsnd", a, blk["gate"].astype(dt))
-    q = rmsnorm(q, blk["q_norm"], cfg.norm_eps)
-    k = rmsnorm(k, blk["k_norm"], cfg.norm_eps)
-    if sliding:         # rotary positions on the window layers only
-        q, k = rope(q, cfg.rope_theta), rope(k, cfg.rope_theta)
-    out = attention(q, k, v, causal=True,
-                    window=cfg.window if sliding else None)
-    out = jnp.einsum("bsnd,ndh->bsh", out * jax.nn.sigmoid(gate),
-                     blk["o"].astype(dt))
+    q = _norm(q, blk["q_norm"], cfg)
+    k = _norm(k, blk["k_norm"], cfg)
+    if positions:
+        q = rope(q, cfg.rope_theta, cfg.rotary_dim)
+        k = rope(k, cfg.rope_theta, cfg.rotary_dim)
+    out = attention(q, k, v, causal=True, window=window)
+    return jnp.einsum("bsnd,ndh->bsh", out * jax.nn.sigmoid(gate),
+                      blk["o"].astype(dt))
+
+
+def _attention_half(x, blk, cfg: DecoderConfig, sliding: bool):
+    # rotary positions on the window layers only
+    out = _gated_attention(rmsnorm(x, blk["norm_in"], cfg.norm_eps), blk, cfg,
+                           sliding, cfg.window if sliding else None)
     return rmsnorm(checkpoint_name(out, POST_NORM_NAME), blk["norm_post"],
                    cfg.norm_eps)
 
@@ -394,6 +519,22 @@ def _latent_layer(x, blk, cfg: DecoderConfig, kind: str):
                         kind == "mla_moe")
 
 
+def _gated_layer(x, blk, cfg: DecoderConfig, kind: str):
+    """A qwen3_next layer: a norm before each half and none after; a
+    Gated DeltaNet or gated attention, then the routed layer."""
+    attn, ffn = blk["attn"], blk["ffn"]
+    if kind == "gdn_moe":
+        with jax.named_scope("bps.gdn"):
+            x = x + gdn_mixer(_norm(x, attn["norm"], cfg), attn, cfg.gdn,
+                              cfg.norm_eps)
+    else:
+        with jax.named_scope("bps.attn"):
+            x = x + _gated_attention(_norm(x, attn["norm"], cfg), attn, cfg,
+                                     True)
+    with jax.named_scope("bps.mlp"):
+        return x + _ffn(_norm(x, ffn["norm"], cfg), ffn, cfg, True)
+
+
 def _mixer_layer(x, blk, cfg: DecoderConfig, kind: str):
     """A layer that is one mixer: ``x + mixer(RMSNorm(x))``."""
     dt = x.dtype
@@ -418,6 +559,8 @@ def _layer(x, blk, cfg: DecoderConfig, kind: str):
         return _mixer_layer(x, blk, cfg, kind)
     if kind in LATENT:
         return _latent_layer(x, blk, cfg, kind)
+    if kind in GATED:
+        return _gated_layer(x, blk, cfg, kind)
     with jax.named_scope("bps.attn"):
         x = x + _attention_half(x, blk["attn"], cfg,
                                 kind.endswith("sliding"))
@@ -435,17 +578,18 @@ def apply(params, cfg: DecoderConfig, tokens) -> jnp.ndarray:
         x = embed_lookup(params["embed"], tokens, dt, math.sqrt(cfg.hidden)
                          if cfg.scale_embedding else None)
     # a layer's checkpoint keeps its input, the flash kernel's output and
-    # row statistics, the routed layer's plan and bf16 weights and what a
-    # norm after a half reads; the rest is recomputed
+    # row statistics, the routed layer's plan and bf16 weights, what a
+    # norm after a half reads and the delta rule's in-chunk inverse; the
+    # rest is recomputed
     policy = jax.checkpoint_policies.save_only_these_names(
-        *SAVED_NAMES, PLAN_NAME, WEIGHTS_NAME, POST_NORM_NAME)
+        *SAVED_NAMES, PLAN_NAME, WEIGHTS_NAME, POST_NORM_NAME, INVERSE_NAME)
     for kind, blk in zip(cfg.layer_kinds, params["layers"]):
         layer = functools.partial(_layer, cfg=cfg, kind=kind)
         if cfg.remat:
             layer = jax.checkpoint(layer, policy=policy)
         x = layer(x, blk)
     with jax.named_scope("bps.head"):    # the final norm feeds the head
-        return rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        return _norm(x, params["final_norm"], cfg)
 
 
 def causal_lm_loss(params, cfg: DecoderConfig, batch) -> jnp.ndarray:

@@ -92,12 +92,13 @@ def init_mixer(key, hidden: int, cfg: SSMConfig, std: float = 0.02):
     }
 
 
-def causal_conv(x, w, bias):
+def causal_conv(x, w, bias=None):
     """Depthwise: ``out_t = bias + sum_k w[k] x_{t - (taps - 1) + k}`` over
-    [b, s, channels], zeros before the first position; float32 sums."""
+    [b, s, channels], zeros before the first position; float32 sums.
+    ``bias`` None: a convolution without one."""
     taps, s = w.shape[0], x.shape[1]
     padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
-    out = bias.astype(jnp.float32)
+    out = 0.0 if bias is None else bias.astype(jnp.float32)
     for k in range(taps):
         out = out + w[k].astype(jnp.float32) * padded[:, k:k + s]
     return out
@@ -110,10 +111,12 @@ def group_rmsnorm(x, scale, groups: int, eps: float):
     return g.reshape(x.shape) * scale
 
 
-def conv_silu(x, w, bias):
+def conv_silu(x, w, bias=None):
     """``silu(causal_conv(x, w, bias))`` in ``x``'s dtype: on the TPU,
     for the shapes ``ops.mamba2_kernels.conv_supported`` takes, the
-    kernels ``bps_ssm_conv_fwd`` / ``_bwd``; elsewhere the XLA form."""
+    kernels ``bps_ssm_conv_fwd`` / ``_bwd``; elsewhere the XLA form. A
+    convolution without a bias (``bias`` None) hands the kernels a row of
+    zeros, a constant whose gradient nobody asks for."""
     kernels = (jax.default_backend() == "tpu"
                and K.conv_supported(x.shape, w.shape))
     note_choice("ssm_conv", "kernels" if kernels else "xla",
@@ -122,15 +125,18 @@ def conv_silu(x, w, bias):
                 f"channels in whole lane tiles, positions in blocks of "
                 f"{K.ROWS[-1]} and 2 to {K.SUB + 1} taps")
     if kernels:
-        return K.conv_silu_kernels(x, w.astype(jnp.float32),
-                                   bias.astype(jnp.float32))
+        return K.conv_silu_kernels(
+            x, w.astype(jnp.float32), jnp.zeros(w.shape[1:], jnp.float32)
+            if bias is None else bias.astype(jnp.float32))
     return jax.nn.silu(causal_conv(x, w, bias)).astype(x.dtype)
 
 
-def gated_norm(y, z, scale, groups: int, eps: float):
-    """``group_rmsnorm(y * silu(z), scale)`` in ``y``'s dtype: on the TPU,
-    for the shapes ``ops.mamba2_kernels.norm_supported`` takes, the
-    kernels ``bps_ssm_norm_fwd`` / ``_bwd``; elsewhere the XLA form."""
+def gated_norm(y, z, scale, groups: int, eps: float, gate_first=True):
+    """``group_rmsnorm(y * silu(z), scale)`` in ``y``'s dtype, or with
+    ``gate_first`` false ``group_rmsnorm(y, scale) * silu(z)`` (the norm
+    first, the gate after: a Gated DeltaNet's): on the TPU, for the shapes
+    ``ops.mamba2_kernels.norm_supported`` takes, the kernels
+    ``bps_ssm_norm_fwd`` / ``_bwd``; elsewhere the XLA form."""
     kernels = (jax.default_backend() == "tpu"
                and K.norm_supported(y.shape, groups))
     note_choice("ssm_norm", "kernels" if kernels else "xla",
@@ -140,9 +146,15 @@ def gated_norm(y, z, scale, groups: int, eps: float):
                 f"most {K.LANES_MOST}, and positions in blocks of "
                 f"{K.ROWS[-1]}")
     if kernels:
-        return K.gated_norm_kernels(y, z, scale.astype(jnp.float32), groups,
-                                    eps)
+        scale = scale.astype(jnp.float32)
+        if gate_first:
+            return K.gated_norm_kernels(y, z, scale, groups, eps)
+        return K.gated_norm_kernels(y, z, scale, groups, eps, 0, K.NORM_STRIP,
+                                    False, False)
     f32 = jnp.float32
+    if not gate_first:
+        return (group_rmsnorm(y, scale, groups, eps)
+                * jax.nn.silu(z.astype(f32))).astype(y.dtype)
     return group_rmsnorm(y.astype(f32) * jax.nn.silu(z.astype(f32)), scale,
                          groups, eps).astype(y.dtype)
 

@@ -264,6 +264,7 @@ class RoutedConfig:
     impl: str = "auto"            # ops.grouped_matmul.grouped_matmul's
     balanced: bool = False        # choose on standardised outputs (route)
     act: str = "gated_silu"       # the experts' function: one of ACTS
+    score: str = "sigmoid"        # the router's scores: one of SCORES
     shared_dim: int = 0           # the shared expert's width, where the
     # layer states one of its own (the weights' shapes say it besides)
 
@@ -278,6 +279,9 @@ class RoutedConfig:
         if self.act not in ACTS:
             raise ValueError(f"experts' function {self.act!r} is none of "
                              f"{sorted(ACTS)}")
+        if self.score not in SCORES:
+            raise ValueError(f"router's scores {self.score!r} are none of "
+                             f"{SCORES}")
 
     @property
     def rows(self):
@@ -289,6 +293,9 @@ class RoutedConfig:
 # products, and the name of the first one's weights ([h, 2 m] fused gate
 # and up under ``gated_silu``, [h, m] under ``relu2``)
 ACTS = {"gated_silu": (gated_silu, "gate_up"), "relu2": (relu2, "up")}
+# the router's scores: each output's own sigmoid, or a softmax over ALL
+# the router's outputs (held here or not)
+SCORES = ("sigmoid", "softmax")
 
 
 # the name a ``jax.checkpoint`` policy keeps the routed layer's plan
@@ -318,9 +325,9 @@ WEIGHTS_NAME = "moe_weights"
 
 
 def route(f, router_w, cfg: RoutedConfig, sequences: int = 1):
-    """(weights [T, k] fp32, experts [T, k] int32): sigmoid scores over
-    ALL the router's outputs in fp32, the k largest, their scores
-    normalised over the k and scaled.
+    """(weights [T, k] fp32, experts [T, k] int32): sigmoid scores (or,
+    ``cfg.score``, a softmax) over ALL the router's outputs in fp32, the k
+    largest, their scores normalised over the k and scaled.
 
     With ``cfg.balanced`` the k are the largest of the router's outputs
     STANDARDISED an expert over the tokens of a sequence (``f`` is
@@ -333,7 +340,8 @@ def route(f, router_w, cfg: RoutedConfig, sequences: int = 1):
     training framework), done on the batch at hand and without state."""
     logits = jnp.dot(f.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    scores = jax.nn.sigmoid(logits)
+    scores = (jax.nn.softmax(logits, -1) if cfg.score == "softmax"
+              else jax.nn.sigmoid(logits))
     ranked = jax.lax.stop_gradient(scores)
     if cfg.balanced:
         by_seq = jax.lax.stop_gradient(logits).reshape(
@@ -498,7 +506,9 @@ def routed_ffn(f, blk, cfg: RoutedConfig, sequences: int = 1):
     ``up`` [h, m]). ``blk``: ``router`` [h, num_experts]; ``experts``
     ``gate_up`` [held, h, 2 m] or ``up`` [held, h, m], ``down``
     [held, m, h]; optional ``shared``, the same MLP once at a width of its
-    own, ``gate_up`` [h, 2 ms] or ``up`` [h, ms], ``down`` [ms, h].
+    own, ``gate_up`` [h, 2 ms] or ``up`` [h, ms], ``down`` [ms, h];
+    optional ``shared_gate`` [h, 1]: the shared expert's output times
+    ``sigmoid(f shared_gate)``, a gate a token.
 
     No row is dropped, whatever the imbalance: the buffer of rows is sized
     for the worst routing. The grouped products and the movement of rows
@@ -534,7 +544,12 @@ def routed_ffn(f, blk, cfg: RoutedConfig, sequences: int = 1):
             out = _combine(y, weights, plan, tile, move)
         if "shared" in blk:
             with jax.named_scope("bps.moe.shared"):
-                out = out + act(
+                shared = act(
                     f @ blk["shared"][first].astype(dt)
                 ) @ blk["shared"]["down"].astype(dt)
+                if "shared_gate" in blk:
+                    shared = shared * jax.nn.sigmoid(jnp.dot(
+                        f, blk["shared_gate"].astype(dt),
+                        preferred_element_type=jnp.float32)).astype(dt)
+                out = out + shared
     return out

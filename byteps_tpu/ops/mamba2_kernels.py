@@ -7,7 +7,11 @@ and direction, and no float32 copy of an activation is made.
 ``conv_silu_kernels``: the depthwise causal convolution (``taps`` taps,
 bias) and SiLU, ``bps_ssm_conv_fwd`` / ``bps_ssm_conv_bwd``.
 ``gated_norm_kernels``: ``y * silu(z)`` and the RMSNorm over each group of
-channels, ``bps_ssm_norm_fwd`` / ``bps_ssm_norm_bwd``. Each a
+channels, ``bps_ssm_norm_fwd`` / ``bps_ssm_norm_bwd``; with ``gate_first``
+false the norm of ``y`` alone comes FIRST and the gate multiplies after it
+(a Gated DeltaNet's, ``models/gated_delta_net.py``: a group is then a head
+of 128 lanes), the same two kernels with the two lines in the other
+order. Each a
 ``jax.custom_vjp`` over ONE ``pl.pallas_call`` a direction; what a
 backward needs of the forward (the pre-activation, SiLU's derivative, a
 row's statistic) it remakes in VMEM from the operands, nothing is saved.
@@ -301,31 +305,37 @@ conv_silu_kernels.defvjp(_conv_fwd, _conv_bwd)
 
 
 # -------------------------------------------------------- the gated norm
-def _gated(y_ref, z_ref, at, strip, eps):
-    """A strip's float32 ``y``, ``z``, ``sigmoid(z)``, the gated value
-    ``y z sigmoid(z)`` and ``rsqrt`` of its mean of squares over the
-    block's lanes (one group) plus ``eps``."""
+def _gated(y_ref, z_ref, at, strip, eps, gate_first=True):
+    """A strip's float32 ``y``, ``z``, ``sigmoid(z)``, the value the norm
+    takes (``y z sigmoid(z)``, or ``y`` alone where the gate comes after)
+    and ``rsqrt`` of its mean of squares over the block's lanes (one
+    group) plus ``eps``."""
     y32 = y_ref[0, pl.ds(at, strip), :].astype(_F32)
     z32 = z_ref[0, pl.ds(at, strip), :].astype(_F32)
     sig = _sigmoid(z32)
-    g = y32 * (z32 * sig)
+    g = y32 * (z32 * sig) if gate_first else y32
     inv = jax.lax.rsqrt(jnp.mean(g * g, -1, keepdims=True) + eps)
     return y32, z32, sig, g, inv
 
 
-def _norm_fwd_kernel(y_ref, z_ref, scale_ref, o_ref, *, strip, eps):
+def _norm_fwd_kernel(y_ref, z_ref, scale_ref, o_ref, *, strip, eps,
+                     gate_first):
     scale = scale_ref[...]
 
     def body(at, carry):
-        _, _, _, g, inv = _gated(y_ref, z_ref, at, strip, eps)
-        o_ref[0, pl.ds(at, strip), :] = (g * inv * scale).astype(o_ref.dtype)
+        _, z32, sig, g, inv = _gated(y_ref, z_ref, at, strip, eps,
+                                     gate_first)
+        out = g * inv * scale
+        if not gate_first:
+            out = out * (z32 * sig)
+        o_ref[0, pl.ds(at, strip), :] = out.astype(o_ref.dtype)
         return carry
 
     _strips(y_ref.shape[1], strip, body, 0)
 
 
 def _norm_bwd_kernel(y_ref, z_ref, scale_ref, do_ref, dy_ref, dz_ref,
-                     part_ref, *, strip, eps):
+                     part_ref, *, strip, eps, gate_first):
     scale = scale_ref[...]
 
     @pl.when(pl.program_id(2) == 0)
@@ -333,12 +343,21 @@ def _norm_bwd_kernel(y_ref, z_ref, scale_ref, do_ref, dy_ref, dz_ref,
         part_ref[...] = jnp.zeros_like(part_ref)
 
     def body(at, carry):
-        y32, z32, sig, g, inv = _gated(y_ref, z_ref, at, strip, eps)
+        y32, z32, sig, g, inv = _gated(y_ref, z_ref, at, strip, eps,
+                                       gate_first)
         do32 = do_ref[0, pl.ds(at, strip), :].astype(_F32)
         normed = g * inv
+        if not gate_first:      # out = normed * scale * silu(z)
+            dz_ref[0, pl.ds(at, strip), :] = (
+                do32 * normed * scale
+                * (sig * (1.0 + z32 * (1.0 - sig)))).astype(dz_ref.dtype)
+            do32 = do32 * (z32 * sig)
         part_ref[0] += _fold(do32 * normed)
         dn = do32 * scale
         dg = inv * (dn - normed * jnp.mean(dn * normed, -1, keepdims=True))
+        if not gate_first:
+            dy_ref[0, pl.ds(at, strip), :] = dg.astype(dy_ref.dtype)
+            return carry
         dy_ref[0, pl.ds(at, strip), :] = (dg * (z32 * sig)).astype(
             dy_ref.dtype)
         dz_ref[0, pl.ds(at, strip), :] = (
@@ -357,11 +376,13 @@ def _norm_specs(y, groups, rows):
 
 
 @functools.partial(jax.jit, static_argnames=("groups", "eps", "rows", "strip",
-                                              "interpret"))
-def _norm_fwd_call(y, z, scale, groups, eps, rows, strip, interpret):
+                                              "interpret", "gate_first"))
+def _norm_fwd_call(y, z, scale, groups, eps, rows, strip, interpret,
+                   gate_first=True):
     grid, block, lane_row, _ = _norm_specs(y, groups, rows)
     return pl.pallas_call(
-        functools.partial(_norm_fwd_kernel, strip=strip, eps=eps),
+        functools.partial(_norm_fwd_kernel, strip=strip, eps=eps,
+                          gate_first=gate_first),
         grid=grid, in_specs=[block, block, lane_row], out_specs=block,
         out_shape=jax.ShapeDtypeStruct(y.shape, y.dtype),
         compiler_params=_SEMANTICS, interpret=interpret,
@@ -370,12 +391,14 @@ def _norm_fwd_call(y, z, scale, groups, eps, rows, strip, interpret):
 
 
 @functools.partial(jax.jit, static_argnames=("groups", "eps", "rows", "strip",
-                                              "interpret"))
-def _norm_bwd_call(y, z, scale, do, groups, eps, rows, strip, interpret):
+                                              "interpret", "gate_first"))
+def _norm_bwd_call(y, z, scale, do, groups, eps, rows, strip, interpret,
+                   gate_first=True):
     """``d y``, ``d z`` and the float32 gradient of ``scale``."""
     grid, block, lane_row, lanes = _norm_specs(y, groups, rows)
     dy, dz, part = pl.pallas_call(
-        functools.partial(_norm_bwd_kernel, strip=strip, eps=eps),
+        functools.partial(_norm_bwd_kernel, strip=strip, eps=eps,
+                          gate_first=gate_first),
         grid=grid, in_specs=[block, block, lane_row, block],
         out_specs=[block, block, _part(SUB, lanes)],
         out_shape=[jax.ShapeDtypeStruct(y.shape, y.dtype),
@@ -387,23 +410,26 @@ def _norm_bwd_call(y, z, scale, do, groups, eps, rows, strip, interpret):
     return dy, dz, part.sum((0, 1))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def gated_norm_kernels(y, z, scale, groups, eps, rows=0, strip=NORM_STRIP,
-                       interpret=False):
+                       interpret=False, gate_first=True):
     """``rmsnorm_group(y * silu(z)) * scale`` over ``y``, ``z`` [batch, s,
     channels] in ``groups`` runs of channels, in ``y``'s dtype; ``scale``
-    [channels] float32. By the kernels whatever the platform; the shapes
+    [channels] float32; with ``gate_first`` false ``rmsnorm_group(y) *
+    scale * silu(z)``. By the kernels whatever the platform; the shapes
     are ``norm_supported``'s."""
-    return _norm_fwd_call(y, z, scale, groups, eps, rows, strip, interpret)
+    return _norm_fwd_call(y, z, scale, groups, eps, rows, strip, interpret,
+                          gate_first)
 
 
-def _norm_fwd(y, z, scale, groups, eps, rows, strip, interpret):
-    return (_norm_fwd_call(y, z, scale, groups, eps, rows, strip, interpret),
-            (y, z, scale))
+def _norm_fwd(y, z, scale, groups, eps, rows, strip, interpret, gate_first):
+    return (_norm_fwd_call(y, z, scale, groups, eps, rows, strip, interpret,
+                           gate_first), (y, z, scale))
 
 
-def _norm_bwd(groups, eps, rows, strip, interpret, res, do):
-    return _norm_bwd_call(*res, do, groups, eps, rows, strip, interpret)
+def _norm_bwd(groups, eps, rows, strip, interpret, gate_first, res, do):
+    return _norm_bwd_call(*res, do, groups, eps, rows, strip, interpret,
+                          gate_first)
 
 
 gated_norm_kernels.defvjp(_norm_fwd, _norm_bwd)

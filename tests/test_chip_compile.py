@@ -432,3 +432,86 @@ def test_the_checkpoint_keeps_the_flash_output_on_v5e(
                         r'op_name="[^"]*bps_moe_combine/pallas_call"', line)]
         assert len(combines) == 6, combines
         assert not any("rematted_computation" in c for c in combines)
+
+
+def test_flash_at_heads_of_256_compiles_for_v5e(compile_for_chip):
+    """Qwen3-Next's full-attention call: 16 query heads of 256 lanes (two
+    lane tiles a row) over 2 kv heads, 2 x 8,192, causal, at the blocks of
+    1024 x 1024 the rule gives it: the online forward and the split
+    backward."""
+    from byteps_tpu.ops.flash_attention import flash_attention, supported
+
+    shape, kv = (2, 8192, 16, 256), (2, 8192, 2, 256)
+    assert supported(shape, kv, kv)
+
+    def loss(q, k, v):
+        return (flash_attention(q, k, v, True).astype(jnp.float32) ** 2).sum()
+
+    text = compile_for_chip(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                            (shape, jnp.bfloat16), *[(kv, jnp.bfloat16)] * 2)
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and "bps_flash" in line]
+    assert len(calls) == 3
+    for kernel in ("bps_flash_fwd", "bps_flash_bwd_dq", "bps_flash_bwd_dkv"):
+        assert kernel in text
+    # k and v cross HBM once a kv head, head-major
+    assert all("bf16[2,2,8192,256]" in line for line in calls)
+
+
+def test_the_stages_beside_the_delta_rule_compile_for_v5e(compile_for_chip):
+    """``bps_ssm_conv_fwd`` / ``_bwd`` over q, k and v side by side
+    [2, 8192, 8192] (64 lane tiles in runs of 4) with a row of zeros for
+    the bias the convolution has not, and ``bps_ssm_norm_fwd`` / ``_bwd``
+    with the gate AFTER the norm over [2, 8192, 4096] in 32 heads of one
+    lane tile, bf16, at Qwen3-Next's shape; nothing float32 of an
+    activation's size leaves a kernel."""
+    from byteps_tpu.ops import mamba2_kernels as K
+
+    bsz, s, value_dim, conv_dim, heads = 2, 8192, 4096, 8192, 32
+    assert K.conv_supported((bsz, s, conv_dim), (4, conv_dim))
+    assert K.norm_supported((bsz, s, value_dim), heads)
+
+    def both(x, w, y, z, scale):
+        out, pull = jax.vjp(
+            lambda x, w: K.conv_silu_kernels(
+                x, w, jnp.zeros((conv_dim,), jnp.float32)), x, w)
+        normed, pull_norm = jax.vjp(
+            lambda *a: K.gated_norm_kernels(*a, heads, 1e-6, 0, K.NORM_STRIP,
+                                            False, False), y, z, scale)
+        return out, pull(out), normed, pull_norm(normed)
+
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    text = compile_for_chip(
+        both, ((bsz, s, conv_dim), bf16), ((4, conv_dim), f32),
+        ((bsz, s, value_dim), bf16), ((bsz, s, value_dim), bf16),
+        ((value_dim,), f32))
+    for kernel in ("bps_ssm_conv_fwd", "bps_ssm_conv_bwd", "bps_ssm_norm_fwd",
+                   "bps_ssm_norm_bwd"):
+        assert kernel in text
+    assert not re.search(rf"f32\[{bsz},{s},\d+\]", text)
+
+
+def test_the_delta_rules_kernels_compile_for_v5e(compile_for_chip):
+    """``bps_gdn_inverse`` / ``_bwd`` (a [128, 128] float32 matrix a grid
+    step), ``bps_gdn_fwd`` (with the states it saves) and ``bps_gdn_bwd``
+    at Qwen3-Next's shape: 2 x 8,192 positions, 32 value
+    heads of 128 over 16 key heads, chunks of 128, bf16; the state before
+    each chunk, float32, leaves the forward that a backward follows."""
+    from byteps_tpu.ops import gated_delta as G
+
+    bsz, s, hk, hv, d, chunk = 2, 8192, 16, 32, 128, 128
+    assert G.supported((bsz, s, hk, d), (bsz, s, hv, d), chunk)
+
+    def both(q, k, v, g, beta):
+        out, pull = jax.vjp(
+            lambda *a: G.gated_delta_kernels(*a, chunk), q, k, v, g, beta)
+        return out, pull(out)
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    text = compile_for_chip(
+        both, *[((bsz, s, hk, d), bf16)] * 2, ((bsz, s, hv, d), bf16),
+        *[((bsz, s, hv), f32)] * 2)
+    for kernel in ("bps_gdn_fwd", "bps_gdn_bwd", "bps_gdn_inverse",
+                   "bps_gdn_inverse_bwd"):
+        assert kernel in text
+    assert f"f32[{bsz},{s // chunk},{hv},{d},{d}]" in text
