@@ -74,14 +74,14 @@ kept value is copied nowhere):
 * the gated delta rule's in-chunk inverse (``gated_delta.INVERSE_NAME``:
   ``T = (I + A)^-1``, [b, chunks, heads, c, c] in the compute dtype, 134 MB
   a ``gdn_moe`` layer at 2 x 8192 x 32 heads in chunks of 128), which is
-  also all that the inverse's backward reads: the series and the merges
+  what the rule's other three kernels read: the series and the merges
   (36 passes of the MXU a matrix) run once a layer and step. Of the rule
-  NOTHING else: the layer's backward remakes the other operands of the
-  chunks (``U``, ``W``, the masked ``q k^T``, 0.7 GB a layer) and runs the
-  pass across the chunks again, which then writes the state before each
-  chunk, [b, chunks, heads, 128, 128] float32, 268 MB, held for as long as
-  that one layer's backward runs. Keeping those from the forward would
-  hold every such layer's at once (3 GB at three layers).
+  NOTHING else: the layer's backward runs ``bps_gdn_fwd`` again (the
+  chunks' other operands, ``U``, ``W``, the masked ``q k^T``, exist in
+  VMEM alone), which then writes the state before each chunk, [b, chunks,
+  heads, 128, 128] float32, 268 MB, held for as long as that one layer's
+  backward runs. Keeping those from the forward would hold every such
+  layer's at once (3 GB at three layers).
 
 Everything else is recomputed in the backward, so the kernel's forward,
 the plan's top-k and sort, the weights' cast and, in afmoe, the combine

@@ -492,11 +492,15 @@ def test_the_stages_beside_the_delta_rule_compile_for_v5e(compile_for_chip):
 
 
 def test_the_delta_rules_kernels_compile_for_v5e(compile_for_chip):
-    """``bps_gdn_inverse`` / ``_bwd`` (a [128, 128] float32 matrix a grid
-    step), ``bps_gdn_fwd`` (with the states it saves) and ``bps_gdn_bwd``
-    at Qwen3-Next's shape: 2 x 8,192 positions, 32 value
-    heads of 128 over 16 key heads, chunks of 128, bf16; the state before
-    each chunk, float32, leaves the forward that a backward follows."""
+    """``bps_gdn_inverse`` / ``_bwd`` and ``bps_gdn_fwd`` / ``_bwd`` (a grid
+    step a key head: both of its value heads' [128, 128] matrices made in
+    VMEM) at Qwen3-Next's shape: 2 x 8,192 positions, 32 value heads of 128
+    over 16 key heads, chunks of 128, bf16. The state before each chunk,
+    float32, leaves the forward that a backward follows; ``T`` and its
+    cotangent cross HBM in bf16; and NO value that XLA makes (a fusion's, a
+    convolution's, a broadcast's or a copy's result) has two chunk axes:
+    ``A``, the decays, ``k k^T``, ``q k^T``, ``U``, ``W`` and their
+    cotangents exist in VMEM alone."""
     from byteps_tpu.ops import gated_delta as G
 
     bsz, s, hk, hv, d, chunk = 2, 8192, 16, 32, 128, 128
@@ -513,5 +517,21 @@ def test_the_delta_rules_kernels_compile_for_v5e(compile_for_chip):
         *[((bsz, s, hv), f32)] * 2)
     for kernel in ("bps_gdn_fwd", "bps_gdn_bwd", "bps_gdn_inverse",
                    "bps_gdn_inverse_bwd"):
-        assert kernel in text
-    assert f"f32[{bsz},{s // chunk},{hv},{d},{d}]" in text
+        assert len(re.findall(rf"custom-call\(.*/{kernel}/pallas_call",
+                              text)) == 1, kernel
+    n = s // chunk
+    two_chunk_axes = re.compile(
+        rf"(f32|bf16)\[{bsz},{n},({hv}|{hk}|{hk},{hv // hk}),{chunk},"
+        rf"{chunk}\]")
+    made = {}
+    for line in text.splitlines():
+        found = re.match(r"\s*(?:ROOT )?%\S+ = (\S+) ([\w-]+)\(", line)
+        if found and two_chunk_axes.match(found.group(1)):
+            made.setdefault(found.group(2), set()).add(
+                found.group(1).split("{")[0])
+    # the kernels' own: T (bf16) from the inverse, the states (float32) as
+    # one of the forward's pair, dT (bf16) as one of the backward's five
+    assert made == {
+        "custom-call": {f"bf16[{bsz},{n},{hv},{chunk},{chunk}]"},
+        "get-tuple-element": {f"f32[{bsz},{n},{hv},{d},{d}]",
+                              f"bf16[{bsz},{n},{hv},{chunk},{chunk}]"}}
