@@ -12,7 +12,17 @@ open from the constructor's entry until the first ``step`` call returns
 - ``recompiles``: the same for a compile inside the trainer's ``step``
   after the record closed, with the ``step_num`` it happened in;
 - ``choices``: calls by ``(site, took)``; ``fallbacks``: calls by ``(site,
-  took, shapes)`` that took XLA's form on a TPU unasked (``note_choice``).
+  took, shapes)`` that took XLA's form on a TPU unasked (``note_choice``);
+- ``step_memory``: ``{fun_name: {args, out, alias, temp, code, peak}}``,
+  the compiler's account of the executable the first step ran, bytes a
+  device (``note_step_memory``: once, inside ``bps.setup.first_step``,
+  under the span ``bps.setup.step_memory``; JAX's cached trace, lowering
+  and executable, so no entry of ``compiles`` comes of it);
+- ``kept``: ``{bytes, by_name: {name: [values, bytes]}, by_scope:
+  {scope: bytes}}``, what the step's forward hands its backward
+  (``common/kept_values.py``): None until somebody calls
+  ``trainer.step_account()``, which walks the step's jaxpr once;
+- ``trainer``: a weak reference, for a reader that is handed no trainer.
 The listener runs when JAX compiles, ``note_choice`` when it traces:
 nothing here runs in a step.
 """
@@ -35,6 +45,12 @@ PARTS = {"/jax/core/compile/jaxpr_trace_duration": "trace_s",
 # the arms that are XLA's form of a kernel, and an ``impl`` that asks for it
 XLA_FORMS = ("xla", "ragged", "naive")
 MAX_ENTRIES = 1024                  # of ``compiles`` and of ``recompiles``
+# ``step_memory``'s parts, as ``CompiledMemoryStats`` names them
+MEMORY_PARTS = {"args": "argument_size_in_bytes",
+                "out": "output_size_in_bytes",
+                "alias": "alias_size_in_bytes",
+                "temp": "temp_size_in_bytes",
+                "code": "generated_code_size_in_bytes"}
 
 _current = None         # the open record that compiles and choices go to
 _listening = False      # the ONE listener is registered
@@ -51,7 +67,8 @@ def open_record(rec: dict = None) -> dict:
         jax.monitoring.register_event_duration_secs_listener(_on_duration)
     _current = rec or {
         "spans": [], "compiles": [], "recompiles": [], "choices": Counter(),
-        "fallbacks": Counter(), "step_funs": (), "closed": False}
+        "fallbacks": Counter(), "step_funs": (), "step_memory": {},
+        "kept": None, "trainer": None, "closed": False}
     from .global_state import GlobalState
     if GlobalState._instance is not None:
         GlobalState._instance.setup_record = _current
@@ -81,6 +98,26 @@ def span(rec: dict, name: str, **args):
             yield s
     finally:
         s["end"] = time.perf_counter()
+
+
+def note_step_memory(rec: dict, fun, *args):
+    """The compiler's account of the executable ``fun`` ran on ``args``, the
+    arguments of a dispatch that has returned: JAX hands ``trace``, ``lower``
+    and ``compile`` what that dispatch made (no second lowering; given
+    shapes in the arrays' place it would make one). ``peak`` is what the
+    program needs on a device: arguments and outputs less the donated,
+    temporaries, code. Returns the trace, whose jaxpr ``kept`` is read from
+    on demand; a backend without an analysis writes nothing."""
+    with span(rec, "bps.setup.step_memory"):
+        traced = fun.trace(*args)
+        stats = traced.lower().compile().memory_analysis()
+        if stats is not None:
+            sizes = {part: int(getattr(stats, field))
+                     for part, field in MEMORY_PARTS.items()}
+            sizes["peak"] = (sizes["args"] + sizes["out"] - sizes["alias"]
+                             + sizes["temp"] + sizes["code"])
+            rec["step_memory"][traced.fun_name] = sizes
+    return traced
 
 
 def _new_entry(name: str, tid: int, trace_s: float):

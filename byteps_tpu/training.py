@@ -19,6 +19,7 @@ from __future__ import annotations
 import functools
 import os
 import time
+import weakref
 from typing import Callable, Optional
 
 import jax
@@ -51,10 +52,16 @@ def _recorded(init):
     the constructor, and the first ``step`` call goes through an
     instance-bound wrapper that spans it (``bps.setup.first_step``),
     closes the record and removes itself: from the second call on
-    ``step`` is the class's own, and nothing of the record runs in it."""
+    ``step`` is the class's own, and nothing of the record runs in it.
+    Where that step dispatched the one jitted program (``_step_fn``; the
+    PS branches have none), the wrapper reads the compiler's account of
+    the executable it ran into the record (``step_memory``) before it
+    closes, and keeps the step's trace for ``step_account``."""
     @functools.wraps(init)
     def __init__(self, *args, **kwargs):
         rec = self._setup = _record.open_record()
+        rec["trainer"] = weakref.ref(self)
+        self._step_traced = None
         try:
             with _record.span(rec, "bps.setup.init"):
                 init(self, *args, **kwargs)
@@ -69,11 +76,25 @@ def _recorded(init):
             if rec["closed"]:           # a caller kept the bound wrapper
                 return type(self).step(self, batch)
             _record.open_record(rec)    # another trainer may have opened its
+            fn, handed = vars(self).get("_step_fn"), []
+
+            def dispatch(params, opt_state, *batch):
+                handed[:] = batch       # as the step hands it on: placed
+                return fn(params, opt_state, *batch)
+
             try:
                 with _record.span(rec, "bps.setup.first_step",
                                   step_num=self.step_count):
-                    return type(self).step(self, batch)
+                    if fn is not None:
+                        self._step_fn = dispatch
+                    loss = type(self).step(self, batch)
+                    if handed:
+                        self._step_traced = _record.note_step_memory(
+                            rec, fn, self.params, self.opt_state, *handed)
+                    return loss
             finally:
+                if fn is not None:
+                    self._step_fn = fn
                 _record.close(rec)
                 self.__dict__.pop("step", None)
 
@@ -443,6 +464,19 @@ class DistributedTrainer:
         which kernels its trace chose (``common/setup_record.py``,
         docs/timeline.md)."""
         return self._setup
+
+    def step_account(self) -> dict:
+        """``{"step_memory", "kept"}`` of the set-up record: the compiled
+        step's bytes by the compiler's categories, there since the first
+        step, and what the step's forward hands its backward by checkpoint
+        name and ``bps.*`` scope, read from the step's jaxpr on the first call
+        here and kept (``common/kept_values.py``; docs/timeline.md). Before
+        the first step, and on the PS branches, ``{}`` and None."""
+        rec, traced = self._setup, self._step_traced
+        if traced is not None:
+            from .common.kept_values import kept
+            rec["kept"], self._step_traced = kept(traced.jaxpr), None
+        return {"step_memory": rec["step_memory"], "kept": rec["kept"]}
 
     def _build_step(self, donate: bool):
         axes, mesh, loss_fn, tx = self.axes, self.mesh, self._loss_fn, self.tx
@@ -1310,6 +1344,7 @@ class ShardedTrainer:
         self.step_count = 0
 
     setup_record = DistributedTrainer.setup_record
+    step_account = DistributedTrainer.step_account
 
     def shard_batch(self, batch):
         from .data import shard_batch

@@ -16,7 +16,10 @@ from byteps_tpu.parallel.mesh import make_mesh
 from byteps_tpu.training import DistributedTrainer, ShardedTrainer
 
 SPANS = ("bps.setup.init", "bps.setup.place_params", "bps.setup.opt_init",
-         "bps.setup.build_step", "bps.setup.first_step")
+         "bps.setup.build_step", "bps.setup.first_step",
+         "bps.setup.step_memory")
+PARENTS = {"bps.setup.init": None, "bps.setup.first_step": None,
+           "bps.setup.step_memory": "bps.setup.first_step"}
 
 
 def _loss(params, batch):
@@ -65,9 +68,8 @@ def test_every_span_names_its_parent_and_lies_inside_it(name):
     spans = {s["name"]: s for s in trainer.setup_record()["spans"]}
     s = spans[name]
     assert s["end"] >= s["start"]
-    top = name in ("bps.setup.init", "bps.setup.first_step")
-    assert s["parent"] == (None if top else "bps.setup.init")
-    if not top:
+    assert s["parent"] == PARENTS.get(name, "bps.setup.init")
+    if s["parent"]:
         outer = spans[s["parent"]]
         assert outer["start"] <= s["start"] and s["end"] <= outer["end"]
     if name == "bps.setup.place_params":
@@ -160,6 +162,204 @@ def test_a_compile_nested_in_a_trace_is_marked_inside_it():
     nested = [e for e in during if not e["step"]]
     assert nested and all(e["inside"] == "step" for e in nested)
     assert "inside" not in _step_entries(trainer.setup_record())[0]
+
+
+# ------------------------------------------- the step's memory account
+
+LOWER_OR_COMPILE = [event for event, part in setup_record.PARTS.items()
+                    if part in ("lower_s", "compile_s")]
+
+
+@pytest.mark.parametrize("kind", ["distributed", "sharded"])
+def test_the_first_step_leaves_the_compilers_account_and_lowers_nothing(kind):
+    """``step_memory`` is read from the executable the step ran: between
+    the step's return and the record's close JAX traces (its cached
+    trace, 0 s) and neither lowers nor compiles."""
+    import threading
+    trainer = _trainer(kind)
+    rec, step_fn, events = trainer.setup_record(), trainer._step_fn, []
+
+    def listen(event, secs, **_):
+        events.append(
+            (event, setup_record._open_span(rec, threading.get_ident())))
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        trainer.step(_batch())
+    finally:
+        jax._src.monitoring.unregister_event_duration_listener(listen)
+    during = [e for e, open_span in events
+              if open_span == "bps.setup.step_memory"]
+    assert during == ["/jax/core/compile/jaxpr_trace_duration"]
+    assert {e for e, _ in events} >= set(LOWER_OR_COMPILE)     # the step's
+    assert len(_step_entries(rec)) == 1
+    assert trainer._step_fn is step_fn and "step" not in vars(trainer)
+    sizes = rec["step_memory"]["step"]
+    assert set(rec["step_memory"]) == {"step"} and set(sizes) == {
+        "args", "out", "alias", "temp", "code", "peak"}
+    stats = step_fn.lower(trainer.params, trainer.opt_state,
+                          trainer.shard_batch(_batch())).compile(
+                              ).memory_analysis()
+    assert sizes["peak"] == (sizes["args"] + sizes["out"] - sizes["alias"]
+                             + sizes["temp"] + sizes["code"]) > 0
+    assert (sizes["args"], sizes["temp"]) == (
+        stats.argument_size_in_bytes, stats.temp_size_in_bytes)
+
+
+def test_step_account_walks_the_jaxpr_once_and_only_when_asked(monkeypatch):
+    from byteps_tpu.common import kept_values
+    walks = []
+    real = kept_values.kept
+    monkeypatch.setattr(kept_values, "kept",
+                        lambda jaxpr: (walks.append(jaxpr), real(jaxpr))[1])
+    trainer = _trainer()
+    rec = trainer.setup_record()
+    assert trainer.step_account() == {"step_memory": {}, "kept": None}
+    trainer.step(_batch())
+    trainer.step(_batch())
+    assert not walks and rec["kept"] is None and rec["trainer"]() is trainer
+    first = trainer.step_account()
+    assert len(walks) == 1 and trainer._step_traced is None
+    assert first == trainer.step_account() and len(walks) == 1
+    assert first == {"step_memory": rec["step_memory"], "kept": rec["kept"]}
+    # the tiny step keeps what the squared error's backward reads
+    assert first["kept"]["bytes"] > 0
+    assert first["kept"]["bytes"] == sum(first["kept"]["by_scope"].values()) \
+        == sum(size for _, size in first["kept"]["by_name"].values())
+
+
+def test_a_ps_branch_trainer_reads_as_nothing(monkeypatch):
+    """The PS branches dispatch no one program: no ``_step_fn``, no span,
+    no account."""
+    import byteps_tpu as bps
+    monkeypatch.setenv("BPS_ENABLE_PS", "1")
+    bps.init(config=bps.Config.from_env())
+    try:
+        mesh = make_mesh({"data": 1}, devices=jax.devices()[:1])
+        trainer = DistributedTrainer(_loss, {"w": jnp.ones((4, 2))},
+                                     optax.sgd(0.1), mesh=mesh,
+                                     name="account-ps")
+        trainer.step(_batch())
+        rec = trainer.setup_record()
+        assert not hasattr(trainer, "_step_fn") and rec["closed"]
+        assert "bps.setup.step_memory" not in [
+            s["name"] for s in rec["spans"]]
+        assert trainer.step_account() == {"step_memory": {}, "kept": None}
+        trainer.close()
+    finally:
+        bps.shutdown()
+
+
+def _decoder_case(tiny: str):
+    from byteps_tpu.models import decoder
+    cfg = getattr(decoder, tiny)(remat=True)
+    params = decoder.init_params(jax.random.PRNGKey(0), cfg)
+    tokens = np.random.RandomState(0).randint(
+        0, cfg.vocab_size, (2, 64)).astype(np.int32)
+    return (lambda p, b: decoder.causal_lm_loss(p, cfg, b)), params, tokens, 1
+
+
+def _scanned_bert_case():
+    """Three BERT layers under ``lax.scan`` at shapes the flash kernels
+    take (traced for a TPU, never lowered): their names are kept."""
+    from byteps_tpu.models import bert, transformer
+    cfg = bert.bert_config(hidden=128, layers=3, heads=2, vocab_size=512,
+                           max_seq=128, remat=True)
+    params = transformer.init_params(jax.random.PRNGKey(0), cfg)
+    rng = np.random.RandomState(0)
+    tokens = rng.randint(0, 512, (2, 128)).astype(np.int32)
+    targets = np.where(rng.rand(2, 128) < 0.15, tokens, -1).astype(np.int32)
+    return ((lambda p, b: bert.mlm_loss(p, cfg, b)), params,
+            (tokens, targets), cfg.layers)
+
+
+KEPT_CASES = {"afmoe_tiny": lambda: _decoder_case("afmoe_tiny"),
+              "qwen3_next_tiny": lambda: _decoder_case("qwen3_next_tiny"),
+              "bert_scanned": _scanned_bert_case}
+KEPT_NAMES = {"afmoe_tiny": {"moe_plan", "moe_weights", "post_norm_in"},
+              "qwen3_next_tiny": {"moe_plan", "moe_weights"},
+              "bert_scanned": {"flash_out", "flash_lse"}}
+
+
+def _residuals(loss, params, batch):
+    """(values, bytes) of ``saved_residuals`` less the function's own
+    arguments and constants."""
+    from jax._src.ad_checkpoint import saved_residuals
+    sizes = [int(np.prod(aval.shape)) * aval.dtype.itemsize
+             for aval, why in saved_residuals(loss, params, batch)
+             if not why.startswith("from ")]
+    return len(sizes), sum(sizes)
+
+
+@pytest.mark.parametrize("case", sorted(KEPT_CASES))
+def test_kept_equals_jaxs_saved_residuals_by_total_and_by_name(
+        monkeypatch, case):
+    """The reference names nothing a kept float (JAX wraps it in a
+    ``reduce_precision``), so it is asked once a name, with the models'
+    policy keeping that name alone: what the name adds to the policy that
+    keeps none is the name's values and bytes. A scan's stacked value is
+    one of the reference's and a value a layer of the walk's."""
+    from byteps_tpu.common.kept_values import kept
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    loss, params, batch, trips = KEPT_CASES[case]()
+    got = kept(jax.jit(jax.value_and_grad(loss)).trace(params, batch).jaxpr)
+    assert got["bytes"] == _residuals(loss, params, batch)[1]
+    names = set(got["by_name"]) - {"layer_input", "unnamed"}
+    assert names == KEPT_NAMES[case]
+    only = jax.checkpoint_policies.save_only_these_names
+    reference = {}
+    for keep in [None, *sorted(names)]:
+        with monkeypatch.context() as patch:
+            patch.setattr(jax.checkpoint_policies, "save_only_these_names",
+                          lambda *all_names, keep=keep: only(
+                              *(n for n in all_names if n == keep)))
+            reference[keep] = _residuals(loss, params, batch)
+    for name in names:
+        values, size = (a - b for a, b in zip(reference[name],
+                                              reference[None]))
+        assert got["by_name"][name] == [trips * values, size] and size > 0
+    # what no name keeps: every layer's input and the custom derivatives'
+    rest = [got["by_name"][n] for n in ("layer_input", "unnamed")]
+    assert sum(size for _, size in rest) == reference[None][1]
+    layers = trips if trips > 1 else len(params["layers"])
+    assert rest[0][0] == layers
+    assert sum(got["by_scope"].values()) == got["bytes"]
+    assert all(scope == "" or scope.startswith("bps.")
+               for scope in got["by_scope"])
+
+
+@pytest.mark.parametrize("cast", [True, False], ids=["a_named_cast",
+                                                     "the_argument"])
+def test_an_operand_a_jitted_derivative_hands_on_is_counted_once(cast):
+    """``grouped_matmul``'s shape: a jitted ``custom_vjp`` whose forward
+    returns its weight among the residuals. The jit's result IS its
+    operand, one buffer: the named cast counts once, under its name, and
+    the step's own argument not at all."""
+    from jax.ad_checkpoint import checkpoint_name
+    from byteps_tpu.common.kept_values import kept
+
+    @jax.custom_vjp
+    def product(x, w):
+        return x @ w
+
+    product.defvjp(lambda x, w: (x @ w, (x, w)),
+                   lambda res, g: (g @ res[1].T, res[0].T @ g))
+
+    def layer(x, w):
+        if cast:
+            w = checkpoint_name(w.astype(jnp.bfloat16), "held")
+        return jnp.tanh(jax.jit(product)(x.astype(w.dtype), w))
+
+    policy = jax.checkpoint_policies.save_only_these_names("held")
+
+    def loss(w, x):
+        return jax.checkpoint(layer, policy=policy)(x * 2.0, w).sum()
+
+    w, x = jnp.ones((8, 4), jnp.float32), jnp.ones((2, 8), jnp.float32)
+    got = kept(jax.jit(jax.value_and_grad(loss)).trace(w, x).jaxpr)
+    assert got["by_name"] == dict(
+        {"held": [1, 8 * 4 * 2]} if cast else {},
+        layer_input=[1, 2 * 8 * 4])
 
 
 def _call_attention(shape):
@@ -314,16 +514,17 @@ def test_ten_thousand_steps_add_nothing_to_the_record():
     rec = trainer.setup_record()
     before = copy.deepcopy(rec)
     calls = []
-    real = setup_record._on_duration
-    listeners = jax._src.monitoring.get_event_duration_listeners()
-    at = listeners.index(real)
-    listeners[at] = lambda *a, **kw: (calls.append(a), real(*a, **kw))
+
+    def listen(*event, **_):        # beside the record's own listener
+        calls.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
     try:
         for _ in range(10_000):
             loss = trainer.step(batch)
         jax.block_until_ready(loss)
     finally:
-        listeners[at] = real
+        jax._src.monitoring.unregister_event_duration_listener(listen)
     assert rec == before and not calls
     assert type(trainer).step is DistributedTrainer.step
     assert "step" not in vars(trainer)
